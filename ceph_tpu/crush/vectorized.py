@@ -29,14 +29,12 @@ import jax
 # flipping the PROCESS-GLOBAL flag at import time would change numeric
 # promotion for every other jax user in an embedding process (importing
 # ceph_tpu must be side-effect free).  The x64 requirement is scoped to
-# the mapper entry points instead via the thread-local enable_x64
+# the mapper entry points instead via the thread-local jax.enable_x64
 # context (the jit caches key on it, so fused-mapper traces always see
 # x64 while the rest of the package traces unchanged).
-from jax.experimental import enable_x64 as _enable_x64
+import jax.numpy as jnp
 
-import jax.numpy as jnp  # noqa: E402
-
-from .ln import RH_LH_TBL, LL_TBL  # noqa: E402
+from .ln import RH_LH_TBL, LL_TBL
 from .types import (
     CrushMap,
     CRUSH_BUCKET_STRAW2,
@@ -55,6 +53,13 @@ from .types import (
 # import time (exactly what this module must not demand)
 S64_MIN = np.int64(-(2**63))
 CRUSH_HASH_SEED = np.uint32(1315423911)
+
+# lanes per device launch.  The retry loops carry (lanes, n_items)
+# int64 tables whose minor dimension the TPU pads to 128, so a launch
+# costs kilobytes of HBM per lane: a 2M-lane launch aborted the v5e
+# runtime outright, and every new lane count is a new multi-minute
+# compile of the emulated-int64 program (CHANGES.md, PR 21).
+MAX_LANES = 1 << 17
 
 
 def _u32(v):
@@ -493,11 +498,24 @@ class VectorCrush:
         return jnp.where(out_o == UNDEF, CRUSH_ITEM_NONE, out_o)
 
     def map_pgs(self, xs, numrep: int, osd_weights) -> np.ndarray:
-        with _enable_x64():
-            xs = jnp.asarray(xs, jnp.int32)
+        """Map every placement seed in ``xs``: (len(xs), numrep) osd
+        ids.  At most ``MAX_LANES`` lanes go into one device launch
+        (longer inputs run as equal-sized launches, the tail padded),
+        which bounds device memory and the number of compiled shapes
+        whatever size a caller hands in."""
+        fn = self.map_firstn if self.firstn else self.map_indep
+        with jax.enable_x64(True):
             w = jnp.asarray(osd_weights, jnp.int32)
-            if self.firstn:
-                # lint: disable=device-path-host-sync -- the single post-launch materialization of the bulk map
-                return np.asarray(self.map_firstn(xs, numrep, w))
-            # lint: disable=device-path-host-sync -- the single post-launch materialization of the bulk map
-            return np.asarray(self.map_indep(xs, numrep, w))
+            # lint: disable=device-path-host-sync -- host-side input marshal of the seeds, no device array involved
+            xs = np.asarray(xs).astype(np.int32)
+            n = xs.shape[0]
+            if n <= MAX_LANES:
+                parts = [xs]
+            else:
+                parts = np.concatenate(
+                    [xs, np.zeros(-n % MAX_LANES, np.int32)]
+                ).reshape(-1, MAX_LANES)
+            # lint: disable=device-path-host-sync -- one materialization per bounded launch of the bulk map
+            out = [np.asarray(fn(jnp.asarray(part), numrep, w))
+                   for part in parts]
+            return np.concatenate(out)[:n]

@@ -25,16 +25,18 @@ This module is that compiler:
   * schedules are cached PROCESS-WIDE keyed by matrix digest (the
     VectorCrush one-compile-serves-all lesson): every OSD of an
     in-process cluster shares one compile;
-  * three executors, all byte-identical by construction: ``apply_host``
-    (numpy rows -- the BitMatrixCodec data path), ``apply_bits_traced``
-    (a jax-traceable (k, N) bytes -> (r, N) bytes block shared by the
-    jitted XLA family and the MeshCodec shard_map block), and a Pallas
-    tile kernel behind the same ``_want_pallas`` gate as the dense
-    family;
+  * two executors, byte-identical by construction: ``apply_host``
+    (numpy rows -- the BitMatrixCodec data path) and
+    ``apply_bits_traced`` (a jax-traceable (k, N) bytes -> (r, N) bytes
+    block shared by the jitted XLA family and the MeshCodec shard_map
+    block).  There is no Pallas variant: on the v5e the per-tile
+    kernel ran no faster than the XLA program for k=8,m=3 and
+    exhausted scoped VMEM at k=10,m=4 (CHANGES.md, PR 21), and the
+    MXU-bearing backends default to the dense family anyway;
   * ``sched_matmul_batch_device`` is the batched kernel family itself:
     the same (B, k, L) signature, padding buckets and one-launch
     contract as the dense ``gN`` family, parity-gated on first use per
-    (matrix, shape) against the host oracle with transparent fallback;
+    (matrix, shape) against the host oracle (a miss raises);
   * ``want_scheduled`` is the per-(matrix, shape) cost model: env
     override, then the autotuned winner recorded in ``gf2_tuned.json``
     (``tools/ec_autotune.py`` sweeps dense-vs-scheduled per
@@ -303,29 +305,24 @@ _SCHEDULES: dict[str, XorSchedule] = {}
 class _Stats:
     """Process-wide scheduled-launch counters.  The per-OSD
     CodecBatcher samples deltas around every coalesced launch into its
-    ``ec_batch`` perf set (xor_sched_launches / xor_sched_fallbacks /
-    xor_terms_saved), so the dynamic counters stay live wherever the
-    scheduled engine actually served."""
+    ``ec_batch`` perf set (xor_sched_launches / xor_terms_saved), so
+    the dynamic counters stay live wherever the scheduled engine
+    actually served."""
 
-    __slots__ = ("launches", "fallbacks", "terms_saved")
+    __slots__ = ("launches", "terms_saved")
 
     def __init__(self) -> None:
         self.launches = 0
-        self.fallbacks = 0
         self.terms_saved = 0
 
-    def snapshot(self) -> tuple[int, int, int]:
+    def snapshot(self) -> tuple[int, int]:
         with _LOCK:
-            return (self.launches, self.fallbacks, self.terms_saved)
+            return (self.launches, self.terms_saved)
 
     def note_launch(self, sched: XorSchedule) -> None:
         with _LOCK:
             self.launches += 1
             self.terms_saved += sched.terms_saved
-
-    def note_fallback(self) -> None:
-        with _LOCK:
-            self.fallbacks += 1
 
 
 STATS = _Stats()
@@ -357,9 +354,8 @@ def registered(digest: str) -> XorSchedule:
 def clear_schedule_cache() -> None:
     with _LOCK:
         _SCHEDULES.clear()
-    _sched_health.clear()
-    for fn in (_compiled_sched_batch, _compiled_sched_pallas):
-        fn.cache_clear()
+    _sched_verified.clear()
+    _compiled_sched_batch.cache_clear()
 
 
 # ---------------------------------------------------------------------------
@@ -455,8 +451,8 @@ def warm_gf8_schedule(matrix: np.ndarray) -> XorSchedule | None:
 def apply_bits_traced(sched: XorSchedule, data_u8):
     """(k, N) bytes -> (n_out//8, N) bytes under trace: unpack to bit
     planes, run the schedule, pack.  The jax-traceable core shared by
-    the jitted XLA family, the MeshCodec shard_map block and the
-    Pallas tile kernel -- same plane order as the dense family (plane
+    the jitted XLA family and the MeshCodec shard_map block -- same
+    plane order as the dense family (plane
     8j+s = bit s of chunk j, matching ``bitmatrix_i8`` columns)."""
     import jax.numpy as jnp
     k = data_u8.shape[0]
@@ -516,44 +512,9 @@ def _compiled_sched_batch(digest: str, b: int, k: int, l: int):
     return jax.jit(fn)
 
 
-def _sched_pallas_kernel_body(sched: XorSchedule, k: int, tile: int):
-    def kernel(data_ref, out_ref):
-        import jax.numpy as jnp
-        data = data_ref[...].reshape(k, tile)
-        rows = apply_bits_traced(sched, data)
-        out_ref[...] = rows.reshape(out_ref.shape).astype(jnp.uint8)
-    return kernel
-
-
-@functools.lru_cache(maxsize=256)
-def _compiled_sched_pallas(digest: str, b: int, k: int, l: int,
-                           tile: int):
-    """Pallas tile path: the scheduled XOR chain fused per VMEM tile,
-    same grid walk as the dense batch kernel."""
-    import jax
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-    sched = registered(digest)
-    r = sched.n_out // 8
-    interpret = bool(os.environ.get("CEPH_TPU_PALLAS_INTERPRET"))
-    fn = pl.pallas_call(
-        _sched_pallas_kernel_body(sched, k, tile),
-        out_shape=jax.ShapeDtypeStruct((b, r, l), np.uint8),
-        grid=(b, l // tile),
-        in_specs=[
-            pl.BlockSpec((1, k, tile), lambda i, j: (i, 0, j),
-                         memory_space=pltpu.VMEM),
-        ],
-        out_specs=pl.BlockSpec((1, r, tile), lambda i, j: (i, 0, j),
-                               memory_space=pltpu.VMEM),
-        interpret=interpret,
-    )
-    return jax.jit(fn)
-
-
-# per (digest, shape) health: None=untested (parity gate runs on first
-# use), True=good, False=fall back to the dense family
-_sched_health: dict[tuple, bool] = {}
+# (digest, shape) keys whose scheduled launch passed its one-time
+# byte-parity gate vs the host oracle (shared with the MeshCodec twins)
+_sched_verified: set[tuple] = set()
 
 
 def _env_off() -> bool:
@@ -615,62 +576,16 @@ def sched_matmul_batch_device(sched: XorSchedule, matrix: np.ndarray,
                               xd, b: int, k: int, l: int):
     """Launch the scheduled kernel family for a (B, k, L) device batch
     of the (r, k) GF(2^8) coefficient ``matrix``; returns the (B, r, L)
-    device output or None (failed / parity-rejected -> the caller's
-    dense family serves).  Same padding buckets and one-launch contract
-    as the dense path; the Pallas tile kernel serves behind the same
-    ``_want_pallas`` gate."""
-    from .gf2kernels import _pick_tile, _want_pallas
+    device output.  Same padding buckets and one-launch contract as the
+    dense path.  A compile failure propagates and a first-launch parity
+    miss raises ``KernelParityError``: the cost model picked this
+    engine, so it serves or the caller hears why not."""
+    from .gf2kernels import check_batch_parity
+    out = _compiled_sched_batch(sched.digest, b, k, l)(xd)
     key = (sched.digest, b, k, l)
-    if _sched_health.get(key) is False:
-        return None
-    try:
-        fn = None
-        if _want_pallas():
-            tile = _pick_tile(l)
-            if tile:
-                fn = _compiled_sched_pallas(sched.digest, b, k, l, tile)
-        if fn is None:
-            fn = _compiled_sched_batch(sched.digest, b, k, l)
-        out = fn(xd)
-        if key not in _sched_health:
-            # one-time byte-parity gate vs the host oracle on a small
-            # slice; a silently-wrong schedule must never serve
-            from ..gf import gf_matmul
-            ncheck = min(256, l)
-            nb = min(b, 2)
-            # lint: disable=device-path-host-sync -- one-time parity gate vs the host oracle, bounded slice
-            got = np.asarray(out[:nb, :, :ncheck])
-            # lint: disable=device-path-host-sync -- one-time parity gate vs the host oracle, bounded slice
-            sample = np.asarray(xd[:nb, :, :ncheck])
-            for i in range(nb):
-                if not np.array_equal(got[i],
-                                      gf_matmul(matrix, sample[i])):
-                    _sched_health[key] = False
-                    STATS.note_fallback()
-                    return None
-            _sched_health[key] = True
-        STATS.note_launch(sched)
-        return out
-    except Exception:
-        _sched_health[key] = False
-        STATS.note_fallback()
-        return None
-
-
-def maybe_batch_scheduled(matrix: np.ndarray, xd, b: int, k: int,
-                          l: int):
-    """The gf2kernels routing hook: run the coefficient-matrix batch
-    through the scheduled family when the cost model picks it.  Returns
-    the device output or None (dense family serves)."""
-    try:
-        import jax
-        backend = jax.default_backend()
-    except Exception:
-        backend = "cpu"
-    from .gf2kernels import _want_pallas, bitmatrix_i8
-    bm = bitmatrix_i8(matrix)
-    sched = want_scheduled(bm, l, backend,
-                           have_packed=_want_pallas())
-    if sched is None:
-        return None
-    return sched_matmul_batch_device(sched, matrix, xd, b, k, l)
+    if key not in _sched_verified:
+        check_batch_parity("scheduled XOR kernel", matrix, xd, out,
+                           min(b, 2))
+        _sched_verified.add(key)
+    STATS.note_launch(sched)
+    return out

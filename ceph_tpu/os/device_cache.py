@@ -141,15 +141,12 @@ class DeviceShardCache:
     def device_view(self, coll: str, oid: str):
         """The entry's buffer as a device array, uploaded at most once
         per residency (decode launches over cached survivors re-use
-        it).  Falls back to the host buffer when jax is unavailable."""
+        it)."""
         entry = self._lru.get((coll, oid))
         if entry is None:
             return None
         if entry._dev is None:
-            try:
-                import jax
-            except ImportError:          # jax-free deployments
-                return entry.buf
+            import jax
             entry._dev = jax.device_put(entry.buf)
             PERF.inc("device_uploads")
             PERF.inc("device_upload_bytes", entry.nbytes)

@@ -511,31 +511,24 @@ class CodecBatcher:
         if mesh is not None:
             # the sharded data plane: ONE launch for the whole
             # coalesced batch, partitioned over every mesh device,
-            # fused CRCs riding the same launch when wanted.  A
-            # mesh failure degrades to the single-device ladder
-            # below instead of failing every waiter.
-            try:
-                if grp.kind == "rmw":
-                    out = mesh.rmw(grp.codec, old_batch, batch,
-                                   out_np=False)
-                elif grp.kind == "encode" and want_crc \
-                        and hasattr(grp.codec, "encode_batch_crc") \
-                        and self._fused_crc_ok():
-                    out, crcs = mesh.encode(grp.codec, batch,
-                                            with_crc=True,
-                                            out_np=False)
-                    if self.perf is not None:
-                        self.perf.inc("crc_fused_launches")
-                elif grp.kind == "encode":
-                    out = mesh.encode(grp.codec, batch, out_np=False)
-                else:
-                    out = mesh.decode(grp.codec, grp.extra, batch,
-                                      out_np=False)
-            except Exception:
-                out = crcs = None
+            # fused CRCs riding the same launch when wanted.  A mesh
+            # failure fails the batch's waiters (``_fail``): it is
+            # never retried on another engine.
+            if grp.kind == "rmw":
+                out = mesh.rmw(grp.codec, old_batch, batch,
+                               out_np=False)
+            elif grp.kind == "encode" and want_crc \
+                    and hasattr(grp.codec, "encode_batch_crc") \
+                    and self._fused_crc_ok():
+                out, crcs = mesh.encode(grp.codec, batch,
+                                        with_crc=True, out_np=False)
                 if self.perf is not None:
-                    self.perf.inc("mesh_fallbacks")
-        if out is not None:
+                    self.perf.inc("crc_fused_launches")
+            elif grp.kind == "encode":
+                out = mesh.encode(grp.codec, batch, out_np=False)
+            else:
+                out = mesh.decode(grp.codec, grp.extra, batch,
+                                  out_np=False)
             return ("plain", out, crcs, xor_stats0)
         if grp.kind == "rmw":
             # single-device delta: parity' = parity ^ encode(delta),
@@ -600,10 +593,9 @@ class CodecBatcher:
             self.perf.hist_sample("stripes_per_batch", st.total)
             if xor_stats0 is not None:
                 from ..ops.xor_schedule import STATS as XOR_STATS
-                l1, f1, t1 = XOR_STATS.snapshot()
-                l0, f0, t0 = xor_stats0
+                l1, t1 = XOR_STATS.snapshot()
+                l0, t0 = xor_stats0
                 self.perf.inc("xor_sched_launches", l1 - l0)
-                self.perf.inc("xor_sched_fallbacks", f1 - f0)
                 self.perf.inc("xor_terms_saved", t1 - t0)
 
     @staticmethod

@@ -1074,6 +1074,14 @@ class OSD:
                 pg.kick_snap_trim(pg.pool.removed_snaps)
         self._maybe_schedule_scrubs(now)
         peers = self._heartbeat_peers()
+        # only MONITORED peers keep a failure-detection clock.  The
+        # capped peer set moves with the map, and a peer outside it
+        # keeps the stamp of whenever it last happened to be heard
+        # (as old as boot); judging that stamp the moment the peer
+        # (re-)enters the set reports a live OSD dead.  Dropping the
+        # stamp makes a newcomer start its clock below.
+        for osd in self._hb_last.keys() - set(peers):
+            del self._hb_last[osd]
         await asyncio.gather(*(self._ping_one(o, now) for o in peers),
                              return_exceptions=True)
         for osd in peers:

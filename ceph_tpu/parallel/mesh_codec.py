@@ -1,9 +1,9 @@
 """MeshCodec: the multichip mesh as a live OSD codec engine.
 
-MULTICHIP_r05 proved the sharded dry runs (parallel/sharded_ec.py) do
-sharded encode, LRC local repair and delta-encoded partial-stripe RMW
-byte-exact over an 8-device mesh -- but nothing in the OSD path called
-them.  This module is the promotion: a shard_map-compiled launch
+The sharded dry runs (parallel/sharded_ec.py) do sharded encode, LRC
+local repair and delta-encoded partial-stripe RMW byte-exact over a
+device mesh -- but nothing in the OSD path calls them.  This module is
+the promotion: a shard_map-compiled launch
 family the per-OSD CodecBatcher feeds its coalesced stripe batches,
 so one launch encodes the batches of many PGs across every chip in
 the slice ("a rack of OSDs per TPU slice").
@@ -49,15 +49,11 @@ import numpy as np
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from .sharded_ec import _gf_matmul_bits, make_data_mesh
-from ..ops.gf2kernels import bitmatrix_i8, bucket_batch
-
-try:                                   # jax >= 0.5 top-level export
-    from jax import shard_map
-except ImportError:                    # 0.4.x keeps it experimental
-    from jax.experimental.shard_map import shard_map
+from ..ops.gf2kernels import bitmatrix_i8, bucket_batch, check_batch_parity
 
 # encode (B,k,L)->(B,m,L) and decode (B,k,L)->(B,r,L) donate a buffer
 # whose shape matches no output; XLA then frees it early instead of
@@ -294,44 +290,28 @@ class MeshCodec:
 
     def _apply_sched(self, matrix: np.ndarray, batch: np.ndarray,
                      with_crc: bool):
-        """The scheduled engine for this batch, or None (dense wins
-        per the cost model, or the scheduled launch failed/parity-
-        rejected and the dense path must serve)."""
+        """The scheduled engine's output for this batch, or None when
+        the cost model picks dense.  A picked schedule serves or
+        raises (``KernelParityError`` on a first-launch parity miss)."""
         from ..ops import xor_schedule as XS
         b, k, lane = batch.shape
         sched = XS.want_scheduled(bitmatrix_i8(matrix), lane,
                                   jax.default_backend())
         if sched is None:
             return None
+        fn = _compiled_apply_sched(self.mesh, sched.digest, b, k, lane,
+                                   with_crc, self.donate)
+        out = self._sched_launch(fn, self._put(batch))
         key = (sched.digest, "mesh", b, k, lane)
-        if XS._sched_health.get(key) is False:
-            return None
-        try:
-            fn = _compiled_apply_sched(self.mesh, sched.digest, b, k,
-                                       lane, with_crc, self.donate)
-            out = self._sched_launch(fn, self._put(batch))
-            if key not in XS._sched_health:
-                # one-time byte-parity gate vs the host oracle on a
-                # small slice (batch is the HOST copy: still readable)
-                from ..gf import gf_matmul
-                parity = out[0] if with_crc else out
-                ncheck = min(256, lane)
-                # lint: disable=device-path-host-sync -- one-time parity gate vs the host oracle, bounded slice
-                got = np.asarray(parity[:1, :, :ncheck])
-                if not np.array_equal(
-                        got[0], gf_matmul(matrix,
-                                          batch[0, :, :ncheck])):
-                    XS._sched_health[key] = False
-                    XS.STATS.note_fallback()
-                    return None
-                XS._sched_health[key] = True
-            self._count(b)
-            XS.STATS.note_launch(sched)
-            return out
-        except Exception:
-            XS._sched_health[key] = False
-            XS.STATS.note_fallback()
-            return None
+        if key not in XS._sched_verified:
+            # one-time byte-parity gate vs the host oracle on a small
+            # slice (batch is the HOST copy: still readable)
+            check_batch_parity("scheduled mesh launch", matrix, batch,
+                               out[0] if with_crc else out, 1)
+            XS._sched_verified.add(key)
+        self._count(b)
+        XS.STATS.note_launch(sched)
+        return out
 
     def _apply(self, matrix: np.ndarray, batch: np.ndarray,
                with_crc: bool):
@@ -478,7 +458,8 @@ class MeshCodec:
 
     def _rmw_sched(self, mat: np.ndarray, old_parity: np.ndarray,
                    delta: np.ndarray):
-        """Scheduled RMW launch, or None (dense serves)."""
+        """Scheduled RMW launch, or None when the cost model picks
+        dense; a picked schedule serves or raises."""
         from ..ops import xor_schedule as XS
         b, k, lane = delta.shape
         m = old_parity.shape[1]
@@ -486,30 +467,18 @@ class MeshCodec:
                                   jax.default_backend())
         if sched is None:
             return None
+        fn = _compiled_rmw_sched(self.mesh, sched.digest, b, m, k, lane,
+                                 self.donate)
+        out = self._sched_rmw_launch(fn, self._put(old_parity),
+                                     self._put(delta))
         key = (sched.digest, "mesh_rmw", b, k, lane)
-        if XS._sched_health.get(key) is False:
-            return None
-        try:
-            fn = _compiled_rmw_sched(self.mesh, sched.digest, b, m, k,
-                                     lane, self.donate)
-            out = self._sched_rmw_launch(fn, self._put(old_parity),
-                                         self._put(delta))
-            if key not in XS._sched_health:
-                from ..gf import gf_matmul
-                ncheck = min(256, lane)
-                # lint: disable=device-path-host-sync -- one-time parity gate vs the host oracle, bounded slice
-                got = np.asarray(out[:1, :, :ncheck])
-                want = old_parity[0, :, :ncheck] ^ gf_matmul(
-                    mat, delta[0, :, :ncheck])
-                if not np.array_equal(got[0], want):
-                    XS._sched_health[key] = False
-                    XS.STATS.note_fallback()
-                    return None
-                XS._sched_health[key] = True
-            self._count(b)
-            XS.STATS.note_launch(sched)
-            return out
-        except Exception:
-            XS._sched_health[key] = False
-            XS.STATS.note_fallback()
-            return None
+        if key not in XS._sched_verified:
+            # new = old ^ encode(delta): gate the encode(delta) term
+            # lint: disable=device-path-host-sync -- one-time parity gate vs the host oracle, bounded slice
+            enc = np.asarray(out[:1, :, :256]) ^ old_parity[:1, :, :256]
+            check_batch_parity("scheduled mesh RMW launch", mat,
+                               delta[:, :, :256], enc, 1)
+            XS._sched_verified.add(key)
+        self._count(b)
+        XS.STATS.note_launch(sched)
+        return out

@@ -24,11 +24,8 @@ import numpy as np
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
-try:                                   # jax >= 0.5 top-level export
-    from jax import shard_map
-except ImportError:                    # 0.4.x keeps it experimental
-    from jax.experimental.shard_map import shard_map
 
 from ..gf import build_decode_matrix, gen_rs_matrix
 from ..ops.gf2kernels import bitmatrix_i8

@@ -40,6 +40,8 @@ import time
 
 import numpy as np
 
+from ..common.compile_cache import enable_compile_cache
+
 
 def log(msg: str) -> None:
     print(msg, file=sys.stderr, flush=True)
@@ -98,11 +100,8 @@ def sweep(k: int, m: int, batch: int, chunk: int,
             break
         tag = f"g={g} unpack={unpack} mm={mm} pack={pack} tile={tile}"
         try:
-            import os
             fn = G._make_pallas_batch_fn_gN(
-                8 * m, k, batch, chunk, g, tile, unpack, mm, pack,
-                interpret=bool(os.environ.get(
-                    "CEPH_TPU_PALLAS_INTERPRET")))
+                8 * m, k, batch, chunk, g, tile, unpack, mm, pack)
             w = G._w_gN_device(mat.tobytes(), m, k, g, mm)
             out = fn(w, xd)
             got = np.asarray(out[:2, :, :512])
@@ -277,6 +276,7 @@ def main(argv=None) -> int:
                          "local-parity / repair / fragment-aggregate "
                          "matrices keyed by their matrix dims")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     import jax
     log(f"backend={jax.default_backend()} devices={jax.devices()}")

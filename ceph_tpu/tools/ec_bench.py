@@ -22,6 +22,7 @@ import time
 
 import numpy as np
 
+from ..common.compile_cache import enable_compile_cache
 from ..ec import registry
 
 
@@ -129,6 +130,7 @@ def main(argv=None) -> int:
                    help="stripes per device launch (TPU pipeline mode)")
     p.add_argument("--verify", action="store_true")
     args = p.parse_args(argv)
+    enable_compile_cache()
 
     profile = parse_profile(args)
     codec = registry().factory(args.plugin, profile)

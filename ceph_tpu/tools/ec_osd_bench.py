@@ -24,6 +24,8 @@ import json
 import sys
 import time
 
+from ..common.compile_cache import enable_compile_cache
+
 
 async def run_osd_path_bench(*, n_osds: int = 3, k: int = 2, m: int = 1,
                              n_objects: int = 48,
@@ -92,8 +94,8 @@ async def run_osd_path_bench(*, n_osds: int = 3, k: int = 2, m: int = 1,
 
         # roll up batch occupancy over every OSD's aggregation stage
         batches = stripes = pad = fallback = 0
-        mesh_launches = mesh_padded = mesh_fallbacks = 0
-        xor_launches = xor_fallbacks = xor_saved = 0
+        mesh_launches = mesh_padded = 0
+        xor_launches = xor_saved = 0
         n_devices = 0
         flush: dict[str, int] = {}
         for osd in osds:
@@ -104,9 +106,7 @@ async def run_osd_path_bench(*, n_osds: int = 3, k: int = 2, m: int = 1,
             fallback += dump.get("fallback_ops", 0)
             mesh_launches += dump.get("mesh_launches", 0)
             mesh_padded += dump.get("mesh_padded_stripes", 0)
-            mesh_fallbacks += dump.get("mesh_fallbacks", 0)
             xor_launches += dump.get("xor_sched_launches", 0)
-            xor_fallbacks += dump.get("xor_sched_fallbacks", 0)
             xor_saved += dump.get("xor_terms_saved", 0)
             n_devices = max(n_devices,
                             int(dump.get("mesh_devices", 0)))
@@ -125,7 +125,6 @@ async def run_osd_path_bench(*, n_osds: int = 3, k: int = 2, m: int = 1,
                     pipeline[key] = pipeline.get(key, 0) + v
         mesh_report = {
             "launches": mesh_launches,
-            "fallbacks": mesh_fallbacks,
             "launches_per_batch": round(mesh_launches / batches, 3)
             if batches else 0.0,
             "n_devices": n_devices,
@@ -145,7 +144,6 @@ async def run_osd_path_bench(*, n_osds: int = 3, k: int = 2, m: int = 1,
             "mesh": mesh_report,
             "xor_sched": {
                 "launches": xor_launches,
-                "fallbacks": xor_fallbacks,
                 "terms_saved": xor_saved,
             },
             "ec_pipeline": pipeline,
@@ -177,6 +175,7 @@ def main(argv=None) -> int:
     p.add_argument("--no-mesh", dest="mesh", action="store_false",
                    help="force the sharded data plane off")
     args = p.parse_args(argv)
+    enable_compile_cache()
     res = asyncio.run(run_osd_path_bench(
         n_osds=args.osds, k=args.k, m=args.m, n_objects=args.objects,
         obj_bytes=args.obj_kib * 1024, concurrency=args.concurrency,

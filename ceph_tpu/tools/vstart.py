@@ -7,7 +7,11 @@ the cluster survives restarts (crash-recovery via WAL).
     python -m ceph_tpu.tools.vstart --osds 3 --mon-port 6789
 
 Multi-process deployments (the qa/standalone ceph-helpers.sh shape)
-run one DAEMON per process instead:
+run one DAEMON per process instead.  An accelerator belongs to ONE
+process at a time: on a chip host, every OSD that launches device work
+lives in one process (``--role all``), or exactly one ``--role osd``
+process runs per chip -- a second process that reaches for a held chip
+fails or hangs.
 
     python -m ceph_tpu.tools.vstart --role mon --mon-port 6789 \
         --store-dir /var/lib/c1
@@ -207,6 +211,9 @@ def main(argv=None) -> int:
     if args.cephx and args.role != "all":
         p.error("--cephx applies to --role all; per-daemon roles "
                 "take --cephx-key (from `auth get-or-create`)")
+    if args.role != "mon":               # roles that launch device work
+        from ..common.compile_cache import enable_compile_cache
+        enable_compile_cache()
     runner = {"all": run_cluster, "mon": run_mon,
               "osd": run_osd}[args.role]
     try:

@@ -1,110 +1,29 @@
-"""Bench harness regressions (ADVICE round 5 / VERDICT next-round).
+"""Bench harness regressions.
 
-* the stale-fallback candidate order must follow PARSED round numbers
-  (reverse-lexicographic filenames break at r100: "r100" < "r99");
 * importing ceph_tpu must not flip process-global JAX precision
-  (jax_enable_x64 stays scoped to the fused CRUSH entry points).
+  (jax_enable_x64 stays scoped to the fused CRUSH entry points);
+* every bench mode's --smoke is a tier-1 tripwire, run against THIS
+  checkout (the tree under test, wherever it is unpacked);
+* a config that raises makes bench.py exit non-zero.
 """
 
 import importlib
+import os
 import sys
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
 def _bench():
-    sys.path.insert(0, ".")
+    sys.path.insert(0, REPO)
     import bench
     return importlib.reload(bench)
-
-
-def test_stale_candidates_sort_by_parsed_round_number(tmp_path,
-                                                      monkeypatch):
-    bench = _bench()
-    for r in (1, 2, 9, 10, 99, 100, 101):
-        (tmp_path / f"BENCH_r{r:02d}.json").write_text("{}") \
-            if r < 10 else \
-            (tmp_path / f"BENCH_r{r}.json").write_text("{}")
-    monkeypatch.setattr(bench, "_REPO", str(tmp_path))
-    cands = bench._stale_candidates()
-    rounds = [bench._bench_round_no(p) for p, key in cands
-              if key == "parsed"]
-    # newest committed round FIRST -- r101 beats r99 even though
-    # "BENCH_r101.json" < "BENCH_r99.json" lexicographically
-    assert rounds == sorted(rounds, reverse=True)
-    assert rounds[0] == 101
-    # the interim capture stays ahead of every committed round
-    assert cands[0][1] is None
-
-
-def test_stale_fallback_carries_provenance_and_warns(tmp_path,
-                                                     monkeypatch,
-                                                     capsys):
-    """The MULTICHIP_r05-is-a-copy-of-r02 trap: an artifact emitted
-    from last-known-good must carry ``stale: true`` + ``source_round``
-    (the round the bytes were REALLY captured in), print a WARNING,
-    and never chain off an already-stale capture."""
-    import json
-    bench = _bench()
-    (tmp_path / "BENCH_r02.json").write_text(json.dumps(
-        {"parsed": {"value": 15.4, "unit": "GiB/s"}}))
-    # a newer round that is itself a stale copy: must be SKIPPED, not
-    # re-laundered into fresh-looking provenance
-    (tmp_path / "BENCH_r05.json").write_text(json.dumps(
-        {"parsed": {"value": 15.4, "stale": True,
-                    "source_round": 2}}))
-    monkeypatch.setattr(bench, "_REPO", str(tmp_path))
-    monkeypatch.setattr(bench, "INTERIM",
-                        str(tmp_path / "BENCH_interim.json"))
-    assert bench._emit_stale("tunnel down (test)") is True
-    out, err = capsys.readouterr()
-    res = json.loads(out.strip().splitlines()[-1])
-    assert res["stale"] is True
-    assert res["source_round"] == 2          # NOT 5: r05 was a copy
-    assert res["stale_source"] == "BENCH_r02.json"
-    assert res["value"] == 15.4
-    assert "WARNING" in err and "COPY" in err
-
-
-def test_stale_fallback_returns_false_with_no_candidates(tmp_path,
-                                                         monkeypatch,
-                                                         capsys):
-    bench = _bench()
-    monkeypatch.setattr(bench, "_REPO", str(tmp_path))
-    monkeypatch.setattr(bench, "INTERIM",
-                        str(tmp_path / "BENCH_interim.json"))
-    assert bench._emit_stale("nothing to fall back to") is False
-    out, _ = capsys.readouterr()
-    assert out.strip() == ""                 # nothing emitted
-
-
-def test_bench_round_no_parses_and_rejects():
-    bench = _bench()
-    assert bench._bench_round_no("/x/BENCH_r07.json") == 7
-    assert bench._bench_round_no("/x/BENCH_r123.json") == 123
-    assert bench._bench_round_no("/x/BENCH_interim.json") == -1
 
 
 def test_import_does_not_flip_global_x64():
     import jax
     import ceph_tpu.crush.vectorized  # noqa: F401 -- the old offender
     assert jax.config.jax_enable_x64 is False
-
-
-def test_probe_skip_on_cpu_platform_and_env_override(monkeypatch):
-    """The ~225 s probe-retry window is skipped outright when the
-    backend is in-process (JAX_PLATFORMS=cpu) or the operator set
-    CEPH_TPU_BENCH_PROBE_WINDOW<=0 (BENCH_r05 burned the full window
-    to conclude 'stale fallback')."""
-    bench = _bench()
-    monkeypatch.setenv("JAX_PLATFORMS", "cpu")
-    assert bench._probe_skip_reason() is not None
-    monkeypatch.setenv("JAX_PLATFORMS", "tpu,cpu")
-    assert bench._probe_skip_reason() is None
-    monkeypatch.delenv("JAX_PLATFORMS", raising=False)
-    assert bench._probe_skip_reason() is None
-    monkeypatch.setenv("CEPH_TPU_BENCH_PROBE_WINDOW", "0")
-    assert bench._probe_skip_reason() is not None
-    monkeypatch.setenv("CEPH_TPU_BENCH_PROBE_WINDOW", "45")
-    assert bench._probe_skip_reason() is None
 
 
 def test_integrity_smoke_exits_zero_with_parity_and_counters():
@@ -119,7 +38,7 @@ def test_integrity_smoke_exits_zero_with_parity_and_counters():
     env = dict(os.environ, JAX_PLATFORMS="cpu")
     r = subprocess.run(
         [sys.executable, "bench.py", "--integrity", "--smoke"],
-        capture_output=True, text=True, cwd="/root/repo", env=env,
+        capture_output=True, text=True, cwd=REPO, env=env,
         timeout=240)
     assert r.returncode == 0, r.stderr[-2000:]
     res = json.loads(r.stdout.strip().splitlines()[-1])
@@ -144,7 +63,7 @@ def test_osd_path_mesh_smoke_gates_hold():
     r = subprocess.run(
         [sys.executable, "bench.py", "--osd-path", "--mesh",
          "--smoke"],
-        capture_output=True, text=True, cwd="/root/repo", env=env,
+        capture_output=True, text=True, cwd=REPO, env=env,
         timeout=240)
     assert r.returncode == 0, r.stderr[-2000:]
     res = json.loads(r.stdout.strip().splitlines()[-1])
@@ -154,22 +73,19 @@ def test_osd_path_mesh_smoke_gates_hold():
     assert gates["parity"] == "ok"
     assert gates["n_devices"] == 8
     assert gates["launches_per_batch"] == 1.0
-    assert gates["mesh_fallbacks"] == 0
     assert gates["scalar_calls_on_batched_paths"] == 0
     cluster = res["mesh"]
     assert cluster["launches"] >= 1
-    assert cluster["fallbacks"] == 0
     assert cluster["launches_per_batch"] == 1.0
     assert cluster["n_devices"] == 8
     # the XOR-schedule rows: >=30% term reduction on the Cauchy
     # k=8,m=3 headline matrix, a CPU wall-clock win on the bitmatrix
-    # host row, and zero scheduled fallbacks in the cluster drive
+    # host row
     xs = res["xor_schedule"]
     assert xs["reduction_pct"] >= 30.0
     assert xs["sched_xor_terms"] < xs["naive_xor_terms"]
     assert xs["bitmatrix_host"]["speedup"] > 1.0
     assert xs["batched_xla"]["speedup"] > 1.0
-    assert res["xor_sched"]["fallbacks"] == 0
 
 
 def test_datapath_smoke_gates_hold():
@@ -186,7 +102,7 @@ def test_datapath_smoke_gates_hold():
     env = dict(os.environ, JAX_PLATFORMS="cpu")
     r = subprocess.run(
         [sys.executable, "bench.py", "--datapath", "--smoke"],
-        capture_output=True, text=True, cwd="/root/repo", env=env,
+        capture_output=True, text=True, cwd=REPO, env=env,
         timeout=240)
     assert r.returncode == 0, r.stderr[-2000:]
     res = json.loads(r.stdout.strip().splitlines()[-1])
@@ -216,7 +132,7 @@ def test_cluster_smoke_exits_zero_with_no_failed_ops():
     env = dict(os.environ, JAX_PLATFORMS="cpu")
     r = subprocess.run(
         [sys.executable, "bench.py", "--cluster", "--smoke"],
-        capture_output=True, text=True, cwd="/root/repo", env=env,
+        capture_output=True, text=True, cwd=REPO, env=env,
         timeout=240)
     assert r.returncode == 0, r.stderr[-2000:]
     res = json.loads(r.stdout.strip().splitlines()[-1])
@@ -256,7 +172,7 @@ def test_straggler_smoke_gates_hold():
     env = dict(os.environ, JAX_PLATFORMS="cpu")
     r = subprocess.run(
         [sys.executable, "bench.py", "--straggler", "--smoke"],
-        capture_output=True, text=True, cwd="/root/repo", env=env,
+        capture_output=True, text=True, cwd=REPO, env=env,
         timeout=240)
     assert r.returncode == 0, r.stderr[-2000:]
     res = json.loads(r.stdout.strip().splitlines()[-1])
@@ -292,7 +208,7 @@ def test_recovery_smoke_gates_hold():
     env = dict(os.environ, JAX_PLATFORMS="cpu")
     r = subprocess.run(
         [sys.executable, "bench.py", "--recovery", "--smoke"],
-        capture_output=True, text=True, cwd="/root/repo", env=env,
+        capture_output=True, text=True, cwd=REPO, env=env,
         timeout=240)
     assert r.returncode == 0, r.stderr[-2000:]
     res = json.loads(r.stdout.strip().splitlines()[-1])
@@ -325,10 +241,65 @@ def test_placement_smoke_exits_zero_with_fused_parity():
     env = dict(os.environ, JAX_PLATFORMS="cpu")
     r = subprocess.run(
         [sys.executable, "bench.py", "--placement", "--smoke"],
-        capture_output=True, text=True, cwd="/root/repo", env=env,
+        capture_output=True, text=True, cwd=REPO, env=env,
         timeout=240)
     assert r.returncode == 0, r.stderr[-2000:]
     res = json.loads(r.stdout.strip().splitlines()[-1])
     assert res["metric"] == "placement_epoch_recompute_pgs_per_s"
     assert res["fused_path"] is True
     assert res["value"] > 0
+
+
+def test_bench_exits_nonzero_when_a_config_raises(monkeypatch, capsys):
+    """A failing config fails the run: the JSON line carries the error
+    and the exit code is non-zero -- it is not logged and dropped from
+    an otherwise healthy-looking result."""
+    import json
+    bench = _bench()
+    monkeypatch.setattr(sys, "argv", ["bench.py"])
+    monkeypatch.setattr(bench, "_headline", lambda rng: {
+        "encode_GiBps": 1.0, "decode_GiBps": 1.0, "batch": 8,
+        "stripe_bytes": 1 << 20})
+    monkeypatch.setattr(bench, "_marshal_4k", lambda rng: 1.0)
+    monkeypatch.setattr(bench, "_crush_batch", lambda: 1.0)
+
+    def boom(rng):
+        raise RuntimeError("cauchy config on fire")
+    monkeypatch.setattr(bench, "_cauchy_decode", boom)
+    assert bench.run() == 1
+    res = json.loads(capsys.readouterr().out.strip().splitlines()[-1])
+    assert "cauchy config on fire" in res["error"]
+    assert res["value"] == 0.0
+
+    # and the same run with no failing config exits 0
+    bench = _bench()
+    monkeypatch.setattr(bench, "_headline", lambda rng: {
+        "encode_GiBps": 1.0, "decode_GiBps": 1.0, "batch": 8,
+        "stripe_bytes": 1 << 20})
+    for name in ("_cauchy_decode", "_marshal_4k"):
+        monkeypatch.setattr(bench, name, lambda rng: 1.0)
+    monkeypatch.setattr(bench, "_crush_batch", lambda: 1.0)
+    assert bench.run() == 0
+    res = json.loads(capsys.readouterr().out.strip().splitlines()[-1])
+    assert "error" not in res and res["value"] == 1.0
+
+
+def test_crush_bench_cli_times_verified_launches():
+    """python -m ceph_tpu.tools.crush_bench (BASELINE config 5's own
+    entry point) runs end to end: exit 0, a mapping rate, and the
+    verified lanes sampled from the timed launches."""
+    import json
+    import subprocess
+
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
+    r = subprocess.run(
+        [sys.executable, "-m", "ceph_tpu.tools.crush_bench",
+         "--pgs", "20000", "--batch", "10000", "--verify", "16"],
+        capture_output=True, text=True, cwd=REPO, env=env, timeout=240)
+    assert r.returncode == 0, r.stderr[-2000:]
+    res = json.loads(r.stdout.strip().splitlines()[-1])
+    assert res["metric"] == "crush_bulk_mappings_per_s"
+    assert res["value"] > 0
+    assert res["n_mappings"] == 20000 and res["launches"] == 2
+    assert res["verified_lanes"] == 16
+    assert res["lane_exact_vs_scalar"] is True

@@ -193,3 +193,19 @@ def test_firstn_exhausted_slot_compacts_like_scalar():
         want = crush_do_rule(cm, 0, int(x), 3, weights)
         trimmed = [v for v in got[i] if v != NONE]
         assert trimmed == list(want), (i, trimmed, want)
+
+
+def test_inputs_beyond_max_lanes_run_as_bounded_launches(monkeypatch):
+    """map_pgs bounds lanes per device launch: a long input runs as
+    equal-sized launches (tail padded) and maps exactly like the
+    scalar engine, ragged tail included."""
+    import ceph_tpu.crush.vectorized as V
+
+    monkeypatch.setattr(V, "MAX_LANES", 128)
+    m = build_two_level_map(4, 3)
+    weights = [0x10000] * 12
+    vc = VectorCrush(m, 0)
+    xs = np.random.default_rng(5).integers(0, 2**31 - 1, size=300)
+    got = vc.map_pgs(xs, 3, weights)
+    assert got.shape == (300, 3)
+    assert np.array_equal(got, scalar_batch(m, 0, xs, 3, weights))

@@ -3,6 +3,7 @@
 
 import io
 import json
+import os
 import subprocess
 import sys
 
@@ -12,6 +13,8 @@ from ceph_tpu.crush import crush_do_rule
 from ceph_tpu.crush.builder import build_two_level_map
 from ceph_tpu.tools.crushtool import (
     CompileError, compile_text, decompile, run_test)
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 MAP_TEXT = """
 # minimal cluster map
@@ -136,14 +139,14 @@ def test_cli_roundtrip(tmp_path):
     r = subprocess.run(
         [sys.executable, "-m", "ceph_tpu.tools.crushtool",
          "-c", str(src), "-o", str(out)],
-        capture_output=True, text=True, cwd="/root/repo")
+        capture_output=True, text=True, cwd=REPO)
     assert r.returncode == 0, r.stderr
     assert json.loads(out.read_text())["buckets"]
     r = subprocess.run(
         [sys.executable, "-m", "ceph_tpu.tools.crushtool",
          "--test", "-i", str(out), "--rule", "0", "--num-rep", "2",
          "--min-x", "0", "--max-x", "15", "--show-utilization"],
-        capture_output=True, text=True, cwd="/root/repo")
+        capture_output=True, text=True, cwd=REPO)
     assert r.returncode == 0, r.stderr
     assert "CRUSH rule 0 x 15" in r.stdout
 

@@ -539,3 +539,42 @@ def test_laggard_replica_healed_after_dropped_subop():
         finally:
             await c.stop()
     run(main())
+
+
+def test_peer_entering_the_heartbeat_set_starts_a_fresh_clock():
+    """The capped heartbeat set moves with the map (at 12 OSDs each
+    daemon monitors 10 of its 11 peers).  A peer that was outside the
+    set keeps no failure-detection stamp: when a death elsewhere pulls
+    it in, it must not be judged on how long ago it was last heard --
+    while a MONITORED peer silent past the grace is still reported."""
+    import time
+
+    osd = OSD(host="h")                  # never started: no messenger
+    sent: list[Message] = []
+
+    async def noop(*a, **k):
+        pass
+
+    async def to_mon(msg):
+        sent.append(msg)
+
+    osd._cephx_refresh = osd._report_to_mgr = osd._ping_one = noop
+    osd._maybe_schedule_scrubs = lambda now: None
+    osd._mon_send_failover = to_mon
+    peers = [1]
+    osd._heartbeat_peers = lambda: list(peers)
+    now = time.monotonic()
+    grace = osd.config["osd_heartbeat_grace"]
+    osd._hb_last = {1: now, 2: now - 100 * grace}   # 2: heard at "boot"
+
+    run(osd._heartbeat_once())
+    peers.append(2)                      # a map change pulls osd.2 in
+    run(osd._heartbeat_once())
+    assert [m.data["target"] for m in sent
+            if m.type == "osd_failure"] == []
+    assert osd._hb_last[2] >= now        # its clock started now
+
+    osd._hb_last[2] -= 2 * grace         # monitored, then silent
+    run(osd._heartbeat_once())
+    assert [m.data["target"] for m in sent
+            if m.type == "osd_failure"] == [2]

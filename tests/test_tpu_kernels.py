@@ -40,14 +40,15 @@ def test_batch_matmul_parity():
 
 
 def test_pallas_kernel_interpret_parity():
-    """Run the actual pallas kernel in interpret mode on CPU."""
+    """Run the actual pallas kernel on CPU (the kernels derive
+    interpret mode from the cpu backend)."""
     import jax.numpy as jnp
     rng = np.random.default_rng(9)
     k, m, n, tile = 8, 3, 1024, 512
     gen = gen_rs_matrix(k + m, k)
     w = bitmatrix_i8(gen[k:])
     data = rng.integers(0, 256, size=(k, n)).astype(np.uint8)
-    fn = _make_pallas_fn(8 * m, k, n, tile, interpret=True)
+    fn = _make_pallas_fn(8 * m, k, n, tile)
     got = np.asarray(fn(jnp.asarray(w), jnp.asarray(data)))
     want = gf_matmul(gen[k:], data)
     assert np.array_equal(want, got)
@@ -115,8 +116,7 @@ def test_pallas_gN_kernel_interpret_parity():
             w = jnp.asarray(wn.astype(jnp.bfloat16) if mm == "bf16"
                             else wn)
             fn = _make_pallas_batch_fn_gN(
-                8 * mat.shape[0], k, b, l, g, 256, unpack, mm, pack,
-                interpret=True)
+                8 * mat.shape[0], k, b, l, g, 256, unpack, mm, pack)
             got = np.asarray(fn(w, jnp.asarray(data)))
             for i in range(b):
                 assert np.array_equal(got[i], gf_matmul(mat, data[i])), \
@@ -138,34 +138,65 @@ def test_pallas_gN_group4_k4():
     mat = np.ascontiguousarray(gen[k:], np.uint8)
     wn = _w_gN_planemajor(mat.tobytes(), m, k, g)
     fn = _make_pallas_batch_fn_gN(8 * m, k, b, l, g, 256, "concat",
-                                  "int8", "vpu", interpret=True)
+                                  "int8", "vpu")
     got = np.asarray(fn(jnp.asarray(wn), jnp.asarray(data)))
     for i in range(b):
         assert np.array_equal(got[i], gf_matmul(mat, data[i])), i
 
 
-def test_g2_selection_and_fallback(monkeypatch):
-    """gf_matmul_batch_device serves the packed kernel when healthy and
-    falls back transparently when the kernel errors."""
+def test_gN_selection_and_failure_raises(monkeypatch):
+    """gf_matmul_batch_device serves the packed kernel when the shape
+    selects it -- and a kernel that errors or returns wrong bytes makes
+    the codec call RAISE instead of returning another engine's
+    result."""
     import ceph_tpu.ops.gf2kernels as g
+    from ceph_tpu.ec import registry
 
-    monkeypatch.setenv("CEPH_TPU_PALLAS_INTERPRET", "1")
     monkeypatch.setattr(g, "_want_pallas", lambda: True)
     g.clear_kernel_cache()
     rng = np.random.default_rng(12)
     k, m, b, l = 8, 3, 4, 512
     gen = gen_rs_matrix(k + m, k)
     data = rng.integers(0, 256, size=(b, k, l)).astype(np.uint8)
+    assert g.batch_engine(gen[k:], b, k, l) == "gN"
     out = g.gf_matmul_batch_device(gen[k:], data, out_np=True)
     for i in range(b):
         assert np.array_equal(out[i], gf_matmul(gen[k:], data[i]))
-    assert any(v is True for v in g._g2_health.values())
+    assert g._gN_verified
 
-    # sabotage the packed compile: the fallback must still serve parity
+    codec = registry().factory("tpu", {"k": str(k), "m": str(m)})
+    real = g._compiled_batch_gN
+
+    # a compile that raises (a Mosaic refusal on the chip)
     g.clear_kernel_cache()
     monkeypatch.setattr(g, "_compiled_batch_gN",
-                        lambda *a: (_ for _ in ()).throw(RuntimeError()))
-    out = g.gf_matmul_batch_device(gen[k:], data, out_np=True)
-    for i in range(b):
-        assert np.array_equal(out[i], gf_matmul(gen[k:], data[i]))
+                        lambda *a: (_ for _ in ()).throw(
+                            RuntimeError("mosaic says no")))
+    with pytest.raises(RuntimeError, match="mosaic says no"):
+        codec.encode_batch(data, out_np=True)
+
+    # a kernel that compiles but returns wrong bytes
     g.clear_kernel_cache()
+    monkeypatch.setattr(
+        g, "_compiled_batch_gN",
+        lambda *a: (lambda w, xd, fn=real(*a): fn(w, xd) ^ 1))
+    with pytest.raises(g.KernelParityError):
+        codec.encode_batch(data, out_np=True)
+    g.clear_kernel_cache()
+
+
+def test_malformed_tuned_table_is_an_error(monkeypatch, tmp_path):
+    import ceph_tpu.ops.gf2kernels as g
+
+    bad = tmp_path / "gf2_tuned.json"
+    bad.write_text("{not json")
+    monkeypatch.setattr(g, "_TUNED_PATH", str(bad))
+    g._tuned_cfgs.cache_clear()
+    try:
+        with pytest.raises(ValueError):
+            g._g2_cfg(8)
+        monkeypatch.setattr(g, "_TUNED_PATH", str(tmp_path / "absent"))
+        g._tuned_cfgs.cache_clear()
+        assert g._g2_cfg(8) == g.G2_DEFAULT
+    finally:
+        g._tuned_cfgs.cache_clear()

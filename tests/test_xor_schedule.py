@@ -15,7 +15,7 @@ Contracts:
   naive XOR count on the headline Cauchy matrix, reduction >= 30%;
 * routing: CodecBatcher/MeshCodec ride the scheduled kernels with the
   one-launch-per-batch contract intact and the ec_batch counters
-  (xor_sched_launches/fallbacks/xor_terms_saved) live;
+  (xor_sched_launches/xor_terms_saved) live;
 * the repair path of BitMatrixCodec recovers every missing chunk from
   ONE launch and rides a schedule warmed at decode-matrix build time;
 * the autotune sweep harness runs under tier-1 (--cpu-smoke) and the
@@ -200,7 +200,7 @@ def test_gf_matmul_batch_device_routes_scheduled(monkeypatch):
     l0 = XS.STATS.snapshot()
     got = gf_matmul_batch_device(mat, data, out_np=True)
     l1 = XS.STATS.snapshot()
-    assert l1[0] == l0[0] + 1 and l1[1] == l0[1]
+    assert l1[0] == l0[0] + 1
     monkeypatch.setenv("CEPH_TPU_XOR_SCHED", "0")
     want = gf_matmul_batch_device(mat, data, out_np=True)
     assert np.array_equal(got, want)
@@ -252,7 +252,6 @@ def test_batcher_scheduled_one_launch_and_counters(monkeypatch):
     assert dump["batches"] == 3
     assert dump["mesh_launches"] == 3           # one launch per batch
     assert dump["xor_sched_launches"] == 3
-    assert dump["xor_sched_fallbacks"] == 0
     assert dump["xor_terms_saved"] > 0
 
 
@@ -344,12 +343,20 @@ def test_repair_rides_schedule_warmed_at_build(monkeypatch):
     repair()                     # cached schedule serves, no compile
     after = XS.STATS.snapshot()
     assert after[0] > before[0]
-    assert after[1] == before[1]
 
 
 # -- autotune sweep harness (tier-1 --cpu-smoke) ----------------------------
 
-def test_autotune_cpu_smoke_writes_winner(tmp_path, capsys):
+@pytest.fixture()
+def own_cache_dir(tmp_path, monkeypatch):
+    """main() places the persistent compile cache unless the operator
+    did: claim the placement, so a main() called in-process does not
+    turn the cache on for the rest of the pytest session."""
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path / "cc"))
+
+
+def test_autotune_cpu_smoke_writes_winner(tmp_path, capsys,
+                                          own_cache_dir):
     from ceph_tpu.tools import ec_autotune
     out = tmp_path / "tuned.json"
     rc = ec_autotune.main(["--k", "4", "--m", "2", "--cpu-smoke",
@@ -364,7 +371,7 @@ def test_autotune_cpu_smoke_writes_winner(tmp_path, capsys):
     assert "4,2,4096" in tuned["xor_sched"]
 
 
-def test_autotune_code_matrices_sweep(tmp_path, capsys):
+def test_autotune_code_matrices_sweep(tmp_path, capsys, own_cache_dir):
     """--codes sweeps the recovery-code matrix families (LRC
     local-parity/local-repair, PMSR parity/fragment-aggregate) into
     xor_sched entries keyed by their matrix dims -- the key the

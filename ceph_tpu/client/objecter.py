@@ -13,6 +13,7 @@ from __future__ import annotations
 import asyncio
 import itertools
 
+from ..common.tracing import get_tracer, section
 from ..mon.osdmap import OSDMap, Incremental
 from ..msg import Message, Messenger
 from ..osd.backend import pack_mutations
@@ -273,7 +274,6 @@ class Objecter:
         reqid = [f"{self.msgr.name}:{self.msgr.incarnation}",
                  next(self._reqid_serial)]
         await self._maybe_refresh_tickets()
-        from ..common.tracing import get_tracer
         span = get_tracer(self.msgr.name).start(
             "client.osd_op", oid=oid, pool=pool_id)
         try:
@@ -281,7 +281,10 @@ class Objecter:
                 span, pool_id, oid, ops, nspace, deadline, timeout,
                 attempt_timeout, ps, extra, reqid, loop)
         finally:
-            span.finish()
+            # once per finished op, whatever its outcome: what the
+            # per-op host times of a trace are divided by
+            with section("client.complete"):
+                span.finish()
 
     async def _op_attempts(self, span, pool_id, oid, ops, nspace,
                            deadline, timeout, attempt_timeout, ps,
@@ -296,19 +299,20 @@ class Objecter:
             if info is None or info.addr is None:
                 await self._pause_and_refresh()
                 continue
-            tid = next(self._tid)
-            fut = loop.create_future()
-            self._waiters[tid] = fut
-            meta, segs = pack_mutations(ops)
+            with section("client.build"):
+                tid = next(self._tid)
+                fut = loop.create_future()
+                self._waiters[tid] = fut
+                meta, segs = pack_mutations(ops)
+                msg = Message("osd_op", {"pgid": pgid, "oid": oid,
+                                         "ops": meta, "tid": tid,
+                                         "reqid": reqid,
+                                         "trace": span.ctx(),
+                                         **(extra or {})},
+                              segments=segs)
             try:
-                await self.msgr.send(
-                    tuple(info.addr), f"osd.{primary}",
-                    Message("osd_op", {"pgid": pgid, "oid": oid,
-                                       "ops": meta, "tid": tid,
-                                       "reqid": reqid,
-                                       "trace": span.ctx(),
-                                       **(extra or {})},
-                            segments=segs))
+                await self.msgr.send(tuple(info.addr),
+                                     f"osd.{primary}", msg)
                 reply = await asyncio.wait_for(
                     fut, min(attempt_timeout, deadline - loop.time()))
             except (ConnectionError, OSError, asyncio.TimeoutError) as e:

@@ -12,14 +12,60 @@ Each daemon keeps its finished spans in a bounded ring, dumpable via
 the admin socket ("dump_tracing"); assembling the rings from every
 daemon yields the full hop tree for any op (the tracepoint + jaeger
 span story, compressed to what this framework can verify in-process).
+
+Spans cross ``await``s, so a span's duration is queueing plus work.
+What the loop's thread is DOING is a ``section``: a plain ``with``
+block that never contains an ``await`` or ``yield``, named
+``<layer>.<what>`` with the layer one of ``SECTION_LAYERS``.  Sections
+land in the jax profiler's trace as host events, on the same clock as
+the device's own events, so a device idle gap can be laid against the
+section the thread was in.  Outside a profiler session a section
+records nothing.
 """
 
 from __future__ import annotations
 
 import contextvars
+import itertools
 import os
+import sys
 import time
 from collections import deque
+
+# the host layers a section name starts with ("wire.decode"); the
+# benchmark's per-layer metrics read these prefixes letter for letter
+SECTION_LAYERS = ("client", "wire", "osd_op", "store", "batcher",
+                  "device_wait")
+
+
+class _NoSection:
+    __slots__ = ()
+
+    def __enter__(self):
+        return self
+
+    def __exit__(self, *exc):
+        return False
+
+
+_NO_SECTION = _NoSection()
+_annotation = None      # jax.profiler.TraceAnnotation, once jax is loaded
+
+
+def section(name: str):
+    """Context manager around synchronous work on the calling thread.
+
+    A ``jax.profiler.TraceAnnotation`` once ``jax`` is in
+    ``sys.modules`` (host and device planes of one profiler session
+    share a clock); the shared no-op while it is not: a
+    replicated-only OSD never imports jax, and no section makes it."""
+    global _annotation
+    if _annotation is None:
+        profiler = getattr(sys.modules.get("jax"), "profiler", None)
+        if profiler is None:
+            return _NO_SECTION
+        _annotation = profiler.TraceAnnotation
+    return _annotation(name)
 
 # the active span of THIS asyncio task (or sync call chain under it)
 current_span: contextvars.ContextVar = contextvars.ContextVar(
@@ -38,11 +84,11 @@ class Span:
         self.name = name
         self.daemon = tracer.daemon
         self.trace_id = trace_id
-        self.span_id = os.urandom(4).hex()
+        self.span_id = tracer.next_id()
         self.parent_id = parent_id
         self.tags = tags
-        self.start = time.time()
-        self.end: float | None = None
+        self.start = time.time_ns()
+        self.end: int | None = None
         self._token = None
 
     def ctx(self) -> dict:
@@ -51,7 +97,7 @@ class Span:
 
     def finish(self) -> None:
         if self.end is None:
-            self.end = time.time()
+            self.end = time.time_ns()
             self._tracer._done(self)
         if self._token is not None:
             current_span.reset(self._token)
@@ -63,12 +109,14 @@ class Span:
         return self
 
     def to_dict(self) -> dict:
+        """Times are integer nanoseconds inside; the dump keeps its
+        seconds and milliseconds."""
         return {"trace_id": self.trace_id, "span_id": self.span_id,
                 "parent_id": self.parent_id, "name": self.name,
-                "daemon": self.daemon, "start": self.start,
-                "end": self.end,
+                "daemon": self.daemon, "start": self.start * 1e-9,
+                "end": None if self.end is None else self.end * 1e-9,
                 "duration_ms": None if self.end is None
-                else round((self.end - self.start) * 1000, 3),
+                else (self.end - self.start) / 1e6,
                 "tags": self.tags}
 
 
@@ -76,6 +124,14 @@ class Tracer:
     def __init__(self, daemon: str) -> None:
         self.daemon = daemon
         self.finished: deque[Span] = deque(maxlen=RING)
+        # ids: daemon name, one random tag per tracer (two processes
+        # may run a client of the same name), then a counter -- no
+        # syscall per span
+        self._id_prefix = f"{daemon}.{os.urandom(3).hex()}-"
+        self._serial = itertools.count(1)
+
+    def next_id(self) -> str:
+        return f"{self._id_prefix}{next(self._serial):x}"
 
     def start(self, name: str, parent: dict | None = None,
               **tags) -> Span:
@@ -89,7 +145,7 @@ class Tracer:
         cur = current_span.get()
         if cur is not None:
             return Span(self, name, cur.trace_id, cur.span_id, tags)
-        return Span(self, name, os.urandom(8).hex(), None, tags)
+        return Span(self, name, self.next_id(), None, tags)
 
     def _done(self, span: Span) -> None:
         self.finished.append(span)
@@ -97,6 +153,20 @@ class Tracer:
     def dump(self, trace_id: str | None = None) -> list[dict]:
         return [s.to_dict() for s in self.finished
                 if trace_id is None or s.trace_id == trace_id]
+
+
+def child_span(name: str, **tags) -> Span | None:
+    """A child of the task's current span, on that span's tracer; None
+    when no op trace is active on this task."""
+    cur = current_span.get()
+    if cur is None:
+        return None
+    return Span(cur._tracer, name, cur.trace_id, cur.span_id, tags)
+
+
+def finish(span: Span | None) -> None:
+    if span is not None:
+        span.finish()
 
 
 # per-process registry (daemon name -> tracer): tests and admin

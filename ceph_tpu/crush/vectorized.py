@@ -34,6 +34,7 @@ import jax
 # x64 while the rest of the package traces unchanged).
 import jax.numpy as jnp
 
+from ..common.tracing import section
 from .ln import RH_LH_TBL, LL_TBL
 from .types import (
     CrushMap,
@@ -141,10 +142,12 @@ def straw2_draws(x, item_ids, r, weights):
     """
     u = (hash32_3_jnp(x[..., None], item_ids, r[..., None])
          & np.uint32(0xFFFF)).astype(jnp.int32)
-    ln = crush_ln_jnp(u) - jnp.int64(0x1000000000000)
-    w = weights.astype(jnp.int64)
-    draws = jax.lax.div(ln, jnp.maximum(w, 1))
-    return jnp.where(w > 0, draws, S64_MIN)
+    # metadata only: names the draw's operations in a device trace
+    with jax.named_scope("straw2_draw"):
+        ln = crush_ln_jnp(u) - jnp.int64(0x1000000000000)
+        w = weights.astype(jnp.int64)
+        draws = jax.lax.div(ln, jnp.maximum(w, 1))
+        return jnp.where(w > 0, draws, S64_MIN)
 
 
 def is_out_jnp(osd_weights, item, x):
@@ -386,7 +389,7 @@ class VectorCrush:
 
     # -- firstn -------------------------------------------------------------
     @partial(jax.jit, static_argnames=("self", "numrep"))
-    def map_firstn(self, xs: jnp.ndarray, numrep: int,
+    def crush_firstn(self, xs: jnp.ndarray, numrep: int,
                    osd_weights: jnp.ndarray) -> jnp.ndarray:
         cm = self.cm
         ids, idx, w = self._tables()
@@ -451,7 +454,7 @@ class VectorCrush:
 
     # -- indep --------------------------------------------------------------
     @partial(jax.jit, static_argnames=("self", "numrep"))
-    def map_indep(self, xs: jnp.ndarray, numrep: int,
+    def crush_indep(self, xs: jnp.ndarray, numrep: int,
                   osd_weights: jnp.ndarray) -> jnp.ndarray:
         cm = self.cm
         ids, idx, w = self._tables()
@@ -503,7 +506,7 @@ class VectorCrush:
         (longer inputs run as equal-sized launches, the tail padded),
         which bounds device memory and the number of compiled shapes
         whatever size a caller hands in."""
-        fn = self.map_firstn if self.firstn else self.map_indep
+        fn = self.crush_firstn if self.firstn else self.crush_indep
         with jax.enable_x64(True):
             w = jnp.asarray(osd_weights, jnp.int32)
             # lint: disable=device-path-host-sync -- host-side input marshal of the seeds, no device array involved
@@ -515,7 +518,11 @@ class VectorCrush:
                 parts = np.concatenate(
                     [xs, np.zeros(-n % MAX_LANES, np.int32)]
                 ).reshape(-1, MAX_LANES)
-            # lint: disable=device-path-host-sync -- one materialization per bounded launch of the bulk map
-            out = [np.asarray(fn(jnp.asarray(part), numrep, w))
-                   for part in parts]
+            out = []
+            for part in parts:
+                # the calling thread, from the launch until its result is
+                # on the host
+                with section("device_wait.crush"):
+                    # lint: disable=device-path-host-sync -- one materialization per bounded launch of the bulk map
+                    out.append(np.asarray(fn(jnp.asarray(part), numrep, w)))
             return np.concatenate(out)[:n]

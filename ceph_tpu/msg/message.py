@@ -24,6 +24,7 @@ from dataclasses import dataclass, field
 from typing import Any
 
 from ..common.denc import Decoder, DencError, Encoder
+from ..common.tracing import section
 from ..native import crc32c
 
 MAGIC = b"CTv3"
@@ -43,6 +44,10 @@ class Message:
     from_name: str = ""
 
     def encode(self) -> bytes:
+        with section("wire.encode"):
+            return self._encode()
+
+    def _encode(self) -> bytes:
         from .wire_types import WIRE_CODECS
         payload = Encoder()
         codec = WIRE_CODECS.get(self.type)
@@ -76,20 +81,27 @@ class Message:
         enc.finish()
         mb = enc.bytes()
         body = mb + b"".join(self.segments)
-        crc = crc32c(body) & 0xFFFFFFFF
+        with section("wire.crc"):
+            crc = crc32c(body) & 0xFFFFFFFF
         return MAGIC + struct.pack("<I", len(mb)) + body + struct.pack(
             "<I", crc)
 
     @classmethod
     def decode(cls, buf: bytes) -> "Message":
+        with section("wire.decode"):
+            return cls._decode(buf)
+
+    @classmethod
+    def _decode(cls, buf: bytes) -> "Message":
         if buf[:4] != MAGIC:
             raise ValueError("bad magic")
         (meta_len,) = struct.unpack_from("<I", buf, 4)
         mb = buf[8:8 + meta_len]
         (crc,) = struct.unpack_from("<I", buf, len(buf) - 4)
         body = buf[8:len(buf) - 4]
-        if (crc32c(body) & 0xFFFFFFFF) != crc:
-            raise ValueError("frame crc mismatch")
+        with section("wire.crc"):
+            if (crc32c(body) & 0xFFFFFFFF) != crc:
+                raise ValueError("frame crc mismatch")
         mtype, seq, from_name, data, seg_lens = _decode_meta(mb)
         segments = []
         off = 8 + meta_len

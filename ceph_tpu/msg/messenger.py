@@ -20,6 +20,7 @@ import time
 from collections import deque
 from typing import Awaitable, Callable
 
+from ..common.tracing import section
 from .message import Message, read_frame, wrap_frame
 
 Dispatcher = Callable[["Connection", Message], Awaitable[None]]
@@ -175,7 +176,8 @@ class Connection:
                 # (ms_inject_socket_failures, qa msgr-failures suites)
                 self.writer.close()
             try:
-                self.writer.write(wire)
+                with section("wire.write"):
+                    self.writer.write(wire)
                 await self.writer.drain()
                 return "sent"
             except (ConnectionError, OSError):
@@ -820,23 +822,8 @@ class Messenger:
             while not conn.closed:
                 buf = await read_frame(conn.reader, conn.compressor,
                                        conn.aead_rx)
-                msg = Message.decode(buf)
-                if msg.type == ACK_TYPE:   # control frame, outside seq space
-                    conn._trim_acked(int(msg.data.get("seq", 0)))
-                    continue
-                if msg.seq <= conn.in_seq:
-                    continue  # duplicate after resend
-                conn.in_seq = msg.seq
-                if not conn.outgoing:
-                    self._sessions[conn.peer_name] = msg.seq
-                conn._note_delivered(len(buf))
-                if msg.type == SUBOP_BATCH_TYPE:
-                    # one framed flush -> the staged sub-ops, delivered
-                    # in staging order (per-peer FIFO preserved)
-                    for sub in unpack_subop_batch(msg):
-                        self._deliver(conn, sub)
-                else:
-                    self._deliver(conn, msg)
+                with section("wire.deliver"):
+                    self._frame_in(conn, buf)
         except (asyncio.IncompleteReadError, ConnectionError, ValueError):
             if conn.outgoing and not conn.closed:
                 # lossless policy: try to re-establish and replay
@@ -860,6 +847,27 @@ class Messenger:
                     pass
         except asyncio.CancelledError:
             pass
+
+    def _frame_in(self, conn: Connection, buf: bytes) -> None:
+        """One received frame, synchronously: decode, seq/ack
+        accounting, delivery of the message(s) it carries."""
+        msg = Message.decode(buf)
+        if msg.type == ACK_TYPE:   # control frame, outside seq space
+            conn._trim_acked(int(msg.data.get("seq", 0)))
+            return
+        if msg.seq <= conn.in_seq:
+            return  # duplicate after resend
+        conn.in_seq = msg.seq
+        if not conn.outgoing:
+            self._sessions[conn.peer_name] = msg.seq
+        conn._note_delivered(len(buf))
+        if msg.type == SUBOP_BATCH_TYPE:
+            # one framed flush -> the staged sub-ops, delivered
+            # in staging order (per-peer FIFO preserved)
+            for sub in unpack_subop_batch(msg):
+                self._deliver(conn, sub)
+        else:
+            self._deliver(conn, msg)
 
     def _deliver(self, conn: Connection, msg: Message) -> None:
         """Fault-inject and dispatch ONE logical message (seq/ack

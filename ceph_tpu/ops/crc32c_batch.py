@@ -448,6 +448,8 @@ def _crc_chunks_compiled(l: int):
             crc = t[0][(crc ^ xu[:, j]) & 0xFF] ^ (crc >> 8)
         return crc
 
+    # a name of its own in a device trace, alone or inlined
+    fn.__name__ = fn.__qualname__ = "crc32c_chunks"
     return jax.jit(fn)
 
 
@@ -458,13 +460,16 @@ def crc32c_chunks_traced(x):
     one sharded launch that produces the parity (the CRC math is
     row-independent, so GSPMD partitions it over the stripe axis with
     no collective)."""
+    import jax
     import jax.numpy as jnp
     xd = jnp.asarray(x, jnp.uint8)
     lead, l = xd.shape[:-1], xd.shape[-1]
     if l == 0:                      # zero-length chunks: seed, no kernel
         return jnp.full(lead, SEED, jnp.uint32)
-    flat = xd.reshape((-1, l))
-    return _crc_chunks_compiled(l)(flat).reshape(lead)
+    # the scope a device trace finds the checksum's operations under
+    with jax.named_scope("crc32c"):
+        flat = xd.reshape((-1, l))
+        return _crc_chunks_compiled(l)(flat).reshape(lead)
 
 
 def crc32c_device_chunks(x):

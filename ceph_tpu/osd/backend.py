@@ -17,6 +17,7 @@ import asyncio
 
 import numpy as np
 
+from ..common import tracing
 from ..os.transaction import Transaction
 from .ec_util import StripeInfo
 from .types import LogEntry, MissingSet, ZERO
@@ -210,16 +211,12 @@ class PGBackend:
     def _queue_txn_traced(self, txn: Transaction, oid: str) -> None:
         """Commit the txn with a store.txn span when an op trace is
         active on this task (the client->OSD->store hop chain)."""
-        from ..common.tracing import current_span, get_tracer
-        cur = current_span.get()
-        if cur is None:
-            self.store.queue_transaction(txn)
-            return
-        sp = get_tracer(cur._tracer.daemon).start("store.txn", oid=oid)
+        sp = tracing.child_span("store.txn", oid=oid)
         try:
-            self.store.queue_transaction(txn)
+            with tracing.section("store.queue_transaction"):
+                self.store.queue_transaction(txn)
         finally:
-            sp.finish()
+            tracing.finish(sp)
 
     async def submit_transaction(self, entry: LogEntry,
                                  muts: list[dict]) -> None:
@@ -344,8 +341,7 @@ class ReplicatedBackend(PGBackend):
         # arrives when the backfill scan reaches it, but their log/
         # last_update must stay in step with the acting set.
         meta, segs = pack_mutations(muts)
-        from ..common.tracing import current_span
-        cur = current_span.get()
+        cur = tracing.current_span.get()
         tr = {"trace": cur.ctx()} if cur is not None else {}
         targets = []
         for o in self.pg.acting:
@@ -1033,9 +1029,87 @@ class ECBackend(PGBackend):
             return await self._submit_partial(entry, content_muts,
                                               attr_muts, old_size,
                                               *plan)
-        logical = bytearray(await self._read_logical(entry.oid))
-        remove = False          # tracks the FINAL state: a remove followed
-        for m in content_muts:  # by a write recreates the object in-order
+        old = await self._read_logical(entry.oid)
+        with tracing.section("osd_op.merge"):
+            logical, remove = self._merge_content(old, content_muts)
+            size = len(logical)
+            padded = bytes(logical) + b"\0" * (
+                self.sinfo.logical_to_next_stripe_offset(size) - size)
+        acting = self.pg.acting
+        if remove:
+            self.cache.invalidate(entry.oid)
+            per_shard = [{"remove": True} for _ in acting]
+            segs_per_shard = [[] for _ in acting]
+        elif padded:
+            # the codec launch returns the shard CRCs along with the
+            # parity: the identity stamp below consumes them instead of
+            # re-hashing bytes the encoder just produced
+            span = tracing.child_span("ec.encode", oid=entry.oid)
+            try:
+                shards, shard_crcs = await self.sinfo.encode_async(
+                    self.codec, padded, batcher=self.batcher,
+                    with_crc=True)
+            finally:
+                tracing.finish(span)
+        else:
+            shards = {i: np.zeros(0, np.uint8)
+                      for i in range(len(acting))}
+            empty_crc = shard_crc(b"")
+            shard_crcs = {i: empty_crc for i in range(len(acting))}
+        awaiting = []
+        with tracing.section("osd_op.sub_writes"):
+            if not remove:
+                sw = self.sinfo.stripe_width
+                self.cache.truncate_beyond(entry.oid, len(padded) // sw)
+                if len(padded) <= self.cache.max_bytes // 4:
+                    for s in range(len(padded) // sw):
+                        self.cache.put(entry.oid, s,
+                                       padded[s * sw:(s + 1) * sw])
+                else:
+                    # a huge rewrite would churn the whole LRU for
+                    # entries that mostly evict each other; drop stale
+                    # ones instead
+                    self.cache.invalidate(entry.oid)
+                per_shard, segs_per_shard = [], []
+                for shard in range(len(acting)):
+                    buf = shards[shard].tobytes()
+                    per_shard.append({"size": size,
+                                      "shard_len": len(buf),
+                                      "attrs": None,
+                                      "crc": int(shard_crcs[shard])})
+                    segs_per_shard.append([buf])
+            # local shard applies in-line; remote shards via
+            # ec_subop_write
+            attr_meta, attr_segs = pack_mutations(attr_muts)
+            for shard, osd in enumerate(acting):
+                if osd < 0:
+                    continue
+                if osd == self.osd.whoami:
+                    self.apply_sub_write(entry, per_shard[shard],
+                                         segs_per_shard[shard],
+                                         attr_muts, shard=shard)
+                elif not self.pg.should_send_to(osd, entry.oid):
+                    awaiting.append(
+                        self._log_only_subop(osd, shard, entry))
+                else:
+                    payload = {"pgid": self.pg.pgid, "oid": entry.oid,
+                               "shard": shard,
+                               "entry": entry.to_dict(),
+                               "w": per_shard[shard],
+                               "attr_muts": attr_meta}
+                    awaiting.append((osd, "ec_subop_write", payload,
+                                     segs_per_shard[shard] + attr_segs))
+        return await self._commit_or_defer(awaiting, entry)
+
+    @staticmethod
+    def _merge_content(old: bytes,
+                       content_muts: list[dict]) -> tuple[bytearray, bool]:
+        """The object's new logical content, and whether the vector's
+        FINAL state is a removal (a remove followed by a write
+        recreates the object in-order)."""
+        logical = bytearray(old)
+        remove = False
+        for m in content_muts:
             if m["op"] == "write":
                 end = m["off"] + len(m["data"])
                 if len(logical) < end:
@@ -1054,66 +1128,7 @@ class ECBackend(PGBackend):
             elif m["op"] == "remove":
                 logical = bytearray()
                 remove = True
-
-        acting = self.pg.acting
-        if remove:
-            self.cache.invalidate(entry.oid)
-            per_shard = [{"remove": True} for _ in acting]
-            segs_per_shard = [[] for _ in acting]
-        else:
-            size = len(logical)
-            padded = bytes(logical) + b"\0" * (
-                self.sinfo.logical_to_next_stripe_offset(size) - size)
-            if padded:
-                # the codec launch returns the shard CRCs along with
-                # the parity: the identity stamp below consumes them
-                # instead of re-hashing bytes the encoder just produced
-                shards, shard_crcs = await self.sinfo.encode_async(
-                    self.codec, padded, batcher=self.batcher,
-                    with_crc=True)
-            else:
-                shards = {i: np.zeros(0, np.uint8)
-                          for i in range(len(acting))}
-                empty_crc = shard_crc(b"")
-                shard_crcs = {i: empty_crc
-                              for i in range(len(acting))}
-            sw = self.sinfo.stripe_width
-            self.cache.truncate_beyond(entry.oid, len(padded) // sw)
-            if len(padded) <= self.cache.max_bytes // 4:
-                for s in range(len(padded) // sw):
-                    self.cache.put(entry.oid, s,
-                                   padded[s * sw:(s + 1) * sw])
-            else:
-                # a huge rewrite would churn the whole LRU for entries
-                # that mostly evict each other; drop stale ones instead
-                self.cache.invalidate(entry.oid)
-            per_shard, segs_per_shard = [], []
-            for shard in range(len(acting)):
-                buf = shards[shard].tobytes()
-                per_shard.append({"size": size, "shard_len": len(buf),
-                                  "attrs": None,
-                                  "crc": int(shard_crcs[shard])})
-                segs_per_shard.append([buf])
-        # local shard applies in-line; remote shards via ec_subop_write
-        awaiting = []
-        for shard, osd in enumerate(acting):
-            if osd < 0:
-                continue
-            if osd == self.osd.whoami:
-                self.apply_sub_write(entry, per_shard[shard],
-                                     segs_per_shard[shard], attr_muts,
-                                     shard=shard)
-            elif not self.pg.should_send_to(osd, entry.oid):
-                awaiting.append(self._log_only_subop(osd, shard, entry))
-            else:
-                payload = {"pgid": self.pg.pgid, "oid": entry.oid,
-                           "shard": shard, "entry": entry.to_dict(),
-                           "w": per_shard[shard],
-                           "attr_muts": pack_mutations(attr_muts)[0]}
-                segs = (segs_per_shard[shard]
-                        + pack_mutations(attr_muts)[1])
-                awaiting.append((osd, "ec_subop_write", payload, segs))
-        return await self._commit_or_defer(awaiting, entry)
+        return logical, remove
 
     # -- partial-stripe RMW pipeline ----------------------------------------
     # The reference's RMWPipeline (ECCommon.cc:704 start_rmw ->
@@ -1324,8 +1339,13 @@ class ECBackend(PGBackend):
                 return await _delta_run(lo, hi)
             return await _full_run(lo, hi)
 
-        for writes in await asyncio.gather(
-                *(_run_one(lo, hi) for lo, hi in runs)):
+        span = tracing.child_span("ec.encode", oid=oid)
+        try:
+            run_writes = await asyncio.gather(
+                *(_run_one(lo, hi) for lo, hi in runs))
+        finally:
+            tracing.finish(span)
+        for writes in run_writes:
             for shard, off, buf in writes:
                 shard_writes[shard].append((off, buf))
         for s in stripes:
@@ -1425,7 +1445,7 @@ class ECBackend(PGBackend):
                 vtuple = (entry.version.epoch, entry.version.version)
         apply_mutations(txn, self.coll, oid, attr_muts)
         self.pg.append_log_and_meta(txn, entry)
-        self.store.queue_transaction(txn)
+        self._queue_txn_traced(txn, oid)
         if not w.get("remove"):
             self._stamp_identity(oid, shard, crc=w.get("crc"),
                                  content=content, size=size,
@@ -1462,7 +1482,8 @@ class ECBackend(PGBackend):
                         str(int(shard)).encode())
         txn.setattr(self.coll, oid, CRC_XATTR,
                     str(int(crc)).encode())
-        self.store.queue_transaction(txn)
+        with tracing.section("store.queue_transaction"):
+            self.store.queue_transaction(txn)
         if self.dcache is not None and content is not None \
                 and size is not None and ver is not None:
             self.dcache.put(self.coll, oid, content, size=size,

@@ -58,6 +58,12 @@ Mechanics:
 Occupancy is surfaced as perf counters (``perf dump`` -> "ec_batch"):
 batches launched, a stripes-per-batch histogram, padding waste, and
 flush-reason counts, so the bench can report achieved batch sizes.
+Three wait counters, integer microseconds summed over launches, say
+where a launch's time goes: ``queue_wait_us`` (a group's first
+submission to its dispatch), ``overlap_us`` (dispatch return to
+completion entry: the launch is in flight while the loop does other
+work) and ``materialize_us`` (the ``np.asarray`` that blocks the
+loop's thread until the device is done).
 Pipeline occupancy (staged batches, overlap windows, staging-full
 stalls) lands in the OSD-wide "ec_pipeline" set.
 """
@@ -65,8 +71,11 @@ stalls) lands in the OSD-wide "ec_pipeline" set.
 from __future__ import annotations
 
 import asyncio
+import time
 
 import numpy as np
+
+from ..common.tracing import section
 
 STRIPE_HIST_BUCKETS = [1.0, 2.0, 4.0, 8.0, 16.0, 32.0, 64.0, 128.0,
                        256.0, 512.0]
@@ -86,7 +95,8 @@ def codec_signature(codec, kind: str, extra: tuple) -> tuple:
 class _Group:
     """One pending batch: submissions awaiting a shared launch."""
 
-    __slots__ = ("codec", "kind", "extra", "items", "n_stripes", "task")
+    __slots__ = ("codec", "kind", "extra", "items", "n_stripes", "task",
+                 "t_first")
 
     def __init__(self, codec, kind: str, extra: tuple) -> None:
         self.codec = codec
@@ -95,6 +105,7 @@ class _Group:
         self.items: list[tuple[np.ndarray, asyncio.Future, bool]] = []
         self.n_stripes = 0
         self.task: asyncio.Task | None = None
+        self.t_first = time.perf_counter_ns()   # queue_wait_us starts
 
 
 class _Staged:
@@ -103,7 +114,7 @@ class _Staged:
     dispatch and the post-launch fan-out remain."""
 
     __slots__ = ("grp", "reason", "batch", "old_batch", "want_crc",
-                 "lane", "total", "b", "payload", "mesh")
+                 "lane", "total", "b", "payload", "mesh", "t_dispatched")
 
     def __init__(self, grp, reason, batch, old_batch, want_crc,
                  lane, total, b, payload, mesh) -> None:
@@ -117,6 +128,7 @@ class _Staged:
         self.b = b
         self.payload = payload
         self.mesh = mesh
+        self.t_dispatched = 0       # perf_counter_ns at _dispatch's return
 
 
 class CodecBatcher:
@@ -452,6 +464,10 @@ class CodecBatcher:
         """Host staging: pad and stack the coalesced submissions into
         one (b, k, lane) launch batch (plus the old-parity batch for
         rmw).  This is the work that overlaps the in-flight launch."""
+        with section("batcher.marshal"):
+            return self._marshal_batch(grp, reason)
+
+    def _marshal_batch(self, grp: _Group, reason: str) -> _Staged:
         # lazy: gf2kernels pulls in jax, which a replicated-only OSD
         # must not pay for at boot (only EC submissions reach here,
         # and by then the codec itself has loaded the stack)
@@ -497,6 +513,15 @@ class CodecBatcher:
         the event loop while the device works.  Returns
         (mode, out, crcs, xor_stats0); ``_complete`` pays the single
         asarray."""
+        if self.perf is not None:
+            self.perf.inc("queue_wait_us", (time.perf_counter_ns()
+                                            - st.grp.t_first) // 1000)
+        with section("batcher.dispatch"):
+            handle = self._dispatch_launch(st)
+        st.t_dispatched = time.perf_counter_ns()
+        return handle
+
+    def _dispatch_launch(self, st: _Staged) -> tuple:
         grp, batch, old_batch = st.grp, st.batch, st.old_batch
         want_crc, mesh = st.want_crc, st.mesh
         # scheduled-engine observability: the XOR-schedule compiler
@@ -550,16 +575,22 @@ class CodecBatcher:
     def _complete(self, st: _Staged, handle: tuple) -> None:
         """Materialize the launch (the single post-launch host hop),
         fan results back to the per-op futures, bump the counters."""
-        grp, items = st.grp, st.grp.items
+        t_in = time.perf_counter_ns()
         mode, out, crcs, xor_stats0 = handle
-        # lint: disable=device-path-host-sync -- the single post-launch materialization
-        out = np.asarray(out)
+        with section("device_wait.materialize"):
+            # lint: disable=device-path-host-sync -- the single post-launch materialization
+            out = np.asarray(out)
+            if crcs is not None:
+                # lint: disable=device-path-host-sync -- the single post-launch materialization (fused CRC side output)
+                crcs = np.asarray(crcs)
+        if self.perf is not None:
+            self.perf.inc("overlap_us", (t_in - st.t_dispatched) // 1000)
+            self.perf.inc("materialize_us",
+                          (time.perf_counter_ns() - t_in) // 1000)
+        grp, items = st.grp, st.grp.items
         if mode == "rmw_host":
             out = st.old_batch ^ out
-        if crcs is not None:
-            # lint: disable=device-path-host-sync -- the single post-launch materialization (fused CRC side output)
-            crcs = np.asarray(crcs)
-        elif st.want_crc:
+        if crcs is None and st.want_crc:
             crcs = self._host_chunk_crcs(st.batch, out)
             if self.perf is not None:
                 self.perf.inc("crc_host_batches")

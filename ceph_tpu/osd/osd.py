@@ -16,6 +16,7 @@ import uuid as uuid_mod
 
 from ..common import AdminSocket, ConfigProxy, PerfCountersCollection, \
     make_task_tracker
+from ..common.tracing import get_tracer, section
 from ..mon.osdmap import OSDMap, Incremental
 from ..msg import Message, Messenger
 from ..os.store import MemStore, make_default_store
@@ -353,7 +354,6 @@ class OSD:
                       "slowest completed ops",
                       historic_ops_by_duration)
         async def dump_tracing(req):
-            from ..common.tracing import get_tracer
             return get_tracer(f"osd.{self.whoami}").dump(
                 (req or {}).get("trace_id"))
 
@@ -1260,7 +1260,6 @@ class OSD:
                 "osd_op_reply", {"tid": msg.data.get("tid"),
                                  "err": "ENXIO no such pg"}))
             return
-        from ..common.tracing import get_tracer
         span = get_tracer(f"osd.{self.whoami}").start(
             "osd.do_op", parent=msg.data.get("trace"),
             pgid=msg.data["pgid"], oid=msg.data["oid"]).activate()
@@ -1296,7 +1295,6 @@ class OSD:
 
     # replication / EC sub-ops
     async def _h_rep_op(self, conn, msg) -> None:
-        from ..common.tracing import get_tracer
         span = get_tracer(f"osd.{self.whoami}").start(
             "osd.rep_op", parent=msg.data.get("trace"),
             pgid=msg.data["pgid"]).activate()
@@ -1328,19 +1326,22 @@ class OSD:
         from .backend import unpack_mutations
         pg = self._get_pg(msg.data["pgid"])
         if pg is not None:
-            entry = LogEntry.from_dict(msg.data["entry"])
-            w = msg.data["w"]
-            if w.get("writes") is not None:      # ranged RMW sub-write
-                n_data_segs = len(w["writes"])
-            elif w.get("remove") or w.get("touch") or w.get("log_only"):
-                n_data_segs = 0
-            else:
-                n_data_segs = 1
-            attr_muts = unpack_mutations(msg.data.get("attr_muts", []),
-                                         msg.segments[n_data_segs:])
-            pg.backend.apply_sub_write(
-                entry, w, msg.segments[:n_data_segs], attr_muts,
-                shard=msg.data.get("shard"))
+            with section("osd_op.sub_write"):
+                entry = LogEntry.from_dict(msg.data["entry"])
+                w = msg.data["w"]
+                if w.get("writes") is not None:  # ranged RMW sub-write
+                    n_data_segs = len(w["writes"])
+                elif w.get("remove") or w.get("touch") \
+                        or w.get("log_only"):
+                    n_data_segs = 0
+                else:
+                    n_data_segs = 1
+                attr_muts = unpack_mutations(
+                    msg.data.get("attr_muts", []),
+                    msg.segments[n_data_segs:])
+                pg.backend.apply_sub_write(
+                    entry, w, msg.segments[:n_data_segs], attr_muts,
+                    shard=msg.data.get("shard"))
             self.perf_osd.inc("subop_w")
         await conn.send(Message("ec_subop_write_reply",
                                 {"tid": msg.data.get("tid"),

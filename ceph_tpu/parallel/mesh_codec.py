@@ -53,6 +53,7 @@ from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from .sharded_ec import _gf_matmul_bits, make_data_mesh
+from ..common.tracing import section
 from ..ops.gf2kernels import bitmatrix_i8, bucket_batch, check_batch_parity
 
 # encode (B,k,L)->(B,m,L) and decode (B,k,L)->(B,r,L) donate a buffer
@@ -81,6 +82,22 @@ def _w_device(mesh: Mesh, mat_bytes: bytes, r: int, k: int):
                           NamedSharding(mesh, P(None, None)))
 
 
+def _jit_as(name: str, fn, donate_argnums: tuple):
+    """``jax.jit`` under a program name of its own: the profiler's
+    trace and the compile cache call the program ``jit_<name>``, so
+    encode, decode and rmw launches stand apart in a trace."""
+    fn.__name__ = fn.__qualname__ = name
+    return jax.jit(fn, donate_argnums=donate_argnums)
+
+
+def _host(out):
+    """The synchronous return of a launch: the calling thread waits
+    here until the device is done."""
+    with section("device_wait.materialize"):
+        # lint: disable=device-path-host-sync -- the single post-launch materialization
+        return np.asarray(out)
+
+
 def _stripe_block(w_local, chunks):
     """Per-device block: my slice of the stripe batch through the GF
     bit-matmul.  No collective -- stripes are independent."""
@@ -91,19 +108,21 @@ def _stripe_block(w_local, chunks):
 
 
 @functools.lru_cache(maxsize=512)
-def _compiled_apply(mesh: Mesh, b: int, k: int, lane: int,
+def _compiled_apply(mesh: Mesh, name: str, b: int, k: int, lane: int,
                     with_crc: bool, donate: bool):
     """One launch: (8r,8k) W x (B,k,L) stripes -> (B,r,L) [+ chunk
     CRCs].  The batch axis shards over 'stripe'; W replicates.  The
     stripe buffer (arg 1) is donated -- consumed by the launch, never
     read again (the donated-buffer-aliasing lint rule guards callers).
+    ``name`` (``ec_encode``, ``ec_decode``) is the program's name: the
+    same matrix product compiles once per caller's kind.
     """
     sharded = shard_map(
         _stripe_block, mesh=mesh,
         in_specs=(P(None, None), P("stripe", None, None)),
         out_specs=P("stripe", None, None))
     if not with_crc:
-        return jax.jit(sharded, donate_argnums=(1,) if donate else ())
+        return _jit_as(name, sharded, (1,) if donate else ())
 
     def fn(w, data):
         from ..ops.crc32c_batch import crc32c_chunks_traced
@@ -112,12 +131,13 @@ def _compiled_apply(mesh: Mesh, b: int, k: int, lane: int,
                                 crc32c_chunks_traced(parity)], axis=1)
         return parity, crcs
 
-    return jax.jit(fn, donate_argnums=(1,) if donate else ())
+    return _jit_as(name + "_crc", fn, (1,) if donate else ())
 
 
 @functools.lru_cache(maxsize=256)
-def _compiled_apply_sched(mesh: Mesh, digest: str, b: int, k: int,
-                          lane: int, with_crc: bool, donate: bool):
+def _compiled_apply_sched(mesh: Mesh, name: str, digest: str, b: int,
+                          k: int, lane: int, with_crc: bool,
+                          donate: bool):
     """The scheduled twin of ``_compiled_apply``: the CSE-minimized
     XOR schedule (ops/xor_schedule.py, looked up by matrix digest) is
     BAKED into the program instead of taking W as an operand, so the
@@ -138,7 +158,8 @@ def _compiled_apply_sched(mesh: Mesh, digest: str, b: int, k: int,
         in_specs=(P("stripe", None, None),),
         out_specs=P("stripe", None, None))
     if not with_crc:
-        return jax.jit(sharded, donate_argnums=(0,) if donate else ())
+        return _jit_as(name + "_sched", sharded,
+                       (0,) if donate else ())
 
     def fn(data):
         from ..ops.crc32c_batch import crc32c_chunks_traced
@@ -147,7 +168,7 @@ def _compiled_apply_sched(mesh: Mesh, digest: str, b: int, k: int,
                                 crc32c_chunks_traced(parity)], axis=1)
         return parity, crcs
 
-    return jax.jit(fn, donate_argnums=(0,) if donate else ())
+    return _jit_as(name + "_crc_sched", fn, (0,) if donate else ())
 
 
 @functools.lru_cache(maxsize=256)
@@ -170,8 +191,7 @@ def _compiled_rmw_sched(mesh: Mesh, digest: str, b: int, m: int,
         block, mesh=mesh,
         in_specs=(P("stripe", None, None), P("stripe", None, None)),
         out_specs=P("stripe", None, None))
-    return jax.jit(sharded,
-                   donate_argnums=(0, 1) if donate else ())
+    return _jit_as("ec_rmw_sched", sharded, (0, 1) if donate else ())
 
 
 @functools.lru_cache(maxsize=256)
@@ -190,8 +210,7 @@ def _compiled_rmw(mesh: Mesh, b: int, m: int, k: int, lane: int,
         in_specs=(P(None, None), P("stripe", None, None),
                   P("stripe", None, None)),
         out_specs=P("stripe", None, None))
-    return jax.jit(sharded,
-                   donate_argnums=(1, 2) if donate else ())
+    return _jit_as("ec_rmw", sharded, (1, 2) if donate else ())
 
 
 @functools.lru_cache(maxsize=256)
@@ -288,8 +307,8 @@ class MeshCodec:
         output in place); never read either after this call."""
         return fn(dev_old, dev_delta)
 
-    def _apply_sched(self, matrix: np.ndarray, batch: np.ndarray,
-                     with_crc: bool):
+    def _apply_sched(self, name: str, matrix: np.ndarray,
+                     batch: np.ndarray, with_crc: bool):
         """The scheduled engine's output for this batch, or None when
         the cost model picks dense.  A picked schedule serves or
         raises (``KernelParityError`` on a first-launch parity miss)."""
@@ -299,8 +318,8 @@ class MeshCodec:
                                   jax.default_backend())
         if sched is None:
             return None
-        fn = _compiled_apply_sched(self.mesh, sched.digest, b, k, lane,
-                                   with_crc, self.donate)
+        fn = _compiled_apply_sched(self.mesh, name, sched.digest, b, k,
+                                   lane, with_crc, self.donate)
         out = self._sched_launch(fn, self._put(batch))
         key = (sched.digest, "mesh", b, k, lane)
         if key not in XS._sched_verified:
@@ -313,16 +332,16 @@ class MeshCodec:
         XS.STATS.note_launch(sched)
         return out
 
-    def _apply(self, matrix: np.ndarray, batch: np.ndarray,
+    def _apply(self, name: str, matrix: np.ndarray, batch: np.ndarray,
                with_crc: bool):
         b, k, lane = batch.shape
         assert b % self.n_devices == 0, (b, self.n_devices)
         matrix = np.ascontiguousarray(matrix, np.uint8)
-        out = self._apply_sched(matrix, batch, with_crc)
+        out = self._apply_sched(name, matrix, batch, with_crc)
         if out is not None:
             return out
         w = _w_device(self.mesh, matrix.tobytes(), *matrix.shape)
-        fn = _compiled_apply(self.mesh, b, k, lane, with_crc,
+        fn = _compiled_apply(self.mesh, name, b, k, lane, with_crc,
                              self.donate)
         out = fn(w, self._put(batch))
         self._count(b)
@@ -344,30 +363,23 @@ class MeshCodec:
             assert not with_crc, "flat dialect has no fused CRC"
             a = codec.alpha
             b, kc, lane = batch.shape
-            out = self._apply(codec.parity_matrix,
+            out = self._apply("ec_encode", codec.parity_matrix,
                               batch.reshape(b, kc * a, lane // a),
                               False)
             out = out.reshape(b, -1, lane)
-            if not out_np:
-                return out
-            # lint: disable=device-path-host-sync -- the single post-launch materialization
-            return np.asarray(out)
+            return _host(out) if out_np else out
         mat = codec.encode_matrix[codec.k:]
         if not with_crc:
-            out = self._apply(mat, batch, False)
-            if not out_np:
-                return out
-            # lint: disable=device-path-host-sync -- the single post-launch materialization
-            return np.asarray(out)
-        out, crcs = self._apply(mat, batch, True)
+            out = self._apply("ec_encode", mat, batch, False)
+            return _host(out) if out_np else out
+        out, crcs = self._apply("ec_encode", mat, batch, True)
         from ..ops.crc32c_batch import PERF
         PERF.inc("fused_launches")
         PERF.inc("fused_crcs", int(batch.shape[0])
                  * (batch.shape[1] + out.shape[1]))
         if not out_np:
             return out, crcs
-        # lint: disable=device-path-host-sync -- the single post-launch materialization
-        return np.asarray(out), np.asarray(crcs)
+        return _host(out), _host(crcs)
 
     def decode(self, codec, erasures, batch: np.ndarray,
                out_np: bool = True):
@@ -381,14 +393,11 @@ class MeshCodec:
             matrix = codec.decode_flat_matrix(list(erasures))
             a = codec.alpha
             b, s, lane = batch.shape
-            out = self._apply(matrix,
+            out = self._apply("ec_decode", matrix,
                               batch.reshape(b, s * a, lane // a),
                               False)
             out = out.reshape(b, -1, lane)
-            if not out_np:
-                return out
-            # lint: disable=device-path-host-sync -- the single post-launch materialization
-            return np.asarray(out)
+            return _host(out) if out_np else out
         if hasattr(codec, "decode_matrix_for"):
             # the plugin's DecodeTableCache: the SAME matrix object
             # decode_batch would use
@@ -397,11 +406,8 @@ class MeshCodec:
             enc = np.ascontiguousarray(codec.encode_matrix, np.uint8)
             matrix = _decode_matrix_cached(enc.tobytes(), *enc.shape,
                                            codec.k, erasures)
-        out = self._apply(matrix, batch, False)
-        if not out_np:
-            return out
-        # lint: disable=device-path-host-sync -- the single post-launch materialization
-        return np.asarray(out)
+        out = self._apply("ec_decode", matrix, batch, False)
+        return _host(out) if out_np else out
 
     def rmw(self, codec, old_parity: np.ndarray,
             delta: np.ndarray, out_np: bool = True):
@@ -418,10 +424,7 @@ class MeshCodec:
             out = self._rmw_flat(codec, old_parity, delta, a)
             if self.perf is not None:
                 self.perf.inc("mesh_rmw_launches")
-            if not out_np:
-                return out
-            # lint: disable=device-path-host-sync -- the single post-launch materialization
-            return np.asarray(out)
+            return _host(out) if out_np else out
         mat = np.ascontiguousarray(codec.encode_matrix[codec.k:],
                                    np.uint8)
         out = self._rmw_sched(mat, old_parity, delta)
@@ -432,10 +435,7 @@ class MeshCodec:
             self._count(b)
         if self.perf is not None:
             self.perf.inc("mesh_rmw_launches")
-        if not out_np:
-            return out
-        # lint: disable=device-path-host-sync -- the single post-launch materialization
-        return np.asarray(out)
+        return _host(out) if out_np else out
 
     def _rmw_flat(self, codec, old_parity: np.ndarray,
                   delta: np.ndarray, a: int):

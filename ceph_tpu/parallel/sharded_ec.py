@@ -58,18 +58,21 @@ def make_data_mesh(n_devices: int | None = None) -> Mesh:
 
 
 def _gf_matmul_bits(w_i8: jnp.ndarray, data_u8: jnp.ndarray) -> jnp.ndarray:
-    """(8r,8k) x (k,N) -> (r,N); same math as ops.gf2kernels."""
-    k, n = data_u8.shape
-    d = data_u8.astype(jnp.int32)
-    planes = [((d >> s) & 1) for s in range(8)]
-    bits = jnp.stack(planes, axis=1).reshape(8 * k, n).astype(jnp.int8)
-    acc = jax.lax.dot_general(
-        w_i8, bits, (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.int32) & 1
-    r = w_i8.shape[0] // 8
-    b = acc.reshape(r, 8, n)
-    shifts = jnp.arange(8, dtype=jnp.int32).reshape(1, 8, 1)
-    return (b << shifts).sum(axis=1).astype(jnp.uint8)
+    """(8r,8k) x (k,N) -> (r,N); same math as ops.gf2kernels.  The
+    ``gf_encode`` scope names these operations in a device trace."""
+    with jax.named_scope("gf_encode"):
+        k, n = data_u8.shape
+        d = data_u8.astype(jnp.int32)
+        planes = [((d >> s) & 1) for s in range(8)]
+        bits = jnp.stack(planes, axis=1).reshape(8 * k, n).astype(
+            jnp.int8)
+        acc = jax.lax.dot_general(
+            w_i8, bits, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.int32) & 1
+        r = w_i8.shape[0] // 8
+        b = acc.reshape(r, 8, n)
+        shifts = jnp.arange(8, dtype=jnp.int32).reshape(1, 8, 1)
+        return (b << shifts).sum(axis=1).astype(jnp.uint8)
 
 
 def _sharded_gf_apply(mesh: Mesh, matrix: np.ndarray,

@@ -5,6 +5,8 @@ the full hop tree (src/common/tracer.h role).
 
 import asyncio
 
+import pytest
+
 from ceph_tpu.client.rados import Rados
 from ceph_tpu.common.tracing import all_spans, get_tracer
 from ceph_tpu.mon import Monitor
@@ -77,4 +79,68 @@ def test_trace_spans_cover_every_hop():
         for o in osds:
             await o.stop()
         await mon.stop()
+    run(main())
+
+
+def test_ec_write_spans_encode_under_do_op():
+    """An EC pool's write: ``ec.encode`` (submit to the batcher until
+    parity and checksums are back) and the primary's own ``store.txn``
+    hang off its ``osd.do_op``, and the op's stages, cut at the spans'
+    edges, add up to the client's root span.  The sub-writes to the
+    other shards carry no trace context: nothing reads a span there."""
+    from ceph_tpu.loadgen.cluster import SimCluster
+
+    async def main():
+        cluster = await SimCluster.create(4)
+        r = await Rados(cluster.addr, name="client.ectraced").connect()
+        try:
+            await r.mon_command(
+                "osd erasure-code-profile set",
+                {"name": "traced-prof", "profile": {
+                    "plugin": "tpu", "k": "2", "m": "1",
+                    "technique": "reed_sol_van"}})
+            await r.pool_create("ecp", pg_num=4, pool_type="erasure",
+                                erasure_code_profile="traced-prof")
+            io = await r.open_ioctx("ecp")
+            await io.write_full("ec-traced", b"shard me" * 2048)
+        finally:
+            await r.shutdown()
+            await cluster.stop()
+
+        roots = [s for s in get_tracer("client.ectraced").dump()
+                 if s["name"] == "client.osd_op"
+                 and s["tags"].get("oid") == "ec-traced"]
+        assert len(roots) == 1
+        root = roots[0]
+        spans = all_spans(root["trace_id"])
+        by_name: dict = {}
+        for s in spans:
+            by_name.setdefault(s["name"], []).append(s)
+            assert s["duration_ms"] is not None and s["duration_ms"] >= 0
+        assert {n: len(v) for n, v in by_name.items()} == {
+            "client.osd_op": 1, "osd.do_op": 1, "ec.encode": 1,
+            "store.txn": 1}
+        do_op, = by_name["osd.do_op"]
+        enc, = by_name["ec.encode"]
+        txn, = by_name["store.txn"]
+        assert do_op["parent_id"] == root["span_id"]
+        for s in (enc, txn):
+            assert s["parent_id"] == do_op["span_id"], s["name"]
+            assert s["daemon"] == do_op["daemon"]
+        assert len({s["daemon"] for s in spans}) == 2
+        # order in time, and the stages sum to the root
+        eps = 1e-6
+        assert root["start"] <= do_op["start"] + eps
+        assert do_op["start"] <= enc["start"] + eps
+        assert enc["end"] <= txn["start"] + eps
+        assert txn["end"] <= do_op["end"] + eps
+        assert do_op["end"] <= root["end"] + eps
+        stages = [do_op["start"] - root["start"],       # to the OSD
+                  enc["start"] - do_op["start"],        # before the encode
+                  enc["end"] - enc["start"],            # encode
+                  do_op["end"] - enc["end"],            # commit and reply
+                  root["end"] - do_op["end"]]           # reply to client
+        assert all(s >= -eps for s in stages)
+        assert sum(stages) * 1e3 == pytest.approx(root["duration_ms"],
+                                                  abs=1e-2)
     run(main())

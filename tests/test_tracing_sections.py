@@ -1,0 +1,207 @@
+"""``tracing.section``: what the loop's thread is doing, as host events
+of a profiler session.  A section is synchronous work: it never holds
+an ``await`` or a ``yield``, it is a shared no-op until jax is loaded,
+and its name starts with one of the layers the benchmark's metrics
+read.  Also the names the device programs carry in a trace, and the
+batcher's three wait counters.
+"""
+
+from __future__ import annotations
+
+import ast
+import asyncio
+import subprocess
+import sys
+from pathlib import Path
+
+import numpy as np
+import pytest
+
+from ceph_tpu.common import tracing
+
+ROOT = Path(__file__).resolve().parents[1]
+SECTIONED = sorted(
+    str(p.relative_to(ROOT)) for p in (ROOT / "ceph_tpu").rglob("*.py")
+    if "section(" in p.read_text() and p.name != "tracing.py")
+
+
+def _is_section(item: ast.withitem) -> bool:
+    call = item.context_expr
+    if not isinstance(call, ast.Call):
+        return False
+    fn = call.func
+    return (isinstance(fn, ast.Name) and fn.id == "section") or (
+        isinstance(fn, ast.Attribute) and fn.attr == "section")
+
+
+def section_faults(source: str) -> list[str]:
+    """Lines where a ``with section(...)`` holds an await or a yield,
+    is itself ``async with``, or carries a name outside the layers."""
+    faults = []
+    for node in ast.walk(ast.parse(source)):
+        if not isinstance(node, (ast.With, ast.AsyncWith)):
+            continue
+        items = [i for i in node.items if _is_section(i)]
+        if not items:
+            continue
+        if isinstance(node, ast.AsyncWith):
+            faults.append(f"{node.lineno}: async with section")
+        for item in items:
+            arg = item.context_expr.args[0]
+            if not (isinstance(arg, ast.Constant)
+                    and isinstance(arg.value, str)
+                    and arg.value.split(".")[0] in tracing.SECTION_LAYERS
+                    and "." in arg.value):
+                faults.append(f"{node.lineno}: section name "
+                              f"{ast.unparse(arg)}")
+        for stmt in node.body:
+            for inner in ast.walk(stmt):
+                if isinstance(inner, (ast.FunctionDef, ast.AsyncFunctionDef,
+                                      ast.Lambda)):
+                    faults.append(f"{inner.lineno}: a function defined "
+                                  f"inside a section")
+                if isinstance(inner, (ast.Await, ast.Yield, ast.YieldFrom,
+                                      ast.AsyncWith, ast.AsyncFor)):
+                    faults.append(f"{inner.lineno}: "
+                                  f"{type(inner).__name__} inside a section")
+    return faults
+
+
+def test_sections_are_placed():
+    assert len(SECTIONED) >= 8, SECTIONED
+
+
+@pytest.mark.parametrize("path", SECTIONED)
+def test_no_await_or_yield_inside_a_section(path):
+    assert section_faults((ROOT / path).read_text()) == []
+
+
+@pytest.mark.parametrize("body,fault", [
+    ("async def f():\n  with section('wire.x'):\n    await g()\n", "Await"),
+    ("def f():\n  with tracing.section('wire.x'):\n    yield 1\n", "Yield"),
+    ("async def f():\n  async with section('wire.x'):\n    pass\n",
+     "async with"),
+    ("def f():\n  with section('nolayer.x'):\n    pass\n", "section name"),
+    ("def f():\n  with section(name):\n    pass\n", "section name"),
+])
+def test_the_section_check_finds_what_it_looks_for(body, fault):
+    assert any(fault in f for f in section_faults(body))
+
+
+def test_section_is_a_shared_noop_until_jax_is_loaded():
+    """In a fresh interpreter: the same no-op object while ``jax`` is
+    absent from ``sys.modules`` (and importing the tracing module does
+    not load it), a profiler annotation after."""
+    code = (
+        "import sys\n"
+        "from ceph_tpu.common import tracing\n"
+        "assert 'jax' not in sys.modules\n"
+        "a, b = tracing.section('wire.x'), tracing.section('client.y')\n"
+        "assert a is b is tracing._NO_SECTION\n"
+        "with a:\n    pass\n"
+        "assert 'jax' not in sys.modules\n"
+        "import jax\n"
+        "c = tracing.section('wire.x')\n"
+        "assert isinstance(c, jax.profiler.TraceAnnotation), c\n"
+        "with c:\n    pass\n"
+        "print('ok')\n")
+    out = subprocess.run([sys.executable, "-c", code], cwd=ROOT,
+                         capture_output=True, text=True, timeout=120,
+                         env={"JAX_PLATFORMS": "cpu", "PATH": "/usr/bin:/bin",
+                              "PYTHONPATH": str(ROOT)})
+    assert out.returncode == 0 and out.stdout.strip() == "ok", out.stderr
+
+
+def test_spans_keep_their_dump_and_take_ids_from_a_counter():
+    t = tracing.Tracer("osd.77")
+    root = t.start("client.osd_op", oid="o").activate()
+    kid = tracing.child_span("ec.encode")
+    assert tracing.child_span("x") is not None
+    kid.finish()
+    root.finish()
+    assert tracing.child_span("x") is None          # no op trace active
+    tracing.finish(None)
+    d_root, d_kid = root.to_dict(), kid.to_dict()
+    assert set(d_kid) == {"trace_id", "span_id", "parent_id", "name",
+                          "daemon", "start", "end", "duration_ms", "tags"}
+    assert isinstance(root.start, int) and isinstance(root.end, int)
+    assert d_kid["parent_id"] == d_root["span_id"]
+    assert d_kid["trace_id"] == d_root["trace_id"]
+    assert d_kid["span_id"].startswith("osd.77.")
+    assert len({d_root["span_id"], d_kid["span_id"], d_root["trace_id"]}) == 3
+    assert d_root["duration_ms"] == (root.end - root.start) / 1e6 >= 0
+    assert d_root["end"] - d_root["start"] == pytest.approx(
+        d_root["duration_ms"] / 1e3, abs=1e-5)
+    assert [s["name"] for s in t.dump()] == ["ec.encode", "client.osd_op"]
+    # two tracers of one name (two processes) do not share ids
+    assert tracing.Tracer("osd.77").next_id() != tracing.Tracer(
+        "osd.77").next_id()
+
+
+def test_batcher_counts_queue_overlap_and_materialize_microseconds():
+    from ceph_tpu.common.perf import PerfCounters
+    from ceph_tpu.ec import registry
+    from ceph_tpu.osd.codec_batcher import CodecBatcher
+
+    codec = registry().factory("tpu", {"k": "2", "m": "1",
+                                       "technique": "reed_sol_van"})
+    perf = PerfCounters("ec_batch")
+    batcher = CodecBatcher(perf=perf)
+    rng = np.random.default_rng(5)
+
+    async def main():
+        await asyncio.gather(*(
+            batcher.encode(codec, rng.integers(0, 256, (2, 2, 256),
+                                               np.uint8), with_crc=True)
+            for _ in range(3)))
+        batcher.close()
+
+    asyncio.run(main())
+    dump = perf.dump()
+    assert dump["batches"] >= 1
+    for key in ("queue_wait_us", "overlap_us", "materialize_us"):
+        assert isinstance(dump[key], int) and dump[key] >= 0, key
+    assert dump["materialize_us"] > 0
+
+
+@pytest.mark.parametrize("call,name", [
+    ("encode_crc", "jit_ec_encode_crc"),
+    ("encode", "jit_ec_encode"),
+    ("decode", "jit_ec_decode"),
+    ("rmw", "jit_ec_rmw"),
+])
+def test_mesh_programs_carry_their_own_names(call, name):
+    import jax
+    import jax.numpy as jnp
+    from ceph_tpu.parallel import mesh_codec as mc
+
+    mesh = mc._shared_mesh(1)
+    w = jnp.zeros((8, 16), jnp.int8)
+    data = jnp.zeros((2, 2, 64), jnp.uint8)
+    if call == "rmw":
+        fn = mc._compiled_rmw(mesh, 2, 1, 2, 64, False)
+        text = fn.lower(w, jnp.zeros((2, 1, 64), jnp.uint8),
+                        data).as_text(debug_info=True)
+    else:
+        fn = mc._compiled_apply(mesh, "ec_" + call.split("_")[0], 2, 2, 64,
+                                call.endswith("_crc"), False)
+        text = fn.lower(w, data).as_text(debug_info=True)
+    assert f"module @{name} " in text
+    assert "gf_encode" in text
+    assert ("crc32c" in text) == call.endswith("_crc")
+    del jax
+
+
+def test_crush_program_carries_its_scopes():
+    import jax
+    import jax.numpy as jnp
+    from ceph_tpu.crush.builder import build_two_level_map
+    from ceph_tpu.crush.vectorized import VectorCrush
+
+    vc = VectorCrush(build_two_level_map(4, 2), 0)
+    with jax.enable_x64(True):
+        text = vc.crush_firstn.lower(
+            vc, jnp.arange(8, dtype=jnp.int32), 2,
+            jnp.full((8,), 0x10000, jnp.int32)).as_text(debug_info=True)
+    assert "module @jit_crush_firstn " in text
+    assert "straw2_draw" in text

@@ -24,10 +24,14 @@ amortized, the XOR/CRC side-path dominates):
   CRCs from a device launch fold into whole-shard CRCs without
   re-reading a byte.
 
-* ``crc32c_device_chunks``: the JAX kernel variant.  The codec batcher
-  feeds it the same (B, k, L) tensors the encode/decode launch just
-  touched, so shard CRCs come back from the device round trip that
-  produced the parity -- no host re-scan.
+* ``crc32c_device_chunks``: the JAX kernel variant, the same algebra
+  on the device: every 512-byte segment's register is one GF(2)
+  bit-matmul of the codec's own family, a log-depth fold of M^width
+  matmuls combines the segments, the seed enters as one constant --
+  no loop over bytes, no table.  The codec batcher feeds it the same
+  (B, k, L) tensors the encode/decode launch just touched, so shard
+  CRCs come back from the device round trip that produced the parity
+  -- no host re-scan.
 
 Observability: the module-global ``PERF`` ("integrity") counts batched
 vs scalar calls, bytes hashed and fused-launch hits; ``native.crc32c``
@@ -414,39 +418,93 @@ def fused_enabled() -> bool:
     return not os.environ.get("CEPH_TPU_NO_FUSED_CRC")
 
 
+# Bytes a row's segment holds: the segment matrix is (8 * _SEG, 32),
+# 128 KiB as int8, and a contraction of 4096 fills the MXU's depth.
+_SEG = 512
+# Segment rows the program unpacks at a time: 8 bit planes of 65536 x
+# 512 bytes are 256 MiB, so a launch's temporary is bounded whatever
+# the buffer (the store's 1408 x 4096 launch is 11264 segment rows:
+# one block, no loop).
+_ROW_BLOCK = 65536
+
+
+@functools.lru_cache(maxsize=1)
+def _segment_matrix() -> np.ndarray:
+    """(8, _SEG, 32) 0/1 int8: entry [b, p] holds the bits of the
+    zero-seed register of a _SEG-byte segment in which only bit b of
+    byte p is set.  That register depends only on the byte's distance
+    from the segment's end, so a shorter segment's matrix is the tail
+    ``[:, _SEG - seg:]`` of this one."""
+    t0 = _tables()[0]
+    regs = np.zeros((8, _SEG), np.uint32)
+    r = t0[1 << np.arange(8)]            # the byte itself, then zeros
+    for p in range(_SEG - 1, -1, -1):
+        regs[:, p] = r
+        r = (r >> np.uint32(8)) ^ t0[r & 0xFF]
+    return _reg_bits(regs)
+
+
+def _reg_bits(regs: np.ndarray) -> np.ndarray:
+    """(...,) uint32 registers -> (..., 32) 0/1 int8, bit j at [j]."""
+    return ((regs[..., None] >> np.arange(32, dtype=np.uint32))
+            & 1).astype(np.int8)
+
+
 @functools.lru_cache(maxsize=64)
 def _crc_chunks_compiled(l: int):
     """Jitted (N, l) uint8 -> (N,) uint32 chunk CRCs (default seed),
-    slice-by-8 fori_loop over the lane axis."""
+    data-parallel over bytes: CRC32C is linear over GF(2), so
+
+    * a row front-pads with zero bytes to S segments of ``seg`` bytes,
+      S a power of two (leading zeros leave a zero-seed register as
+      it is);
+    * every segment's zero-seed register is ONE bit-matmul, (rows * S,
+      8 * seg) bit planes x the (8 * seg, 32) segment matrix, summed
+      exactly in int32 and reduced mod 2 afterwards;
+    * log2(S) fold levels combine halves, ``M^width . left ^ right``,
+      each a (.., 32) x (32, 32) bit-matmul;
+    * the seed enters once, as the constant ``M^l . SEED``.
+
+    Every ``l`` and every platform runs this one formulation; S and
+    the row block derive from the shape."""
     import jax
     import jax.numpy as jnp
-    # host constant staged per trace: device-caching the tables here
-    # would capture a tracer when the first call happens inside an
-    # outer trace (the MeshCodec fused launch) and poison the cache
-    tnp = _tables()
-    n8 = l // 8
+    seg = min(_SEG, _next_pow2(l))
+    s = _next_pow2(-(-l // seg))
+    # host constants staged per trace: device-caching them here would
+    # capture a tracer when the first call happens inside an outer
+    # trace (the MeshCodec fused launch) and poison the cache
+    wnp = _segment_matrix()[:, _SEG - seg:].reshape(8 * seg, 32)
+    # level i folds halves of (s >> i) segments each
+    folds = [_reg_bits(_zeros_matrix((s >> i) * seg))
+             for i in range(1, s.bit_length())]
+    seed_term = np.uint32(crc32c_zeros(SEED, l))
+    shifts = np.arange(8, dtype=np.uint8).reshape(8, 1)
 
     def fn(x):
-        t = jnp.asarray(tnp)
-        crc = jnp.full((x.shape[0],), SEED, jnp.uint32)
-        xu = x.astype(jnp.uint32)
+        n = x.shape[0]
 
-        def body8(j, crc):
-            b = jax.lax.dynamic_slice_in_dim(xu, 8 * j, 8, axis=1)
-            lo = (crc ^ b[:, 0] ^ (b[:, 1] << 8)
-                  ^ (b[:, 2] << 16) ^ (b[:, 3] << 24))
-            hi = (b[:, 4] ^ (b[:, 5] << 8)
-                  ^ (b[:, 6] << 16) ^ (b[:, 7] << 24))
-            return (t[7][lo & 0xFF] ^ t[6][(lo >> 8) & 0xFF]
-                    ^ t[5][(lo >> 16) & 0xFF] ^ t[4][lo >> 24]
-                    ^ t[3][hi & 0xFF] ^ t[2][(hi >> 8) & 0xFF]
-                    ^ t[1][(hi >> 16) & 0xFF] ^ t[0][hi >> 24])
+        def segment_bits(row):
+            """(seg,) uint8 -> (32,) int32 0/1 register bits."""
+            planes = ((row[None, :] >> shifts) & 1).astype(jnp.int8)
+            return jnp.dot(planes.reshape(8 * seg), wnp,
+                           preferred_element_type=jnp.int32) & 1
 
-        if n8:
-            crc = jax.lax.fori_loop(0, n8, body8, crc)
-        for j in range(8 * n8, l):       # static tail, < 8 steps
-            crc = t[0][(crc ^ xu[:, j]) & 0xFF] ^ (crc >> 8)
-        return crc
+        rows = jnp.pad(x, ((0, 0), (s * seg - l, 0))).reshape(
+            n * s, seg)
+        # one matmul over all rows up to _ROW_BLOCK of them, a loop
+        # over such blocks beyond
+        bits = jax.lax.map(segment_bits, rows, batch_size=_ROW_BLOCK)
+        bits = bits.reshape(n, s, 32)
+        for mat in folds:
+            left, right = jnp.split(bits, 2, axis=1)
+            bits = (jnp.dot(left.astype(jnp.int8), mat,
+                            preferred_element_type=jnp.int32)
+                    ^ right) & 1
+        crc = (bits[:, 0].astype(jnp.uint32)
+               << jnp.arange(32, dtype=jnp.uint32)).sum(
+                   axis=1, dtype=jnp.uint32)
+        return crc ^ seed_term
 
     # a name of its own in a device trace, alone or inlined
     fn.__name__ = fn.__qualname__ = "crc32c_chunks"
@@ -499,8 +557,9 @@ def crc32c_resident(buf) -> int:
     n = arr.size
     if n == 0:
         return SEED
-    # up to ~256 parallel lanes; the fold is log-free (linear scan of
-    # few chunk registers), so chunk count stays small
+    # up to 256 chunks: the device kernel is parallel over every byte
+    # of every chunk, so the chunk count does not set its speed; it
+    # bounds the host fold below, a linear scan of the chunk registers
     chunk = max(64, _next_pow2(-(-n // 256)))
     pad = (-n) % chunk
     if pad:

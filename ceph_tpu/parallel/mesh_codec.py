@@ -21,7 +21,10 @@ Shape of the thing:
     lesson as the VectorCrush digest cache);
   * the fused CRC32C side-path (ops/crc32c_batch.crc32c_chunks_traced)
     rides inside the same jitted program, so chunk checksums come back
-    from the one device round trip that produced the parity;
+    from the one device round trip that produced the parity; it is a
+    bit-matmul like the encode itself (segment registers, then a
+    log-depth GF(2) fold), a small part of the launch, not a serial
+    walk over the chunk's bytes;
   * stripe buffers are DONATED (``donate_argnums``): the launch owns
     the device copy of the input batch -- callers must never read it
     again (the donated-buffer-aliasing lint rule), XLA may free or

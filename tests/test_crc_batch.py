@@ -138,13 +138,121 @@ def test_fold_chunk_crcs_equals_whole_buffer():
 
 # -- device kernel / fused encode+CRC ---------------------------------------
 
-def test_device_chunk_crcs_match_scalar():
-    rng = np.random.default_rng(7)
-    for l in (0, 1, 7, 8, 100, 776):
-        x = rng.integers(0, 256, size=(6, l), dtype=np.uint8)
+@pytest.mark.parametrize("l", (0, 1, 7, 8, 100, 511, 512, 513, 776,
+                               4096, 5000, 131072))
+def test_device_chunk_crcs_match_scalar(l):
+    rng = np.random.default_rng(7 + l)
+    x = rng.integers(0, 256, size=(6, l), dtype=np.uint8)
+    got = np.asarray(cb.crc32c_device_chunks(x))
+    assert got.shape == (6,) and got.dtype == np.uint32
+    for i in range(6):
+        assert int(got[i]) == native.crc32c(x[i].tobytes()), (l, i)
+
+
+def test_device_chunk_crcs_keep_leading_axes_and_extremes():
+    """(..., L) in, (...,) out; all-zero and all-ones rows are where a
+    wrong seed term or a dropped leading segment would show."""
+    x = np.zeros((2, 3, 1000), np.uint8)
+    x[1] = 0xFF
+    x[0, 2, -1] = 1
+    got = np.asarray(cb.crc32c_device_chunks(x))
+    assert got.shape == (2, 3)
+    for i in range(2):
+        for j in range(3):
+            assert int(got[i, j]) == native.crc32c(x[i, j].tobytes())
+
+
+def test_device_chunk_crcs_blocked_rows_match_unblocked(monkeypatch):
+    """More segment rows than a block holds: the program maps over
+    blocks (a ragged last block included) and gives the same CRCs."""
+    rng = np.random.default_rng(70)
+    x = rng.integers(0, 256, size=(5, 3000), dtype=np.uint8)
+    want = cb.crc32c_rows(x)
+    monkeypatch.setattr(cb, "_ROW_BLOCK", 16)    # 5 rows x 8 segments
+    cb._crc_chunks_compiled.cache_clear()
+    try:
         got = np.asarray(cb.crc32c_device_chunks(x))
-        for i in range(6):
-            assert int(got[i]) == native.crc32c(x[i].tobytes()), l
+    finally:
+        cb._crc_chunks_compiled.cache_clear()
+    assert np.array_equal(got, want)
+
+
+def test_device_kernel_has_no_loop_over_bytes():
+    """The store's launch shape (128 stripes of k=8,m=3 at 4096 bytes)
+    compiles to straight-line matmuls: no ``while`` in the jaxpr or in
+    the compiled program, so the serial table walk cannot come back
+    unnoticed."""
+    import jax
+    fn = cb._crc_chunks_compiled(4096)
+    x = jax.ShapeDtypeStruct((1408, 4096), np.uint8)
+
+    def primitives(jaxpr):
+        for eqn in jaxpr.eqns:
+            yield eqn.primitive.name
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                yield from primitives(sub)
+
+    names = set(primitives(jax.make_jaxpr(fn)(x).jaxpr))
+    assert "dot_general" in names
+    assert not names & {"while", "scan", "gather", "dynamic_slice"}, names
+    assert "while" not in fn.lower(x).compile().as_text()
+
+
+def test_segment_matrix_rows_are_single_bit_registers():
+    """Row (b, p) of the segment matrix is the zero-seed register of a
+    segment whose only set bit is bit b of byte p."""
+    w = cb._segment_matrix()
+    assert w.shape == (8, cb._SEG, 32) and w.dtype == np.int8
+    for b, p in ((0, 0), (7, 0), (3, 200), (0, cb._SEG - 1),
+                 (7, cb._SEG - 1)):
+        seg = bytearray(cb._SEG)
+        seg[p] = 1 << b
+        reg = native.crc32c(bytes(seg), 0)
+        assert int((w[b, p].astype(np.uint32)
+                    << np.arange(32, dtype=np.uint32)).sum()) == reg
+
+
+STORE_SHAPES = [
+    # (k, m, technique, stripe rows written, rows launched)
+    ("8", "3", "reed_sol_van", 128, 128),     # rs_k8m3, 4 MiB object
+    ("10", "4", "cauchy", 103, 128),          # cauchy_k10m4: 103 -> 128
+]
+
+
+@pytest.mark.parametrize("k,m,technique,rows,launched", STORE_SHAPES)
+def test_mesh_encode_with_crc_store_shapes_match_host_rehash(
+        k, m, technique, rows, launched):
+    """The benchmark cells' launch shapes at real width through
+    MeshCodec.encode(with_crc=True): every chunk CRC equals a host
+    re-hash of the emitted shard bytes, padding rows included."""
+    from ceph_tpu.ec import registry
+    from ceph_tpu.parallel.mesh_codec import MeshCodec
+    codec = registry().factory("tpu", {"k": k, "m": m,
+                                       "technique": technique})
+    ki, mi = int(k), int(m)
+    rng = np.random.default_rng(14)
+    data = np.zeros((launched, ki, 4096), np.uint8)
+    data[:rows] = rng.integers(0, 256, (rows, ki, 4096), dtype=np.uint8)
+    parity, crcs = MeshCodec().encode(codec, data.copy(), with_crc=True)
+    assert crcs.shape == (launched, ki + mi)
+    assert np.array_equal(
+        parity, np.asarray(codec.encode_batch(data, out_np=True)))
+    full = np.concatenate([data, parity], axis=1)
+    want = cb.crc32c_rows(full.reshape(-1, 4096))
+    assert np.array_equal(np.asarray(crcs, np.uint32).reshape(-1), want)
+    assert int(crcs[0, 0]) == native.crc32c(data[0, 0].tobytes())
+
+
+@pytest.mark.parametrize("n", (0, 1, 63, 64, 1000, 64 * 256 + 17,
+                               1_000_003))
+def test_crc32c_resident_matches_scalar_on_ragged_buffers(n):
+    """Whole-buffer CRC of a resident shard: buffers that are not a
+    multiple of the chunk the launch splits them into."""
+    rng = np.random.default_rng(15)
+    buf = rng.integers(0, 256, n, dtype=np.uint8)
+    assert cb.crc32c_resident(buf) == native.crc32c(buf.tobytes())
+    assert cb.crc32c_resident(buf.tobytes()) == native.crc32c(
+        buf.tobytes())
 
 
 def test_fused_encode_crc_byte_identity_vs_host_recompute():

@@ -34,8 +34,8 @@ from collections import deque
 
 # the host layers a section name starts with ("wire.decode"); the
 # benchmark's per-layer metrics read these prefixes letter for letter
-SECTION_LAYERS = ("client", "wire", "osd_op", "store", "batcher",
-                  "device_wait")
+SECTION_LAYERS = ("client", "wire", "osd_op", "osd_read", "store",
+                  "batcher", "device_wait")
 
 
 class _NoSection:

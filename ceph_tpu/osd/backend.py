@@ -548,16 +548,18 @@ class ECBackend(PGBackend):
                     else e.buf[rng[0]:rng[0] + rng[1]]
                 return buf, e.size, e.ver, e.shard, e.crc, True
         off, length = rng if rng else (0, None)
-        try:
-            raw = self.store.read(self.coll, oid, off, length)
-        except FileNotFoundError:
-            raw = b""
-        sx = self.store.getattr(self.coll, oid, SIZE_XATTR)
-        size = int(sx) if sx else 0
-        ver = ver_decode(self.store.getattr(self.coll, oid, VER_XATTR))
-        label = self.shard_label(oid)
-        crc_raw = self.store.getattr(self.coll, oid, CRC_XATTR)
-        crc = int(crc_raw) if crc_raw is not None else None
+        with tracing.section("store.read"):
+            try:
+                raw = self.store.read(self.coll, oid, off, length)
+            except FileNotFoundError:
+                raw = b""
+            sx = self.store.getattr(self.coll, oid, SIZE_XATTR)
+            size = int(sx) if sx else 0
+            ver = ver_decode(self.store.getattr(self.coll, oid,
+                                                VER_XATTR))
+            label = self.shard_label(oid)
+            crc_raw = self.store.getattr(self.coll, oid, CRC_XATTR)
+            crc = int(crc_raw) if crc_raw is not None else None
         buf = np.frombuffer(raw, np.uint8)
         if cache is not None:
             cache.note_host_read(len(raw))
@@ -593,8 +595,22 @@ class ECBackend(PGBackend):
 
     def _admit_entries(self, entries: list[tuple],
                        rng: tuple[int, int] | None,
-                       out: dict, failed: set,
-                       relabeled: dict) -> set[int]:
+                       out: dict, failed: set, relabeled: dict,
+                       span=None) -> set[int]:
+        """``_verify_entries`` for one batch of a gather; under the
+        ``osd_read.verify`` section when the gather serves a client
+        read (``span`` is its ``ec.gather`` span)."""
+        if span is None:
+            return self._verify_entries(entries, rng, out, failed,
+                                        relabeled)
+        with tracing.section("osd_read.verify"):
+            return self._verify_entries(entries, rng, out, failed,
+                                        relabeled)
+
+    def _verify_entries(self, entries: list[tuple],
+                        rng: tuple[int, int] | None,
+                        out: dict, failed: set,
+                        relabeled: dict) -> set[int]:
         """Verify one batch of gathered entries into the caller's
         (out, failed, relabeled) state; returns the accepted shards.
 
@@ -642,7 +658,8 @@ class ECBackend(PGBackend):
                             timeout: float = 10.0, *,
                             want: set[int] | None = None,
                             have: frozenset = frozenset(),
-                            rejected: frozenset = frozenset()
+                            rejected: frozenset = frozenset(),
+                            span=None
                             ) -> tuple[dict, set[int], dict]:
         """Fetch several shards' (buf, size, ver) in ONE parallel pass
         (the hot read path: serial round trips would multiply latency
@@ -684,23 +701,23 @@ class ECBackend(PGBackend):
                 entries.append((s, label, crc, buf, size, ver, cached))
             else:
                 remote.append(s)
-        self._admit_entries(entries, rng, out, failed, relabeled)
+        self._admit_entries(entries, rng, out, failed, relabeled, span)
         if not remote:
             return out, failed, relabeled
         hedger = self.hedger
         if want is not None and hedger is not None and hedger.enabled:
             await self._fetch_remote_hedged(
                 oid, remote, avail, rng, timeout, set(want),
-                set(have), set(rejected), out, failed, relabeled)
+                set(have), set(rejected), out, failed, relabeled, span)
         else:
             await self._fetch_remote_fanout(
                 oid, remote, avail, rng, timeout, out, failed,
-                relabeled)
+                relabeled, span)
         return out, failed, relabeled
 
     async def _fetch_remote_fanout(self, oid, remote, avail, rng,
                                    timeout, out, failed,
-                                   relabeled) -> None:
+                                   relabeled, span=None) -> None:
         """Legacy fixed fan-out: one parallel wait for every reply."""
         payload = {"pgid": self.pg.pgid, "oid": oid}
         if rng is not None:
@@ -723,13 +740,14 @@ class ECBackend(PGBackend):
             if e[0] is None or e[0] not in remote:
                 continue
             entries.append(e)
-        self._admit_entries(entries, rng, out, failed, relabeled)
+        self._admit_entries(entries, rng, out, failed, relabeled, span)
         failed |= {s for s in remote
                    if s not in out and s not in failed}
 
     async def _fetch_remote_hedged(self, oid, remote, avail, rng,
                                    timeout, want, have, rejected,
-                                   out, failed, relabeled) -> None:
+                                   out, failed, relabeled,
+                                   span=None) -> None:
         """First-k-of-(k+h) remote gather through the OSD's
         HedgedGather engine.
 
@@ -769,7 +787,7 @@ class ECBackend(PGBackend):
         def flush():
             if pending_entries:
                 self._admit_entries(pending_entries, rng, out, failed,
-                                    relabeled)
+                                    relabeled, span)
                 pending_entries.clear()
 
         def sufficient():
@@ -811,6 +829,8 @@ class ECBackend(PGBackend):
             hedge_pool=pool, choose_extras=choose_extras,
             timeout=timeout)
         flush()
+        if span is not None:
+            span.tags["hedged"] += len(outcome.hedged)
         if not outcome.completed:
             # sources that never answered (and were still needed) are
             # failures for the caller's re-plan; cancelled sub-reads
@@ -823,7 +843,8 @@ class ECBackend(PGBackend):
     async def _gather_shards(self, oid: str,
                              need_shards: set[int] | None = None,
                              rng: tuple[int, int] | None = None,
-                             exclude: set[int] | None = None
+                             exclude: set[int] | None = None,
+                             served: bool = False
                              ) -> tuple[dict[int, np.ndarray], int]:
         """Read enough CONSISTENT shard buffers to decode.
 
@@ -834,7 +855,23 @@ class ECBackend(PGBackend):
         Every shard write stamps VER_XATTR; here only shards carrying the
         newest version seen participate, and minimum_to_decode is re-run
         over the survivors when a shard is rejected.
+
+        ``served`` marks the gather of a client read: it runs under an
+        ``ec.gather`` span of the op (first sub-read issued until a
+        verified sufficient set is in hand; tags: sub-reads asked for,
+        of which hedges, shards rejected), and its verify passes under
+        the ``osd_read.verify`` section.  A write's look at the old
+        content and recovery's gathers carry neither.
         """
+        span = tracing.child_span("ec.gather", oid=oid, asked=0, hedged=0,
+                                  rejected=0) if served else None
+        try:
+            return await self._gather_rounds(oid, need_shards, rng,
+                                             exclude, span)
+        finally:
+            tracing.finish(span)
+
+    async def _gather_rounds(self, oid, need_shards, rng, exclude, span):
         acting = self.pg.acting
         avail: dict[int, int] = {}           # shard -> osd
         for shard, osd in enumerate(acting):
@@ -868,13 +905,17 @@ class ECBackend(PGBackend):
                               if s in avail)
             got, failed, relabeled = await self._fetch_shards(
                 oid, to_fetch, avail, rng, timeout, want=want,
-                have=frozenset(fetched), rejected=frozenset(rejected))
+                have=frozenset(fetched), rejected=frozenset(rejected),
+                span=span)
             fetched.update(got)
             for label, item in relabeled.items():
                 # direct position-keyed fetches take precedence over
                 # salvage; salvage never overwrites either
                 fetched.setdefault(label, item)
             rejected |= failed
+            if span is not None:
+                span.tags["asked"] += len(to_fetch)
+                span.tags["rejected"] = len(rejected)
             # decodable from what's in hand?  A hedged fetch may have
             # completed with a DIFFERENT sufficient set than the
             # pre-fetch plan (the late-set switch), so re-plan over the
@@ -922,15 +963,34 @@ class ECBackend(PGBackend):
             f"EIO {oid}: no consistent shard set "
             f"(rejected {sorted(rejected)})")
 
-    async def _read_logical(self, oid: str) -> bytes:
-        bufs, size, _ = await self._gather_shards(oid)
+    async def _read_data_shards(self, oid: str, served: bool = False
+                                ) -> tuple[dict | None, int]:
+        """The object's k data shard buffers, gathered and (where a
+        data shard is lost) reconstructed, with its logical size; None
+        for an object with no content.  ``served`` as in
+        ``_gather_shards``; a served reconstruction also runs under an
+        ``ec.decode`` span (submit to the batcher until the recovered
+        chunks are back), which a plain read does not have."""
+        bufs, size, _ = await self._gather_shards(oid, served=served)
         if not bufs or not any(len(b) for b in bufs.values()):
-            return b""
-        if not set(self.sinfo.data_positions(self.codec)) <= set(bufs):
+            return None, 0
+        dpos = set(self.sinfo.data_positions(self.codec))
+        span = None
+        if not dpos <= set(bufs):
             self._count("reconstructions")   # decode fills a data shard
-        data = await self.sinfo.reconstruct_logical_async(
-            self.codec, bufs, batcher=self.batcher)
-        return data[:size]
+            if served:
+                span = tracing.child_span("ec.decode", oid=oid)
+        try:
+            return await self.sinfo.decode_async(
+                self.codec, bufs, want=dpos, batcher=self.batcher), size
+        finally:
+            tracing.finish(span)
+
+    async def _read_logical(self, oid: str) -> bytes:
+        shards, size = await self._read_data_shards(oid)
+        if shards is None:
+            return b""
+        return self.sinfo.interleave_logical(self.codec, shards)[:size]
 
     async def collect_shard_states(self, oid: str
                                    ) -> tuple[list[tuple], int]:
@@ -1491,10 +1551,15 @@ class ECBackend(PGBackend):
 
     # -- read path ----------------------------------------------------------
     async def object_read(self, oid, off, length) -> bytes:
-        data = await self._read_logical(oid)
-        if length is None:
-            return data[off:]
-        return data[off:off + length]
+        shards, size = await self._read_data_shards(oid, served=True)
+        if shards is None:
+            return b""
+        with tracing.section("osd_read.assemble"):
+            data = self.sinfo.interleave_logical(self.codec,
+                                                  shards)[:size]
+            if length is None:
+                return data[off:]
+            return data[off:off + length]
 
     async def object_size(self, oid) -> int:
         sx = self.store.getattr(self.coll, oid, SIZE_XATTR)

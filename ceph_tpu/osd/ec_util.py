@@ -279,7 +279,7 @@ class StripeInfo:
         data_shards = await self.decode_async(codec, shard_bufs,
                                               want=set(dpos),
                                               batcher=batcher)
-        return self._interleave_logical(codec, data_shards)
+        return self.interleave_logical(codec, data_shards)
 
     @staticmethod
     def data_positions(codec) -> list[int]:
@@ -330,10 +330,10 @@ class StripeInfo:
         """Rebuild the logical byte stream from shard buffers."""
         dpos = self.data_positions(codec)
         data_shards = self.decode(codec, shard_bufs, want=set(dpos))
-        return self._interleave_logical(codec, data_shards)
+        return self.interleave_logical(codec, data_shards)
 
-    def _interleave_logical(self, codec,
-                            data_shards: Mapping[int, np.ndarray]) -> bytes:
+    def interleave_logical(self, codec,
+                           data_shards: Mapping[int, np.ndarray]) -> bytes:
         dpos = self.data_positions(codec)
         shard_len = len(next(iter(data_shards.values())))
         n_stripes = shard_len // self.chunk_size

@@ -1516,28 +1516,30 @@ class OSD:
                 await conn.send(Message("ec_subop_read_reply", data,
                                         segments=[buf]))
                 return
-            try:
-                buf = self.store.read(pg.coll, oid, off, length)
-            except FileNotFoundError:
-                buf = b""
             from .backend import (CRC_XATTR, SIZE_XATTR, VER_XATTR,
                                   ver_decode)
-            sx = self.store.getattr(pg.coll, oid, SIZE_XATTR)
-            data["size"] = int(sx) if sx else 0
-            data["ver"] = list(ver_decode(
-                self.store.getattr(pg.coll, oid, VER_XATTR)))
-            # report the WRITE-TIME identity of the stored bytes (per-
-            # object pin, PG pin fallback), NOT the current acting-set
-            # index: after a re-peer the index is a claim about where
-            # shards SHOULD live; the label is what these bytes ARE.
-            # The reader rejects a mismatch instead of decoding garbage.
-            label = pg.backend.shard_label(oid) \
-                if hasattr(pg.backend, "shard_label") else None
-            if label is not None:
-                data["shard"] = int(label)
-            crc = self.store.getattr(pg.coll, oid, CRC_XATTR)
-            if crc is not None:
-                data["crc"] = int(crc)
+            with section("store.read"):
+                try:
+                    buf = self.store.read(pg.coll, oid, off, length)
+                except FileNotFoundError:
+                    buf = b""
+                sx = self.store.getattr(pg.coll, oid, SIZE_XATTR)
+                data["size"] = int(sx) if sx else 0
+                data["ver"] = list(ver_decode(
+                    self.store.getattr(pg.coll, oid, VER_XATTR)))
+                # report the WRITE-TIME identity of the stored bytes
+                # (per-object pin, PG pin fallback), NOT the current
+                # acting-set index: after a re-peer the index is a
+                # claim about where shards SHOULD live; the label is
+                # what these bytes ARE.  The reader rejects a mismatch
+                # instead of decoding garbage.
+                label = pg.backend.shard_label(oid) \
+                    if hasattr(pg.backend, "shard_label") else None
+                if label is not None:
+                    data["shard"] = int(label)
+                crc = self.store.getattr(pg.coll, oid, CRC_XATTR)
+                if crc is not None:
+                    data["crc"] = int(crc)
             if self.shard_cache is not None:
                 self.shard_cache.note_host_read(len(buf))
                 if length is None and off == 0 and (buf or data["size"]):

@@ -85,6 +85,12 @@ def _w_device(mesh: Mesh, mat_bytes: bytes, r: int, k: int):
                           NamedSharding(mesh, P(None, None)))
 
 
+# The decode's program name.  Not ``ec_decode``: compile caches hold an
+# executable of that name built before the ``gf_decode`` scope existed,
+# and their keys ignore scope metadata, so the old name would load it.
+DECODE_PROGRAM = "ec_decode_rows"
+
+
 def _jit_as(name: str, fn, donate_argnums: tuple):
     """``jax.jit`` under a program name of its own: the profiler's
     trace and the compile cache call the program ``jit_<name>``, so
@@ -101,12 +107,12 @@ def _host(out):
         return np.asarray(out)
 
 
-def _stripe_block(w_local, chunks):
+def _stripe_block(w_local, chunks, scope: str = "gf_encode"):
     """Per-device block: my slice of the stripe batch through the GF
     bit-matmul.  No collective -- stripes are independent."""
     bl, kk, ll = chunks.shape
     flat = chunks.transpose(1, 0, 2).reshape(kk, bl * ll)
-    rows = _gf_matmul_bits(w_local, flat)
+    rows = _gf_matmul_bits(w_local, flat, scope)
     return rows.reshape(-1, bl, ll).transpose(1, 0, 2)
 
 
@@ -117,11 +123,13 @@ def _compiled_apply(mesh: Mesh, name: str, b: int, k: int, lane: int,
     CRCs].  The batch axis shards over 'stripe'; W replicates.  The
     stripe buffer (arg 1) is donated -- consumed by the launch, never
     read again (the donated-buffer-aliasing lint rule guards callers).
-    ``name`` (``ec_encode``, ``ec_decode``) is the program's name: the
-    same matrix product compiles once per caller's kind.
+    ``name`` (``ec_encode``, ``DECODE_PROGRAM``) is the program's name:
+    the same matrix product compiles once per caller's kind, and a
+    decode's matmul sits under the ``gf_decode`` scope.
     """
+    scope = "gf_decode" if name == DECODE_PROGRAM else "gf_encode"
     sharded = shard_map(
-        _stripe_block, mesh=mesh,
+        functools.partial(_stripe_block, scope=scope), mesh=mesh,
         in_specs=(P(None, None), P("stripe", None, None)),
         out_specs=P("stripe", None, None))
     if not with_crc:
@@ -396,7 +404,7 @@ class MeshCodec:
             matrix = codec.decode_flat_matrix(list(erasures))
             a = codec.alpha
             b, s, lane = batch.shape
-            out = self._apply("ec_decode", matrix,
+            out = self._apply(DECODE_PROGRAM, matrix,
                               batch.reshape(b, s * a, lane // a),
                               False)
             out = out.reshape(b, -1, lane)
@@ -409,7 +417,7 @@ class MeshCodec:
             enc = np.ascontiguousarray(codec.encode_matrix, np.uint8)
             matrix = _decode_matrix_cached(enc.tobytes(), *enc.shape,
                                            codec.k, erasures)
-        out = self._apply("ec_decode", matrix, batch, False)
+        out = self._apply(DECODE_PROGRAM, matrix, batch, False)
         return _host(out) if out_np else out
 
     def rmw(self, codec, old_parity: np.ndarray,

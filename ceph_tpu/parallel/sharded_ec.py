@@ -57,10 +57,12 @@ def make_data_mesh(n_devices: int | None = None) -> Mesh:
     return Mesh(np.asarray(devs[:n]), ("stripe",))
 
 
-def _gf_matmul_bits(w_i8: jnp.ndarray, data_u8: jnp.ndarray) -> jnp.ndarray:
+def _gf_matmul_bits(w_i8: jnp.ndarray, data_u8: jnp.ndarray,
+                    scope: str = "gf_encode") -> jnp.ndarray:
     """(8r,8k) x (k,N) -> (r,N); same math as ops.gf2kernels.  The
-    ``gf_encode`` scope names these operations in a device trace."""
-    with jax.named_scope("gf_encode"):
+    scope names these operations in a device trace: ``gf_encode``
+    for encode and rmw, ``gf_decode`` where the caller reconstructs."""
+    with jax.named_scope(scope):
         k, n = data_u8.shape
         d = data_u8.astype(jnp.int32)
         planes = [((d >> s) & 1) for s in range(8)]

@@ -167,7 +167,7 @@ def test_batcher_counts_queue_overlap_and_materialize_microseconds():
 @pytest.mark.parametrize("call,name", [
     ("encode_crc", "jit_ec_encode_crc"),
     ("encode", "jit_ec_encode"),
-    ("decode", "jit_ec_decode"),
+    ("decode_rows", "jit_ec_decode_rows"),
     ("rmw", "jit_ec_rmw"),
 ])
 def test_mesh_programs_carry_their_own_names(call, name):
@@ -183,11 +183,14 @@ def test_mesh_programs_carry_their_own_names(call, name):
         text = fn.lower(w, jnp.zeros((2, 1, 64), jnp.uint8),
                         data).as_text(debug_info=True)
     else:
-        fn = mc._compiled_apply(mesh, "ec_" + call.split("_")[0], 2, 2, 64,
-                                call.endswith("_crc"), False)
+        fn = mc._compiled_apply(mesh, "ec_" + call.removesuffix("_crc"), 2,
+                                2, 64, call.endswith("_crc"), False)
         text = fn.lower(w, data).as_text(debug_info=True)
     assert f"module @{name} " in text
-    assert "gf_encode" in text
+    # a decode's matmul stands under its own scope, the others' under
+    # gf_encode
+    assert ("gf_decode" in text) == (name == "jit_" + mc.DECODE_PROGRAM)
+    assert ("gf_encode" in text) == (name != "jit_" + mc.DECODE_PROGRAM)
     assert ("crc32c" in text) == call.endswith("_crc")
     del jax
 

@@ -121,6 +121,7 @@ class Monitor:
         # observability (Paxos registers PerfCounters too, Paxos.cc:117)
         self.perf = PerfCountersCollection()
         self.perf_paxos = self.perf.create("paxos")
+        self.perf.adopt(self.msgr.perf)
         self.admin_socket: AdminSocket | None = None
         self._admin_socket_path = admin_socket_path
         # the other PaxosServices (auth/config/log/health) ride the

@@ -6,8 +6,13 @@ The meta envelope is the repo's own versioned denc encoding
 msg/wire_types.py) get explicit MOSDOp-style field layouts, everything
 else rides the generic tagged-value encoding, and a json escape hatch
 remains only for payloads denc cannot express.  Raw binary segments
-stay zero-copy -- the same meta/payload segment split ProtocolV2
-frames use (4 segments + epilogue crcs, src/msg/async/frames_v2.cc).
+ride beside the meta, not inside it -- the same meta/payload segment
+split ProtocolV2 frames use (4 segments + epilogue crcs,
+src/msg/async/frames_v2.cc) -- so a frame's payload bytes are touched
+once a side by the crc and otherwise moved by the kernel: the sender
+hands meta and segments to the socket as a list (``encode_parts``),
+the receiver reads into the frame's own buffers (``FrameReader``) and
+copies each segment out once (``decode_parts``).
 
 meta envelope (denc, struct_v 1):
   string t | u64 seq | string from | u8 kind | blob payload |
@@ -26,9 +31,14 @@ from typing import Any
 from ..common.denc import Decoder, DencError, Encoder
 from ..common.tracing import section
 from ..native import crc32c
+from .wire_types import WIRE_CODECS
 
 MAGIC = b"CTv3"
 MAX_FRAME = 256 << 20
+# a segment this long goes to the socket as it is (an iovec entry and
+# a CRC call of its own); shorter ones are joined with their
+# neighbours first
+SCATTER_MIN = 16 << 10
 
 KIND_VALUE = 0
 KIND_JSON = 1
@@ -44,11 +54,23 @@ class Message:
     from_name: str = ""
 
     def encode(self) -> bytes:
+        """The frame as one buffer: for a connection that compresses
+        or encrypts (``wrap_frame`` takes the whole frame) and for
+        tools; a plain connection sends ``encode_parts`` as it is."""
         with section("wire.encode"):
-            return self._encode()
+            return b"".join(self._encode_parts())
 
-    def _encode(self) -> bytes:
-        from .wire_types import WIRE_CODECS
+    def encode_parts(self) -> list[bytes]:
+        """The frame as the buffers a scatter-gather send takes, in
+        wire order: header, meta, segments, crc.  A segment of
+        ``SCATTER_MIN`` bytes or more is handed on as the object it
+        is; shorter neighbours are joined, since below that size a
+        memcpy costs less than one more CRC call and iovec entry (a
+        frame without a long segment is one buffer)."""
+        with section("wire.encode"):
+            return self._encode_parts()
+
+    def _encode_parts(self) -> list[bytes]:
         payload = Encoder()
         codec = WIRE_CODECS.get(self.type)
         try:
@@ -80,40 +102,96 @@ class Message:
         enc.list([len(s) for s in self.segments], Encoder.u32)
         enc.finish()
         mb = enc.bytes()
-        body = mb + b"".join(self.segments)
+        parts = [MAGIC + struct.pack("<I", len(mb))]
+        # the crc register carries on from part to part: the word the
+        # receiver gets from one pass over meta + segments
+        crc = 0xFFFFFFFF
+        run = [mb]
         with section("wire.crc"):
-            crc = crc32c(body) & 0xFFFFFFFF
-        return MAGIC + struct.pack("<I", len(mb)) + body + struct.pack(
-            "<I", crc)
+            for seg in self.segments:
+                if len(seg) < SCATTER_MIN:
+                    run.append(seg)
+                    continue
+                if run:
+                    parts.append(b"".join(run))
+                    crc = crc32c(parts[-1], crc)
+                    run = []
+                if type(seg) is not bytes:
+                    # the transport may hold a view of a part after
+                    # the send returns: only of what cannot change
+                    seg = bytes(seg)
+                parts.append(seg)
+                crc = crc32c(seg, crc)
+            if run:
+                parts.append(b"".join(run))
+                crc = crc32c(parts[-1], crc)
+        parts.append(struct.pack("<I", crc & 0xFFFFFFFF))
+        if len(parts) == 3:
+            # header, one joined run, crc: no segment worth an iovec
+            # entry, so the frame leaves as one small buffer
+            return [b"".join(parts)]
+        return parts
 
     @classmethod
     def decode(cls, buf: bytes) -> "Message":
-        with section("wire.decode"):
-            return cls._decode(buf)
+        """One whole plain frame in one buffer."""
+        return cls.decode_parts(memoryview(buf), _NO_BYTES)
 
     @classmethod
-    def _decode(cls, buf: bytes) -> "Message":
-        if buf[:4] != MAGIC:
+    def decode_parts(cls, head: memoryview,
+                     rest: memoryview) -> "Message":
+        """One whole plain frame whose bytes are ``head`` then
+        ``rest`` (``FrameReader`` hands them over so; the meta lies
+        in ``head``).  The crc is checked over the views where they
+        lie and each segment leaves as ``bytes`` with one copy."""
+        with section("wire.decode"):
+            return cls._decode_parts(head, rest)
+
+    @classmethod
+    def _decode_parts(cls, head: memoryview,
+                      rest: memoryview) -> "Message":
+        total = len(head) + len(rest)
+        if total < 12 or head[:4] != MAGIC:
             raise ValueError("bad magic")
-        (meta_len,) = struct.unpack_from("<I", buf, 4)
-        mb = buf[8:8 + meta_len]
-        (crc,) = struct.unpack_from("<I", buf, len(buf) - 4)
-        body = buf[8:len(buf) - 4]
-        with section("wire.crc"):
-            if (crc32c(body) & 0xFFFFFFFF) != crc:
-                raise ValueError("frame crc mismatch")
-        mtype, seq, from_name, data, seg_lens = _decode_meta(mb)
-        segments = []
+        (meta_len,) = struct.unpack_from("<I", head, 4)
         off = 8 + meta_len
+        if off > len(head) or off + 4 > total:
+            raise ValueError("truncated frame")
+        (crc,) = struct.unpack(
+            "<I", b"".join(_span(head, rest, total - 4, total)))
+        with section("wire.crc"):
+            got = 0xFFFFFFFF
+            for view in _span(head, rest, 8, total - 4):
+                got = crc32c(view, got)
+            if (got & 0xFFFFFFFF) != crc:
+                raise ValueError("frame crc mismatch")
+        mtype, seq, from_name, data, seg_lens = _decode_meta(head[8:off])
+        if off + sum(seg_lens) + 4 != total:
+            raise ValueError("segment lengths do not fill the frame")
+        segments = []
         for ln in seg_lens:
-            segments.append(buf[off:off + ln])
+            segments.append(b"".join(_span(head, rest, off, off + ln)))
             off += ln
         return cls(type=mtype, data=data, segments=segments,
                    seq=seq, from_name=from_name)
 
 
+_NO_BYTES = memoryview(b"")
+
+
+def _span(head: memoryview, rest: memoryview, a: int,
+          b: int) -> tuple:
+    """Views of bytes [a, b) of a frame stored as ``head`` then
+    ``rest``."""
+    n = len(head)
+    if b <= n:
+        return (head[a:b],)
+    if a >= n:
+        return (rest[a - n:b - n],)
+    return (head[a:], rest[:b - n])
+
+
 def _decode_meta(mb) -> tuple:
-    from .wire_types import WIRE_CODECS
     dec = Decoder(mb)
     dec.start(1)
     mtype = dec.string()
@@ -188,7 +266,7 @@ def unwrap_frame(buf: bytes, compressor=None) -> bytes:
                                         max_length=raw_len)
         except Exception as e:
             # corrupt input must look like any other framing error so
-            # the read loop's reconnect/teardown path handles it
+            # the protocol's abort -> reconnect/teardown path handles it
             raise ValueError(f"frame decompress failed: {e}") from e
         if len(out) != raw_len:
             raise ValueError("compressed frame length mismatch")
@@ -196,61 +274,184 @@ def unwrap_frame(buf: bytes, compressor=None) -> bytes:
     return _parse_plain(buf)
 
 
-async def read_frame(reader, compressor=None, aead=None) -> bytes:
-    """Read one full (plain) frame from an asyncio StreamReader,
-    transparently unwrapping the connection's negotiated encryption
-    and compression layers."""
-    magic = await reader.readexactly(4)
-    if aead is not None and magic != SEC_MAGIC:
+def decrypt_frame(buf: bytes, aead) -> bytes:
+    """The frame inside one whole ``SEC_MAGIC`` frame (which may in
+    turn be a compressed one: ``unwrap_frame`` is next)."""
+    nonce, ct = buf[8:20], buf[20:]
+    try:
+        return aead.decrypt(nonce, ct, b"")
+    except ValueError:
+        raise
+    except Exception as e:
+        raise ValueError(f"frame decrypt failed: {e}") from e
+
+
+def frame_need(head: memoryview, secure: bool) -> tuple[int, bool]:
+    """How many bytes the frame that starts at ``head[0]`` needs
+    before more can be said about it, and whether that count is the
+    whole frame.  ``head`` holds 8 bytes or more.  Every length is
+    checked here, before anything is allocated for the frame."""
+    magic = head[:4]
+    if secure and magic != SEC_MAGIC:
         # a secure connection must never accept plaintext: an injected
         # cleartext frame would bypass the channel's authentication
         raise ValueError("plaintext frame on a secure connection")
+    (n,) = struct.unpack_from("<I", head, 4)
     if magic == SEC_MAGIC:
-        if aead is None:
+        if not secure:
             raise ValueError("encrypted frame on a plain connection")
-        (ct_len,) = struct.unpack("<I", await reader.readexactly(4))
-        if ct_len > MAX_WRAPPED:
+        if n > MAX_WRAPPED:
             raise ValueError("oversized encrypted frame")
-        nonce = await reader.readexactly(12)
-        ct = await reader.readexactly(ct_len)
-        try:
-            if ct_len > OFFLOAD_THRESHOLD:
-                # big decrypts off the event loop: heartbeats must not
-                # stall behind a multi-MB AES pass
-                import asyncio as _asyncio
-                inner = await _asyncio.get_event_loop().run_in_executor(
-                    None, aead.decrypt, nonce, ct, b"")
-            else:
-                inner = aead.decrypt(nonce, ct, b"")
-        except ValueError:
-            raise
-        except Exception as e:
-            raise ValueError(f"frame decrypt failed: {e}") from e
-        return unwrap_frame(inner, compressor)
+        return 20 + n, True
     if magic == COMP_MAGIC:
-        lens = await reader.readexactly(8)
-        raw_len, comp_len = struct.unpack("<II", lens)
-        if max(raw_len, comp_len) > MAX_WRAPPED:
+        if len(head) < 12:
+            return 12, False
+        (comp_len,) = struct.unpack_from("<I", head, 8)
+        if max(n, comp_len) > MAX_WRAPPED:
             raise ValueError("oversized compressed frame")
-        comp = await reader.readexactly(comp_len)
-        return unwrap_frame(magic + lens + comp, compressor)
+        return 12 + comp_len, True
     if magic != MAGIC:
         raise ValueError("bad magic")
-    hdr = magic + await reader.readexactly(4)
-    (meta_len,) = struct.unpack_from("<I", hdr, 4)
-    if meta_len > MAX_FRAME:
+    if n > MAX_FRAME:
         raise ValueError("oversized meta")
-    mb = await reader.readexactly(meta_len)
-    total_segs = sum(_meta_seg_lens(mb))
+    if len(head) < 8 + n:
+        return 8 + n, False
+    total_segs = sum(_meta_seg_lens(head[8:8 + n]))
     if total_segs > MAX_FRAME:
         raise ValueError("oversized frame")
-    rest = await reader.readexactly(total_segs + 4)
-    return hdr + mb + rest
+    return 8 + n + total_segs + 4, True
 
 
-def _meta_seg_lens(mb: bytes) -> list[int]:
-    """Just the segment lengths from a meta envelope (what the stream
-    reader needs to size the rest of the frame)."""
+RECV_BUF = 64 << 10
+# which tail buffers a messenger keeps for its next long frames, and
+# how many: at most SPARE_COUNT * SPARE_MAX bytes lie idle
+SPARE_MIN, SPARE_MAX, SPARE_COUNT = 64 << 10, 8 << 20, 4
+# a frame big enough for the executor never lies whole in the receive
+# buffer, so nothing is parsed behind it while it is being decrypted
+assert RECV_BUF < OFFLOAD_THRESHOLD
+
+
+class FrameReader:
+    """The receive side of a socket, without the socket: hands out
+    the buffer the next bytes go into and finds the frames in what
+    has arrived.
+
+    Bytes land in a receive buffer of ``RECV_BUF`` bytes, and every
+    frame that lies whole in it is handed to ``on_frame(head, rest)``
+    in the same call, ``rest`` empty.  A frame that reaches past what
+    has arrived gets a tail buffer (a spare one, or a new one) of
+    which exactly its missing bytes are offered, so the socket fills
+    it without reading into the next frame; it is handed over as the
+    two views.  Payload bytes are therefore never
+    moved between buffers here: the one copy is ``decode_parts``
+    making ``bytes`` of each segment.
+
+    Before ``start_frames`` the bytes are the handshake's, taken with
+    ``take``."""
+
+    def __init__(self, on_frame, spare: list[bytearray]) -> None:
+        self.on_frame = on_frame
+        # tail buffers that are free again, shared by the readers of
+        # one messenger: a fresh multi-megabyte bytearray costs a
+        # zero-fill and its page faults, a used one nothing
+        self.spare = spare
+        self.secure = False
+        self._framed = False
+        # the receive buffer exists while it holds bytes or a read is
+        # under way: an idle connection keeps none
+        self._buf: bytearray | None = None
+        self._lo = self._hi = 0          # unparsed bytes of _buf
+        self._rest: bytearray | None = None
+        self._rest_len = 0               # bytes of _rest the frame needs
+        self._rest_n = 0                 # bytes of them that arrived
+
+    def get_buffer(self) -> memoryview:
+        if self._rest is not None:
+            return memoryview(self._rest)[self._rest_n:self._rest_len]
+        if self._buf is None:
+            self._buf = bytearray(RECV_BUF)
+        return memoryview(self._buf)[self._hi:]
+
+    def buffer_updated(self, nbytes: int) -> None:
+        if self._rest is None:
+            self._hi += nbytes
+            if self._framed:
+                self._parse()
+            return
+        self._rest_n += nbytes
+        if self._rest_n == self._rest_len:
+            head = memoryview(self._buf)[self._lo:self._hi]
+            tail, self._rest = self._rest, None
+            self._buf = None
+            self._lo = self._hi = 0
+            with memoryview(tail) as whole, whole[:self._rest_len] as rest:
+                self.on_frame(head, rest)
+            if SPARE_MIN <= len(tail) <= SPARE_MAX \
+                    and len(self.spare) < SPARE_COUNT:
+                self.spare.append(tail)
+
+    def take(self, n: int) -> bytes | None:
+        """``n`` handshake bytes, or None until they have arrived."""
+        if n > RECV_BUF:
+            raise ValueError("oversized handshake")
+        if self._hi - self._lo < n:
+            return None
+        if not n:
+            return b""
+        out = bytes(self._buf[self._lo:self._lo + n])
+        self._lo += n
+        if self._lo == self._hi:
+            self._buf = None
+            self._lo = self._hi = 0
+        return out
+
+    def start_frames(self, secure: bool) -> None:
+        """The handshake is over: what has arrived since, and all that
+        follows, is frames."""
+        self.secure = secure
+        self._framed = True
+        self._parse()
+
+    def _parse(self) -> None:
+        while self._hi > self._lo:
+            avail = self._hi - self._lo
+            view = memoryview(self._buf)[self._lo:self._hi]
+            need, whole = (8, False) if avail < 8 \
+                else frame_need(view, self.secure)
+            if avail >= need:
+                self._lo += need
+                self.on_frame(view[:need], _NO_BYTES)
+            elif whole:
+                self._rest_len = need - avail
+                self._rest_n = 0
+                self._rest = self._tail_buffer(self._rest_len)
+                return
+            else:
+                self._make_room(need)
+                return
+        self._buf = None
+        self._lo = self._hi = 0
+
+    def _tail_buffer(self, n: int) -> bytearray:
+        for i, buf in enumerate(self.spare):
+            if len(buf) >= n:
+                return self.spare.pop(i)
+        return bytearray(n)
+
+    def _make_room(self, need: int) -> None:
+        """A header or a meta envelope that is still short moves to
+        the front of a buffer that can hold all of it."""
+        if self._lo + need <= len(self._buf):
+            return
+        short = self._buf[self._lo:self._hi]
+        self._buf = bytearray(max(need, RECV_BUF))
+        self._buf[:len(short)] = short
+        self._lo, self._hi = 0, len(short)
+
+
+def _meta_seg_lens(mb: memoryview) -> list[int]:
+    """Just the segment lengths from a meta envelope (what
+    ``frame_need`` sizes the rest of the frame by)."""
     dec = Decoder(mb)
     dec.start(1)
     dec.string()        # t

@@ -20,8 +20,12 @@ import time
 from collections import deque
 from typing import Awaitable, Callable
 
+from ..common.perf import PerfCounters
+from ..common.throttle import injector as _fault
 from ..common.tracing import section
-from .message import Message, read_frame, wrap_frame
+from .message import (COMP_MAGIC, MAGIC, OFFLOAD_THRESHOLD, SEC_MAGIC,
+                      FrameReader, Message, decrypt_frame, unwrap_frame,
+                      wrap_frame)
 
 Dispatcher = Callable[["Connection", Message], Awaitable[None]]
 
@@ -43,21 +47,178 @@ ACK_TYPE = "__ack"
 # per-peer sub-op coalescing (the PR-12 write pipeline): concurrent
 # ops' sub-writes bound for the same peer inside one flush window ride
 # ONE framed message instead of one send per shard -- one seq, one
-# frame header, one syscall, one read-loop wakeup.  The receiver
+# frame header, one syscall, one receive callback.  The receiver
 # unpacks and dispatches the sub-messages in staging order, so
 # per-peer FIFO (what keeps replica logs in version order) is exactly
 # as strong as the unbatched path.
 SUBOP_BATCH_TYPE = "__subop_batch"
 
 
+class FrameProtocol(asyncio.BufferedProtocol):
+    """One socket of the messenger, both directions.
+
+    Receive: the transport reads straight into the ``FrameReader``'s
+    buffers (``recv_into``); every frame that has arrived whole is
+    checked, decoded and delivered inside that one callback, under
+    ``wire.recv``.  Send: a frame's buffers go to the transport as a
+    list (``writelines``: the selector transport keeps them as views
+    and sends them with ``sendmsg``), and ``drain`` holds a sender back
+    while the transport's buffer is over its high-water mark.  Until
+    ``start_frames`` the socket is the handshake's: ``read_exactly``
+    and ``write``."""
+
+    def __init__(self, messenger: "Messenger", on_accept=None) -> None:
+        self.messenger = messenger
+        self._on_accept = on_accept      # server side: the handshake
+        self.conn: Connection | None = None
+        self.transport: asyncio.Transport | None = None
+        self.reader = FrameReader(self._on_frame, messenger._rx_spare)
+        self._lost = False
+        self._write_paused = False
+        # one future each: the handshake waiting for bytes, senders
+        # waiting for the transport's buffer to drain
+        self._readable: asyncio.Future | None = None
+        self._drained: asyncio.Future | None = None
+
+    # -- transport callbacks ------------------------------------------------
+    def connection_made(self, transport) -> None:
+        self.transport = transport
+        if self._on_accept is not None:
+            self.messenger._spawn(self._on_accept(self))
+
+    def get_buffer(self, sizehint: int) -> memoryview:
+        return self.reader.get_buffer()
+
+    def buffer_updated(self, nbytes: int) -> None:
+        with section("wire.recv"):
+            try:
+                self.reader.buffer_updated(nbytes)
+            except ValueError:
+                # bad magic, length, crc or envelope: the stream cannot
+                # be trusted from here on
+                self.transport.abort()
+        self._wake(self._readable)
+
+    def connection_lost(self, exc) -> None:
+        self._lost = True
+        self._wake(self._readable)
+        self._wake(self._drained)
+        if self.conn is not None:
+            self.messenger._socket_lost(self.conn, self)
+
+    def pause_writing(self) -> None:
+        self._write_paused = True
+
+    def resume_writing(self) -> None:
+        self._write_paused = False
+        self._wake(self._drained)
+
+    @staticmethod
+    def _wake(fut: asyncio.Future | None) -> None:
+        if fut is not None and not fut.done():
+            fut.set_result(None)
+
+    # -- the handshake's reads and every write ------------------------------
+    async def read_exactly(self, n: int) -> bytes:
+        while True:
+            data = self.reader.take(n)
+            if data is not None:
+                return data
+            if self._lost:
+                raise ConnectionResetError("closed during the handshake")
+            self._readable = asyncio.get_event_loop().create_future()
+            await self._readable
+
+    def write(self, parts: list[bytes]) -> None:
+        """Hand one frame's (or the handshake's) buffers to the
+        transport.  On a socket that is closing they are dropped, as
+        ``transport.write`` drops them; ``drain`` then raises."""
+        if self.transport.is_closing():
+            return
+        with section("wire.write"):
+            if len(parts) == 1:
+                self.transport.write(parts[0])
+            else:
+                self.transport.writelines(parts)
+
+    async def drain(self) -> None:
+        if self.transport.is_closing():
+            await asyncio.sleep(0)       # let connection_lost run
+        while not self._lost and self._write_paused:
+            if self._drained is None or self._drained.done():
+                self._drained = asyncio.get_event_loop().create_future()
+            await self._drained
+        if self._lost:
+            raise ConnectionResetError("connection lost")
+
+    def start_frames(self, conn: "Connection") -> None:
+        """The handshake is done and ``conn`` rides this socket: frames
+        from here on (and whatever arrived behind the handshake)."""
+        self.conn = conn
+        try:
+            self.reader.start_frames(secure=conn.aead_rx is not None)
+        except ValueError:
+            self.transport.abort()
+
+    # -- frames in ------------------------------------------------------------
+    def _on_frame(self, head: memoryview, rest: memoryview) -> None:
+        nbytes = len(head) + len(rest)
+        magic = head[:4]
+        if magic == MAGIC:
+            self._deliver(Message.decode_parts(head, rest), nbytes, 0)
+            return
+        buf = b"".join((head, rest))
+        if magic == SEC_MAGIC and nbytes > OFFLOAD_THRESHOLD:
+            # big decrypts off the event loop: heartbeats must not
+            # stall behind a multi-MB AES pass.  Nothing is read
+            # meanwhile, so frames still arrive in order.
+            self.transport.pause_reading()
+            self.messenger._spawn(self._decrypt_off_loop(buf))
+            return
+        copied = nbytes
+        if magic == SEC_MAGIC:
+            buf = decrypt_frame(buf, self.conn.aead_rx)
+            copied += len(buf)
+        self._deliver_wrapped(buf, nbytes, copied)
+
+    async def _decrypt_off_loop(self, buf: bytes) -> None:
+        try:
+            inner = await asyncio.get_event_loop().run_in_executor(
+                None, decrypt_frame, buf, self.conn.aead_rx)
+            if self.transport.is_closing():
+                return           # the socket went meanwhile: the replay
+            self._deliver_wrapped(inner, len(buf), len(buf) + len(inner))
+        except ValueError:
+            self.transport.abort()
+            return
+        self.transport.resume_reading()
+
+    def _deliver_wrapped(self, buf: bytes, nbytes: int,
+                         copied: int) -> None:
+        if buf[:4] == COMP_MAGIC:
+            buf = unwrap_frame(buf, self.conn.compressor)
+            copied += len(buf)
+        self._deliver(Message.decode(buf), nbytes, copied)
+
+    def _deliver(self, msg: Message, nbytes: int, copied: int) -> None:
+        perf = self.messenger.perf
+        perf.inc("rx_frames")
+        perf.inc("rx_bytes", nbytes)
+        perf.inc("rx_copied_bytes",
+                 copied + sum(map(len, msg.segments)))
+        with section("wire.deliver"):
+            self.messenger._frame_in(self.conn, msg, nbytes)
+
+
 class Connection:
     def __init__(self, messenger: "Messenger", peer_name: str,
-                 reader, writer, *, outgoing: bool,
+                 proto: "FrameProtocol", *, outgoing: bool,
                  peer_addr: tuple[str, int] | None = None) -> None:
         self.messenger = messenger
         self.peer_name = peer_name
-        self.reader = reader
-        self.writer = writer
+        # the socket this connection rides now; a reconnect swaps in
+        # another
+        self.proto = proto
         self.outgoing = outgoing
         self.peer_addr = peer_addr
         self.out_seq = 0
@@ -80,8 +241,12 @@ class Connection:
         self._reconnect_lock = asyncio.Lock()
         self._window_open = asyncio.Event()
         self._window_open.set()
-        self._read_task: asyncio.Task | None = None
         self._ack_task: asyncio.Task | None = None
+
+    @property
+    def writer(self) -> asyncio.Transport:
+        """The write half of the current socket."""
+        return self.proto.transport
 
     def _window_full(self) -> bool:
         m = self.messenger
@@ -150,41 +315,64 @@ class Connection:
             self.out_seq += 1
             msg.seq = self.out_seq
             msg.from_name = self.messenger.name
-            buf = msg.encode()
-            self.unacked.append((msg, len(buf)))
-            self.unacked_bytes += len(buf)
-            from .message import OFFLOAD_THRESHOLD
-            if (self.compressor or self.aead_tx) \
-                    and len(buf) > OFFLOAD_THRESHOLD:
-                # multi-MB compress/encrypt off the event loop so
-                # heartbeat handling doesn't stall behind it; ordering
-                # is preserved -- we still hold the send lock, and a
-                # reconnect cannot swap the writer or renegotiate keys
-                # under us because its swap+replay also requires the
-                # send lock.
-                wire = await asyncio.get_event_loop().run_in_executor(
-                    None, wrap_frame, buf, self.compressor,
-                    self.aead_tx)
-                if self.closed:
-                    raise ConnectionError(f"{self.peer_name} closed")
+            wraps = self._wraps()
+            if wraps:
+                buf = msg.encode()
+                nbytes = len(buf)
             else:
-                wire = wrap_frame(buf, self.compressor, self.aead_tx)
-            from ..common.throttle import injector as _fault
+                parts = msg.encode_parts()
+                nbytes = sum(map(len, parts))
+            self.unacked.append((msg, nbytes))
+            self.unacked_bytes += nbytes
+            if wraps:
+                if nbytes > OFFLOAD_THRESHOLD:
+                    # multi-MB compress/encrypt off the event loop so
+                    # heartbeat handling doesn't stall behind it;
+                    # ordering is preserved -- we still hold the send
+                    # lock, and a reconnect cannot swap the socket or
+                    # renegotiate keys under us because its
+                    # swap+replay also requires the send lock.
+                    wire = await asyncio.get_event_loop().run_in_executor(
+                        None, wrap_frame, buf, self.compressor,
+                        self.aead_tx)
+                    if self.closed:
+                        raise ConnectionError(f"{self.peer_name} closed")
+                else:
+                    wire = wrap_frame(buf, self.compressor, self.aead_tx)
+                parts = [wire]
+                self.messenger.perf.inc("tx_frames_joined")
             if _fault.check("ms_inject_socket_failures"):
                 # chaos: drop the transport mid-send; the lossless
                 # reconnect+replay machinery must absorb it
                 # (ms_inject_socket_failures, qa msgr-failures suites)
                 self.writer.close()
             try:
-                with section("wire.write"):
-                    self.writer.write(wire)
-                await self.writer.drain()
+                self._write_frame(parts)
+                await self.proto.drain()
                 return "sent"
             except (ConnectionError, OSError):
                 if not self.outgoing:
                     await self.close()
                     raise
                 return "reconnect"
+
+    def _wraps(self) -> bool:
+        """This connection compresses or encrypts, so a frame has to be
+        one buffer before it leaves."""
+        return self.compressor is not None or self.aead_tx is not None
+
+    def _frame_parts(self, msg: Message) -> list[bytes]:
+        """The buffers of one frame as this connection sends it."""
+        if not self._wraps():
+            return msg.encode_parts()
+        self.messenger.perf.inc("tx_frames_joined")
+        return [wrap_frame(msg.encode(), self.compressor, self.aead_tx)]
+
+    def _write_frame(self, parts: list[bytes]) -> None:
+        perf = self.messenger.perf
+        perf.inc("tx_frames")
+        perf.inc("tx_bytes", sum(map(len, parts)))
+        self.proto.write(parts)
 
     def _note_delivered(self, nbytes: int) -> None:
         """Receive side: count a delivery toward the ack cadence and
@@ -205,11 +393,7 @@ class Connection:
         self._ack_pending_bytes = 0
         ack = Message(ACK_TYPE, {"seq": self.in_seq})
         ack.from_name = self.messenger.name
-        try:
-            self.writer.write(wrap_frame(ack.encode(), None,
-                                         self.aead_tx))
-        except (ConnectionError, OSError):
-            pass
+        self._write_frame(self._frame_parts(ack))
 
     async def _ack_flusher(self) -> None:
         try:
@@ -221,21 +405,15 @@ class Connection:
 
     async def _resend_unacked(self) -> None:
         for msg, _ in list(self.unacked):
-            self.writer.write(wrap_frame(msg.encode(), self.compressor,
-                                         self.aead_tx))
-        await self.writer.drain()
+            self._write_frame(self._frame_parts(msg))
+        await self.proto.drain()
 
     async def close(self) -> None:
         self.closed = True
         self._window_open.set()      # wake throttled senders to error out
-        if self._read_task:
-            self._read_task.cancel()
         if self._ack_task:
             self._ack_task.cancel()
-        try:
-            self.writer.close()
-        except Exception:
-            pass
+        self.writer.close()
 
 
 def pack_subop_batch(msgs: list[Message]) -> Message:
@@ -467,31 +645,51 @@ class Messenger:
         self._server: asyncio.base_events.Server | None = None
         self.addr: tuple[str, int] | None = None
         self._accept_tasks: set[asyncio.Task] = set()
+        # tx_frames, tx_frames_joined (sent as one buffer because the
+        # connection compresses or encrypts), tx_bytes, rx_frames,
+        # rx_bytes, rx_copied_bytes (bytes the receive path copied in
+        # user space: on a plain connection each segment once); a
+        # daemon adopts the set into its own collection
+        self.perf = PerfCounters("msgr")
+        self._rx_spare: list[bytearray] = []     # see FrameReader.spare
 
     # -- server -------------------------------------------------------------
     async def bind(self, host: str = "127.0.0.1", port: int = 0) -> tuple[str, int]:
-        self._server = await asyncio.start_server(self._on_accept, host, port)
+        self._server = await asyncio.get_event_loop().create_server(
+            lambda: FrameProtocol(self, self._on_accept), host, port)
         self.addr = self._server.sockets[0].getsockname()[:2]
         return self.addr
+
+    def _spawn(self, coro) -> None:
+        """A task this messenger owns until it ends or shuts down."""
+        t = asyncio.ensure_future(coro)
+        self._accept_tasks.add(t)
+        t.add_done_callback(self._accept_tasks.discard)
 
     def add_dispatcher(self, fn: Dispatcher) -> None:
         self.dispatchers.append(fn)
 
-    async def _on_accept(self, reader, writer) -> None:
+    async def _on_accept(self, proto: FrameProtocol) -> None:
+        """An accepted socket's handshake; the socket is closed again
+        unless a connection came of it."""
+        try:
+            await self._accept(proto)
+        finally:
+            if proto.conn is None:
+                proto.transport.close()
+
+    async def _accept(self, proto: FrameProtocol) -> None:
         if self._shutting_down:
-            writer.close()
             return
         try:
             peer_name, inst, nego, hs_nonce, hs_cnonce, hs_secret = \
-                await self._handshake_server_read(reader, writer)
-        except (asyncio.IncompleteReadError, ValueError, ConnectionError):
-            writer.close()
+                await self._handshake_server_read(proto)
+        except (ValueError, ConnectionError):
             return
         if self._shutting_down:      # raced shutdown during handshake
-            writer.close()
             return
         # close any stale conn from this peer BEFORE touching session
-        # state: its read loop must not repopulate _sessions with an
+        # state: its socket must not repopulate _sessions with an
         # old seq between our reset and the in_seq snapshot below
         old = self.conns_in.get(peer_name)
         if old is not None:
@@ -503,18 +701,17 @@ class Messenger:
         last_seq = self._sessions.get(peer_name, 0)
         try:
             nego_blob = json.dumps(nego).encode()
-            writer.write(b"ACK!" + struct.pack("<Q", last_seq)
-                         + struct.pack("<I", len(nego_blob)) + nego_blob)
-            await writer.drain()
+            proto.write([b"ACK!" + struct.pack("<Q", last_seq)
+                         + struct.pack("<I", len(nego_blob)) + nego_blob])
+            await proto.drain()
         except (ConnectionError, OSError):
-            writer.close()
             return
-        conn = Connection(self, peer_name, reader, writer, outgoing=False)
+        conn = Connection(self, peer_name, proto, outgoing=False)
         self._apply_negotiation(conn, nego, hs_nonce, hs_cnonce,
                                 is_server=True, secret=hs_secret)
         conn.in_seq = last_seq
         self.conns_in[peer_name] = conn
-        conn._read_task = asyncio.ensure_future(self._read_loop(conn))
+        proto.start_frames(conn)
 
     # -- handshake (HMAC challenge, cephx-lite) ------------------------------
     def _ticket_for(self, peer_name: str) -> dict | None:
@@ -574,7 +771,7 @@ class Messenger:
         return {"compression": comp, "secure": secure,
                 "salt": os.urandom(16).hex()}
 
-    async def _handshake_server_read(self, reader, writer):
+    async def _handshake_server_read(self, proto: FrameProtocol):
         """Server side up to (not including) the ACK: returns
         (peer name, peer incarnation, negotiated transforms, nonce,
         cnonce, connection secret)."""
@@ -585,17 +782,17 @@ class Messenger:
         flags = (HELLO_ACCEPTS_TICKETS
                  if self.ticket_validator is not None else 0) \
             | (HELLO_REQUIRES_TICKET if self.require_ticket else 0)
-        writer.write(HELLO_MAGIC + struct.pack("<16sB", nonce, flags))
-        await writer.drain()
-        hdr = await reader.readexactly(4)
+        proto.write([HELLO_MAGIC + struct.pack("<16sB", nonce, flags)])
+        await proto.drain()
+        hdr = await proto.read_exactly(4)
         if hdr != HELLO_MAGIC:
             raise ValueError("bad hello")
-        (nlen,) = struct.unpack("<I", await reader.readexactly(4))
-        payload = json.loads(await reader.readexactly(nlen))
+        (nlen,) = struct.unpack("<I", await proto.read_exactly(4))
+        payload = json.loads(await proto.read_exactly(nlen))
 
         async def reject(why: str):
-            writer.write(b"NACK")
-            await writer.drain()
+            proto.write([b"NACK"])
+            await proto.drain()
             raise ValueError(why)
 
         # cephx: a presented ticket, once validated against the
@@ -674,9 +871,9 @@ class Messenger:
             else:
                 conn.aead_tx, conn.aead_rx = c2s, s2c
 
-    async def _handshake_client(self, reader, writer,
+    async def _handshake_client(self, proto: FrameProtocol,
                                 peer_name: str = ""):
-        hdr = await reader.readexactly(21)
+        hdr = await proto.read_exactly(21)
         if hdr[:4] != HELLO_MAGIC:
             raise ValueError("bad hello")
         nonce = hdr[4:20]
@@ -704,15 +901,27 @@ class Messenger:
             "proof": proof.hex(), "cnonce": cnonce.hex(),
             "compress": [self.compression] if self.compression else [],
             "secure": self.secure, **fields}).encode()
-        writer.write(HELLO_MAGIC + struct.pack("<I", len(payload)) + payload)
-        await writer.drain()
-        ack = await reader.readexactly(4)
+        proto.write([HELLO_MAGIC + struct.pack("<I", len(payload)) + payload])
+        await proto.drain()
+        ack = await proto.read_exactly(4)
         if ack != b"ACK!":
             raise ConnectionError("auth rejected")
-        (last_seq,) = struct.unpack("<Q", await reader.readexactly(8))
-        (nego_len,) = struct.unpack("<I", await reader.readexactly(4))
-        nego = json.loads(await reader.readexactly(nego_len))
+        (last_seq,) = struct.unpack("<Q", await proto.read_exactly(8))
+        (nego_len,) = struct.unpack("<I", await proto.read_exactly(4))
+        nego = json.loads(await proto.read_exactly(nego_len))
         return last_seq, nego, nonce, cnonce, secret
+
+    async def _open(self, addr: tuple[str, int], peer_name: str):
+        """A new socket to ``addr`` with the client's handshake done:
+        its protocol and what the handshake returned.  The socket is
+        closed again if the handshake fails."""
+        _, proto = await asyncio.get_event_loop().create_connection(
+            lambda: FrameProtocol(self), addr[0], addr[1])
+        try:
+            return proto, await self._handshake_client(proto, peer_name)
+        except BaseException:
+            proto.transport.close()
+            raise
 
     # -- client -------------------------------------------------------------
     async def connect(self, addr: tuple[str, int],
@@ -736,20 +945,22 @@ class Messenger:
                     return conn
             elif conn is not None and conn.closed:
                 replay = [m for m, _ in conn.unacked]
-            reader, writer = await asyncio.open_connection(
-                addr[0], addr[1])
-            last_seq, nego, hs_nonce, hs_cnonce, hs_secret = \
-                await self._handshake_client(reader, writer, peer_name)
-            conn = Connection(self, peer_name, reader, writer,
+            proto, (last_seq, nego, hs_nonce, hs_cnonce, hs_secret) = \
+                await self._open(addr, peer_name)
+            conn = Connection(self, peer_name, proto,
                               outgoing=True, peer_addr=addr)
-            self._apply_negotiation(conn, nego, hs_nonce, hs_cnonce,
-                                    is_server=False, secret=hs_secret)
+            try:
+                self._apply_negotiation(conn, nego, hs_nonce, hs_cnonce,
+                                        is_server=False, secret=hs_secret)
+            except ValueError:
+                proto.transport.close()
+                raise
             # continue the server's seq space: a same-incarnation
             # session survives connection churn, and starting below
             # last_seq would get every message deduped as a replay
             conn.out_seq = last_seq
             self.conns[peer_name] = conn
-            conn._read_task = asyncio.ensure_future(self._read_loop(conn))
+            proto.start_frames(conn)
             for msg in replay:
                 if msg.seq > last_seq:
                     await conn.send(msg)     # re-stamps seq past last_seq
@@ -759,9 +970,9 @@ class Messenger:
         """Lossless policy: reopen and replay unacked in order.
 
         Serialized per connection — the send error path and the
-        read-loop EOF path can both request a reconnect concurrently;
-        the second requester finds the generation already advanced and
-        returns without racing reader/writer swaps.
+        socket's ``connection_lost`` can both request a reconnect
+        concurrently; the second requester finds the generation
+        already advanced and returns without racing the socket swap.
         """
         if conn.peer_addr is None:
             await conn.close()
@@ -773,12 +984,11 @@ class Messenger:
             if conn.generation != gen:
                 return               # someone else already reconnected
             for attempt in range(5):
+                proto = None
                 try:
-                    reader, writer = await asyncio.open_connection(
-                        conn.peer_addr[0], conn.peer_addr[1])
-                    last_seq, nego, hs_nonce, hs_cnonce, hs_secret = \
-                        await self._handshake_client(reader, writer,
-                                                     conn.peer_name)
+                    proto, (last_seq, nego, hs_nonce, hs_cnonce,
+                            hs_secret) = await self._open(
+                        conn.peer_addr, conn.peer_name)
                     # swap + replay under the SEND lock: a sender mid-
                     # flight must not write a newer seq onto the fresh
                     # stream before the replay of older unacked frames
@@ -790,14 +1000,12 @@ class Messenger:
                                                 is_server=False,
                                                 secret=hs_secret)
                         conn._trim_acked(last_seq)
-                        conn.reader, conn.writer = reader, writer
+                        old, conn.proto = conn.proto, proto
+                        old.transport.close()
                         # server->client stream restarts on new accept
                         conn.in_seq = 0
                         conn.generation += 1
-                        if conn._read_task:
-                            conn._read_task.cancel()
-                        conn._read_task = asyncio.ensure_future(
-                            self._read_loop(conn))
+                        proto.start_frames(conn)
                         await conn._resend_unacked()
                     return
                 except (ConnectionError, OSError):
@@ -807,6 +1015,8 @@ class Messenger:
                     # unknown compressor): retrying cannot help; close
                     # so connect() replaces the conn instead of
                     # returning a zombie forever
+                    if proto is not None:
+                        proto.transport.close()
                     break
             await conn.close()
             raise ConnectionError(f"reconnect to {conn.peer_name} failed")
@@ -817,41 +1027,29 @@ class Messenger:
         await conn.send(msg)
 
     # -- dispatch -----------------------------------------------------------
-    async def _read_loop(self, conn: Connection) -> None:
-        try:
-            while not conn.closed:
-                buf = await read_frame(conn.reader, conn.compressor,
-                                       conn.aead_rx)
-                with section("wire.deliver"):
-                    self._frame_in(conn, buf)
-        except (asyncio.IncompleteReadError, ConnectionError, ValueError):
-            if conn.outgoing and not conn.closed:
-                # lossless policy: try to re-establish and replay
-                # unacked; on failure the conn is closed so connect()
-                # replaces it instead of returning a cached corpse
-                try:
-                    t = asyncio.ensure_future(self._try_reconnect(conn))
-                    self._accept_tasks.add(t)
-                    t.add_done_callback(self._accept_tasks.discard)
-                except RuntimeError:      # event loop shutting down
-                    conn.closed = True
-                    conn._window_open.set()
-            else:
-                conn.closed = True
-                # wake any sender blocked on the flow-control window so
-                # it raises instead of hanging on a dead connection
-                conn._window_open.set()
-                try:
-                    conn.writer.close()
-                except Exception:
-                    pass
-        except asyncio.CancelledError:
-            pass
+    def _socket_lost(self, conn: Connection, proto: FrameProtocol) -> None:
+        """``conn``'s socket closed, was aborted over a bad frame, or
+        failed."""
+        if conn.proto is not proto or conn.closed:
+            return               # a reconnect already replaced it
+        if conn.outgoing:
+            # lossless policy: try to re-establish and replay
+            # unacked; on failure the conn is closed so connect()
+            # replaces it instead of returning a cached corpse
+            try:
+                self._spawn(self._try_reconnect(conn))
+                return
+            except RuntimeError:      # event loop shutting down
+                pass
+        conn.closed = True
+        # wake any sender blocked on the flow-control window so
+        # it raises instead of hanging on a dead connection
+        conn._window_open.set()
 
-    def _frame_in(self, conn: Connection, buf: bytes) -> None:
-        """One received frame, synchronously: decode, seq/ack
-        accounting, delivery of the message(s) it carries."""
-        msg = Message.decode(buf)
+    def _frame_in(self, conn: Connection, msg: Message,
+                  nbytes: int) -> None:
+        """One received frame of ``nbytes`` on the wire, synchronously:
+        seq/ack accounting, delivery of the message(s) it carries."""
         if msg.type == ACK_TYPE:   # control frame, outside seq space
             conn._trim_acked(int(msg.data.get("seq", 0)))
             return
@@ -860,7 +1058,7 @@ class Messenger:
         conn.in_seq = msg.seq
         if not conn.outgoing:
             self._sessions[conn.peer_name] = msg.seq
-        conn._note_delivered(len(buf))
+        conn._note_delivered(nbytes)
         if msg.type == SUBOP_BATCH_TYPE:
             # one framed flush -> the staged sub-ops, delivered
             # in staging order (per-peer FIFO preserved)
@@ -888,15 +1086,12 @@ class Messenger:
                 and delay == 0.0 and self.fast_dispatch(conn, msg)):
             return
         # dispatch in a task: a handler that itself RPCs back to
-        # this peer must not block the read loop its reply rides
+        # this peer must not block the receive callback its reply rides
         # on (the reference's DispatchQueue decoupling).  Task
         # creation order preserves ordering for handlers'
         # synchronous prefixes.
         for _ in range(copies):
-            t = asyncio.ensure_future(
-                self._dispatch_one(conn, msg, delay))
-            self._accept_tasks.add(t)
-            t.add_done_callback(self._accept_tasks.discard)
+            self._spawn(self._dispatch_one(conn, msg, delay))
 
     async def _try_reconnect(self, conn: Connection) -> None:
         try:

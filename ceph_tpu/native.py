@@ -60,7 +60,7 @@ def _load():
         ]
         lib.ceph_crc32c.restype = ctypes.c_uint32
         lib.ceph_crc32c.argtypes = [
-            ctypes.c_uint32, ctypes.POINTER(ctypes.c_uint8), ctypes.c_size_t]
+            ctypes.c_uint32, ctypes.c_void_p, ctypes.c_size_t]
         lib.rjenkins_hash3.restype = ctypes.c_uint32
         lib.rjenkins_hash3.argtypes = [ctypes.c_uint32] * 3
         if hasattr(lib, "ceph_crc32c_batch"):
@@ -173,18 +173,29 @@ def _count_scalar(nbytes: int) -> None:
     perf.inc("scalar_bytes", nbytes)
 
 
-def crc32c(data: bytes, crc: int = 0xFFFFFFFF) -> int:
-    """CRC32-C; default initial value matches the common -1 seed."""
+def crc32c(data, crc: int = 0xFFFFFFFF) -> int:
+    """CRC32-C of any bytes-like ``data``, continuing the raw register
+    ``crc`` (no final xor, so ``crc32c(b, crc32c(a))`` is the register
+    of ``a + b``); the default matches the common -1 seed.
+
+    ``bytes`` and a writable ``memoryview`` (the messenger's frames)
+    go to the library by address; anything else through numpy, which
+    costs several microseconds more a call."""
     _count_scalar(len(data))
     lib = _load()
     if lib is None:
         return _crc32c_py(data, crc)
-    buf = np.frombuffer(data, dtype=np.uint8)
-    if len(buf) == 0:
+    if type(data) is bytes:
+        ptr, n = data, len(data)
+    elif (type(data) is memoryview and data.nbytes
+          and not data.readonly and data.c_contiguous):
+        ptr, n = ctypes.byref(ctypes.c_char.from_buffer(data)), data.nbytes
+    else:
+        buf = np.frombuffer(data, dtype=np.uint8)
+        ptr, n = buf.ctypes.data, len(buf)
+    if n == 0:
         return crc
-    return int(lib.ceph_crc32c(
-        ctypes.c_uint32(crc),
-        buf.ctypes.data_as(ctypes.POINTER(ctypes.c_uint8)), len(buf)))
+    return lib.ceph_crc32c(crc, ptr, n)
 
 
 def crc32c_batch_native(crcs: np.ndarray, flat: np.ndarray,

@@ -238,6 +238,7 @@ class OSD:
         self.msgr = Messenger(name, secret=self.secret,
                               faults=self.faults,
                               **(self.msgr_opts or {}))
+        self.perf.adopt(self.msgr.perf)
         self.msgr.add_dispatcher(self._dispatch)
         self.msgr.fast_dispatch = self.fast_dispatch
         if self.pipeline_enabled:

@@ -85,6 +85,25 @@ def test_numpy_one_is_the_py_fallback():
         assert native._crc32c_py(b, 0xFFFFFFFF) == native.crc32c(b)
 
 
+@pytest.mark.parametrize("n", [0, 1, 13, 70000])
+def test_scalar_crc_takes_every_kind_of_buffer_and_chains(n):
+    """``bytes`` and writable views go to the library by address, the
+    rest through numpy: one answer, and ``crc32c(b, crc32c(a))`` is the
+    register of ``a + b`` (the messenger chains a frame's parts so)."""
+    b = np.random.default_rng(n).integers(0, 256, n, np.uint8).tobytes()
+    want = native._crc32c_py(b, 0xFFFFFFFF)
+    ba = bytearray(b)
+    for buf in (b, ba, memoryview(ba), memoryview(b),
+                np.frombuffer(b, np.uint8), memoryview(ba)[n // 3:]):
+        part = b[n // 3:] if len(buf) != n else b
+        assert native.crc32c(buf) == (
+            want if part is b else native._crc32c_py(part, 0xFFFFFFFF))
+    cut = n // 2
+    assert native.crc32c(memoryview(ba)[cut:],
+                         native.crc32c(b[:cut])) == want
+    assert native.crc32c(b, 7) == native._crc32c_py(b, 7)
+
+
 def test_empty_batch_and_empty_buffers():
     assert cb.crc32c_batch([]).shape == (0,)
     got = cb.crc32c_batch([b"", b"", b""])

@@ -67,6 +67,8 @@ async def _served_reads(profile: dict, n_osds: int, n_obj: int) -> dict:
         return {"reads": reads,
                 "degraded": cluster.perf_counters("ec_degraded"),
                 "batch": cluster.perf_counters("ec_batch"),
+                "msgr": cluster.perf_counters("msgr"),
+                "perf_dump_sets": sorted(cluster.osds[0].perf.dump()),
                 "downs": [e["message"] for e in
                           cluster.mon.services.cluster_log
                           if drv.MARKED_DOWN in e["message"]]}
@@ -111,6 +113,23 @@ def test_served_reads_reconstruct_where_the_hole_is_a_data_shard(pool):
     assert out["batch"]["decode_launches"] >= 1
     assert out["batch"].get("fallback_ops", 0) == 0
     assert out["downs"] == [f"osd.{VICTIM} marked down after 1 reports"]
+
+
+@pytest.mark.parametrize("pool", sorted(POOLS))
+def test_the_osds_msgr_counters_add_up_over_the_cluster(pool):
+    """Plain connections: no frame is joined to be sent, and the
+    receive path copies the segments out once and nothing else (the
+    stream reader it replaced copied every frame about five times)."""
+    out = served(pool)
+    msgr = out["msgr"]
+    assert "msgr" in out["perf_dump_sets"]
+    assert msgr["tx_frames"] > 0 and msgr["rx_frames"] > 0
+    assert msgr.get("tx_frames_joined", 0) == 0
+    assert 0 < msgr["rx_copied_bytes"] < msgr["rx_bytes"]
+    payload = sum(len(r[2]) for r in out["reads"])
+    # every object went to the primary once and (k+m-1)/k of it on to
+    # the other shards' OSDs, less what stayed on the primary
+    assert msgr["rx_copied_bytes"] > payload
 
 
 RS83 = {"plugin": "tpu", "k": 8, "m": 3, "technique": "reed_sol_van",

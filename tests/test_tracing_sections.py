@@ -76,6 +76,31 @@ def test_no_await_or_yield_inside_a_section(path):
     assert section_faults((ROOT / path).read_text()) == []
 
 
+def test_the_wire_layer_has_a_section_for_each_way_through_it():
+    """``wire.recv`` is the protocol's ``buffer_updated``: parse, crc,
+    decode and deliver of every frame that arrived, so what asyncio's
+    stream reader did outside any section is the wire layer's now;
+    ``wire.write`` holds the hand-over to the transport."""
+    names = set()
+    for path in ("ceph_tpu/msg/message.py", "ceph_tpu/msg/messenger.py"):
+        for node in ast.walk(ast.parse((ROOT / path).read_text())):
+            if isinstance(node, ast.With):
+                names.update(i.context_expr.args[0].value
+                             for i in node.items if _is_section(i))
+    assert names == {"wire.encode", "wire.crc", "wire.decode",
+                     "wire.deliver", "wire.write", "wire.recv"}
+    from ceph_tpu.msg.messenger import FrameProtocol
+    src = ast.parse((ROOT / "ceph_tpu/msg/messenger.py").read_text())
+    (cls,) = [n for n in src.body if isinstance(n, ast.ClassDef)
+              and n.name == FrameProtocol.__name__]
+    inside = {fn.name: {i.context_expr.args[0].value
+                        for w in ast.walk(fn) if isinstance(w, ast.With)
+                        for i in w.items if _is_section(i)}
+              for fn in cls.body if isinstance(fn, ast.FunctionDef)}
+    assert inside["buffer_updated"] == {"wire.recv"}
+    assert inside["write"] == {"wire.write"}
+
+
 @pytest.mark.parametrize("body,fault", [
     ("async def f():\n  with section('wire.x'):\n    await g()\n", "Await"),
     ("def f():\n  with tracing.section('wire.x'):\n    yield 1\n", "Yield"),

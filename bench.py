@@ -840,17 +840,11 @@ def _cluster_spec(smoke: bool):
     (BENCH_CLUSTER_* env overrides for exploration)."""
     from ceph_tpu.loadgen import WorkloadSpec
 
-    # BENCH_CLUSTER_PIPELINE=0 drives the serial-chain oracle (the
-    # osd_pipeline_enabled kill switch) for before/after comparisons
-    # on identical specs/seeds
-    extra = {}
-    if os.environ.get("BENCH_CLUSTER_PIPELINE", "1") == "0":
-        extra = {"osd_config": {"osd_pipeline_enabled": False}}
     if smoke:
         return WorkloadSpec(
             n_osds=5, pg_num=32, n_objects=96, obj_size=8 << 10,
             n_ops=400, n_clients=8, recovery_ops=160, kill_osds=1,
-            seed=7, extra=extra).validate()
+            seed=7).validate()
     return WorkloadSpec(
         n_osds=int(os.environ.get("BENCH_CLUSTER_OSDS", "64")),
         pg_num=int(os.environ.get("BENCH_CLUSTER_PGS", "256")),
@@ -861,8 +855,7 @@ def _cluster_spec(smoke: bool):
         recovery_ops=int(os.environ.get("BENCH_CLUSTER_REC_OPS",
                                         "1200")),
         kill_osds=1, size_dist="lognormal",
-        seed=int(os.environ.get("BENCH_CLUSTER_SEED", "1")),
-        extra=extra).validate()
+        seed=int(os.environ.get("BENCH_CLUSTER_SEED", "1"))).validate()
 
 
 def _cluster_mode(smoke: bool) -> int:
@@ -940,23 +933,13 @@ def _cluster_mode(smoke: bool) -> int:
     if not qos.get("steady", {}).get("dispatched_client"):
         log("ERROR: scheduler perf set recorded no client dispatch")
         rc = 1
-    # pipelined write spine (PR 12): with the pipeline on (default),
-    # the overlap counters must be LIVE -- a silent fall-back to the
-    # serial chain would report serial numbers as pipelined ones
-    pipeline_on = "osd_config" not in (spec.extra or {}) or \
-        (spec.extra["osd_config"] or {}).get("osd_pipeline_enabled",
-                                             True)
+    # pipelined write spine: the overlap counters must be LIVE
     pipe = report["counters"].get("ec_pipeline", {})
-    if pipeline_on:
-        for key in ("staged_batches", "overlapped_commits",
-                    "commit_overlap_ms", "flush_windows"):
-            if not pipe.get(key):
-                log(f"ERROR: pipeline on but ec_pipeline.{key} never "
-                    f"moved (serial chain leaked through?)")
-                rc = 1
-    elif pipe.get("staged_batches") or pipe.get("overlapped_commits"):
-        log("ERROR: kill switch off but the pipeline still staged")
-        rc = 1
+    for key in ("staged_batches", "overlapped_commits",
+                "commit_overlap_ms", "flush_windows"):
+        if not pipe.get(key):
+            log(f"ERROR: ec_pipeline.{key} never moved")
+            rc = 1
     return rc
 
 
@@ -1194,7 +1177,6 @@ def _osd_path_mode(mesh: bool = False, smoke: bool = False) -> int:
         concurrency=int(os.environ.get("BENCH_OSD_CONCURRENCY",
                                        "8" if smoke else "16")),
         batch_max=int(os.environ.get("BENCH_OSD_BATCH", "64")),
-        mesh=mesh or None,
     ))
     log(f"osd path: {res['osd_path_GiBps']} GiB/s, "
         f"{res['stripes_per_launch']} stripes/launch "

@@ -58,15 +58,11 @@ ROOTS = (
     "crc32c_batch",
     "crc32c_rows",
     "crc32c_device_chunks",
-    "ErasureCodeTpu.encode_batch_crc",
-    "JaxBackend.matmul_batch_crc",
     # the XOR-schedule compiler's launch entry points
     # (ops/xor_schedule.py): the batched scheduled kernel family and
     # the host scheduled executor the BitMatrixCodec data path rides
     "sched_matmul_batch_device",
     "scheduled_xor_matmul",
-    "MeshCodec._apply_sched",
-    "MeshCodec._rmw_sched",
     # the hedged gather spine (osd/hedged_gather.py): reply buffers
     # flow straight into decode launches, so a stray host sync in the
     # engine re-serializes every gather.  (The ECBackend fetch shims
@@ -82,13 +78,11 @@ ROOTS = (
     "CodecBatcher._complete",
     # the flat linear codec family (ec/linear_codec.py): lrc/pmsr
     # encode/decode ride the batched scheduled/dense kernels through
-    # these, and the mesh flat-dialect RMW reshape wraps the same
-    # launches -- a host hop inside any of them re-serializes every
+    # these -- a host hop inside any of them re-serializes every
     # layered/regenerating launch
     "LinearSubchunkCodec.encode_batch",
     "LinearSubchunkCodec.decode_batch",
     "LinearSubchunkCodec._batch_matmul",
-    "MeshCodec._rmw_flat",
 )
 
 # ambiguity budget: a fuzzy call edge that could hit more than this

@@ -43,15 +43,13 @@ _JIT_LEAVES = {"jit", "pjit"}
 
 # Launch wrappers whose donation the AST cannot see (the jit carrying
 # donate_argnums comes out of a cached compile factory, so no literal
-# reaches the call site): seeded into the donor fixpoint by name, the
-# way device_path.ROOTS anchors reachability.  Positions are call-arg
-# indices after self.  The scheduled-kernel mesh launches
-# (parallel/mesh_codec.py) consume their donated device buffers
-# through exactly these entry points.
-ROOTS = (
-    ("MeshCodec._sched_launch", (1,)),
-    ("MeshCodec._sched_rmw_launch", (1, 2)),
-)
+# reaches the call site): ``("Class.method", (call-arg indices after
+# self,))`` entries are seeded into the donor fixpoint by name, the
+# way device_path.ROOTS anchors reachability.  None today: MeshCodec
+# hands each factory-made launch a fresh ``_put`` result and binds no
+# name to a donated buffer (tests/test_lint.py drives the mechanism
+# with a fixture of its own).
+ROOTS: tuple[tuple[str, tuple[int, ...]], ...] = ()
 
 
 def _donated_positions(call: ast.Call) -> tuple[int, ...] | None:

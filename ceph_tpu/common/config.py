@@ -149,15 +149,6 @@ DEFAULT_SCHEMA: list[Option] = [
            min=0.0),
     Option("osd_ec_batch_eager_flush", OPT_BOOL, True,
            "flush the codec batch when the event loop goes idle"),
-    Option("osd_ec_mesh_enabled", OPT_BOOL, True,
-           "launch coalesced EC batches through the sharded device "
-           "mesh (stripe axis partitioned over all visible chips; "
-           "single-device is a 1-device mesh on the same code path)"),
-    Option("osd_ec_mesh_devices", OPT_INT, 0,
-           "devices in the codec mesh (0 = all visible)", min=0),
-    Option("osd_ec_mesh_donate", OPT_BOOL, True,
-           "donate stripe buffers to mesh launches (consume the "
-           "device copy in place instead of defensive-copying it)"),
     Option("osd_datapath_cache_enabled", OPT_BOOL, True,
            "keep hot shard buffers device-resident across encode -> "
            "commit -> read-verify -> scrub -> decode (the (object, "
@@ -179,12 +170,6 @@ DEFAULT_SCHEMA: list[Option] = [
            "(parity' = parity XOR encode(delta)) instead of "
            "re-encoding whole stripes; unchanged data shards ship "
            "version-stamp-only sub-writes"),
-    Option("osd_pipeline_enabled", OPT_BOOL, True,
-           "pipeline the OSD write hot path: double-buffered codec "
-           "launches, commits/flushes awaited outside the PG lock "
-           "(per-(PG, object) ordering preserved), per-peer sub-op "
-           "coalescing.  The kill switch: false restores the serial "
-           "gather -> encode -> commit -> fan-out chain end to end"),
     Option("osd_pipeline_staging_depth", OPT_INT, 4,
            "marshaled codec batches parked between staging and "
            "launch; a flush finding the queue full launches inline "

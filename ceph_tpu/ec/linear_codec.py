@@ -53,12 +53,11 @@ class LinearSubchunkCodec(ErasureCode):
     generator columns by LOGICAL data chunk and rows by position.
     """
 
-    #: the CodecBatcher may coalesce this codec's launches even with a
-    #: chunk remapping: the batched drivers place chunks by
-    #: ``chunk_index`` (see StripeInfo.encode_async)
-    batch_chunk_mapping_ok = True
     #: the MeshCodec flat dialect: launches use ``parity_matrix`` /
-    #: ``decode_flat_matrix`` reshaped to sub-chunk rows
+    #: ``decode_flat_matrix`` reshaped to sub-chunk rows, keyed by
+    #: position, so they coalesce even with a chunk remapping (the
+    #: batched drivers place chunks by ``chunk_index``, see
+    #: StripeInfo.encode_async)
     mesh_flat_ok = True
 
     def __init__(self) -> None:

@@ -28,14 +28,6 @@ class ErasureCodeTpu(ErasureCodeIsa):
         return self.backend.matmul_batch(
             self.encode_matrix[self.k:], data, out_np=out_np)
 
-    def encode_batch_crc(self, data: np.ndarray):
-        """encode_batch plus device-fused integrity: returns
-        ((B, m, L) parity, (B, k+m) uint32 chunk CRCs) from one device
-        round trip -- the CodecBatcher consumes this so shard CRCs are
-        never a host re-hash of bytes the accelerator already held."""
-        return self.backend.matmul_batch_crc(
-            self.encode_matrix[self.k:], data)
-
     def decode_signature(self, erasures) -> str:
         """DecodeTableCache key for an erasure pattern.  Also the
         grouping key the per-OSD CodecBatcher uses to decide which

@@ -46,7 +46,6 @@ fallback are jax-free); the device kernel imports lazily.
 from __future__ import annotations
 
 import functools
-import os
 
 import numpy as np
 
@@ -412,11 +411,6 @@ def crc32c_batch(bufs, seed: int = SEED,
 
 
 # -- JAX device kernel ------------------------------------------------------
-
-def fused_enabled() -> bool:
-    """Device-fused CRC allowed (CEPH_TPU_NO_FUSED_CRC gates it off)."""
-    return not os.environ.get("CEPH_TPU_NO_FUSED_CRC")
-
 
 # Bytes a row's segment holds: the segment matrix is (8 * _SEG, 32),
 # 128 KiB as int8, and a contraction of 4096 fills the MXU's depth.

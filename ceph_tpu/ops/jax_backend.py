@@ -19,21 +19,3 @@ class JaxBackend:
     def matmul_batch(self, matrix: np.ndarray, data: np.ndarray,
                      out_np: bool = False):
         return gf_matmul_batch_device(matrix, data, out_np=out_np)
-
-    def matmul_batch_crc(self, matrix: np.ndarray, data: np.ndarray):
-        """Batched stripes (B, k, L) -> ((B, r, L) parity, (B, k+r)
-        uint32 chunk CRCs), all computed before anything crosses back
-        to the host: the CRC kernel runs on the same device-resident
-        tensors the matmul launch just touched (data chunks and fresh
-        parity), so the shard checksums ride the round trip that
-        produced the parity instead of a host re-scan.
-        """
-        from .crc32c_batch import crc32c_device_chunks
-        parity = self.matmul_batch(matrix, data, out_np=False)
-        crc_d = crc32c_device_chunks(data)
-        crc_p = crc32c_device_chunks(parity)
-        # lint: disable=device-path-host-sync -- the single post-launch materialization of the fused launch
-        return (np.asarray(parity),
-                # lint: disable=device-path-host-sync -- the single post-launch materialization of the fused launch
-                np.concatenate([np.asarray(crc_d), np.asarray(crc_p)],
-                               axis=1))

@@ -27,9 +27,8 @@ This module is that compiler:
     in-process cluster shares one compile;
   * two executors, byte-identical by construction: ``apply_host``
     (numpy rows -- the BitMatrixCodec data path) and
-    ``apply_bits_traced`` (a jax-traceable (k, N) bytes -> (r, N) bytes
-    block shared by the jitted XLA family and the MeshCodec shard_map
-    block).  There is no Pallas variant: on the v5e the per-tile
+    ``apply_bits_traced`` (the jax-traceable (k, N) bytes -> (r, N)
+    bytes block of the jitted XLA family).  There is no Pallas variant: on the v5e the per-tile
     kernel ran no faster than the XLA program for k=8,m=3 and
     exhausted scoped VMEM at k=10,m=4 (CHANGES.md, PR 21), and the
     MXU-bearing backends default to the dense family anyway;
@@ -303,11 +302,9 @@ _SCHEDULES: dict[str, XorSchedule] = {}
 
 
 class _Stats:
-    """Process-wide scheduled-launch counters.  The per-OSD
-    CodecBatcher samples deltas around every coalesced launch into its
-    ``ec_batch`` perf set (xor_sched_launches / xor_terms_saved), so
-    the dynamic counters stay live wherever the scheduled engine
-    actually served."""
+    """Process-wide scheduled-launch counters: every launch of the
+    scheduled engine, host executor or XLA family, notes itself
+    here."""
 
     __slots__ = ("launches", "terms_saved")
 
@@ -450,9 +447,8 @@ def warm_gf8_schedule(matrix: np.ndarray) -> XorSchedule | None:
 
 def apply_bits_traced(sched: XorSchedule, data_u8):
     """(k, N) bytes -> (n_out//8, N) bytes under trace: unpack to bit
-    planes, run the schedule, pack.  The jax-traceable core shared by
-    the jitted XLA family and the MeshCodec shard_map block -- same
-    plane order as the dense family (plane
+    planes, run the schedule, pack.  The jax-traceable core of the
+    jitted XLA family -- same plane order as the dense family (plane
     8j+s = bit s of chunk j, matching ``bitmatrix_i8`` columns)."""
     import jax.numpy as jnp
     k = data_u8.shape[0]
@@ -513,7 +509,7 @@ def _compiled_sched_batch(digest: str, b: int, k: int, l: int):
 
 
 # (digest, shape) keys whose scheduled launch passed its one-time
-# byte-parity gate vs the host oracle (shared with the MeshCodec twins)
+# byte-parity gate vs the host oracle
 _sched_verified: set[tuple] = set()
 
 

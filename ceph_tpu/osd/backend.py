@@ -186,13 +186,6 @@ class PGBackend:
     def __init__(self, pg) -> None:
         self.pg = pg
         self.osd = pg.osd
-        # pipelined write spine (PR 12): when on, submit_transaction
-        # stages its sub-op sends through the per-peer coalescing pipe
-        # and RETURNS the commit wait instead of awaiting it -- the PG
-        # releases its lock before awaiting, so the next op's
-        # gather/encode/store phases overlap this op's peer round
-        # trip.  Snapshot at construction (hot-path-config-read).
-        self._pipeline = self._cfg("osd_pipeline_enabled", True)
 
     def _cfg(self, name: str, default):
         cfg = getattr(self.osd, "config", None)
@@ -281,10 +274,10 @@ class PGBackend:
         synchronous, so the per-peer wire order is the submit order
         (replica logs apply in version order) -- and return a Task
         that resolves when the commits land, with the same laggard
-        healing and min_size semantics.  None when the pipeline is
-        off (kill switch) or the coalescing pipe is not up."""
+        healing and min_size semantics.  None when the coalescing
+        pipe is not up (before start, during shutdown)."""
         pipe = getattr(self.osd, "subop_pipe", None)
-        if not self._pipeline or pipe is None or pipe.closed:
+        if pipe is None or pipe.closed:
             return None
         futs = self.osd.fanout_staged(awaiting)
 
@@ -298,17 +291,18 @@ class PGBackend:
         return _commit()
 
     async def _commit_or_defer(self, awaiting, entry: LogEntry):
-        """Serial chain (await the fan-out under the caller) or
-        pipelined chain (return the commit wait for the PG to await
-        OUTSIDE its lock).  The two paths share the send payloads and
-        the healing tail; only WHERE the await happens differs.
+        """Stage the sub-op sends through the per-peer coalescing pipe
+        and RETURN the commit wait for the PG to await OUTSIDE its
+        lock, so the next op's gather/encode/store phases overlap this
+        op's peer round trip.  With the pipe down (shutdown) the
+        fan-out is awaited here, under the caller: same send payloads,
+        same healing tail, only WHERE the await happens differs.
 
         The staged sends deliberately ship from the pipe's per-peer
         workers, NOT inline here: an inline send runs under the PG
         lock, and a dead peer's reconnect backoff would hold the lock
         across it -- measured at 64 OSDs as the degraded phase
-        collapsing into wedged ops (the serial chain's exact failure
-        mode, reintroduced).  The one scheduling pass a worker costs
+        collapsing into wedged ops.  The one scheduling pass a worker costs
         is the price of keeping peer death out of the lock."""
         if not awaiting:
             return None
@@ -408,13 +402,12 @@ class ECBackend(PGBackend):
     docstring's claim).
 
     Codec launches go through the per-OSD CodecBatcher
-    (osd.codec_batcher): all stripes of an op share one
-    encode_batch/decode_batch launch, and concurrent ops across PGs
-    coalesce into common launches.  The batcher in turn launches
-    coalesced batches through the sharded device mesh
-    (parallel/mesh_codec.MeshCodec) when one is configured, so
-    full-stripe writes, degraded-read decodes and recovery
-    reconstructions all ride the multichip data plane transparently
+    (osd.codec_batcher): all stripes of an op share one launch, and
+    concurrent ops across PGs coalesce into common launches.  The
+    batcher launches them through the sharded device mesh
+    (parallel/mesh_codec.MeshCodec), so full-stripe writes,
+    degraded-read decodes and recovery reconstructions all ride the
+    multichip data plane transparently
     -- on a single device that is a 1-device mesh, same code path.
     """
 
@@ -1336,9 +1329,8 @@ class ECBackend(PGBackend):
         dpos = self.sinfo.data_positions(self.codec)
         ppos = [i for i in range(self.sinfo.k + self.sinfo.m)
                 if i not in dpos]
-        from .codec_batcher import CodecBatcher
         delta_ok = (self._rmw_delta and self.batcher is not None
-                    and CodecBatcher.supports(self.codec)
+                    and self.batcher.supports(self.codec)
                     and len(acting) == self.sinfo.k + self.sinfo.m)
         avail = {shard: osd for shard, osd in enumerate(acting)
                  if osd >= 0 and self.osd.osd_is_up(osd)}

@@ -11,11 +11,11 @@ host path for small writes.
 The CodecBatcher is the aggregation stage in between: every ECBackend
 on an OSD (across ALL its PGs) submits encode/decode work here, the
 batcher coalesces stripe sets from concurrently in-flight ops into
-single ``encode_batch`` / ``decode_batch`` launches, and fans results
-back to per-op futures byte-identically.  The role analog in the
-reference is the RMW pipelining of src/osd/ECCommon.cc:704-789 --
-there the overhead amortized is the read-modify-write round trip, here
-it is the accelerator launch.
+single launches, and fans results back to per-op futures
+byte-identically.  The role analog in the reference is the RMW
+pipelining of src/osd/ECCommon.cc:704-789 -- there the overhead
+amortized is the read-modify-write round trip, here it is the
+accelerator launch.
 
 Mechanics:
 
@@ -28,32 +28,31 @@ Mechanics:
     column-independent, so zero-padding the lane axis and slicing the
     result back is byte-exact, and the batch axis is rounded up to a
     power-of-two bucket so the jit cache stays bounded
-    (gf2kernels.bucket_batch);
+    (the engine's ``pad_batch``);
   * a group flushes when it reaches ``max_batch`` stripes, when the
     event loop completes a pass with no new submissions (the Nagle-off
     fast path: nothing else is going to coalesce, launch now), or on a
     short timer backstop;
-  * codecs without batch entry points (isa/jerasure host plugins,
-    layered codes with chunk remapping) fall back transparently to the
-    per-op path -- ``supports`` gates at the call site;
-  * coalesced batches of mesh-capable codecs launch through the
-    sharded data plane (parallel/mesh_codec.MeshCodec): ONE
-    shard_map-compiled launch partitions the stripe-batch axis over
-    every visible device with donated stripe buffers and the CRC
-    side-path fused into the same program -- a single device is just
-    a 1-device mesh, so the code path is identical from laptop CPU to
-    a full slice;
-  * the launch spine is DOUBLE-BUFFERED (the PR-12 write pipeline):
-    a flush marshals its batch on host (pad, stack, stage) and hands
-    it to a single-slot launch driver instead of launching inline, so
-    batch N+1's host staging overlaps launch N's device time -- the
-    dispatch/materialize split (``out_np=False`` launches, one
-    ``np.asarray`` at completion) is what opens the window, and the
-    donation contracts from the mesh path already make the buffer
-    handoff safe.  ``osd_pipeline_enabled=false`` is the kill switch
-    that restores the serial marshal->launch->fan-out chain (the
-    parity oracle: both paths are the same three functions, only the
-    interleaving differs).
+  * every batch launches through ONE engine, by default the sharded
+    data plane (parallel/mesh_codec.MeshCodec): one shard_map-compiled
+    launch partitions the stripe-batch axis over every visible device
+    with donated stripe buffers and the CRC side-path fused into the
+    same program -- a single device is just a 1-device mesh, so the
+    code path is identical from laptop CPU to a full slice.  The
+    batcher knows queues, padding and futures; what a launch is made
+    of is the engine's business;
+  * a codec the engine cannot launch (isa/jerasure host plugins,
+    layered codes with chunk remapping) takes the per-op path --
+    ``supports`` asks the engine, and the call site gates on it;
+  * the launch spine is DOUBLE-BUFFERED: a flush marshals its batch on
+    host (pad, stack, stage) and hands it to a single-slot launch
+    driver instead of launching inline, so batch N+1's host staging
+    overlaps launch N's device time -- the dispatch/materialize split
+    (``out_np=False`` launches, one ``np.asarray`` at completion) is
+    what opens the window, and the engine's donation contract makes
+    the buffer handoff safe.  The shutdown drain and a full staging
+    queue run the same three functions (marshal, dispatch, complete)
+    inline.
 
 Occupancy is surfaced as perf counters (``perf dump`` -> "ec_batch"):
 batches launched, a stripes-per-batch histogram, padding waste, and
@@ -114,10 +113,10 @@ class _Staged:
     dispatch and the post-launch fan-out remain."""
 
     __slots__ = ("grp", "reason", "batch", "old_batch", "want_crc",
-                 "lane", "total", "b", "payload", "mesh", "t_dispatched")
+                 "lane", "total", "b", "payload", "t_dispatched")
 
     def __init__(self, grp, reason, batch, old_batch, want_crc,
-                 lane, total, b, payload, mesh) -> None:
+                 lane, total, b, payload) -> None:
         self.grp = grp
         self.reason = reason
         self.batch = batch
@@ -127,7 +126,6 @@ class _Staged:
         self.total = total
         self.b = b
         self.payload = payload
-        self.mesh = mesh
         self.t_dispatched = 0       # perf_counter_ns at _dispatch's return
 
 
@@ -140,14 +138,18 @@ class CodecBatcher:
     decode-index order resolves to the (n, len(erasures), L) recovered
     chunks.  Results are byte-identical to per-stripe codec.encode /
     codec.decode.
+
+    ``engine`` launches the batches: ``supports(codec)``,
+    ``pad_batch(total)`` and ``encode`` / ``decode`` / ``rmw`` with
+    MeshCodec's signatures.  Left out, it is a MeshCodec over every
+    visible device, built on first use (a replicated-only OSD never
+    pays the jax import).
     """
 
     def __init__(self, *, max_batch: int = 64,
                  flush_timeout: float = 0.002,
                  eager_flush: bool = True, perf=None,
-                 mesh="auto", mesh_devices: int = 0,
-                 mesh_donate: bool = True,
-                 pipeline: bool = True, staging_depth: int = 4,
+                 engine=None, staging_depth: int = 4,
                  pipe_perf=None) -> None:
         self.max_batch = max(1, int(max_batch))
         self.flush_timeout = float(flush_timeout)
@@ -158,23 +160,15 @@ class CodecBatcher:
         # marshal overlaps the current launch's device time.  Depth
         # bounds parked host memory; a flush finding the queue full
         # launches inline (a counted stall, never an unbounded queue).
-        self.pipeline = bool(pipeline)
         self.staging_depth = max(1, int(staging_depth))
         self.pipe_perf = pipe_perf
         from collections import deque
         self._staged: deque[_Staged] = deque()
         self._drive_task: asyncio.Task | None = None
-        # sharded data plane (parallel/mesh_codec.py): "auto" builds a
-        # MeshCodec over the visible devices LAZILY on the first
-        # mesh-eligible launch (a replicated-only OSD never pays the
-        # jax import), None keeps the single-device codec launches, or
-        # pass a MeshCodec instance directly.  All knobs are SNAPSHOT
-        # here -- no config object is retained and nothing is looked
-        # up per batch (from_config + the test_mesh_codec assertion).
-        self._mesh = mesh if mesh != "auto" else None
-        self._mesh_auto = mesh == "auto"
-        self._mesh_devices = int(mesh_devices)
-        self._mesh_donate = bool(mesh_donate)
+        # All knobs are SNAPSHOT here -- no config object is retained
+        # and nothing is looked up per batch (from_config + the
+        # test_mesh_codec assertion).
+        self._engine = engine
         self._groups: dict[tuple, _Group] = {}
         self._closed = False
         if perf is not None:
@@ -183,8 +177,8 @@ class CodecBatcher:
     @classmethod
     def from_config(cls, conf, perf=None,
                     pipe_perf=None) -> "CodecBatcher | None":
-        """Construction-time snapshot of every batcher/mesh/pipeline
-        knob (the hot launch loop must never call ``conf.get``).
+        """Construction-time snapshot of every batcher knob (the hot
+        launch loop must never call ``conf.get``).
         Returns None when EC batching is disabled."""
         if not conf.get("osd_ec_batch_enabled", True):
             return None
@@ -194,45 +188,23 @@ class CodecBatcher:
                                          0.002)),
             eager_flush=bool(conf.get("osd_ec_batch_eager_flush",
                                       True)),
-            mesh=("auto" if conf.get("osd_ec_mesh_enabled", True)
-                  else None),
-            mesh_devices=int(conf.get("osd_ec_mesh_devices", 0)),
-            mesh_donate=bool(conf.get("osd_ec_mesh_donate", True)),
-            pipeline=bool(conf.get("osd_pipeline_enabled", True)),
             staging_depth=int(conf.get("osd_pipeline_staging_depth",
                                        4)),
             perf=perf, pipe_perf=pipe_perf)
 
-    def _mesh_for(self, codec):
-        """The sharded launch engine for this codec, or None (then the
-        codec's own single-device batch entry points serve)."""
-        if self._mesh is None and not self._mesh_auto:
-            return None
-        from ..parallel.mesh_codec import MeshCodec
-        if not MeshCodec.supports(codec):
-            return None
-        if self._mesh is None:
-            self._mesh = MeshCodec(n_devices=self._mesh_devices,
-                                   donate=self._mesh_donate,
-                                   perf=self.perf)
-        return self._mesh
+    @property
+    def engine(self):
+        """The launch engine every batch goes through."""
+        if self._engine is None:
+            from ..parallel.mesh_codec import MeshCodec
+            self._engine = MeshCodec(perf=self.perf)
+        return self._engine
 
     # -- capability gate ----------------------------------------------------
-    @staticmethod
-    def supports(codec) -> bool:
-        """Batched entry points exist and the chunk layout is the plain
-        positional one (a chunk remapping would decouple shard ids from
-        matrix rows, which the batch kernels do not model) -- unless
-        the codec declares ``batch_chunk_mapping_ok``: the flat linear
-        family (ec/linear_codec.py) keys its generator by position and
-        the StripeInfo drivers place its chunks via ``chunk_index``, so
-        mapped layouts (lrc) coalesce safely."""
-        return (hasattr(codec, "encode_batch")
-                and hasattr(codec, "decode_batch")
-                and getattr(codec, "encode_matrix", None) is not None
-                and (not codec.get_chunk_mapping()
-                     or getattr(codec, "batch_chunk_mapping_ok",
-                                False)))
+    def supports(self, codec) -> bool:
+        """The engine can launch this codec's stripes; otherwise the
+        caller takes the per-op path (and says so: ``note_fallback``)."""
+        return self.engine.supports(codec)
 
     # -- submission ---------------------------------------------------------
     async def encode(self, codec, stripes: np.ndarray,
@@ -241,11 +213,11 @@ class CodecBatcher:
 
         With ``with_crc`` the result is ``(parity, crcs)`` where crcs
         is (n, k+m) uint32 -- the CRC32C of every data and parity chunk
-        of every stripe, computed in the launch itself when the codec
-        exposes ``encode_batch_crc`` (device-fused; no host re-scan of
-        bytes the accelerator just touched) and by one host
-        ``crc32c_rows`` pass otherwise.  Callers fold them into
-        whole-shard CRCs with ``fold_chunk_crcs``.
+        of every stripe, computed in the launch itself where the engine
+        fuses them (no host re-scan of bytes the accelerator just
+        touched) and by one host ``crc32c_rows`` pass otherwise.
+        Callers fold them into whole-shard CRCs with
+        ``fold_chunk_crcs``.
         """
         return await self._submit("encode", codec, stripes, (),
                                   want_crc=with_crc)
@@ -263,9 +235,9 @@ class CodecBatcher:
         parity + (n, k, L) data delta (zeros outside the written
         range) -> (n, m, L) new parity = old XOR encode(delta), by GF
         linearity.  Coalesces across concurrently-submitting ops like
-        encode/decode; through the mesh the old-parity device buffer is
-        donated and ALIASED in place (MeshCodec.rmw), so the update
-        never holds two parity copies."""
+        encode/decode; the old-parity device buffer is donated and
+        ALIASED in place (MeshCodec.rmw), so the update never holds
+        two parity copies."""
         old_parity = np.ascontiguousarray(old_parity, np.uint8)
         assert old_parity.ndim == 3, old_parity.shape
         return await self._submit("rmw", codec, delta, (),
@@ -287,14 +259,6 @@ class CodecBatcher:
                       extra: tuple, want_crc: bool = False, old=None):
         arr = np.ascontiguousarray(arr, dtype=np.uint8)
         assert arr.ndim == 3, arr.shape
-        if self._closed:
-            # late stragglers during shutdown: launch solo
-            if kind == "rmw":
-                return old ^ self._launch_one("encode", codec, (), arr)
-            out = self._launch_one(kind, codec, extra, arr)
-            if want_crc:
-                return out, self._host_chunk_crcs(arr, out)
-            return out
         key = codec_signature(codec, kind, extra)
         grp = self._groups.get(key)
         if grp is None:
@@ -303,7 +267,10 @@ class CodecBatcher:
         fut = loop.create_future()
         grp.items.append((arr, fut, want_crc, old))
         grp.n_stripes += arr.shape[0]
-        if grp.n_stripes >= self.max_batch:
+        if self._closed:
+            # late straggler during shutdown: nothing to wait for
+            self._flush(key, "close")
+        elif grp.n_stripes >= self.max_batch:
             self._flush(key, "full")
         elif grp.task is None:
             grp.task = loop.create_task(self._linger(key, grp))
@@ -340,10 +307,10 @@ class CodecBatcher:
         grp = self._groups.pop(key, None)
         if grp is None or not grp.items:
             return
-        if not self.pipeline or self._closed:
+        if self._closed:
             self._run_batch(grp, reason)
             return
-        # pipelined: marshal NOW (this is exactly the host staging that
+        # marshal NOW (this is exactly the host staging that
         # overlaps the in-flight launch), park the batch, and let the
         # driver launch it.  A full staging queue degrades to an inline
         # launch -- bounded memory, and the stall is counted so the
@@ -416,30 +383,19 @@ class CodecBatcher:
 
     def close(self) -> None:
         """Launch whatever is pending so in-flight ops complete, then
-        refuse further coalescing (stragglers launch solo)."""
+        refuse further coalescing (a straggler launches at once, on
+        its own)."""
         self._closed = True
         self.flush_all("close")
         self._drain_staged()
 
     # -- the launch ----------------------------------------------------------
-    def _launch_one(self, kind: str, codec, extra: tuple,
-                    arr: np.ndarray, out_np: bool = True):
-        if kind == "encode":
-            if not out_np:      # deferred: one asarray at completion
-                return codec.encode_batch(arr, out_np=False)
-            # lint: disable=device-path-host-sync -- the single post-launch materialization (out_np=True: already host)
-            return np.asarray(codec.encode_batch(arr, out_np=True))
-        if not out_np:
-            return codec.decode_batch(list(extra), arr, out_np=False)
-        # lint: disable=device-path-host-sync -- the single post-launch materialization (out_np=True: already host)
-        return np.asarray(codec.decode_batch(list(extra), arr,
-                                             out_np=True))
-
     @staticmethod
     def _host_chunk_crcs(data: np.ndarray,
                          out: np.ndarray) -> np.ndarray:
-        """Host fallback for codecs without a fused CRC entry point:
-        still ONE batched pass over all chunks, never per-buffer."""
+        """Chunk CRCs of a launch that did not fuse them (the flat
+        dialect): still ONE batched pass over all chunks, never
+        per-buffer."""
         from ..ops.crc32c_batch import crc32c_rows
         b, k, lane = data.shape
         r = out.shape[1]
@@ -449,11 +405,9 @@ class CodecBatcher:
                                crcs[b * k:].reshape(b, r)], axis=1)
 
     def _run_batch(self, grp: _Group, reason: str) -> None:
-        """The serial chain (kill-switch path and shutdown drain):
-        marshal -> dispatch -> complete inline.  The pipelined driver
-        runs the SAME three functions with a yield between dispatch
-        and complete -- byte parity between the two modes is by
-        construction, not by test luck."""
+        """Marshal -> dispatch -> complete inline (shutdown drain, full
+        staging queue).  The staged driver runs the SAME three
+        functions with a yield between dispatch and complete."""
         st = self._marshal(grp, reason)
         try:
             self._complete(st, self._dispatch(st))
@@ -468,17 +422,11 @@ class CodecBatcher:
             return self._marshal_batch(grp, reason)
 
     def _marshal_batch(self, grp: _Group, reason: str) -> _Staged:
-        # lazy: gf2kernels pulls in jax, which a replicated-only OSD
-        # must not pay for at boot (only EC submissions reach here,
-        # and by then the codec itself has loaded the stack)
-        from ..ops.gf2kernels import bucket_batch
         items = grp.items
         k = items[0][0].shape[1]
         lane = max(a.shape[2] for a, _, _, _ in items)
         total = sum(a.shape[0] for a, _, _, _ in items)
-        mesh = self._mesh_for(grp.codec)
-        b = mesh.pad_batch(total) if mesh is not None \
-            else bucket_batch(total)
+        b = self.engine.pad_batch(total)
         payload = sum(a.size for a, _, _, _ in items)
         if len(items) == 1 and b == total:
             batch = items[0][0]
@@ -505,14 +453,13 @@ class CodecBatcher:
                     row += n
         want_crc = any(w for _, _, w, _ in items)
         return _Staged(grp, reason, batch, old_batch, want_crc,
-                       lane, total, b, payload, mesh)
+                       lane, total, b, payload)
 
     def _dispatch(self, st: _Staged) -> tuple:
-        """Device dispatch WITHOUT materialization: launches return
+        """Device dispatch WITHOUT materialization: the launch returns
         device futures (``out_np=False``), so control comes back to
-        the event loop while the device works.  Returns
-        (mode, out, crcs, xor_stats0); ``_complete`` pays the single
-        asarray."""
+        the event loop while the device works.  Returns (out, crcs);
+        ``_complete`` pays the single asarray."""
         if self.perf is not None:
             self.perf.inc("queue_wait_us", (time.perf_counter_ns()
                                             - st.grp.t_first) // 1000)
@@ -522,61 +469,31 @@ class CodecBatcher:
         return handle
 
     def _dispatch_launch(self, st: _Staged) -> tuple:
-        grp, batch, old_batch = st.grp, st.batch, st.old_batch
-        want_crc, mesh = st.want_crc, st.mesh
-        # scheduled-engine observability: the XOR-schedule compiler
-        # (ops/xor_schedule.py) counts process-wide; sampling the
-        # delta around THIS launch keeps the ec_batch counters live
-        # on every scheduled launch (the perf-coherence contract)
-        xor_stats0 = None
-        if self.perf is not None:
-            from ..ops.xor_schedule import STATS as XOR_STATS
-            xor_stats0 = XOR_STATS.snapshot()
-        out = crcs = None
-        if mesh is not None:
-            # the sharded data plane: ONE launch for the whole
-            # coalesced batch, partitioned over every mesh device,
-            # fused CRCs riding the same launch when wanted.  A mesh
-            # failure fails the batch's waiters (``_fail``): it is
-            # never retried on another engine.
-            if grp.kind == "rmw":
-                out = mesh.rmw(grp.codec, old_batch, batch,
-                               out_np=False)
-            elif grp.kind == "encode" and want_crc \
-                    and hasattr(grp.codec, "encode_batch_crc") \
-                    and self._fused_crc_ok():
-                out, crcs = mesh.encode(grp.codec, batch,
-                                        with_crc=True, out_np=False)
-                if self.perf is not None:
-                    self.perf.inc("crc_fused_launches")
-            elif grp.kind == "encode":
-                out = mesh.encode(grp.codec, batch, out_np=False)
-            else:
-                out = mesh.decode(grp.codec, grp.extra, batch,
-                                  out_np=False)
-            return ("plain", out, crcs, xor_stats0)
+        """ONE launch for the whole coalesced batch, fused CRCs riding
+        it when wanted.  A launch failure fails the batch's waiters
+        (``_fail``): it is never retried another way."""
+        grp, engine = st.grp, self.engine
+        crcs = None
         if grp.kind == "rmw":
-            # single-device delta: parity' = parity ^ encode(delta),
-            # the XOR applied at completion on the materialized encode
-            enc = self._launch_one("encode", grp.codec, (), batch,
-                                   out_np=False)
-            return ("rmw_host", enc, None, xor_stats0)
-        if want_crc and grp.kind == "encode" \
-                and hasattr(grp.codec, "encode_batch_crc") \
-                and self._fused_crc_ok():
-            out, crcs = grp.codec.encode_batch_crc(batch)
-            if self.perf is not None:
+            out = engine.rmw(grp.codec, st.old_batch, st.batch,
+                             out_np=False)
+        elif grp.kind == "decode":
+            out = engine.decode(grp.codec, grp.extra, st.batch,
+                                out_np=False)
+        elif st.want_crc:
+            out, crcs = engine.encode(grp.codec, st.batch,
+                                      with_crc=True, out_np=False)
+            if crcs is not None and self.perf is not None:
                 self.perf.inc("crc_fused_launches")
-            return ("plain", out, crcs, xor_stats0)
-        out = self._launch_one(grp.kind, grp.codec, grp.extra, batch,
-                               out_np=False)
-        return ("plain", out, crcs, xor_stats0)
+        else:
+            out = engine.encode(grp.codec, st.batch, out_np=False)
+        return out, crcs
 
     def _complete(self, st: _Staged, handle: tuple) -> None:
         """Materialize the launch (the single post-launch host hop),
         fan results back to the per-op futures, bump the counters."""
         t_in = time.perf_counter_ns()
-        mode, out, crcs, xor_stats0 = handle
+        out, crcs = handle
         with section("device_wait.materialize"):
             # lint: disable=device-path-host-sync -- the single post-launch materialization
             out = np.asarray(out)
@@ -588,8 +505,6 @@ class CodecBatcher:
             self.perf.inc("materialize_us",
                           (time.perf_counter_ns() - t_in) // 1000)
         grp, items = st.grp, st.grp.items
-        if mode == "rmw_host":
-            out = st.old_batch ^ out
         if crcs is None and st.want_crc:
             crcs = self._host_chunk_crcs(st.batch, out)
             if self.perf is not None:
@@ -622,14 +537,3 @@ class CodecBatcher:
                           st.b * st.batch.shape[1] * lane - st.payload)
             self.perf.inc(f"flush_{st.reason}")
             self.perf.hist_sample("stripes_per_batch", st.total)
-            if xor_stats0 is not None:
-                from ..ops.xor_schedule import STATS as XOR_STATS
-                l1, t1 = XOR_STATS.snapshot()
-                l0, t0 = xor_stats0
-                self.perf.inc("xor_sched_launches", l1 - l0)
-                self.perf.inc("xor_terms_saved", t1 - t0)
-
-    @staticmethod
-    def _fused_crc_ok() -> bool:
-        from ..ops.crc32c_batch import fused_enabled
-        return fused_enabled()

@@ -123,10 +123,10 @@ class StripeInfo:
     async def encode_async(self, codec, data: bytes, batcher=None,
                            with_crc: bool = False):
         """Batched analog of encode(): every stripe of ``data`` rides
-        ONE ``encode_batch`` launch, and with a CodecBatcher the launch
-        is shared with other concurrently-submitting ops (cross-PG
-        coalescing).  Byte-identical to encode(); codecs without batch
-        entry points fall back transparently.
+        ONE launch of the CodecBatcher's engine, shared with other
+        concurrently-submitting ops (cross-PG coalescing).
+        Byte-identical to encode(); without a batcher, or for a codec
+        its engine cannot launch, encode() itself serves.
 
         With ``with_crc`` the result is ``(shards, crcs)`` where
         ``crcs[i]`` is the CRC32C of shard i's whole buffer: per-stripe
@@ -135,8 +135,7 @@ class StripeInfo:
         with the GF(2) combine -- the write path stamps them without
         ever re-hashing shard bytes.
         """
-        from .codec_batcher import CodecBatcher
-        if batcher is None or not CodecBatcher.supports(codec):
+        if batcher is None or not batcher.supports(codec):
             if batcher is not None:
                 batcher.note_fallback()
             shards = self.encode(codec, data)
@@ -197,17 +196,16 @@ class StripeInfo:
                            want: set[int] | None = None,
                            batcher=None) -> dict[int, np.ndarray]:
         """Batched analog of decode(): all stripes' reconstructions in
-        one ``decode_batch`` launch, grouped in the batcher by erasure
+        one launch, grouped in the batcher by erasure
         signature (the DecodeTableCache keying) so concurrent recovery
         reads with the same down-shard pattern coalesce."""
-        from .codec_batcher import CodecBatcher
         from ..gf.matrices import decode_index_for
         want = (set(self.data_positions(codec)) if want is None
                 else set(want))
         have = set(shard_bufs)
         k, m = self.k, self.m
         erasures = sorted(i for i in range(k + m) if i not in have)
-        if batcher is None or not CodecBatcher.supports(codec):
+        if batcher is None or not batcher.supports(codec):
             if batcher is not None:
                 batcher.note_fallback()
             return self.decode(codec, shard_bufs, want)

@@ -148,16 +148,13 @@ class OSD:
         # stage stalls), the deferred commit path (commit_overlap_ms)
         # and the per-peer sub-op coalescer (coalesced_subops,
         # flush_windows) all report here.  Pipeline knobs are SNAPSHOT
-        # at construction -- the kill switch osd_pipeline_enabled=false
-        # restores the serial chain end to end.
+        # at construction.
         self.perf_pipeline = self.perf.create("ec_pipeline")
         for key in ("staged_batches", "inflight_overlap_windows",
                     "stage_stalls", "overlapped_commits",
                     "commit_overlap_ms", "coalesced_subops",
                     "flush_windows"):
             self.perf_pipeline.inc(key, 0)    # visible even when idle
-        self.pipeline_enabled = bool(
-            self.config.get("osd_pipeline_enabled", True))
         self._pipeline_flush_window = float(
             self.config.get("osd_pipeline_flush_window", 0.002))
         self.subop_pipe = None       # built in start() (needs msgr)
@@ -165,8 +162,8 @@ class OSD:
         # OSD funnels encode/decode work through ONE batcher so
         # concurrent ops share accelerator launches
         # (ceph_tpu/osd/codec_batcher.py)
-        # every knob (batching AND the sharded-mesh data plane) is
-        # snapshot here, once: the launch loop never reads config
+        # every knob is snapshot here, once: the launch loop never
+        # reads config
         from .codec_batcher import CodecBatcher
         self.codec_batcher = CodecBatcher.from_config(
             self.config, perf=self.perf.create("ec_batch"),
@@ -241,15 +238,14 @@ class OSD:
         self.perf.adopt(self.msgr.perf)
         self.msgr.add_dispatcher(self._dispatch)
         self.msgr.fast_dispatch = self.fast_dispatch
-        if self.pipeline_enabled:
-            # per-peer sub-op coalescing (msg/messenger.py SubOpPipe):
-            # concurrent ops' sub-writes to one peer share a framed
-            # flush per window instead of one send per shard
-            from ..msg.messenger import SubOpPipe
-            self.subop_pipe = SubOpPipe(
-                self.msgr,
-                flush_window=self._pipeline_flush_window,
-                perf=self.perf_pipeline)
+        # per-peer sub-op coalescing (msg/messenger.py SubOpPipe):
+        # concurrent ops' sub-writes to one peer share a framed
+        # flush per window instead of one send per shard
+        from ..msg.messenger import SubOpPipe
+        self.subop_pipe = SubOpPipe(
+            self.msgr,
+            flush_window=self._pipeline_flush_window,
+            perf=self.perf_pipeline)
         addr = await self.msgr.bind(host, port)
         ack = await self._mon_request(
             "osd_boot", {"uuid": self.uuid, "host": self.host,

@@ -1351,10 +1351,10 @@ class PG:
         """Resolve logical ops to offset-explicit mutations, append a log
         entry, run the backend transaction.
 
-        Returns ``(err, commit)``: on the pipelined spine ``commit``
-        is the deferred remote-commit Task (local apply + sub-op sends
-        already happened; the caller awaits it OUTSIDE the PG lock),
-        None on the serial chain or pure-local writes."""
+        Returns ``(err, commit)``: ``commit`` is the deferred
+        remote-commit Task (local apply + sub-op sends already
+        happened; the caller awaits it OUTSIDE the PG lock), None for
+        pure-local writes or with the sub-op pipe down."""
         await self.wait_for_backfill_pushes(oid)
         size = await self.backend.object_size(oid)
         snap_muts: list[dict] = []

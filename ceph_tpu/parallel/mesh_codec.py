@@ -35,12 +35,12 @@ Shape of the thing:
   * single-device is just a 1-device mesh: the CPU tier-1 suite runs
     the identical partitioned program, and
     ``XLA_FLAGS=--xla_force_host_platform_device_count=8`` runs the
-    real 8-way SPMD program on CPU (tests/test_mesh_codec.py,
-    ``bench.py --osd-path --mesh --smoke``).
+    real 8-way SPMD program on CPU (tests/test_mesh_codec.py).
 
-Config is SNAPSHOT at construction (CodecBatcher.from_config): the
-mesh never holds a config object and no ``conf.get`` runs inside the
-launch loop (pinned by the test_mesh_codec micro-assertion).
+There is one program family: the dense bit-matmul with the coefficient
+matrix as an operand.  What the tests byte-check on the CPU is the
+program the chip runs.  The mesh holds no config object and reads no
+environment variable.
 """
 
 from __future__ import annotations
@@ -57,7 +57,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from .sharded_ec import _gf_matmul_bits, make_data_mesh
 from ..common.tracing import section
-from ..ops.gf2kernels import bitmatrix_i8, bucket_batch, check_batch_parity
+from ..ops.gf2kernels import bitmatrix_i8, bucket_batch
 
 # encode (B,k,L)->(B,m,L) and decode (B,k,L)->(B,r,L) donate a buffer
 # whose shape matches no output; XLA then frees it early instead of
@@ -94,7 +94,9 @@ DECODE_PROGRAM = "ec_decode_rows"
 def _jit_as(name: str, fn, donate_argnums: tuple):
     """``jax.jit`` under a program name of its own: the profiler's
     trace and the compile cache call the program ``jit_<name>``, so
-    encode, decode and rmw launches stand apart in a trace."""
+    encode, decode and rmw launches stand apart in a trace.  The
+    stripe buffers at ``donate_argnums`` are donated -- consumed by
+    the launch, never read again."""
     fn.__name__ = fn.__qualname__ = name
     return jax.jit(fn, donate_argnums=donate_argnums)
 
@@ -118,7 +120,7 @@ def _stripe_block(w_local, chunks, scope: str = "gf_encode"):
 
 @functools.lru_cache(maxsize=512)
 def _compiled_apply(mesh: Mesh, name: str, b: int, k: int, lane: int,
-                    with_crc: bool, donate: bool):
+                    with_crc: bool):
     """One launch: (8r,8k) W x (B,k,L) stripes -> (B,r,L) [+ chunk
     CRCs].  The batch axis shards over 'stripe'; W replicates.  The
     stripe buffer (arg 1) is donated -- consumed by the launch, never
@@ -133,7 +135,7 @@ def _compiled_apply(mesh: Mesh, name: str, b: int, k: int, lane: int,
         in_specs=(P(None, None), P("stripe", None, None)),
         out_specs=P("stripe", None, None))
     if not with_crc:
-        return _jit_as(name, sharded, (1,) if donate else ())
+        return _jit_as(name, sharded, (1,))
 
     def fn(w, data):
         from ..ops.crc32c_batch import crc32c_chunks_traced
@@ -142,72 +144,11 @@ def _compiled_apply(mesh: Mesh, name: str, b: int, k: int, lane: int,
                                 crc32c_chunks_traced(parity)], axis=1)
         return parity, crcs
 
-    return _jit_as(name + "_crc", fn, (1,) if donate else ())
+    return _jit_as(name + "_crc", fn, (1,))
 
 
 @functools.lru_cache(maxsize=256)
-def _compiled_apply_sched(mesh: Mesh, name: str, digest: str, b: int,
-                          k: int, lane: int, with_crc: bool,
-                          donate: bool):
-    """The scheduled twin of ``_compiled_apply``: the CSE-minimized
-    XOR schedule (ops/xor_schedule.py, looked up by matrix digest) is
-    BAKED into the program instead of taking W as an operand, so the
-    executable cache keys on the digest.  Same sharding, same fused
-    CRC side-path, same donation contract: the stripe buffer (arg 0)
-    is donated -- consumed by the launch, never read again."""
-    from ..ops.xor_schedule import apply_bits_traced, registered
-    sched = registered(digest)
-
-    def block(chunks):
-        bl, kk, ll = chunks.shape
-        flat = chunks.transpose(1, 0, 2).reshape(kk, bl * ll)
-        rows = apply_bits_traced(sched, flat)
-        return rows.reshape(-1, bl, ll).transpose(1, 0, 2)
-
-    sharded = shard_map(
-        block, mesh=mesh,
-        in_specs=(P("stripe", None, None),),
-        out_specs=P("stripe", None, None))
-    if not with_crc:
-        return _jit_as(name + "_sched", sharded,
-                       (0,) if donate else ())
-
-    def fn(data):
-        from ..ops.crc32c_batch import crc32c_chunks_traced
-        parity = sharded(data)
-        crcs = jnp.concatenate([crc32c_chunks_traced(data),
-                                crc32c_chunks_traced(parity)], axis=1)
-        return parity, crcs
-
-    return _jit_as(name + "_crc_sched", fn, (0,) if donate else ())
-
-
-@functools.lru_cache(maxsize=256)
-def _compiled_rmw_sched(mesh: Mesh, digest: str, b: int, m: int,
-                        k: int, lane: int, donate: bool):
-    """Scheduled RMW: new_parity = old_parity XOR schedule(delta) in
-    one launch, old-parity donated and ALIASED in place exactly like
-    the dense ``_compiled_rmw`` (shapes match)."""
-    from ..ops.xor_schedule import apply_bits_traced, registered
-    sched = registered(digest)
-
-    def block(oldp, delta):
-        bl, kk, ll = delta.shape
-        flat = delta.transpose(1, 0, 2).reshape(kk, bl * ll)
-        rows = apply_bits_traced(sched, flat)
-        return jnp.bitwise_xor(
-            oldp, rows.reshape(-1, bl, ll).transpose(1, 0, 2))
-
-    sharded = shard_map(
-        block, mesh=mesh,
-        in_specs=(P("stripe", None, None), P("stripe", None, None)),
-        out_specs=P("stripe", None, None))
-    return _jit_as("ec_rmw_sched", sharded, (0, 1) if donate else ())
-
-
-@functools.lru_cache(maxsize=256)
-def _compiled_rmw(mesh: Mesh, b: int, m: int, k: int, lane: int,
-                  donate: bool):
+def _compiled_rmw(mesh: Mesh, b: int, m: int, k: int, lane: int):
     """Delta-encoded partial-stripe RMW in one launch: new_parity =
     old_parity XOR encode(delta) (GF linearity; the sharded rendering
     of ECCommon.cc:704's pipeline).  old_parity (arg 1) is donated and
@@ -221,7 +162,7 @@ def _compiled_rmw(mesh: Mesh, b: int, m: int, k: int, lane: int,
         in_specs=(P(None, None), P("stripe", None, None),
                   P("stripe", None, None)),
         out_specs=P("stripe", None, None))
-    return _jit_as("ec_rmw", sharded, (1, 2) if donate else ())
+    return _jit_as("ec_rmw", sharded, (1, 2))
 
 
 @functools.lru_cache(maxsize=256)
@@ -238,7 +179,6 @@ def _decode_matrix_cached(mat_bytes: bytes, rows: int, k_total: int,
 
 def clear_mesh_cache() -> None:
     for fn in (_shared_mesh, _w_device, _compiled_apply, _compiled_rmw,
-               _compiled_apply_sched, _compiled_rmw_sched,
                _decode_matrix_cached):
         fn.cache_clear()
 
@@ -253,11 +193,9 @@ class MeshCodec:
     size the CodecBatcher pads to.
     """
 
-    def __init__(self, n_devices: int = 0, donate: bool = True,
-                 perf=None) -> None:
+    def __init__(self, n_devices: int = 0, perf=None) -> None:
         self.mesh = _shared_mesh(int(n_devices))
         self.n_devices = self.mesh.devices.size
-        self.donate = bool(donate)
         self.perf = perf
         self._data_sharding = NamedSharding(self.mesh,
                                             P("stripe", None, None))
@@ -267,18 +205,23 @@ class MeshCodec:
     # -- capability gate ----------------------------------------------------
     @staticmethod
     def supports(codec) -> bool:
-        """The mesh speaks two coefficient-matrix dialects: the jax
-        codec family (the ``encode_batch_crc`` marker -- the encode
-        matrix drives the launch directly and the decode matrix is the
-        same build_decode_matrix product decode_batch uses) and the
+        """Whether this codec's stripes launch through the mesh; one
+        that does not takes the per-op path.  The mesh speaks two
+        coefficient-matrix dialects: the positional matrix family
+        (batch entry points, an ``encode_matrix`` whose rows are the
+        chunk positions and no chunk remapping -- the encode matrix
+        drives the launch directly and the decode matrix is the same
+        build_decode_matrix product ``decode_batch`` uses) and the
         flat sub-chunk family (the ``mesh_flat_ok`` marker,
         ec/linear_codec.py -- chunks reshape to alpha sub-chunk rows
         around the same launches, matrices come from
-        ``parity_matrix``/``decode_flat_matrix``; fused CRC stays with
-        the first dialect, whose CRCs are chunk-granular)."""
+        ``parity_matrix``/``decode_flat_matrix``, keyed by position
+        so mapped layouts (lrc) launch too; fused CRC stays with the
+        first dialect, whose CRCs are chunk-granular)."""
         if getattr(codec, "mesh_flat_ok", False):
             return True
-        return (hasattr(codec, "encode_batch_crc")
+        return (hasattr(codec, "encode_batch")
+                and hasattr(codec, "decode_batch")
                 and getattr(codec, "encode_matrix", None) is not None
                 and not codec.get_chunk_mapping())
 
@@ -307,53 +250,13 @@ class MeshCodec:
         return jax.device_put(np.ascontiguousarray(arr, np.uint8),
                               self._data_sharding)
 
-    def _sched_launch(self, fn, dev_batch):
-        """``dev_batch`` is DONATED to the compiled scheduled launch:
-        the launch owns it; never read it after this call (the
-        donated-buffer-aliasing ROOTS name this entry point)."""
-        return fn(dev_batch)
-
-    def _sched_rmw_launch(self, fn, dev_old, dev_delta):
-        """Both device buffers are DONATED (old parity aliases the
-        output in place); never read either after this call."""
-        return fn(dev_old, dev_delta)
-
-    def _apply_sched(self, name: str, matrix: np.ndarray,
-                     batch: np.ndarray, with_crc: bool):
-        """The scheduled engine's output for this batch, or None when
-        the cost model picks dense.  A picked schedule serves or
-        raises (``KernelParityError`` on a first-launch parity miss)."""
-        from ..ops import xor_schedule as XS
-        b, k, lane = batch.shape
-        sched = XS.want_scheduled(bitmatrix_i8(matrix), lane,
-                                  jax.default_backend())
-        if sched is None:
-            return None
-        fn = _compiled_apply_sched(self.mesh, name, sched.digest, b, k,
-                                   lane, with_crc, self.donate)
-        out = self._sched_launch(fn, self._put(batch))
-        key = (sched.digest, "mesh", b, k, lane)
-        if key not in XS._sched_verified:
-            # one-time byte-parity gate vs the host oracle on a small
-            # slice (batch is the HOST copy: still readable)
-            check_batch_parity("scheduled mesh launch", matrix, batch,
-                               out[0] if with_crc else out, 1)
-            XS._sched_verified.add(key)
-        self._count(b)
-        XS.STATS.note_launch(sched)
-        return out
-
     def _apply(self, name: str, matrix: np.ndarray, batch: np.ndarray,
                with_crc: bool):
         b, k, lane = batch.shape
         assert b % self.n_devices == 0, (b, self.n_devices)
         matrix = np.ascontiguousarray(matrix, np.uint8)
-        out = self._apply_sched(name, matrix, batch, with_crc)
-        if out is not None:
-            return out
         w = _w_device(self.mesh, matrix.tobytes(), *matrix.shape)
-        fn = _compiled_apply(self.mesh, name, b, k, lane, with_crc,
-                             self.donate)
+        fn = _compiled_apply(self.mesh, name, b, k, lane, with_crc)
         out = fn(w, self._put(batch))
         self._count(b)
         return out
@@ -361,24 +264,24 @@ class MeshCodec:
     def encode(self, codec, batch: np.ndarray, with_crc: bool = False,
                out_np: bool = True):
         """(B, k, L) data chunks -> (B, m, L) parity in one sharded
-        launch; ``with_crc`` adds the (B, k+m) chunk CRCs computed
-        inside the SAME launch (no second round trip, no host
-        re-scan).  ``out_np=False`` leaves the result on device (the
-        pipelined batcher defers the materialization past its overlap
-        window)."""
+        launch; ``with_crc`` returns ``(parity, crcs)`` with the
+        (B, k+m) chunk CRCs computed inside the SAME launch (no second
+        round trip, no host re-scan), or ``(parity, None)`` for a flat
+        codec, whose caller hashes on the host.  ``out_np=False``
+        leaves the result on device (the batcher defers the
+        materialization past its overlap window)."""
         if self._flat(codec):
             # sub-chunk dialect: (B, k, L) -> (B, k*alpha, L/alpha)
             # rows around the same sharded launch; fused CRC is the
-            # other dialect's contract (the batcher routes CRC wants
-            # through the host batched pass for flat codecs)
-            assert not with_crc, "flat dialect has no fused CRC"
+            # other dialect's contract (its CRCs are chunk-granular)
             a = codec.alpha
             b, kc, lane = batch.shape
             out = self._apply("ec_encode", codec.parity_matrix,
                               batch.reshape(b, kc * a, lane // a),
                               False)
             out = out.reshape(b, -1, lane)
-            return _host(out) if out_np else out
+            out = _host(out) if out_np else out
+            return (out, None) if with_crc else out
         mat = codec.encode_matrix[codec.k:]
         if not with_crc:
             out = self._apply("ec_encode", mat, batch, False)
@@ -430,66 +333,21 @@ class MeshCodec:
         m = old_parity.shape[1]
         assert b % self.n_devices == 0, (b, self.n_devices)
         if self._flat(codec):
-            # GF linearity holds per sub-chunk row identically
+            # GF linearity holds per sub-chunk row identically: both
+            # operands reshape to sub-chunk rows around the launch
             a = codec.alpha
-            out = self._rmw_flat(codec, old_parity, delta, a)
-            if self.perf is not None:
-                self.perf.inc("mesh_rmw_launches")
-            return _host(out) if out_np else out
-        mat = np.ascontiguousarray(codec.encode_matrix[codec.k:],
-                                   np.uint8)
-        out = self._rmw_sched(mat, old_parity, delta)
-        if out is None:
-            w = _w_device(self.mesh, mat.tobytes(), *mat.shape)
-            fn = _compiled_rmw(self.mesh, b, m, k, lane, self.donate)
-            out = fn(w, self._put(old_parity), self._put(delta))
-            self._count(b)
+            mat = codec.parity_matrix
+            old_parity = old_parity.reshape(b, m * a, lane // a)
+            delta = delta.reshape(b, k * a, lane // a)
+        else:
+            mat = np.ascontiguousarray(codec.encode_matrix[codec.k:],
+                                       np.uint8)
+        w = _w_device(self.mesh, mat.tobytes(), *mat.shape)
+        fn = _compiled_rmw(self.mesh, b, old_parity.shape[1],
+                           *delta.shape[1:])
+        out = fn(w, self._put(old_parity), self._put(delta))
+        out = out.reshape(b, m, lane)
+        self._count(b)
         if self.perf is not None:
             self.perf.inc("mesh_rmw_launches")
         return _host(out) if out_np else out
-
-    def _rmw_flat(self, codec, old_parity: np.ndarray,
-                  delta: np.ndarray, a: int):
-        """Flat-dialect RMW: both operands reshape to sub-chunk rows,
-        then the standard scheduled/dense RMW ladder serves with the
-        codec's parity matrix."""
-        b, m, lane = old_parity.shape
-        k = delta.shape[1]
-        oldr = old_parity.reshape(b, m * a, lane // a)
-        deltar = delta.reshape(b, k * a, lane // a)
-        mat = codec.parity_matrix
-        out = self._rmw_sched(mat, oldr, deltar)
-        if out is None:
-            w = _w_device(self.mesh, mat.tobytes(), *mat.shape)
-            fn = _compiled_rmw(self.mesh, b, m * a, k * a, lane // a,
-                               self.donate)
-            out = fn(w, self._put(oldr), self._put(deltar))
-            self._count(b)
-        return out.reshape(b, m, lane)
-
-    def _rmw_sched(self, mat: np.ndarray, old_parity: np.ndarray,
-                   delta: np.ndarray):
-        """Scheduled RMW launch, or None when the cost model picks
-        dense; a picked schedule serves or raises."""
-        from ..ops import xor_schedule as XS
-        b, k, lane = delta.shape
-        m = old_parity.shape[1]
-        sched = XS.want_scheduled(bitmatrix_i8(mat), lane,
-                                  jax.default_backend())
-        if sched is None:
-            return None
-        fn = _compiled_rmw_sched(self.mesh, sched.digest, b, m, k, lane,
-                                 self.donate)
-        out = self._sched_rmw_launch(fn, self._put(old_parity),
-                                     self._put(delta))
-        key = (sched.digest, "mesh_rmw", b, k, lane)
-        if key not in XS._sched_verified:
-            # new = old ^ encode(delta): gate the encode(delta) term
-            # lint: disable=device-path-host-sync -- one-time parity gate vs the host oracle, bounded slice
-            enc = np.asarray(out[:1, :, :256]) ^ old_parity[:1, :, :256]
-            check_batch_parity("scheduled mesh RMW launch", mat,
-                               delta[:, :, :256], enc, 1)
-            XS._sched_verified.add(key)
-        self._count(b)
-        XS.STATS.note_launch(sched)
-        return out

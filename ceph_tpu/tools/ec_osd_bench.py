@@ -34,15 +34,12 @@ async def run_osd_path_bench(*, n_osds: int = 3, k: int = 2, m: int = 1,
                              pg_num: int = 8,
                              batch_max: int = 64,
                              batch_timeout: float = 0.002,
-                             rounds: int = 2,
-                             mesh: bool | None = None) -> dict:
+                             rounds: int = 2) -> dict:
     """Drive N concurrent EC writes; return throughput + occupancy.
 
-    ``mesh`` forces the sharded data plane on (True) or off (False);
-    None keeps the config default.  With the mesh, the report adds the
-    per-OSD mesh occupancy: device launches per coalesced batch (the
-    exactly-one gate), devices in the mesh, and padded stripes per
-    device per launch (the sharding factor)."""
+    The report carries the per-OSD mesh occupancy: device launches
+    per coalesced batch (the exactly-one gate), devices in the mesh,
+    and padded stripes per device per launch (the sharding factor)."""
     import numpy as np
     from ..client.rados import Rados
     from ..mon import Monitor
@@ -56,8 +53,6 @@ async def run_osd_path_bench(*, n_osds: int = 3, k: int = 2, m: int = 1,
             "osd_ec_batch_max": batch_max,
             "osd_ec_batch_timeout": batch_timeout,
         }
-        if mesh is not None:
-            cfg["osd_ec_mesh_enabled"] = bool(mesh)
         osd = OSD(host=f"host{i}", config=cfg)
         await osd.start(addr)
         osds.append(osd)
@@ -95,7 +90,6 @@ async def run_osd_path_bench(*, n_osds: int = 3, k: int = 2, m: int = 1,
         # roll up batch occupancy over every OSD's aggregation stage
         batches = stripes = pad = fallback = 0
         mesh_launches = mesh_padded = 0
-        xor_launches = xor_saved = 0
         n_devices = 0
         flush: dict[str, int] = {}
         for osd in osds:
@@ -106,8 +100,6 @@ async def run_osd_path_bench(*, n_osds: int = 3, k: int = 2, m: int = 1,
             fallback += dump.get("fallback_ops", 0)
             mesh_launches += dump.get("mesh_launches", 0)
             mesh_padded += dump.get("mesh_padded_stripes", 0)
-            xor_launches += dump.get("xor_sched_launches", 0)
-            xor_saved += dump.get("xor_terms_saved", 0)
             n_devices = max(n_devices,
                             int(dump.get("mesh_devices", 0)))
         for osd in osds:
@@ -142,10 +134,6 @@ async def run_osd_path_bench(*, n_osds: int = 3, k: int = 2, m: int = 1,
             "pad_waste_bytes": pad,
             "fallback_ops": fallback,
             "mesh": mesh_report,
-            "xor_sched": {
-                "launches": xor_launches,
-                "terms_saved": xor_saved,
-            },
             "ec_pipeline": pipeline,
             "flush_reasons": flush,
             "n_osds": n_osds, "k": k, "m": m,
@@ -170,17 +158,13 @@ def main(argv=None) -> int:
     p.add_argument("--pg-num", type=int, default=8)
     p.add_argument("--batch-max", type=int, default=64)
     p.add_argument("--rounds", type=int, default=2)
-    p.add_argument("--mesh", dest="mesh", action="store_true",
-                   default=None, help="force the sharded data plane on")
-    p.add_argument("--no-mesh", dest="mesh", action="store_false",
-                   help="force the sharded data plane off")
     args = p.parse_args(argv)
     enable_compile_cache()
     res = asyncio.run(run_osd_path_bench(
         n_osds=args.osds, k=args.k, m=args.m, n_objects=args.objects,
         obj_bytes=args.obj_kib * 1024, concurrency=args.concurrency,
         pg_num=args.pg_num, batch_max=args.batch_max,
-        rounds=args.rounds, mesh=args.mesh))
+        rounds=args.rounds))
     print(json.dumps(res), flush=True)
     return 0
 

@@ -1,10 +1,10 @@
 """Cross-PG EC codec batching (ceph_tpu/osd/codec_batcher.py).
 
 The aggregation stage must (a) coalesce concurrent encode/decode
-submissions into few ``encode_batch``/``decode_batch`` launches,
-(b) stay BYTE-IDENTICAL to the per-op path across ragged tails and
-padding, (c) fall back transparently for codecs without batch entry
-points, and (d) surface occupancy via perf counters.  The cluster
+submissions into few launches of its engine, (b) stay BYTE-IDENTICAL
+to the per-op path across ragged tails and padding, (c) leave codecs
+the engine cannot launch to the per-op path, counted, and (d) surface
+occupancy via perf counters.  The cluster
 tests drive the real OSD write path: N concurrent client EC writes
 across >=2 PGs must share launches and leave the same shard bytes on
 disk as an unbatched cluster.
@@ -16,6 +16,7 @@ import math
 import numpy as np
 import pytest
 
+from ceph_tpu import native
 from ceph_tpu.common.perf import PerfCounters
 from ceph_tpu.ec import registry
 from ceph_tpu.ops.jax_backend import JaxBackend
@@ -132,10 +133,10 @@ def test_fallback_for_non_batch_codec():
     """isa/jerasure (no encode_batch/decode_batch) take the per-op
     path transparently and the fallback is counted."""
     isa = registry().factory("isa", {"k": "2", "m": "1"})
-    assert not CodecBatcher.supports(isa)
     si = StripeInfo.for_codec(isa, stripe_unit=64)
     perf = PerfCounters("ec_batch")
     b = CodecBatcher(perf=perf)
+    assert not b.supports(isa)
     rng = np.random.default_rng(3)
     data = rng.integers(0, 256, si.stripe_width * 3,
                         dtype=np.uint8).tobytes()
@@ -153,6 +154,94 @@ def test_fallback_for_non_batch_codec():
     dump = perf.dump()
     assert dump["fallback_ops"] == 2
     assert "batches" not in dump or dump["batches"] == 0
+
+
+# every plugin under ceph_tpu/ec/plugins/: (plugin, profile, launches
+# through the engine)
+PLUGIN_CASES = [
+    ("clay", {"k": "4", "m": "2"}, False),
+    ("example", {}, False),
+    ("isa", {"k": "4", "m": "2"}, False),
+    ("jerasure", {"k": "4", "m": "2", "technique": "reed_sol_van"},
+     False),
+    ("lrc", {"k": "4", "m": "2", "l": "3"}, True),
+    ("pmsr", {"k": "3", "m": "2"}, True),
+    ("shec", {"k": "4", "m": "3", "c": "2"}, False),
+    ("tpu", {"k": "4", "m": "2", "technique": "reed_sol_van"}, True),
+    # a chunk remapping decouples shard ids from the matrix rows
+    ("tpu", {"k": "2", "m": "1", "mapping": "_DD"}, False),
+]
+
+
+@pytest.mark.parametrize(
+    "plugin,profile,launches", PLUGIN_CASES,
+    ids=[p + ("-mapped" if "mapping" in prof else "")
+         for p, prof, _ in PLUGIN_CASES])
+def test_every_plugin_launches_through_the_engine_or_per_op(
+        plugin, profile, launches):
+    """A codec either launches through the engine (one mesh launch a
+    batch, no fallback) or ``supports`` is false and the per-op path
+    serves, counted: none takes a third way, and the shard bytes and
+    CRCs are the codec's own either way."""
+    import pathlib
+    import ceph_tpu.ec.plugins as plugins
+    on_disk = {f.stem for f in pathlib.Path(plugins.__file__).parent.glob(
+        "*.py")} - {"__init__"}
+    assert on_disk == {p for p, _, _ in PLUGIN_CASES}
+
+    codec = registry().factory(plugin, dict(profile))
+    si = StripeInfo.for_codec(codec, codec.get_alignment())
+    perf = PerfCounters("ec_batch")
+    b = CodecBatcher(max_batch=8, flush_timeout=0.2, perf=perf)
+    assert b.supports(codec) == launches
+    rng = np.random.default_rng(12)
+    data = rng.integers(0, 256, si.stripe_width * 3,
+                        dtype=np.uint8).tobytes()
+    shards, crcs = run(si.encode_async(codec, data, batcher=b,
+                                       with_crc=True))
+    want = si.encode(codec, data)
+    assert set(shards) == set(want)
+    for i in want:
+        assert np.array_equal(shards[i], want[i]), i
+        assert crcs[i] == native.crc32c(want[i].tobytes()), i
+    dump = perf.dump()
+    if launches:
+        assert dump["batches"] == dump["mesh_launches"] == 1
+        assert dump.get("fallback_ops", 0) == 0
+    else:
+        assert dump["fallback_ops"] == 1
+        assert dump.get("batches", 0) == dump.get("mesh_launches", 0) == 0
+
+
+def test_submission_after_close_launches_through_the_engine():
+    """A straggler submitted after close() is not coalesced, but it
+    launches through the engine like any batch and is counted."""
+    codec = _codec()
+    perf = PerfCounters("ec_batch")
+    b = CodecBatcher(max_batch=64, flush_timeout=5.0, perf=perf)
+    b.close()
+    rng = np.random.default_rng(13)
+    arr = rng.integers(0, 256, (3, 2, 64), dtype=np.uint8)
+    oldp = rng.integers(0, 256, (3, 1, 64), dtype=np.uint8)
+
+    async def main():
+        parity, crcs = await asyncio.wait_for(
+            b.encode(codec, arr, with_crc=True), timeout=2.0)
+        return parity, crcs, await asyncio.wait_for(
+            b.rmw(codec, oldp, arr), timeout=2.0)
+
+    parity, crcs, new_parity = run(main())
+    for s in range(3):
+        want = codec.encode(set(range(3)), arr[s].tobytes())
+        assert np.array_equal(parity[s, 0], want[2]), s
+        assert int(crcs[s, 2]) == native.crc32c(want[2].tobytes()), s
+    assert np.array_equal(new_parity, oldp ^ parity)
+    dump = perf.dump()
+    assert dump["batches"] == dump["mesh_launches"] == 2
+    assert dump["flush_close"] == 2
+    assert dump["crc_fused_launches"] == 1
+    assert dump.get("crc_host_batches", 0) == 0
+    assert not b._groups and not b._staged
 
 
 def test_timer_flush_when_not_eager():
@@ -190,17 +279,17 @@ def test_drain_flush_is_prompt():
 
 
 def test_launch_error_propagates_to_all_waiters():
-    # mesh=None pins the contract on the single-device engine (with a
-    # mesh, a broken codec driver is ROUTED AROUND -- the mesh launch
-    # computes from the coefficient matrix directly; mesh-launch
-    # failures themselves degrade, pinned by test_mesh_codec)
     codec = _codec()
-    b = CodecBatcher(max_batch=2, flush_timeout=0.05, mesh=None)
 
-    def boom(*a, **k):
-        raise RuntimeError("driver on fire")
+    class BoomEngine:
+        def pad_batch(self, total):
+            return total
 
-    codec.encode_batch = boom
+        def encode(self, *a, **k):
+            raise RuntimeError("driver on fire")
+
+    b = CodecBatcher(max_batch=2, flush_timeout=0.05,
+                     engine=BoomEngine())
 
     async def main():
         jobs = [b.encode(codec, np.zeros((1, 2, 64), np.uint8))

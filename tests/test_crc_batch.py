@@ -275,14 +275,17 @@ def test_crc32c_resident_matches_scalar_on_ragged_buffers(n):
 
 
 def test_fused_encode_crc_byte_identity_vs_host_recompute():
-    """codec.encode_batch_crc: parity identical to encode_batch, CRCs
-    identical to a host re-hash of the emitted chunks."""
+    """MeshCodec.encode(with_crc=True) on a one-device mesh: parity
+    identical to the codec's own encode_batch, CRCs identical to a
+    host re-hash of the emitted chunks."""
     from ceph_tpu.ec import registry
+    from ceph_tpu.parallel.mesh_codec import MeshCodec
     codec = registry().factory("tpu", {"k": "3", "m": "2",
                                        "technique": "reed_sol_van"})
     rng = np.random.default_rng(8)
     data = rng.integers(0, 256, size=(4, 3, 512), dtype=np.uint8)
-    parity, crcs = codec.encode_batch_crc(data)
+    parity, crcs = MeshCodec(n_devices=1).encode(codec, data.copy(),
+                                                 with_crc=True)
     want_parity = np.asarray(codec.encode_batch(data, out_np=True))
     assert np.array_equal(parity, want_parity)
     full = np.concatenate([data, parity], axis=1)

@@ -269,8 +269,8 @@ def test_lrc_flat_generator_matches_layered_encode(registry):
 
 
 def test_lrc_batched_launches_match_host(registry):
-    """The mapped layout rides the CodecBatcher (padding buckets +
-    scheduled/dense kernels): encode_async/decode_async byte-parity
+    """The mapped layout rides the CodecBatcher (padding buckets, the
+    mesh's flat dialect): encode_async/decode_async byte-parity
     vs the per-stripe host driver, including a LOCAL batched repair
     (sources fewer than k, inexpressible in the positional
     decode-index dialect)."""
@@ -278,13 +278,13 @@ def test_lrc_batched_launches_match_host(registry):
     from ceph_tpu.osd.codec_batcher import CodecBatcher
     from ceph_tpu.osd.ec_util import StripeInfo
     codec = registry.factory("lrc", {"k": "4", "m": "2", "l": "3"})
-    assert CodecBatcher.supports(codec)
     sinfo = StripeInfo.for_codec(codec, 1024)
     data = rand_bytes(sinfo.stripe_width * 3, seed=23)
     host = sinfo.encode(codec, data)
 
     async def drive():
-        batcher = CodecBatcher(max_batch=8, mesh=None)
+        batcher = CodecBatcher(max_batch=8)
+        assert batcher.supports(codec)
         shards = await sinfo.encode_async(codec, data,
                                           batcher=batcher)
         for i in host:

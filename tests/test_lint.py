@@ -152,11 +152,12 @@ def test_sched_executor_host_sync_flagged(tmp_path):
     assert "sched_matmul_batch_device" in kept[0].message
 
 
-def test_donated_roots_flag_sched_launch_reuse(tmp_path):
-    """The donated-aliasing ROOTS seed the scheduled mesh launch
-    wrappers as donors: a device buffer read after being fed into
-    MeshCodec._sched_launch is a use-after-donate finding, even
-    though the jit carrying donate_argnums never appears in the AST."""
+def test_donated_roots_flag_sched_launch_reuse(tmp_path, monkeypatch):
+    """The donated-aliasing ROOTS seed a launch wrapper as a donor: a
+    device buffer read after being fed into it is a use-after-donate
+    finding, even though the jit carrying donate_argnums never appears
+    in the AST.  Without the root the same file is clean."""
+    from ceph_tpu.analysis.checkers import donated_aliasing
     _write(tmp_path, "meshy.py",
            "import jax\n\n\n"
            "class MeshCodec:\n"
@@ -167,6 +168,11 @@ def test_donated_roots_flag_sched_launch_reuse(tmp_path):
            "        return out, dev.sum()   # read-after-donate\n")
     kept, _, _ = lint(["meshy.py"], str(tmp_path),
                       rules=["donated-buffer-aliasing"])
+    assert kept == []
+    monkeypatch.setattr(donated_aliasing, "ROOTS",
+                        (("MeshCodec._sched_launch", (1,)),))
+    kept, _, _ = lint(["meshy.py"], str(tmp_path),
+                      rules=["donated-buffer-aliasing"])
     assert len(kept) == 1, [f.render() for f in kept]
     assert "dev" in kept[0].message
 
@@ -174,12 +180,16 @@ def test_donated_roots_flag_sched_launch_reuse(tmp_path):
 def test_device_path_roots_cover_the_dynamic_gate():
     """Every launch entry point the scalar_calls_on_batched_paths
     bench gate drives resolves to a real function, so the static rule
-    anchors at (at least) the paths the dynamic gate watches."""
+    anchors at (at least) the paths the dynamic gate watches; so does
+    every declared donor root of donated-buffer-aliasing."""
     from ceph_tpu.analysis.checkers.device_path import ROOTS
+    from ceph_tpu.analysis.checkers.donated_aliasing import \
+        ROOTS as DONOR_ROOTS
     _, project = analysis.run(TREE_PATHS, REPO,
                               rules=["device-path-host-sync"])
     graph = project.graph()
-    missing = [spec for spec in ROOTS if not graph.lookup(spec)]
+    missing = [spec for spec in ROOTS + tuple(s for s, _ in DONOR_ROOTS)
+               if not graph.lookup(spec)]
     assert missing == [], missing
 
 

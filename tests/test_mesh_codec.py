@@ -198,7 +198,7 @@ def test_exactly_one_mesh_launch_per_coalesced_batch():
 
 def test_mesh_launch_failure_reaches_the_waiters():
     """A broken mesh fails the batch's waiters with ITS error: the
-    batch is never quietly re-run on the single-device engine."""
+    batch is never quietly re-run another way."""
     codec = _codec(k="2", m="1")
     perf = PerfCounters("ec_batch")
 
@@ -210,7 +210,7 @@ def test_mesh_launch_failure_reaches_the_waiters():
             raise RuntimeError("mesh on fire")
 
     b = CodecBatcher(max_batch=8, flush_timeout=0.2, perf=perf,
-                     mesh=BoomMesh())
+                     engine=BoomMesh())
     arr = np.random.default_rng(8).integers(0, 256, (2, 2, 64),
                                             dtype=np.uint8)
     with pytest.raises(RuntimeError, match="mesh on fire"):
@@ -222,28 +222,26 @@ def test_donated_rmw_old_parity_aliases_in_place():
     """donate_argnums is live where it can bite: the RMW launch's
     old-parity buffer has the output's exact shape, so donating it
     lets XLA alias the update IN PLACE on device -- the buffer is
-    consumed (is_deleted) with donate=True and kept with donate=False.
-    (Encode/decode donations are advisory: no output matches the
-    (B, k, L) input, so XLA only gets an early-free hint there.)"""
+    consumed (is_deleted).  (Encode/decode donations are advisory: no
+    output matches the (B, k, L) input, so XLA only gets an early-free
+    hint there.)"""
     from ceph_tpu.parallel.mesh_codec import _compiled_rmw, _w_device
 
     codec = _codec(k="2", m="1")
     rng = np.random.default_rng(9)
     data = rng.integers(0, 256, (8, 2, 128), dtype=np.uint8)
-    for donate in (True, False):
-        mesh = MeshCodec(donate=donate)
-        parity = mesh.encode(codec, data)
-        mat = np.ascontiguousarray(codec.encode_matrix[codec.k:],
-                                   np.uint8)
-        w = _w_device(mesh.mesh, mat.tobytes(), *mat.shape)
-        fn = _compiled_rmw(mesh.mesh, 8, 1, 2, 128, donate)
-        oldp = mesh._put(parity)
-        out = fn(w, oldp, mesh._put(np.zeros_like(data)))
-        out.block_until_ready()
-        assert oldp.is_deleted() == donate
-        # the aliased update is still byte-correct (zero delta = same
-        # parity)
-        assert np.array_equal(np.asarray(out), parity)
+    mesh = MeshCodec()
+    parity = mesh.encode(codec, data)
+    mat = np.ascontiguousarray(codec.encode_matrix[codec.k:], np.uint8)
+    w = _w_device(mesh.mesh, mat.tobytes(), *mat.shape)
+    fn = _compiled_rmw(mesh.mesh, 8, 1, 2, 128)
+    oldp = mesh._put(parity)
+    out = fn(w, oldp, mesh._put(np.zeros_like(data)))
+    out.block_until_ready()
+    assert oldp.is_deleted()
+    # the aliased update is still byte-correct (zero delta = same
+    # parity)
+    assert np.array_equal(np.asarray(out), parity)
 
 
 def test_config_snapshot_no_lookup_in_launch_loop():
@@ -260,8 +258,7 @@ def test_config_snapshot_no_lookup_in_launch_loop():
             self.gets += 1
             return super().get(*a, **kw)
 
-    conf = CountingConf({"osd_ec_batch_max": 8,
-                         "osd_ec_mesh_enabled": True})
+    conf = CountingConf({"osd_ec_batch_max": 8})
     perf = PerfCounters("ec_batch")
     b = CodecBatcher.from_config(conf, perf=perf)
     assert b is not None
@@ -277,14 +274,12 @@ def test_config_snapshot_no_lookup_in_launch_loop():
         "config lookup inside the launch loop"
     assert perf.get("mesh_launches") == 3
     # no retained handle through which a lookup COULD happen
-    held = list(vars(b).values()) + list(vars(b._mesh).values())
+    held = list(vars(b).values()) + list(vars(b.engine).values())
     assert not any(v is conf for v in held)
 
-    # disabled batching snapshots to None, disabled mesh to no mesh
+    # disabled batching snapshots to None
     assert CodecBatcher.from_config(
         {"osd_ec_batch_enabled": False}) is None
-    b2 = CodecBatcher.from_config({"osd_ec_mesh_enabled": False})
-    assert b2._mesh is None and not b2._mesh_auto
 
 
 def test_mesh_vs_scalar_oracle_on_stripe_info_write_path():

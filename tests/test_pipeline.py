@@ -1,12 +1,10 @@
-"""The pipelined OSD write hot path (PR 12).
+"""The pipelined OSD write hot path.
 
-Three contracts, each pinned against the serial chain the kill switch
-restores:
+Three contracts:
 
-* BYTE PARITY: a pipelined cluster drive produces byte-identical
-  object content to the serial-chain oracle on identical seeds -- the
-  double-buffered batcher, the deferred commits and the coalesced
-  sub-op flushes may reorder WORK, never BYTES;
+* BYTE PARITY: a cluster drive reads back exactly the payloads it
+  wrote -- the double-buffered batcher, the deferred commits and the
+  coalesced sub-op flushes may reorder WORK, never BYTES;
 * ORDERING: per (PG, object), commits complete and replies ack in
   version order even when the fan-outs overlap, and the final content
   is the last write's;
@@ -56,23 +54,30 @@ async def _boot_ec_cluster(n_osds=4, *, osd_config=None, faults=None,
     return cluster, rados, io
 
 
-async def _drive(osd_config, n_objects=24, size=12 << 10):
+async def _drive(n_objects=24, size=12 << 10):
     """Write a deterministic working set (full writes + overwrites +
-    partial RMWs), read every object back, return the content map
-    plus the summed ec_pipeline counters."""
-    cluster, rados, io = await _boot_ec_cluster(osd_config=osd_config)
+    partial RMWs), read every object back, return what was written,
+    what was read and the summed ec_pipeline counters."""
+    cluster, rados, io = await _boot_ec_cluster()
     try:
         names = [f"obj-{i:03d}" for i in range(n_objects)]
+        written = {n: _payload(i, size) for i, n in enumerate(names)}
         # concurrent full writes: this is what coalesces and overlaps
-        await asyncio.gather(*(io.write_full(n, _payload(i, size))
-                               for i, n in enumerate(names)))
+        await asyncio.gather(*(io.write_full(n, written[n])
+                               for n in names))
         # overwrite a slice of them concurrently (per-object chains)
-        await asyncio.gather(*(io.write_full(n, _payload(i + 500, size))
-                               for i, n in enumerate(names[:8])))
+        for i, n in enumerate(names[:8]):
+            written[n] = _payload(i + 500, size)
+        await asyncio.gather(*(io.write_full(n, written[n])
+                               for n in names[:8]))
         # ranged RMWs ride the delta path
-        await asyncio.gather(*(io.write(n, _payload(i + 900, 2048),
-                                        offset=1024)
-                               for i, n in enumerate(names[8:16])))
+        patches = {n: _payload(i + 900, 2048)
+                   for i, n in enumerate(names[8:16])}
+        for n, patch in patches.items():
+            written[n] = (written[n][:1024] + patch
+                          + written[n][1024 + len(patch):])
+        await asyncio.gather(*(io.write(n, patch, offset=1024)
+                               for n, patch in patches.items()))
         content = {}
         for n in names:
             content[n] = await io.read(n)
@@ -84,27 +89,21 @@ async def _drive(osd_config, n_objects=24, size=12 << 10):
             for key, val in pc.dump().items():
                 if isinstance(val, (int, float)):
                     pipe[key] = pipe.get(key, 0) + val
-        return content, pipe
+        return written, content, pipe
     finally:
         await rados.shutdown()
         await cluster.stop()
 
 
 @pytest.mark.slow
-def test_pipelined_bytes_match_serial_oracle():
-    """The acceptance oracle: identical seeds through the serial
-    chain (kill switch) and the pipelined spine produce byte-identical
-    objects, and the pipelined drive's overlap counters are live."""
-    serial, pipe_off = run(_drive(
-        {"osd_pipeline_enabled": False}))
-    pipelined, pipe_on = run(_drive({}))
-    assert set(serial) == set(pipelined)
-    for name in serial:
-        assert serial[name] == pipelined[name], name
-    # the serial chain must not touch the pipeline at all
-    assert not pipe_off.get("staged_batches")
-    assert not pipe_off.get("overlapped_commits")
-    # the pipelined spine must actually pipeline
+def test_pipelined_bytes_match_payloads_written():
+    """Every object reads back as the payloads written to it, in
+    order, and the drive's overlap counters are live."""
+    written, read, pipe_on = run(_drive())
+    assert set(written) == set(read)
+    for name in written:
+        assert written[name] == read[name], name
+    # the spine must actually pipeline
     assert pipe_on.get("staged_batches", 0) > 0
     assert pipe_on.get("overlapped_commits", 0) > 0
     assert pipe_on.get("commit_overlap_ms", 0) > 0
@@ -207,24 +206,38 @@ def test_kill_mid_pipeline_drains_clean():
 # -- batcher double-buffering units (tier-1 fast) ---------------------------
 
 class _XorCodec:
-    """Tiny deterministic stand-in codec: parity = XOR of data rows."""
+    """What the batcher reads of a codec: the grouping signature."""
 
     def __init__(self, k=3, m=1):
         self.k, self.m = k, m
-        rows = np.vstack([np.eye(k, dtype=np.uint8),
-                          np.ones((m, k), np.uint8)])
-        self.encode_matrix = rows
+        self.encode_matrix = np.vstack([np.eye(k, dtype=np.uint8),
+                                        np.ones((m, k), np.uint8)])
 
-    def get_chunk_mapping(self):
-        return []
 
-    def encode_batch(self, data, out_np=False):
-        out = np.bitwise_xor.reduce(data, axis=1, keepdims=True)
-        return np.repeat(out, self.m, axis=1)
+class _XorEngine:
+    """Tiny deterministic stand-in launch engine: every output row is
+    the XOR of the input rows, host arrays throughout."""
 
-    def decode_batch(self, erasures, chunks, out_np=False):
-        out = np.bitwise_xor.reduce(chunks, axis=1, keepdims=True)
-        return np.repeat(out, len(erasures), axis=1)
+    def supports(self, codec):
+        return True
+
+    def pad_batch(self, total):
+        return 1 << max(0, total - 1).bit_length()
+
+    @staticmethod
+    def _xor_rows(batch, rows):
+        out = np.bitwise_xor.reduce(batch, axis=1, keepdims=True)
+        return np.repeat(out, rows, axis=1)
+
+    def encode(self, codec, batch, with_crc=False, out_np=True):
+        out = self._xor_rows(batch, codec.m)
+        return (out, None) if with_crc else out
+
+    def decode(self, codec, erasures, batch, out_np=True):
+        return self._xor_rows(batch, len(erasures))
+
+    def rmw(self, codec, old_parity, delta, out_np=True):
+        return old_parity ^ self._xor_rows(delta, codec.m)
 
 
 def _stripes(seed, n=4, k=3, lane=512):
@@ -233,8 +246,8 @@ def _stripes(seed, n=4, k=3, lane=512):
 
 
 def test_batcher_pipeline_parity_and_counters():
-    """Pipelined and serial batchers produce byte-identical results
-    from identical concurrent submissions; the pipelined one stages."""
+    """Concurrent submissions, coalesced, padded and staged, come back
+    byte-identical to the engine called once per op."""
     class Perf(dict):
         def inc(self, k, by=1):
             self[k] = self.get(k, 0) + by
@@ -245,20 +258,19 @@ def test_batcher_pipeline_parity_and_counters():
         def hist_sample(self, *a):
             pass
 
-    async def drive(pipeline):
+    engine, codec = _XorEngine(), _XorCodec()
+
+    async def drive():
         perf = Perf()
-        b = CodecBatcher(max_batch=64, mesh=None, pipeline=pipeline,
-                         pipe_perf=perf)
-        codec = _XorCodec()
+        b = CodecBatcher(max_batch=64, engine=engine, pipe_perf=perf)
         outs = await asyncio.gather(*(
             b.encode(codec, _stripes(s)) for s in range(6)))
         b.close()
-        return [np.asarray(o) for o in outs], perf
+        return outs, perf
 
-    serial, _ = run(drive(False))
-    pipelined, perf = run(drive(True))
-    for a, c in zip(serial, pipelined):
-        assert np.array_equal(a, c)
+    outs, perf = run(drive())
+    for s, out in enumerate(outs):
+        assert np.array_equal(out, engine.encode(codec, _stripes(s)))
     assert perf.get("staged_batches", 0) > 0
 
 
@@ -266,7 +278,7 @@ def test_batcher_close_drains_staged():
     """close() launches every parked batch synchronously -- no staged
     batch may outlive the batcher (an orphan wedges its op)."""
     async def main():
-        b = CodecBatcher(max_batch=1024, mesh=None, pipeline=True,
+        b = CodecBatcher(max_batch=1024, engine=_XorEngine(),
                          flush_timeout=60.0, eager_flush=False)
         codec = _XorCodec()
         fut = asyncio.ensure_future(b.encode(codec, _stripes(1)))
@@ -297,7 +309,7 @@ def test_staging_depth_bounds_and_counts_stalls():
 
     async def main():
         perf = Perf()
-        b = CodecBatcher(max_batch=1, mesh=None, pipeline=True,
+        b = CodecBatcher(max_batch=1, engine=_XorEngine(),
                          staging_depth=1, pipe_perf=perf)
         codec = _XorCodec()
         # max_batch=1: every submission flushes instantly; depth=1

@@ -176,13 +176,13 @@ def test_batched_encode_decode_matches_host(registry):
     from ceph_tpu.osd.codec_batcher import CodecBatcher
     from ceph_tpu.osd.ec_util import StripeInfo
     codec = make(registry, 3, 2)
-    assert CodecBatcher.supports(codec)
     sinfo = StripeInfo.for_codec(codec, codec.get_alignment())
     data = rand_bytes(sinfo.stripe_width * 3, seed=9)
     host = sinfo.encode(codec, data)
 
     async def drive():
-        batcher = CodecBatcher(max_batch=8, mesh=None)
+        batcher = CodecBatcher(max_batch=8)
+        assert batcher.supports(codec)
         shards = await sinfo.encode_async(codec, data,
                                           batcher=batcher)
         for i in host:
@@ -200,26 +200,22 @@ def test_batched_encode_decode_matches_host(registry):
 
 def test_scheduled_engine_parity(registry, monkeypatch):
     """CEPH_TPU_XOR_SCHED=1 forces the CSE-minimized scheduled engine:
-    encode through the batcher must stay byte-identical (a parity-gate
-    miss would raise)."""
+    the codec's ``encode_batch`` must stay byte-identical to the host
+    driver (a parity-gate miss would raise)."""
     monkeypatch.setenv("CEPH_TPU_XOR_SCHED", "1")
     from ceph_tpu.ops.xor_schedule import STATS
-    from ceph_tpu.osd.codec_batcher import CodecBatcher
     from ceph_tpu.osd.ec_util import StripeInfo
     codec = make(registry, 3, 2)
     sinfo = StripeInfo.for_codec(codec, codec.get_alignment())
     data = rand_bytes(sinfo.stripe_width * 2, seed=13)
     host = sinfo.encode(codec, data)
     before = STATS.snapshot()
-
-    async def drive():
-        batcher = CodecBatcher(max_batch=8, mesh=None)
-        shards = await sinfo.encode_async(codec, data,
-                                          batcher=batcher)
-        for i in host:
-            assert np.array_equal(host[i], shards[i]), i
-        batcher.close()
-
-    asyncio.new_event_loop().run_until_complete(drive())
+    arr = np.frombuffer(data, np.uint8).reshape(
+        2, codec.k, sinfo.chunk_size)
+    parity = np.asarray(codec.encode_batch(arr, out_np=True))
+    for r, pos in enumerate(p for p in range(codec.get_chunk_count())
+                            if p not in sinfo.data_positions(codec)):
+        assert np.array_equal(host[pos],
+                              parity[:, r].reshape(-1)), pos
     after = STATS.snapshot()
     assert after[0] > before[0]          # scheduled launches served

@@ -204,12 +204,12 @@ def test_mesh_programs_carry_their_own_names(call, name):
     w = jnp.zeros((8, 16), jnp.int8)
     data = jnp.zeros((2, 2, 64), jnp.uint8)
     if call == "rmw":
-        fn = mc._compiled_rmw(mesh, 2, 1, 2, 64, False)
+        fn = mc._compiled_rmw(mesh, 2, 1, 2, 64)
         text = fn.lower(w, jnp.zeros((2, 1, 64), jnp.uint8),
                         data).as_text(debug_info=True)
     else:
         fn = mc._compiled_apply(mesh, "ec_" + call.removesuffix("_crc"), 2,
-                                2, 64, call.endswith("_crc"), False)
+                                2, 64, call.endswith("_crc"))
         text = fn.lower(w, data).as_text(debug_info=True)
     assert f"module @{name} " in text
     # a decode's matmul stands under its own scope, the others' under

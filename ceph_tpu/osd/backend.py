@@ -211,8 +211,11 @@ class PGBackend:
         finally:
             tracing.finish(sp)
 
-    async def submit_transaction(self, entry: LogEntry,
-                                 muts: list[dict]) -> None:
+    async def submit_transaction(self, entry: LogEntry, muts: list[dict],
+                                 old_size: int | None = None) -> None:
+        """``old_size``: the object's size before this vector where the
+        PG already asked for it (``write_old_size``), None where no op
+        needed it."""
         raise NotImplementedError
 
     async def object_read(self, oid: str, off: int,
@@ -221,6 +224,11 @@ class PGBackend:
 
     async def object_size(self, oid: str) -> int:
         raise NotImplementedError
+
+    async def write_old_size(self, oid: str) -> int:
+        """The size a write finds, for an op whose offsets depend on it
+        (PG._do_writes asks once a vector, and only then)."""
+        return await self.object_size(oid)
 
     # recovery: full-object state transfer units
     async def read_recovery_payload(self, oid: str, shard: int) -> dict:
@@ -320,7 +328,7 @@ def build_pg_backend(pg):
 
 
 class ReplicatedBackend(PGBackend):
-    async def submit_transaction(self, entry, muts) -> None:
+    async def submit_transaction(self, entry, muts, old_size=None) -> None:
         txn = Transaction()
         apply_mutations(txn, self.coll, entry.oid, muts)
         if not entry.is_delete():
@@ -390,9 +398,14 @@ class ECBackend(PGBackend):
     Shard i of every object lives on acting[i] (shard id = position in
     the acting set, ErasureCodeInterface.h:39-78).  Writes that cover
     whole objects (fresh objects, truncate/remove chains, rewrites of
-    every stripe) run full-object RMW: reconstruct current logical
-    bytes, apply the mutation, re-encode, distribute per-shard
-    sub-writes.  Partial overwrites of existing objects take the
+    every stripe) run the full-object path: apply the mutation to the
+    current logical bytes, re-encode, distribute per-shard sub-writes.
+    The current bytes are reconstructed only where the result depends
+    on them: a vector that opens with ``truncate 0`` or ``remove``
+    (writefull, remove) reads nothing, and no shard holds content
+    where the old size is 0 (submit_transaction;
+    tests/test_ec_write_reads_nothing.py counts the gathers).
+    Partial overwrites of existing objects take the
     RMW pipeline (ECCommon.cc:704 start_rmw analog, _plan_rmw /
     _submit_partial below): only the touched stripes are read (the
     ExtentCache feeds repeats), merged, re-encoded and shipped as
@@ -436,6 +449,9 @@ class ECBackend(PGBackend):
         # of trusting the repair-math claim
         self.perf_recovery = perf.create("ec_recovery") \
             if perf is not None else None
+        # what a write read of the old object (the OSD-wide "ec_pipeline"
+        # set): write_old_gathers, writes_blind
+        self.perf_pipeline = getattr(self.osd, "perf_pipeline", None)
         # hot-path config SNAPSHOT (the ROADMAP config-reads-on-hot-
         # paths item): _gather_shards runs per degraded read; looking
         # these up per call put a dict probe chain on the read path
@@ -472,6 +488,10 @@ class ECBackend(PGBackend):
     def _rcount(self, key: str, by: int = 1) -> None:
         if self.perf_recovery is not None:
             self.perf_recovery.inc(key, by)
+
+    def _pcount(self, key: str) -> None:
+        if self.perf_pipeline is not None:
+            self.perf_pipeline.inc(key)
 
     @property
     def batcher(self):
@@ -1045,8 +1065,18 @@ class ECBackend(PGBackend):
         return stored, n_acting
 
     # -- write path ---------------------------------------------------------
-    async def submit_transaction(self, entry, muts) -> None:
-        """Full-object RMW: new logical content -> k+m shard writes."""
+    async def submit_transaction(self, entry, muts, old_size=None) -> None:
+        """New logical content -> k+m shard writes.
+
+        Old state is read only when the vector's result depends on it.
+        A vector whose first content mutation is ``truncate`` to 0 or
+        ``remove`` (what ``writefull`` and ``remove`` resolve to) has a
+        result that is a function of the vector alone: it takes the
+        full-object path over ``b""`` with no gather at all, whatever
+        the shards hold.  Any other vector needs the old size (the one
+        handed down, else asked here, once) to choose between the
+        partial-stripe path and the full-object one, and the old
+        content only where that size is not 0."""
         data_muts = [m for m in muts if m["op"] in
                      ("create", "write", "truncate", "zero", "remove")]
         attr_muts = [m for m in muts if m not in data_muts]
@@ -1075,14 +1105,25 @@ class ECBackend(PGBackend):
                                "attr_muts": attr_meta}
                     awaiting.append((osd, "ec_subop_write", payload,
                                      attr_segs))
+            self._pcount("writes_blind")
             return await self._commit_or_defer(awaiting, entry)
-        old_size = await self.object_size(entry.oid)
-        plan = self._plan_rmw(content_muts, old_size)
-        if plan is not None:
-            return await self._submit_partial(entry, content_muts,
-                                              attr_muts, old_size,
-                                              *plan)
-        old = await self._read_logical(entry.oid)
+        first = content_muts[0]
+        if first["op"] == "remove" or (first["op"] == "truncate"
+                                       and first["size"] == 0):
+            self._pcount("writes_blind")
+            old = b""
+        else:
+            if old_size is None:
+                old_size = await self.write_old_size(entry.oid)
+            plan = self._plan_rmw(content_muts, old_size)
+            if plan is not None:
+                return await self._submit_partial(entry, content_muts,
+                                                  attr_muts, old_size,
+                                                  *plan)
+            old = b""
+            if old_size:
+                self._pcount("write_old_gathers")
+                old = await self._read_logical(entry.oid)
         with tracing.section("osd_op.merge"):
             logical, remove = self._merge_content(old, content_muts)
             size = len(logical)
@@ -1259,6 +1300,7 @@ class ECBackend(PGBackend):
                 misses.append(s)
         async def _fetch_run(lo: int, hi: int):
             rng = (lo * cs, (hi - lo + 1) * cs)
+            self._pcount("write_old_gathers")
             bufs, _, _ = await self._gather_shards(oid, rng=rng)
             return lo, hi, await self.sinfo.decode_async(
                 self.codec, bufs, want=set(dpos), batcher=self.batcher)
@@ -1559,6 +1601,11 @@ class ECBackend(PGBackend):
             return int(sx)
         _, size, _ = await self._gather_shards(oid)
         return size
+
+    async def write_old_size(self, oid) -> int:
+        if self.store.getattr(self.coll, oid, SIZE_XATTR) is None:
+            self._pcount("write_old_gathers")   # object_size gathers
+        return await self.object_size(oid)
 
     async def read_recovery_payload(self, oid, shard) -> dict:
         """Reconstruct the target shard's buffer for a recovering peer.

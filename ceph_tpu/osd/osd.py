@@ -147,13 +147,17 @@ class OSD:
         # double-buffered batcher (staged_batches, overlap windows,
         # stage stalls), the deferred commit path (commit_overlap_ms)
         # and the per-peer sub-op coalescer (coalesced_subops,
-        # flush_windows) all report here.  Pipeline knobs are SNAPSHOT
-        # at construction.
+        # flush_windows) all report here, and so does what an erasure
+        # write read of the old object (write_old_gathers: the gathers
+        # it issued for the old size or content; writes_blind: the
+        # vectors that needed none).  Pipeline knobs are SNAPSHOT at
+        # construction.
         self.perf_pipeline = self.perf.create("ec_pipeline")
         for key in ("staged_batches", "inflight_overlap_windows",
                     "stage_stalls", "overlapped_commits",
                     "commit_overlap_ms", "coalesced_subops",
-                    "flush_windows"):
+                    "flush_windows", "write_old_gathers",
+                    "writes_blind"):
             self.perf_pipeline.inc(key, 0)    # visible even when idle
         self._pipeline_flush_window = float(
             self.config.get("osd_pipeline_flush_window", 0.002))

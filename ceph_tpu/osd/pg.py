@@ -1305,11 +1305,12 @@ class PG:
 
     # -- snapshots (snaps.py; SnapMapper.h:339, make_writeable) --------------
     async def _prepare_cow(self, oid: str, snapc: dict,
-                           size: int) -> list[dict] | str:
+                           old_size) -> list[dict] | str:
         """Clone-on-write: the first mutation after a newer snap clones
         the head so the snap keeps its frozen content.  Returns the
         snapset-update mutations to ride with the write entry, or an
-        error string."""
+        error string.  ``old_size`` is _do_writes' one question to the
+        backend, awaited only where a clone is recorded."""
         from .backend import ReplicatedBackend
         from .snaps import clone_oid, load_snapset
         if not isinstance(self.backend, ReplicatedBackend):
@@ -1335,7 +1336,7 @@ class PG:
                 await self.backend.submit_transaction(
                     centry, [{"op": "clone_from", "src": oid,
                               "snaps": newly}])
-                ss["clones"].append([cid, newly, size])
+                ss["clones"].append([cid, newly, await old_size()])
         if not exists:
             # created (or re-created after a delete) under this snap
             # context: snaps <= seq predate this incarnation, so reads
@@ -1356,10 +1357,32 @@ class PG:
         happened; the caller awaits it OUTSIDE the PG lock), None for
         pure-local writes or with the sub-op pipe down."""
         await self.wait_for_backfill_pushes(oid)
-        size = await self.backend.object_size(oid)
+        # The old size is asked for at most once, and only by an op
+        # whose offsets depend on it (append, zero, a snapc that has to
+        # clone): on an erasure pool whose primary holds no size xattr
+        # the question is a gather of k shards under this PG's lock.
+        # What was learned (None: not asked) goes down with the vector.
+        old_size: int | None = None
+
+        async def ask_old_size() -> int:
+            nonlocal old_size
+            if old_size is None:
+                old_size = await self.backend.write_old_size(oid)
+            return old_size
+
+        # the size as the ops so far leave it; None while that is still
+        # max(old size, floor) with the old size not asked
+        size: int | None = None
+        floor = 0
+
+        async def known_size() -> int:
+            if size is not None:
+                return size
+            return max(await ask_old_size(), floor)
+
         snap_muts: list[dict] = []
         if snapc and snapc.get("snaps"):
-            got = await self._prepare_cow(oid, snapc, size)
+            got = await self._prepare_cow(oid, snapc, ask_old_size)
             if isinstance(got, str):
                 return got, None
             snap_muts = got
@@ -1372,9 +1395,12 @@ class PG:
                 is_delete = False
             elif name == "write":
                 data = op["data"]
-                muts.append({"op": "write", "off": op.get("off", 0),
-                             "data": data})
-                size = max(size, op.get("off", 0) + len(data))
+                off = op.get("off", 0)
+                muts.append({"op": "write", "off": off, "data": data})
+                if size is None:
+                    floor = max(floor, off + len(data))
+                else:
+                    size = max(size, off + len(data))
                 is_delete = False
             elif name == "writefull":
                 data = op["data"]
@@ -1384,6 +1410,7 @@ class PG:
                 is_delete = False
             elif name == "append":
                 data = op["data"]
+                size = await known_size()
                 muts.append({"op": "write", "off": size, "data": data})
                 size += len(data)
                 is_delete = False
@@ -1394,6 +1421,7 @@ class PG:
             elif name == "zero":
                 # reference semantics: zero never extends the object
                 # (PrimaryLogPG CEPH_OSD_OP_ZERO truncates the range)
+                size = await known_size()
                 zlen = min(op["len"], max(0, size - op["off"]))
                 if zlen > 0:
                     muts.append({"op": "zero", "off": op["off"],
@@ -1425,7 +1453,8 @@ class PG:
             version=EVersion(self.osd.osdmap.epoch,
                              self.info.last_update.version + 1),
             prior_version=prior, mutations=[], reqid=reqid)
-        commit = await self.backend.submit_transaction(entry, muts)
+        commit = await self.backend.submit_transaction(entry, muts,
+                                                       old_size)
         return None, commit
 
     # -- recovery -----------------------------------------------------------

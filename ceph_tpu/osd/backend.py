@@ -489,9 +489,9 @@ class ECBackend(PGBackend):
         if self.perf_recovery is not None:
             self.perf_recovery.inc(key, by)
 
-    def _pcount(self, key: str) -> None:
+    def _pcount(self, key: str, by: int = 1) -> None:
         if self.perf_pipeline is not None:
-            self.perf_pipeline.inc(key)
+            self.perf_pipeline.inc(key, by)
 
     @property
     def batcher(self):
@@ -1283,7 +1283,21 @@ class ECBackend(PGBackend):
                             old_size: int) -> dict[int, bytearray]:
         """Old logical content of ``stripes``: ExtentCache first, then
         ranged shard gathers (degraded-safe: _gather_shards picks shards
-        via minimum_to_decode and decodes when data shards are down)."""
+        via minimum_to_decode and decodes when data shards are down).
+
+        Runs under an ``ec.rmw_read`` span of the op (tags: stripes of
+        stored content asked for, of which the ExtentCache served);
+        the same two counts go to ``ec_pipeline`` ``rmw_stripes_read``
+        and ``rmw_stripes_cached``."""
+        span = tracing.child_span("ec.rmw_read", oid=oid, asked=0,
+                                  cached=0)
+        try:
+            return await self._read_stripes_traced(oid, stripes,
+                                                   old_size, span)
+        finally:
+            tracing.finish(span)
+
+    async def _read_stripes_traced(self, oid, stripes, old_size, span):
         sw, cs = self.sinfo.stripe_width, self.sinfo.chunk_size
         n_old = self.sinfo.logical_to_next_stripe_offset(old_size) // sw
         dpos = self.sinfo.data_positions(self.codec)
@@ -1298,6 +1312,13 @@ class ECBackend(PGBackend):
                 out[s] = bytearray(c)
             else:
                 misses.append(s)
+        asked = sum(s < n_old for s in stripes)
+        cached = asked - len(misses)
+        self._pcount("rmw_stripes_read", asked)
+        self._pcount("rmw_stripes_cached", cached)
+        if span is not None:
+            span.tags.update(asked=asked, cached=cached)
+
         async def _fetch_run(lo: int, hi: int):
             rng = (lo * cs, (hi - lo + 1) * cs)
             self._pcount("write_old_gathers")
@@ -1317,20 +1338,14 @@ class ECBackend(PGBackend):
                      for p in dpos]).tobytes())
         return out
 
-    async def _submit_partial(self, entry, content_muts: list[dict],
-                              attr_muts: list[dict], old_size: int,
-                              new_size: int, stripes: list[int]) -> None:
-        oid = entry.oid
-        sw, cs = self.sinfo.stripe_width, self.sinfo.chunk_size
-        stripe_data = await self._read_stripes(oid, stripes, old_size)
-        # snapshot the OLD stripe bytes before merging: the delta-RMW
-        # parity path encodes (new XOR old) and XORs it onto the stored
-        # parity (GF linearity) instead of re-encoding whole stripes
-        old_data = {s: bytes(d) for s, d in stripe_data.items()} \
-            if self._rmw_delta else {}
-        # merge the mutations into the touched stripes; `cur` tracks the
-        # running logical size so a zero clamps against what earlier
-        # writes in this vector extended, not the stale old_size
+    def _merge_into_stripes(self, stripe_data: dict[int, bytearray],
+                            content_muts: list[dict],
+                            old_size: int) -> None:
+        """Apply the vector's writes and zeros to the touched stripes'
+        bytes in place.  ``cur`` tracks the running logical size so a
+        zero clamps against what earlier writes in this vector
+        extended, not the stale old_size."""
+        sw = self.sinfo.stripe_width
         cur = old_size
         for m in content_muts:
             if m["op"] == "write":
@@ -1343,15 +1358,30 @@ class ECBackend(PGBackend):
                 data = None
             else:
                 continue
-            for s in stripes:
+            for s, buf in stripe_data.items():
                 lo, hi = s * sw, (s + 1) * sw
                 a, b = max(off, lo), min(end, hi)
                 if a >= b:
                     continue
                 if data is None:
-                    stripe_data[s][a - lo:b - lo] = b"\0" * (b - a)
+                    buf[a - lo:b - lo] = b"\0" * (b - a)
                 else:
-                    stripe_data[s][a - lo:b - lo] = data[a - off:b - off]
+                    buf[a - lo:b - lo] = data[a - off:b - off]
+
+    async def _submit_partial(self, entry, content_muts: list[dict],
+                              attr_muts: list[dict], old_size: int,
+                              new_size: int, stripes: list[int]) -> None:
+        oid = entry.oid
+        sw, cs = self.sinfo.stripe_width, self.sinfo.chunk_size
+        stripe_data = await self._read_stripes(oid, stripes, old_size)
+        with tracing.section("osd_op.rmw_merge"):
+            # snapshot the OLD stripe bytes before merging: the
+            # delta-RMW parity path encodes (new XOR old) and XORs it
+            # onto the stored parity (GF linearity) instead of
+            # re-encoding whole stripes
+            old_data = {s: bytes(d) for s, d in stripe_data.items()} \
+                if self._rmw_delta else {}
+            self._merge_into_stripes(stripe_data, content_muts, old_size)
         # process each contiguous run in one driver call (runs submit
         # concurrently so the batcher coalesces them — and any other
         # op's stripes — into a single launch); collect ranged
@@ -1388,30 +1418,34 @@ class ECBackend(PGBackend):
             return [(shard, lo * cs, shards[shard].tobytes())
                     for shard in range(len(acting))]
 
-        async def _delta_run(lo: int, hi: int):
+        async def _old_parity(lo: int, hi: int):
+            """The run's stored parity chunks, (n, m, cs); None where a
+            parity source is down, stale or short, so that the delta
+            has nothing sound to XOR onto."""
+            n = hi - lo + 1
+            pbufs, pfailed, _ = await self._fetch_shards(
+                oid, [p for p in ppos if p in avail], avail,
+                (lo * cs, n * cs), self._read_timeout)
+            if pfailed or set(ppos) - set(pbufs) or any(
+                    len(pbufs[p][0]) != n * cs for p in ppos):
+                return None
+            return np.stack(
+                [np.asarray(pbufs[p][0], np.uint8).reshape(n, cs)
+                 for p in ppos], axis=1)
+
+        async def _delta_run(lo: int, hi: int, old_parity):
             """Delta-update parity in place; ship only changed data
             chunks + the m parity chunks."""
             n = hi - lo + 1
-            rng = (lo * cs, n * cs)
-            pbufs, pfailed, _ = await self._fetch_shards(
-                oid, [p for p in ppos if p in avail], avail, rng,
-                self._read_timeout)
-            if pfailed or set(ppos) - set(pbufs) or any(
-                    len(pbufs[p][0]) != n * cs for p in ppos):
-                # a parity source is down/stale/short: the delta has
-                # nothing sound to XOR onto -- re-encode instead
-                return await _full_run(lo, hi)
-            old_parity = np.stack(
-                [np.asarray(pbufs[p][0], np.uint8).reshape(n, cs)
-                 for p in ppos], axis=1)              # (n, m, cs)
-            new_arr = np.frombuffer(
-                b"".join(bytes(stripe_data[s])
-                         for s in range(lo, hi + 1)),
-                np.uint8).reshape(n, self.sinfo.k, cs)
-            old_arr = np.frombuffer(
-                b"".join(old_data[s] for s in range(lo, hi + 1)),
-                np.uint8).reshape(n, self.sinfo.k, cs)
-            delta = new_arr ^ old_arr
+            with tracing.section("osd_op.rmw_merge"):
+                new_arr = np.frombuffer(
+                    b"".join(bytes(stripe_data[s])
+                             for s in range(lo, hi + 1)),
+                    np.uint8).reshape(n, self.sinfo.k, cs)
+                old_arr = np.frombuffer(
+                    b"".join(old_data[s] for s in range(lo, hi + 1)),
+                    np.uint8).reshape(n, self.sinfo.k, cs)
+                delta = new_arr ^ old_arr
             new_parity = await self.batcher.rmw(self.codec,
                                                 old_parity, delta)
             self.batcher.note_rmw(delta=True)
@@ -1427,10 +1461,24 @@ class ECBackend(PGBackend):
                     new_parity[:, r]).reshape(-1).tobytes()))
             return out
 
+        # the delta runs' stored parity first, every run's fetch in
+        # flight at once (span ``ec.rmw_parity``), then every run's
+        # launch (span ``ec.encode``: on this path the launch wait
+        # alone); a run whose parity cannot be had re-encodes whole
+        delta_runs = [(lo, hi) for lo, hi in runs
+                      if delta_ok and hi < n_old and all(
+                          s in old_data for s in range(lo, hi + 1))]
+        span = tracing.child_span("ec.rmw_parity", oid=oid,
+                                  runs=len(delta_runs))
+        try:
+            parity = dict(zip(delta_runs, await asyncio.gather(
+                *(_old_parity(lo, hi) for lo, hi in delta_runs))))
+        finally:
+            tracing.finish(span)
+
         async def _run_one(lo: int, hi: int):
-            if delta_ok and hi < n_old and all(
-                    s in old_data for s in range(lo, hi + 1)):
-                return await _delta_run(lo, hi)
+            if parity.get((lo, hi)) is not None:
+                return await _delta_run(lo, hi, parity[lo, hi])
             return await _full_run(lo, hi)
 
         span = tracing.child_span("ec.encode", oid=oid)
@@ -1454,6 +1502,9 @@ class ECBackend(PGBackend):
                  "writes": [[off, len(buf)]
                             for off, buf in shard_writes[shard]]}
             segs = [buf for _, buf in shard_writes[shard]]
+            if not segs:
+                # an unchanged data shard: the version stamp alone
+                self._pcount("rmw_subwrites_empty")
             if osd == self.osd.whoami:
                 self.apply_sub_write(entry, w, segs, attr_muts,
                                      shard=shard)
@@ -1560,28 +1611,35 @@ class ECBackend(PGBackend):
         When the final content is in hand it becomes the cache entry
         for ``(coll, oid)`` -- the write's encoded bytes flow straight
         into residency, so the next read/scrub/decode never touches
-        the store."""
-        if crc is None:
-            if content is None:
-                try:
-                    content = self.store.read(self.coll, oid, 0, None)
-                except FileNotFoundError:
-                    return
-                if self.dcache is not None:
-                    self.dcache.note_host_read(len(content))
-            crc = shard_crc(content)
-        txn = Transaction()
-        if shard is not None:
-            txn.setattr(self.coll, oid, SHARD_XATTR,
-                        str(int(shard)).encode())
-        txn.setattr(self.coll, oid, CRC_XATTR,
-                    str(int(crc)).encode())
-        with tracing.section("store.queue_transaction"):
-            self.store.queue_transaction(txn)
-        if self.dcache is not None and content is not None \
-                and size is not None and ver is not None:
-            self.dcache.put(self.coll, oid, content, size=size,
-                            ver=ver, shard=shard, crc=int(crc))
+        the store.
+
+        Section ``osd_op.stamp``: the read-back and the CRC where the
+        write handed none down, and building the tag; the tag's own
+        transaction stays the store's (``store.queue_transaction``),
+        as every other transaction is."""
+        with tracing.section("osd_op.stamp"):
+            if crc is None:
+                if content is None:
+                    try:
+                        content = self.store.read(self.coll, oid, 0,
+                                                  None)
+                    except FileNotFoundError:
+                        return
+                    if self.dcache is not None:
+                        self.dcache.note_host_read(len(content))
+                crc = shard_crc(content)
+            txn = Transaction()
+            if shard is not None:
+                txn.setattr(self.coll, oid, SHARD_XATTR,
+                            str(int(shard)).encode())
+            txn.setattr(self.coll, oid, CRC_XATTR,
+                        str(int(crc)).encode())
+            with tracing.section("store.queue_transaction"):
+                self.store.queue_transaction(txn)
+            if self.dcache is not None and content is not None \
+                    and size is not None and ver is not None:
+                self.dcache.put(self.coll, oid, content, size=size,
+                                ver=ver, shard=shard, crc=int(crc))
 
     # -- read path ----------------------------------------------------------
     async def object_read(self, oid, off, length) -> bytes:

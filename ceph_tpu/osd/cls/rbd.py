@@ -17,7 +17,7 @@ import json
 from . import CLS_METHOD_RD, CLS_METHOD_WR, ClsError, register
 
 # omap keys on the header object
-K_META = "rbd_meta"                 # {size, order, object_prefix, features}
+K_META = "rbd_meta"     # {size, order, object_prefix, features[, data_pool]}
 K_SNAPSEQ = "snap_seq"
 K_SNAP = "snapshot_"                # snapshot_<id:016x> -> {name,size,protected}
 K_PARENT = "parent"                 # {pool_id, image_id, snap_id, overlap}
@@ -51,16 +51,16 @@ def create(hctx, indata: bytes) -> bytes:
     if not 12 <= order <= 26:
         raise ClsError("EINVAL", f"order {order} out of range")
     hctx.create(exclusive=True)
-    hctx.map_set_vals({
-        K_META: json.dumps({
-            "size": int(q["size"]), "order": order,
+    meta = {"size": int(q["size"]), "order": order,
             "object_prefix": q["object_prefix"],
             "features": q.get("features", ["layering"]),
             "stripe_unit": int(q.get("stripe_unit", 1 << order)),
-            "stripe_count": int(q.get("stripe_count", 1)),
-        }).encode(),
-        K_SNAPSEQ: b"0",
-    })
+            "stripe_count": int(q.get("stripe_count", 1))}
+    if q.get("data_pool"):
+        # rbd create --data-pool: the data objects' pool, by name
+        meta["data_pool"] = str(q["data_pool"])
+    hctx.map_set_vals({K_META: json.dumps(meta).encode(),
+                       K_SNAPSEQ: b"0"})
     return b""
 
 

@@ -150,14 +150,19 @@ class OSD:
         # flush_windows) all report here, and so does what an erasure
         # write read of the old object (write_old_gathers: the gathers
         # it issued for the old size or content; writes_blind: the
-        # vectors that needed none).  Pipeline knobs are SNAPSHOT at
-        # construction.
+        # vectors that needed none) and what a partial-stripe overwrite
+        # cost (rmw_stripes_read: stripes of stored content it asked
+        # for; rmw_stripes_cached: of those, served by the ExtentCache;
+        # rmw_subwrites_empty: sub-writes that carried the version
+        # stamp alone, to data shards it left unchanged).  Pipeline
+        # knobs are SNAPSHOT at construction.
         self.perf_pipeline = self.perf.create("ec_pipeline")
         for key in ("staged_batches", "inflight_overlap_windows",
                     "stage_stalls", "overlapped_commits",
                     "commit_overlap_ms", "coalesced_subops",
                     "flush_windows", "write_old_gathers",
-                    "writes_blind"):
+                    "writes_blind", "rmw_stripes_read",
+                    "rmw_stripes_cached", "rmw_subwrites_empty"):
             self.perf_pipeline.inc(key, 0)    # visible even when idle
         self._pipeline_flush_window = float(
             self.config.get("osd_pipeline_flush_window", 0.002))

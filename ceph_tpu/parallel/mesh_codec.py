@@ -350,4 +350,5 @@ class MeshCodec:
         self._count(b)
         if self.perf is not None:
             self.perf.inc("mesh_rmw_launches")
+            self.perf.inc("mesh_rmw_padded_stripes", b)
         return _host(out) if out_np else out

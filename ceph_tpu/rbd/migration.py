@@ -119,7 +119,7 @@ async def migration_execute(dst_ioctx, dst_name: str) -> int:
                     return 0
                 oid = dst._data_obj(objectno)
                 try:
-                    await dst.ioctx.stat(oid)
+                    await dst.data_ioctx.stat(oid)
                     return 0      # already materialized: skip the
                                   # source read entirely (re-runs,
                                   # client-written objects)

@@ -7,6 +7,12 @@ Layout follows rbd format 2 (src/librbd/image/CreateRequest.cc):
     rbd_header.<id>                image metadata omap (cls_rbd methods)
     rbd_data.<id>.<objectno:016x>  data objects, 2^order bytes each
 
+With ``data_pool`` (rbd create --data-pool) only the data objects live
+on that pool, which may be erasure coded with overwrites; directory,
+header, object map, journal and locks stay on the image's own pool
+(omap is not supported on an erasure pool), and the header names the
+data pool so that ``open`` finds it.
+
 The I/O path mirrors src/librbd/io/ImageRequest.cc: an image extent is
 cut into per-object extents (the striper's map_extents with
 su=2^order, sc=1 by default; fancy striping supported), object ops are
@@ -61,7 +67,11 @@ class RBD:
     async def create(self, ioctx, name: str, size: int, order: int = 22,
                      stripe_unit: int | None = None,
                      stripe_count: int = 1,
-                     features: list[str] | None = None) -> str:
+                     features: list[str] | None = None,
+                     data_pool: IoCtx | None = None) -> str:
+        """``data_pool``: the pool the data objects go to (rbd create
+        --data-pool), recorded by name in the header; left out, they
+        share ``ioctx``'s pool with the header."""
         iid = os.urandom(8).hex()
         try:
             await ioctx.exec(RBD_DIRECTORY, "rbd", "dir_add_image",
@@ -69,13 +79,16 @@ class RBD:
                                          "id": iid}).encode())
         except RadosError as e:
             raise _wrap(e) from e
-        try:
-            await ioctx.exec(_header(iid), "rbd", "create", json.dumps({
-                "size": int(size), "order": order,
+        spec = {"size": int(size), "order": order,
                 "object_prefix": f"rbd_data.{iid}",
                 "features": features or ["layering"],
                 "stripe_unit": stripe_unit or (1 << order),
-                "stripe_count": stripe_count}).encode())
+                "stripe_count": stripe_count}
+        if data_pool is not None:
+            spec["data_pool"] = data_pool.pool_name
+        try:
+            await ioctx.exec(_header(iid), "rbd", "create",
+                             json.dumps(spec).encode())
         except RadosError as e:
             # roll the directory entry back so a failed create does not
             # leave a dangling name
@@ -175,6 +188,14 @@ async def _gather_bounded(coros, limit: int = 16):
     return await asyncio.gather(*(one(c) for c in coros))
 
 
+async def _open_data_pool(ioctx, meta: dict) -> IoCtx | None:
+    """A private ioctx on the pool the header names as ``data_pool``;
+    None where header and data share a pool."""
+    if not meta.get("data_pool"):
+        return None
+    return await ioctx.rados.open_ioctx(meta["data_pool"])
+
+
 class Image:
     """An open image handle (librbd::Image).
 
@@ -183,8 +204,12 @@ class Image:
     """
 
     def __init__(self, ioctx, name: str, iid: str, meta: dict,
-                 read_only: bool, snap_id: int | None) -> None:
+                 read_only: bool, snap_id: int | None,
+                 data_ioctx=None) -> None:
         self.ioctx = ioctx
+        # where the data objects live: the header's pool, or the
+        # header's ``data_pool``
+        self.data_ioctx = data_ioctx or ioctx
         self.name = name
         self.id = iid
         self.meta = meta
@@ -200,7 +225,7 @@ class Image:
         self.cacher = None
         # DATA-path ioctx: plain, or a CryptoIoCtx when the image is
         # encrypted (crypto sits below the cache, above the wire)
-        self._dio = ioctx
+        self._dio = self.data_ioctx
         self._no_data_key = False
         # live migration: destination images fall through to the
         # source for not-yet-copied data (librbd/migration)
@@ -247,9 +272,13 @@ class Image:
         # opened on a shared ioctx would clobber the first image's
         # write snapc (silently skipping COW for its snapshots)
         ioctx = IoCtx(ioctx.rados, ioctx.pool_name, ioctx.pool_id)
+        try:
+            data_ioctx = await _open_data_pool(ioctx, meta)
+        except RadosError as e:
+            raise _wrap(e) from e
         snap_id = None
         img = Image(ioctx, name, iid, meta, read_only or bool(snapshot),
-                    snap_id)
+                    snap_id, data_ioctx)
         # encryption gate BEFORE any data I/O: an encrypted image
         # without its passphrase must refuse, not serve ciphertext
         from .crypto import (CryptoIoCtx, ENVELOPE_XATTR,
@@ -278,7 +307,7 @@ class Image:
                 key = unwrap_key(json.loads(env_raw), passphrase)
             except WrongPassphrase as e:
                 raise RbdError("EPERM", str(e)) from e
-            img._dio = CryptoIoCtx(img.ioctx, key)
+            img._dio = CryptoIoCtx(img.data_ioctx, key)
         if snapshot is not None:
             img.snap_id = img._snap_by_name(snapshot)["id"]
         from .migration import (MIG_DST_XATTR, MIG_SRC_XATTR,
@@ -330,7 +359,7 @@ class Image:
             raise RbdError("EEXIST", "image is already encrypted")
         key = await format_encryption(self.ioctx, _header(self.id),
                                       passphrase)
-        self._dio = CryptoIoCtx(self.ioctx, key)
+        self._dio = CryptoIoCtx(self.data_ioctx, key)
         if self.cacher is not None:
             self.cacher.ioctx = self._dio
 
@@ -563,7 +592,7 @@ class Image:
         write COWs against the image's snapshots."""
         snapc = json.loads(await self.ioctx.exec(
             _header(self.id), "rbd", "get_snapcontext", b""))
-        self.ioctx.set_snap_context(snapc["seq"], snapc["snaps"])
+        self.data_ioctx.set_snap_context(snapc["seq"], snapc["snaps"])
 
     async def size(self) -> int:
         if self.snap_id is not None:
@@ -575,6 +604,7 @@ class Image:
     def stat(self) -> dict:
         return {"size": self.meta["size"], "order": self.meta["order"],
                 "id": self.id, "object_prefix": self.meta["object_prefix"],
+                "data_pool": self.meta.get("data_pool"),
                 "num_objs": self._object_count(self.meta["size"]),
                 "parent": self.meta.get("parent"),
                 "snapshots": self.meta["snapshots"]}
@@ -594,7 +624,8 @@ class Image:
             meta = json.loads(await pioctx.exec(
                 _header(pref["image_id"]), "rbd", "get_image_meta", b""))
             self._parent = Image(pioctx, "", pref["image_id"], meta,
-                                 True, pref["snap_id"])
+                                 True, pref["snap_id"],
+                                 await _open_data_pool(pioctx, meta))
         return self._parent
 
     async def _mig_source_img(self) -> "Image | None":
@@ -717,9 +748,9 @@ class Image:
         a live client writer can race -- first creator wins, the other
         no-ops and never clobbers newer data.  Encrypted images ship
         the payload pre-encrypted (the cls path bypasses CryptoIoCtx)."""
-        if self._dio is not self.ioctx:
+        if self._dio is not self.data_ioctx:
             buf = self._dio.encrypt_full(oid, buf)
-        await self.ioctx.exec(oid, "rbd", "copyup", bytes(buf))
+        await self.data_ioctx.exec(oid, "rbd", "copyup", bytes(buf))
 
     async def write(self, off: int, data: bytes) -> int:
         if self._no_data_key:
@@ -746,7 +777,7 @@ class Image:
                 await self.object_map.mark_written(objectno)
             if has_parent and lay.stripe_count == 1:
                 try:
-                    await self.ioctx.stat(self._data_obj(objectno))
+                    await self.data_ioctx.stat(self._data_obj(objectno))
                 except RadosError as e:
                     if e.errno_name == "ENOENT":
                         await self._copyup(objectno)
@@ -798,7 +829,7 @@ class Image:
             try:
                 if obj_off == 0 and n == lay.object_size \
                         and not has_parent:
-                    await self.ioctx.remove(oid)
+                    await self.data_ioctx.remove(oid)
                     if self.object_map is not None:
                         await self.object_map.mark_removed(objectno)
                     return
@@ -807,7 +838,7 @@ class Image:
                     # zero() is a no-op on a missing object and reads
                     # would fall through to PARENT bytes, not zeros
                     try:
-                        await self.ioctx.stat(oid)
+                        await self.data_ioctx.stat(oid)
                     except RadosError as e:
                         if e.errno_name != "ENOENT":
                             raise
@@ -826,7 +857,7 @@ class Image:
 
     async def _remove_data_obj(self, objectno: int) -> None:
         try:
-            await self.ioctx.remove(self._data_obj(objectno))
+            await self.data_ioctx.remove(self._data_obj(objectno))
         except RadosError as e:
             if e.errno_name != "ENOENT":
                 raise
@@ -888,14 +919,14 @@ class Image:
         if self.journal is not None:
             jseq = await self.journal.append(
                 {"op": "snap_create", "name": snap_name})
-        sid = await self.ioctx.selfmanaged_snap_create()
+        sid = await self.data_ioctx.selfmanaged_snap_create()
         try:
             await self.ioctx.exec(
                 _header(self.id), "rbd", "snapshot_add",
                 json.dumps({"snap_id": sid,
                             "name": snap_name}).encode())
         except RadosError as e:
-            await self.ioctx.selfmanaged_snap_remove(sid)
+            await self.data_ioctx.selfmanaged_snap_remove(sid)
             raise _wrap(e) from e
         if self.object_map is not None:
             # freeze the map under this snap id; head entries go CLEAN
@@ -929,7 +960,7 @@ class Image:
                 json.dumps({"snap_id": snap["id"]}).encode())
         except RadosError as e:
             raise _wrap(e) from e
-        await self.ioctx.selfmanaged_snap_remove(snap["id"])
+        await self.data_ioctx.selfmanaged_snap_remove(snap["id"])
         await self._refresh_meta()
         await self._refresh_snapc()
         await self._notify_header()
@@ -969,8 +1000,8 @@ class Image:
         async def roll(objectno):
             oid = self._data_obj(objectno)
             try:
-                buf = await self.ioctx.read(oid, snap=snap["id"])
-                await self.ioctx.write_full(oid, buf)
+                buf = await self.data_ioctx.read(oid, snap=snap["id"])
+                await self.data_ioctx.write_full(oid, buf)
             except RadosError as e:
                 if e.errno_name != "ENOENT":
                     raise
@@ -993,7 +1024,7 @@ class Image:
 
         async def up(objectno):
             try:
-                await self.ioctx.stat(self._data_obj(objectno))
+                await self.data_ioctx.stat(self._data_obj(objectno))
             except RadosError as e:
                 if e.errno_name == "ENOENT":
                     await self._copyup(objectno)

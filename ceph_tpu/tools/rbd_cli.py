@@ -4,6 +4,8 @@ snapshots, clone/flatten, export/import, and a micro write bench.
 Usage (against a vstart cluster):
     python -m ceph_tpu.tools.rbd_cli --mon 127.0.0.1:6789 \
         create -p rbd --size 64M img1
+    python -m ceph_tpu.tools.rbd_cli -p rbd create --size 1G \
+        --data-pool ecpool img2      # header on rbd, data on ecpool
 """
 
 from __future__ import annotations
@@ -35,9 +37,13 @@ async def amain(args) -> int:
         io = await rados.open_ioctx(args.pool)
         rbd = RBD()
         if args.cmd == "create":
+            data_io = (await rados.open_ioctx(args.data_pool)
+                       if args.data_pool else None)
             await rbd.create(io, args.image, parse_size(args.size),
-                             order=args.order)
-            print(f"created {args.image} ({args.size})")
+                             order=args.order, data_pool=data_io)
+            print(f"created {args.image} ({args.size})"
+                  + (f", data objects on {args.data_pool}"
+                     if args.data_pool else ""))
         elif args.cmd == "ls":
             for name in await rbd.list(io):
                 print(name)
@@ -51,6 +57,8 @@ async def amain(args) -> int:
                   f"({1 << st['order']} byte objects)")
             print(f"\tid: {st['id']}")
             print(f"\tblock_name_prefix: {st['object_prefix']}")
+            if st["data_pool"]:
+                print(f"\tdata_pool: {st['data_pool']}")
             if st["parent"]:
                 print(f"\tparent: pool {st['parent']['pool_id']} "
                       f"image {st['parent']['image_id']} "
@@ -175,6 +183,10 @@ def main(argv=None) -> int:
     sp.add_argument("image")
     sp.add_argument("--size", required=True)
     sp.add_argument("--order", type=int, default=22)
+    sp.add_argument("--data-pool", default=None, metavar="POOL",
+                    help="put the data objects on POOL (e.g. an "
+                         "erasure pool with overwrites); header, "
+                         "directory and locks stay on --pool")
     sub.add_parser("ls")
     sp = sub.add_parser("info"); sp.add_argument("image")
     sp = sub.add_parser("rm"); sp.add_argument("image")

@@ -2,11 +2,11 @@
 
 The PR 1 hazard: flipping ``jax_enable_x64`` globally (at import time
 or anywhere else) changes dtype semantics for *every* computation in
-the process -- the CRUSH straw2 64-bit hash needs x64, but the EC
-GF(2) kernels and everything jitted elsewhere must keep the default.
-The sanctioned mechanism is the scoped context manager
-(``jax.experimental.enable_x64``), exactly how
-``crush/vectorized.py`` wraps its mapper entry points.
+the process -- the CRUSH mapper once needed x64 for its straw2 draw
+(it computes in 32-bit limbs since PR 32 and holds no 64-bit type),
+and the EC GF(2) kernels and everything jitted elsewhere must keep the
+default.  The sanctioned mechanism is the scoped context manager
+(``jax.enable_x64``); nothing in the package uses it today.
 
 Flagged everywhere, with no sanctioned call sites:
 

@@ -3,10 +3,13 @@
 The reference recomputes full-cluster mappings on host thread pools
 (ParallelPGMapper, src/osd/OSDMapMapping.h:18; used by the balancer and
 OSDMonitor's PrimeTempJob).  Here the whole job is one data-parallel
-program over the PG axis: straw2 draws become gathers into the fixed-point
-log tables plus an argmax, and the firstn/indep retry loops become bounded
-`lax.while_loop`s with per-lane masks -- decision-identical to the scalar
-mapper (ceph_tpu/crush/mapper.py), which is itself pinned to mapper.c.
+program over the PG axis: a straw2 draw is a lookup of 2^48 - crush_ln(u),
+an exact quotient by the item weight and a lexicographic argmin, all in
+32-bit limbs (the TPU has no 64-bit integers, and the program holds no
+64-bit type whether or not an embedding process runs jax with x64 on);
+the firstn/indep retry loops become bounded `lax.while_loop`s with
+per-lane masks -- decision-identical to the scalar mapper
+(ceph_tpu/crush/mapper.py), which is itself pinned to mapper.c.
 
 Supported map shape for the fused path: uniform-depth straw2
 hierarchies of ANY depth (root->osds up through root->row->rack->host->
@@ -24,18 +27,10 @@ from functools import partial
 import numpy as np
 
 import jax
-
-# straw2 draws are 64-bit fixed-point, which needs jax's x64 mode -- but
-# flipping the PROCESS-GLOBAL flag at import time would change numeric
-# promotion for every other jax user in an embedding process (importing
-# ceph_tpu must be side-effect free).  The x64 requirement is scoped to
-# the mapper entry points instead via the thread-local jax.enable_x64
-# context (the jit caches key on it, so fused-mapper traces always see
-# x64 while the rest of the package traces unchanged).
 import jax.numpy as jnp
 
 from ..common.tracing import section
-from .ln import RH_LH_TBL, LL_TBL
+from .ln import LL_TBL, RH_LH_TBL, crush_ln
 from .types import (
     CrushMap,
     CRUSH_BUCKET_STRAW2,
@@ -50,16 +45,12 @@ from .types import (
     CRUSH_RULE_SET_CHOOSELEAF_TRIES,
 )
 
-# numpy constant: materializing a jnp.int64 here would require x64 at
-# import time (exactly what this module must not demand)
-S64_MIN = np.int64(-(2**63))
 CRUSH_HASH_SEED = np.uint32(1315423911)
 
-# lanes per device launch.  The retry loops carry (lanes, n_items)
-# int64 tables whose minor dimension the TPU pads to 128, so a launch
-# costs kilobytes of HBM per lane: a 2M-lane launch aborted the v5e
-# runtime outright, and every new lane count is a new multi-minute
-# compile of the emulated-int64 program (CHANGES.md, PR 21).
+# lanes per device launch: bounds a launch's device memory and the number
+# of compiled shapes whatever size a caller hands in.  The value dates
+# from the 64-bit program (PR 21: a 2M-lane launch aborted the v5e
+# runtime); nothing has measured the 32-bit program beyond it.
 MAX_LANES = 1 << 17
 
 
@@ -105,49 +96,158 @@ def hash32_3_jnp(a, b, c):
     return h
 
 
-# keep the int64 log tables as NUMPY at module scope: a jnp.asarray
-# here would run outside the enable_x64 scope and silently truncate to
-# int32.  They become trace-time constants inside crush_ln_jnp, which
-# only ever traces under x64.
-_RH_LH_NP = np.asarray(RH_LH_TBL, np.int64)   # (258,)
-_LL_NP = np.asarray(LL_TBL, np.int64)         # (256,)
+_U32_MAX = np.uint32(0xFFFFFFFF)
+# typed, so that no weak 64-bit scalar is traced when x64 is on
+_NONE = np.int32(CRUSH_ITEM_NONE)
+
+
+def _byte_limbs(columns) -> tuple[np.ndarray, tuple[int, ...]]:
+    """A lookup table for ``_lookup``: each (values, shift, n) of
+    ``columns`` becomes the n low bytes of values >> shift as float32
+    columns (a byte is exact in bfloat16).  Returns the table (entries,
+    bytes) and every n."""
+    cols = [(np.asarray(values, dtype=object) >> (shift + 8 * b)) & 0xFF
+            for values, shift, n in columns for b in range(n)]
+    return (np.stack(cols, -1).astype(np.float32),
+            tuple(n for _, _, n in columns))
+
+
+def _ln_tables():
+    """crush_ln's two tables as ``_byte_limbs``, for the steps of
+    ``crush_ln_jnp``.  By k = (x >> 8) - 128 of the normalized x (the
+    129th entry, x = 2^16, is the one u = 0xffff and stands apart): RH
+    and x's product with it at its low byte 0, A = (128 + k) * 256 * RH,
+    both in 24-bit limbs (of A's top limb only bits 48-55 are used), and
+    LH; by index2: LL."""
+    rh = [int(v) for v in RH_LH_TBL[0:256:2]]
+    lh = [int(v) for v in RH_LH_TBL[1:256:2]]
+    a = [(128 + k) * 256 * v for k, v in enumerate(rh)]
+    ll = [int(v) for v in LL_TBL]
+    return (_byte_limbs([(rh, 0, 3), (rh, 24, 4), (a, 0, 3), (a, 24, 3),
+                         (a, 48, 1), (lh, 0, 3), (lh, 24, 3)]),
+            _byte_limbs([(ll, 0, 3), (ll, 24, 3)]))
+
+
+_BY_K, _BY_INDEX2 = _ln_tables()
+# 2^48 - crush_ln(0xffff) in 24-bit limbs
+_M_LAST = tuple(np.uint32(v) for v in
+                divmod((1 << 48) - crush_ln(0xFFFF), 1 << 24))
+
+
+def _lookup(table, index):
+    """table[index] for a ``_byte_limbs`` table as uint32 values, one
+    per column: a one-hot row times the byte columns on the MXU (one
+    term a sum, so exact): a gather costs the v5e 6-10 ns an index,
+    this a fraction of it (PERF.md section 5)."""
+    limbs, widths = table
+    hot = index[..., None] == jnp.arange(limbs.shape[0], dtype=jnp.uint32)
+    got = jnp.einsum("...k,kc->...c", hot.astype(jnp.bfloat16),
+                     jnp.asarray(limbs, jnp.bfloat16),
+                     preferred_element_type=jnp.float32).astype(jnp.uint32)
+    out, c = [], 0
+    for n in widths:
+        value = got[..., c]
+        for b in range(1, n):
+            value = value | (got[..., c + b] << (8 * b))
+        out.append(value)
+        c += n
+    return out
+
+
+def straw2_recip(weights) -> np.ndarray:
+    """Host-built per-item factor of ``straw2_quotient``:
+    float32((1 - 2^-20) / w), 0 where w == 0."""
+    w = np.asarray(weights, np.float64)
+    return np.where(w > 0, (1.0 - 2.0 ** -20) / np.maximum(w, 1.0),
+                    0.0).astype(np.float32)
 
 
 def crush_ln_jnp(u):
-    """Vector crush_ln over int32 u in [0, 0xffff] -> int64."""
-    _RH_LH = jnp.asarray(_RH_LH_NP)
-    _LL = jnp.asarray(_LL_NP)
-    x = u.astype(jnp.int64) + 1
-    need = (x & 0x18000) == 0
-    masked = (x & 0x1FFFF).astype(jnp.int32)
-    # bit_length via 31 - clz
-    bl = 32 - jax.lax.clz(masked)
-    bits = jnp.where(need, 16 - bl, 0).astype(jnp.int64)
-    x = x << bits
-    iexpon = (15 - bits).astype(jnp.int64)
-    index1 = ((x >> 8) << 1).astype(jnp.int32)
-    rh = _RH_LH[index1 - 256]
-    lh = _RH_LH[index1 + 1 - 256]
-    xl64 = (x * rh) >> 48
-    index2 = (xl64 & 0xFF).astype(jnp.int32)
-    ll = _LL[index2]
-    return (iexpon << 44) + ((lh + ll) >> 4)
+    """M = 2^48 - crush_ln(u) over uint32 u in [0, 0xffff] as two uint32
+    limbs (bits 24 and up, bits 0-23): 0 < M <= 2^48, 49 bits, u = 0
+    giving 2^48 itself.  mapper.c's steps (crush_ln, :229-269) in
+    32-bit arithmetic; crush_ln is not monotone in u, so nothing
+    shorter than its value orders two draws."""
+    u32 = jnp.uint32
+    x = u + u32(1)
+    # x << bits so that bit 15 leads; iexpon = 15 - bits.  (x = 2^16,
+    # which u = 0xffff alone gives, looks up nothing and is set below.)
+    iexpon = u32(31) - jax.lax.clz(x)
+    x = x << (u32(15) - iexpon)
+    low = x & u32(0xFF)
+    rh_lo, rh_hi, a_lo, a_mid, a_top, lh_lo, lh_hi = _lookup(
+        _BY_K, (x >> 8) - u32(128))
+    # index2 = bits 48-55 of x * RH = A + low * RH, 24 bits at a time
+    t = low * rh_hi + a_mid + ((low * rh_lo + a_lo) >> 24)
+    ll_lo, ll_hi = _lookup(_BY_INDEX2, ((t >> 24) + a_top) & u32(0xFF))
+    # LH + LL (49 bits), then ln = (iexpon << 44) + ((LH + LL) >> 4)
+    lo = lh_lo + ll_lo
+    hi = lh_hi + ll_hi + (lo >> 24)
+    ln_lo = ((hi & u32(0xF)) << 20) | ((lo & u32(0xFFFFFF)) >> 4)
+    ln_hi = (iexpon << 20) + (hi >> 4)
+    m_lo = (u32(1 << 24) - ln_lo) & u32(0xFFFFFF)
+    m_hi = u32(1 << 24) - ln_hi - (ln_lo != 0).astype(u32)
+    last = u == u32(0xFFFF)
+    return (jnp.where(last, _M_LAST[0], m_hi),
+            jnp.where(last, _M_LAST[1], m_lo))
 
 
-def straw2_draws(x, item_ids, r, weights):
-    """Draw values for one bucket: shapes broadcast over (..., n_items).
+def straw2_quotient(m_hi, m_lo, w, rw):
+    """Exact M // w as uint32 (bits 32 and up, bits 0-31) for
+    M = m_hi * 2^24 + m_lo <= 2^48 and uint32 0 < w < 2^31, with
+    rw = straw2_recip(w); w == 0 gives a value without meaning.
 
-    x: (...,) int32 lanes; item_ids/weights: (..., n) int32.
-    Returns (..., n) int64 draws (S64_MIN where weight==0).
-    """
-    u = (hash32_3_jnp(x[..., None], item_ids, r[..., None])
-         & np.uint32(0xFFFF)).astype(jnp.int32)
+    Three estimate-and-subtract steps and one compare.  An estimate is
+    floor(float32(R) * rw) for the running remainder R: rw's bias
+    outweighs every rounding in it (2^-24 each: rw's own on the host,
+    R's conversion and the product on the device), so it never exceeds
+    R / w and falls short by under R / w * 2^-19 + 1.  The remainder
+    R - q * w is therefore never negative and under w + R * 2^-19: under
+    w + 2^29 < 2^32 after the first step, so exact in wrapping uint32
+    arithmetic from there on, under w * (1 + 2^-19) + 2^10 after the
+    second and under 2 * w after the third."""
+    f32, u32 = jnp.float32, jnp.uint32
+    f = (m_hi.astype(f32) * f32(2.0 ** 24) + m_lo.astype(f32)) * rw
+    # floor(f) < 2^49 as limbs: both products and the difference are exact
+    f_hi = jnp.floor(f * f32(2.0 ** -24))
+    q_lo = (f_hi.astype(u32) << 24) + (f - f_hi * f32(2.0 ** 24)).astype(u32)
+    q_hi = f_hi.astype(u32) >> 8
+    r = ((m_hi << 24) | m_lo) - q_lo * w
+    for _ in range(2):
+        q = (r.astype(f32) * rw).astype(u32)
+        r = r - q * w
+        q_lo = q_lo + q
+        q_hi = q_hi + (q_lo < q).astype(u32)
+    last = (r >= w).astype(u32)
+    q_lo = q_lo + last
+    return q_hi + (q_lo < last).astype(u32), q_lo
+
+
+def straw2_draws(x, item_ids, r, weights, recips):
+    """Draws of one bucket per lane, items along axis 0.
+
+    x, r: (lanes,) int32; item_ids, weights: (n, lanes) int32; recips:
+    straw2_recip of the weights.  Returns q = (2^48 - crush_ln(u)) // w
+    as two (n, lanes) uint32 limbs: mapper.c's draw div64_s64(crush_ln(u)
+    - 2^48, w) is -q (a non-positive numerator truncates toward zero),
+    so the item it picks, the largest draw and the first of equals, is
+    ``straw2_choose``'s smallest q.  Both limbs all-ones where w == 0."""
+    u = hash32_3_jnp(x[None], item_ids, r[None]) & np.uint32(0xFFFF)
     # metadata only: names the draw's operations in a device trace
     with jax.named_scope("straw2_draw"):
-        ln = crush_ln_jnp(u) - jnp.int64(0x1000000000000)
-        w = weights.astype(jnp.int64)
-        draws = jax.lax.div(ln, jnp.maximum(w, 1))
-        return jnp.where(w > 0, draws, S64_MIN)
+        w = weights.astype(jnp.uint32)
+        # w == 0 divides nothing (its factor is 0) and is masked here
+        q_hi, q_lo = straw2_quotient(*crush_ln_jnp(u), w, recips)
+        return (jnp.where(w > 0, q_hi, _U32_MAX),
+                jnp.where(w > 0, q_lo, _U32_MAX))
+
+
+def straw2_choose(q_hi, q_lo):
+    """Index along axis 0 of the smallest (q_hi, q_lo), the first of
+    equals: an all-zero-weight bucket picks item 0, as mapper.c does."""
+    top = q_hi == jnp.min(q_hi, axis=0)
+    low = jnp.where(top, q_lo, _U32_MAX)
+    return jax.lax.argmax(top & (low == jnp.min(low, axis=0)), 0, jnp.int32)
 
 
 def is_out_jnp(osd_weights, item, x):
@@ -165,7 +265,7 @@ class CompiledMap:
 
     Level l holds every bucket at distance l from the take root as
     padded tables; the choose phase descends them in lockstep (one
-    straw2 draw + argmax per level per lane), exactly the recursive
+    straw2 draw + argmin per level per lane), exactly the recursive
     descent of mapper.c crush_choose_firstn/indep, but data-parallel
     over the lane axis.  Arbitrary depth (root->rack->host->osd and
     deeper) compiles; non-uniform leaf depth or non-straw2 buckets
@@ -320,40 +420,53 @@ class VectorCrush:
             raise ValueError("fused path implements jewel tunables")
 
     def _tables(self):
+        """Per level one int32 table (4 * N, P, B), all that a choice
+        in a bucket needs: every item's hashed id, its weight at each
+        weight-set position, the bits of that weight's straw2_recip,
+        and its child (a row of the next level, or the osd).  Items
+        lead, so that one gather of a lane's bucket row lands all four
+        as (n, lanes)."""
         cm = self.cm
-        ids = [jnp.asarray(t) for t in cm.child_ids]
-        idx = [jnp.asarray(t) for t in cm.child_idx]
-        if cm.cw is not None:
-            w = [jnp.asarray(t) for t in cm.cw]      # (P, B, N)
-        else:
-            w = [jnp.asarray(t)[None] for t in cm.weights]
-        return ids, idx, w
+        per_pos = cm.cw if cm.cw is not None else \
+            [t[None] for t in cm.weights]                # (P, B, N)
+        return [jnp.asarray(np.concatenate(
+            [np.broadcast_to(ids, w.shape), w,
+             straw2_recip(w).view(np.int32), np.broadcast_to(idx, w.shape)],
+            axis=2).transpose(2, 0, 1))
+            for ids, idx, w in zip(cm.child_ids, cm.child_idx, per_pos)]
 
-    def _descend(self, ids, idx, w, xs, r, pos, upto: int):
+    def _choose(self, tables, lvl, xs, cur, r, pos):
+        """One straw2 choice per lane in bucket row ``cur`` of level
+        ``lvl``: the chosen item's child.  ``pos`` is the choose_args
+        weight-set position -- a scalar, or a PER-LANE vector when
+        lanes have placed different counts (firstn's outpos)."""
+        table = tables[lvl]
+        p = jnp.clip(jnp.asarray(pos, jnp.int32), np.int32(0),
+                     np.int32(table.shape[1] - 1))
+        ids, w, rw, child = jnp.split(table[:, p, cur], 4)
+        j = straw2_choose(*straw2_draws(
+            xs, ids, r, w, jax.lax.bitcast_convert_type(rw, jnp.float32)))
+        # the chosen row of ``child``: a select, not a second gather
+        rows = jnp.arange(child.shape[0], dtype=jnp.int32)[:, None]
+        return jnp.sum(jnp.where(rows == j, child, np.int32(0)), axis=0,
+                       dtype=jnp.int32)
+
+    def _descend(self, tables, xs, r, pos, upto: int):
         """Lockstep descent: levels 0..upto-1, one draw per level.
         Returns row indices into level ``upto``'s tables (or osd ids
-        when upto == n_levels).  ``pos`` is the choose_args weight-set
-        position -- a scalar, or a PER-LANE vector when lanes have
-        placed different counts (firstn's outpos)."""
-        L = xs.shape[0]
-        cur = jnp.zeros((L,), jnp.int32)
+        when upto == n_levels)."""
+        cur = jnp.zeros(xs.shape, jnp.int32)
         for l in range(upto):
-            wl = w[l]
-            p = jnp.clip(jnp.asarray(pos), 0, wl.shape[0] - 1)
-            draws = straw2_draws(xs, ids[l][cur], r, wl[p, cur])
-            j = jnp.argmax(draws, axis=-1)
-            cur = idx[l][cur, j]
+            cur = self._choose(tables, l, xs, cur, r, pos)
         return cur
 
-    def _leaf_descend(self, ids, idx, w, xs, host_idx, sub_r, rep,
+    def _leaf_descend(self, tables, xs, host_idx, sub_r, rep,
                       numrep, osd_weights, taken, pos):
         """chooseleaf recursion into the chosen last-level bucket:
         up to recurse_tries draws, rejecting out osds and (firstn)
         collisions with already-placed osds."""
         lvl = self.cm.n_levels - 1
         L = xs.shape[0]
-        wl = w[lvl]
-        pos = jnp.clip(jnp.asarray(pos), 0, wl.shape[0] - 1)
 
         def cond(st):
             ft, found, _ = st
@@ -370,10 +483,7 @@ class VectorCrush:
                 r_leaf = (sub_r + ft).astype(jnp.int32)
             else:
                 r_leaf = (rep + sub_r + numrep * ft).astype(jnp.int32)
-            draws = straw2_draws(xs, ids[lvl][host_idx], r_leaf,
-                                 wl[pos, host_idx])
-            j = jnp.argmax(draws, axis=-1)
-            cand = idx[lvl][host_idx, j]
+            cand = self._choose(tables, lvl, xs, host_idx, r_leaf, pos)
             bad = is_out_jnp(osd_weights, cand, xs)
             if taken is not None:
                 for t in taken:
@@ -383,7 +493,7 @@ class VectorCrush:
             return ft + 1, found | ok, osd
 
         init = (jnp.int32(0), jnp.zeros((L,), bool),
-                jnp.full((L,), CRUSH_ITEM_NONE, jnp.int32))
+                jnp.full((L,), _NONE))
         _, found, osd = jax.lax.while_loop(cond, body, init)
         return osd, found
 
@@ -392,13 +502,13 @@ class VectorCrush:
     def crush_firstn(self, xs: jnp.ndarray, numrep: int,
                    osd_weights: jnp.ndarray) -> jnp.ndarray:
         cm = self.cm
-        ids, idx, w = self._tables()
+        tables = self._tables()
         L = xs.shape[0]
         # chooseleaf targets the last bucket level; plain choose (no
         # leaf recursion) targets the device level
         bucket_levels = cm.n_levels - 1 if self.leaf else cm.n_levels
-        out = jnp.full((L, numrep), CRUSH_ITEM_NONE, jnp.int32)
-        out_sel = jnp.full((L, numrep), jnp.int32(2**31 - 1), jnp.int32)
+        # one column per replica slot placed so far: osd, chosen bucket
+        out, out_sel = [], []
         # per-lane count of PLACED replicas: the scalar engine's
         # outpos, which is the choose_args weight-set position (a lane
         # whose earlier slot exhausted its tries keeps drawing later
@@ -412,24 +522,23 @@ class VectorCrush:
 
             def body(state):
                 ftotal, done, sel, osd = state
-                r = (rep + ftotal).astype(jnp.int32)
-                cand_sel = self._descend(ids, idx, w, xs, r, placed,
+                r = rep + ftotal
+                cand_sel = self._descend(tables, xs, r, placed,
                                          bucket_levels)
                 collide = jnp.zeros((L,), bool)
-                for j in range(rep):
-                    collide |= out_sel[:, j] == cand_sel
+                for prev in out_sel:
+                    collide |= prev == cand_sel
                 if self.leaf:
                     # vary_r=1: sub_r = r >> 0 = r
                     cand_osd, found = self._leaf_descend(
-                        ids, idx, w, xs, cand_sel, r, rep, numrep,
-                        osd_weights,
-                        [out[:, j] for j in range(rep)], placed)
+                        tables, xs, cand_sel, r, rep, numrep,
+                        osd_weights, out, placed)
                     reject = ~found
                 else:
                     cand_osd = cand_sel
                     reject = is_out_jnp(osd_weights, cand_osd, xs)
-                    for j in range(rep):
-                        reject |= out[:, j] == cand_osd
+                    for prev in out:
+                        reject |= prev == cand_osd
                 ok = ~done & ~collide & ~reject
                 sel = jnp.where(ok, cand_sel, sel)
                 osd = jnp.where(ok, cand_osd, osd)
@@ -438,67 +547,65 @@ class VectorCrush:
                 return ftotal, newdone, sel, osd
 
             init = (jnp.zeros((L,), jnp.int32), jnp.zeros((L,), bool),
-                    jnp.full((L,), 2**31 - 1, jnp.int32),
-                    jnp.full((L,), CRUSH_ITEM_NONE, jnp.int32))
-            ftotal, done, sel, osd = jax.lax.while_loop(cond, body, init)
-            out = out.at[:, rep].set(
-                jnp.where(done, osd, CRUSH_ITEM_NONE))
-            out_sel = out_sel.at[:, rep].set(
-                jnp.where(done, sel, 2**31 - 1))
+                    jnp.full((L,), _NONE), jnp.full((L,), _NONE))
+            _, done, sel, osd = jax.lax.while_loop(cond, body, init)
+            out.append(jnp.where(done, osd, _NONE))
+            out_sel.append(jnp.where(done, sel, _NONE))
             placed = placed + done.astype(jnp.int32)
         # scalar firstn COMPACTS (an exhausted slot leaves no hole):
         # shift placed entries left, NONE-pad the tail
-        is_none = out == CRUSH_ITEM_NONE
-        order = jnp.argsort(is_none, axis=1, stable=True)
-        return jnp.take_along_axis(out, order, axis=1)
+        out = jnp.stack(out, axis=1)
+        return jax.lax.sort(
+            ((out == _NONE).astype(jnp.int32), out),
+            dimension=1, is_stable=True, num_keys=1)[1]
 
     # -- indep --------------------------------------------------------------
     @partial(jax.jit, static_argnames=("self", "numrep"))
     def crush_indep(self, xs: jnp.ndarray, numrep: int,
                   osd_weights: jnp.ndarray) -> jnp.ndarray:
         cm = self.cm
-        ids, idx, w = self._tables()
+        tables = self._tables()
         L = xs.shape[0]
         UNDEF = jnp.int32(0x7FFFFFFE)
         bucket_levels = cm.n_levels - 1 if self.leaf else cm.n_levels
 
         def cond(state):
-            ftotal, out_h, out_o = state
-            return (ftotal < self.choose_tries) & jnp.any(out_h == UNDEF)
+            ftotal, out_h, _ = state
+            return (ftotal < self.choose_tries) & \
+                jnp.any(jnp.stack(out_h) == UNDEF)
 
         def body(state):
+            # one column per replica slot: chosen bucket, osd
             ftotal, out_h, out_o = state
+            out_h, out_o = list(out_h), list(out_o)
             for rep in range(numrep):
-                slot_undef = out_h[:, rep] == UNDEF
-                r = (rep + numrep * ftotal).astype(jnp.int32)
+                slot_undef = out_h[rep] == UNDEF
+                r = rep + numrep * ftotal
                 # weight-set position is the top call's OUTPOS (0),
                 # not the replica slot (crush_choose_indep passes its
                 # own outpos down); the leaf recursion's outpos IS the
                 # slot, so _leaf_descend keeps rep
-                cand_sel = self._descend(ids, idx, w, xs, r, 0,
-                                         bucket_levels)
+                cand_sel = self._descend(tables, xs, r, 0, bucket_levels)
                 collide = jnp.zeros((L,), bool)
-                for j in range(numrep):
-                    collide |= out_h[:, j] == cand_sel
+                for col in out_h:
+                    collide |= col == cand_sel
                 if self.leaf:
                     osd, found = self._leaf_descend(
-                        ids, idx, w, xs, cand_sel, r, rep, numrep,
+                        tables, xs, cand_sel, r, rep, numrep,
                         osd_weights, None, rep)
                 else:
                     osd = cand_sel
                     found = ~is_out_jnp(osd_weights, osd, xs)
                 ok = slot_undef & ~collide & found
-                out_h = out_h.at[:, rep].set(
-                    jnp.where(ok, cand_sel, out_h[:, rep]))
-                out_o = out_o.at[:, rep].set(
-                    jnp.where(ok, osd, out_o[:, rep]))
-            return ftotal + 1, out_h, out_o
+                out_h[rep] = jnp.where(ok, cand_sel, out_h[rep])
+                out_o[rep] = jnp.where(ok, osd, out_o[rep])
+            return ftotal + 1, tuple(out_h), tuple(out_o)
 
-        init = (jnp.int32(0),
-                jnp.full((L, numrep), UNDEF, jnp.int32),
-                jnp.full((L, numrep), UNDEF, jnp.int32))
-        _, out_h, out_o = jax.lax.while_loop(cond, body, init)
-        return jnp.where(out_o == UNDEF, CRUSH_ITEM_NONE, out_o)
+        undef = (jnp.full((L,), UNDEF, jnp.int32),) * numrep
+        _, _, out_o = jax.lax.while_loop(cond, body,
+                                         (jnp.int32(0), undef, undef))
+        out_o = jnp.stack(out_o, axis=1)
+        return jnp.where(out_o == UNDEF, _NONE, out_o)
 
     def map_pgs(self, xs, numrep: int, osd_weights) -> np.ndarray:
         """Map every placement seed in ``xs``: (len(xs), numrep) osd
@@ -507,22 +614,21 @@ class VectorCrush:
         which bounds device memory and the number of compiled shapes
         whatever size a caller hands in."""
         fn = self.crush_firstn if self.firstn else self.crush_indep
-        with jax.enable_x64(True):
-            w = jnp.asarray(osd_weights, jnp.int32)
-            # lint: disable=device-path-host-sync -- host-side input marshal of the seeds, no device array involved
-            xs = np.asarray(xs).astype(np.int32)
-            n = xs.shape[0]
-            if n <= MAX_LANES:
-                parts = [xs]
-            else:
-                parts = np.concatenate(
-                    [xs, np.zeros(-n % MAX_LANES, np.int32)]
-                ).reshape(-1, MAX_LANES)
-            out = []
-            for part in parts:
-                # the calling thread, from the launch until its result is
-                # on the host
-                with section("device_wait.crush"):
-                    # lint: disable=device-path-host-sync -- one materialization per bounded launch of the bulk map
-                    out.append(np.asarray(fn(jnp.asarray(part), numrep, w)))
-            return np.concatenate(out)[:n]
+        w = jnp.asarray(osd_weights, jnp.int32)
+        # lint: disable=device-path-host-sync -- host-side input marshal of the seeds, no device array involved
+        xs = np.asarray(xs).astype(np.int32)
+        n = xs.shape[0]
+        if n <= MAX_LANES:
+            parts = [xs]
+        else:
+            parts = np.concatenate(
+                [xs, np.zeros(-n % MAX_LANES, np.int32)]
+            ).reshape(-1, MAX_LANES)
+        out = []
+        for part in parts:
+            # the calling thread, from the launch until its result is
+            # on the host
+            with section("device_wait.crush"):
+                # lint: disable=device-path-host-sync -- one materialization per bounded launch of the bulk map
+                out.append(np.asarray(fn(jnp.asarray(part), numrep, w)))
+        return np.concatenate(out)[:n]

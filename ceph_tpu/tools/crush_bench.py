@@ -33,7 +33,7 @@ def run_crush_bench(pgs: int = 10_000_000, osds: int = 1000,
                     batch: int = 2_000_000) -> dict:
     """Map ``pgs`` placement seeds in ``batch``-lane launches through
     ``VectorCrush.map_pgs`` (the entry point the placement cache
-    calls, so every launch runs in the mapper's own x64 scope), then
+    calls, bounded launches included), then
     check ``verify`` lanes SAMPLED FROM THE TIMED LAUNCHES against the
     scalar ``crush_do_rule``.  Raises on any mismatch; returns the
     report dict.  Launch time includes the seed upload and the result

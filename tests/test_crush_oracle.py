@@ -10,6 +10,7 @@ import pytest
 
 from ceph_tpu.crush import crush_do_rule
 from ceph_tpu.crush.builder import build_two_level_map
+from ceph_tpu.crush.types import CRUSH_ITEM_NONE
 from ceph_tpu.native import available, crush_oracle_do_rule
 
 
@@ -89,3 +90,24 @@ def test_all_three_agree_depth4(ruleno):
             oracle = crush_oracle_do_rule(cm, ruleno, int(x), 3, w)
             assert oracle == want, (trial, i, want, oracle)
             assert list(vec[i]) == want, (trial, i, want, list(vec[i]))
+
+
+@pytest.mark.parametrize("case", ["firstn3", "indep11", "weighted"])
+def test_all_three_agree_on_the_cell_tree(case):
+    """crush_1000osd_bulk's own tree (fanouts 5, 5, 4, 10, chooseleaf
+    over hosts, jewel) with 4,096 of its driver's placement seeds: as
+    configured, under the erasure rule at 11 positions, and with
+    non-uniform item weights, reweighted and out OSDs."""
+    from ceph_tpu.crush.vectorized import VectorCrush
+    from test_crush_vectorized import cell_pps, cell_tree
+
+    cm, ruleno, numrep, w = cell_tree(case)
+    xs = cell_pps(4096, 2)
+    vec = VectorCrush(cm, ruleno).map_pgs(xs, numrep, w)
+    none = [CRUSH_ITEM_NONE]
+    for lane, x in enumerate(xs):
+        want = crush_do_rule(cm, ruleno, int(x), numrep, w)
+        oracle = crush_oracle_do_rule(cm, ruleno, int(x), numrep, w)
+        assert oracle == want, (case, lane)
+        assert list(vec[lane]) == want + none * (numrep - len(want)), \
+            (case, lane)

@@ -209,3 +209,151 @@ def test_inputs_beyond_max_lanes_run_as_bounded_launches(monkeypatch):
     got = vc.map_pgs(xs, 3, weights)
     assert got.shape == (300, 3)
     assert np.array_equal(got, scalar_batch(m, 0, xs, 3, weights))
+
+
+# -- the straw2 draw in 32-bit limbs ----------------------------------------
+
+DRAW_WEIGHTS = [1, 2, 3, 0xFFFF, 0x10000, 0x10001, 0xA0000, 0x280000,
+                0xC80000, 2**30 + 12345, 2**31 - 1]
+_rng = np.random.default_rng(32)
+DRAW_WEIGHTS += [int(w) for w in _rng.integers(1, 2**31, size=10)]
+DRAW_WEIGHTS += [int(2 ** e) for e in _rng.uniform(0, 31, size=10)]
+
+
+@pytest.mark.parametrize("w", DRAW_WEIGHTS, ids=hex)
+def test_draw_is_the_exact_quotient_for_every_u(w):
+    """(2^48 - crush_ln(u)) // w for all 65,536 u, against numpy int64;
+    a zero weight never wins against w, equal draws pick the first
+    item, an all-zero bucket picks item 0."""
+    import jax
+    import jax.numpy as jnp
+    from ceph_tpu.crush.ln import crush_ln_np
+    from ceph_tpu.crush.vectorized import (
+        crush_ln_jnp, hash32_3_jnp, straw2_choose, straw2_draws,
+        straw2_quotient, straw2_recip)
+
+    u = np.arange(0x10000)
+    want = ((1 << 48) - crush_ln_np(u)) // w
+    ws = np.full(u.shape, w, np.uint32)
+    q_hi, q_lo = jax.jit(lambda u, ws, rw: straw2_quotient(
+        *crush_ln_jnp(u), ws, rw))(jnp.asarray(u, jnp.uint32), ws,
+                                   straw2_recip(ws))
+    assert q_hi.dtype == q_lo.dtype == jnp.uint32
+    assert np.array_equal(np.asarray(q_hi), want >> 32)
+    assert np.array_equal(np.asarray(q_lo), want & 0xFFFFFFFF)
+
+    # buckets of four items hashing alike, so every positive weight
+    # draws the same q: [0, w, w, 0] picks item 1, [0, 0, 0, 0] item 0
+    xs = jnp.arange(4096, dtype=jnp.int32)
+    r = jnp.zeros_like(xs)
+    ids = jnp.full((4, 4096), -7, jnp.int32)
+    for weights, picked in (([0, w, w, 0], 1), ([0, 0, 0, 0], 0)):
+        wt = np.repeat(np.asarray(weights, np.int32)[:, None], 4096, 1)
+        q_hi, q_lo = straw2_draws(xs, ids, r, wt, straw2_recip(wt))
+        zero = np.asarray(wt) == 0
+        assert (np.asarray(q_hi)[zero] == 0xFFFFFFFF).all()
+        assert (np.asarray(q_lo)[zero] == 0xFFFFFFFF).all()
+        assert (np.asarray(straw2_choose(q_hi, q_lo)) == picked).all()
+    # what a bucket draws is the quotient of the u its hash gives
+    one = np.full((1, 4096), w, np.int32)
+    q_hi, q_lo = straw2_draws(xs, ids[:1], r, one, straw2_recip(one))
+    us = np.asarray(hash32_3_jnp(xs, ids[0], r)) & 0xFFFF
+    assert np.array_equal(np.asarray(q_hi)[0], want[us] >> 32)
+    assert np.array_equal(np.asarray(q_lo)[0], want[us] & 0xFFFFFFFF)
+
+
+def _wide_avals(jaxpr):
+    """Every 64-bit operand or result of the jaxpr's equations, the
+    bodies of its loops and calls included."""
+    import jax
+
+    found = []
+    for eqn in jaxpr.eqns:
+        for v in (*eqn.invars, *eqn.outvars):
+            dtype = getattr(getattr(v, "aval", None), "dtype", None)
+            if dtype is not None and dtype.itemsize > 4:
+                found.append((eqn.primitive.name, str(v.aval)))
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            found += _wide_avals(sub)
+    return found
+
+
+@pytest.mark.parametrize("x64", [False, True], ids=["x64_off", "x64_on"])
+@pytest.mark.parametrize("ruleno", [0, 1], ids=["firstn", "indep"])
+def test_no_64_bit_type_reaches_the_device(ruleno, x64, monkeypatch):
+    """The mapper's program holds no 64-bit type whether an embedding
+    process has jax's x64 flag on or off, maps like the scalar engine
+    under both, and map_pgs never touches the flag."""
+    import jax
+    import jax.numpy as jnp
+    from ceph_tpu.crush.builder import build_hierarchy
+
+    cm = build_hierarchy([3, 3, 4])
+    weights = [0x10000] * 36
+    weights[5], weights[20] = 0, 0x8000
+    vc = VectorCrush(cm, ruleno)
+    fn = vc.crush_firstn if ruleno == 0 else vc.crush_indep
+    xs = np.arange(0, 6400, 25, dtype=np.int32)
+    want = scalar_batch(cm, ruleno, xs, 3, weights)
+    with jax.enable_x64(x64):
+        jaxpr = jax.make_jaxpr(lambda xs, w: fn(xs, 3, w))(
+            jnp.asarray(xs), jnp.asarray(weights, jnp.int32))
+        assert _wide_avals(jaxpr.jaxpr) == []
+        assert len(jaxpr.jaxpr.eqns) > 0
+        # no way into the flag from the mapper
+        monkeypatch.setattr(jax, "enable_x64", None)
+        assert np.array_equal(vc.map_pgs(xs, 3, weights), want)
+
+
+# -- the benchmark cell's own widths ----------------------------------------
+
+CELL_FANOUTS = [5, 5, 4, 10]         # crush_1000osd_3rep: 1000 osds
+
+
+def cell_pps(lanes: int, pool: int) -> np.ndarray:
+    """The placement seeds benchmark/drivers/crush_bulk.py maps."""
+    from ceph_tpu.crush.vectorized import hash32_2_jnp
+
+    return np.asarray(hash32_2_jnp(np.arange(lanes, dtype=np.uint32),
+                                   np.uint32(pool))) & 0x7FFFFFFF
+
+
+def cell_tree(case: str):
+    """(map, ruleno, numrep, osd weights) of the cell's tree: as the
+    configuration states it, under the erasure rule at 11 positions, or
+    with everything a deployment's map can carry."""
+    from ceph_tpu.crush.builder import build_hierarchy
+
+    cm = build_hierarchy(CELL_FANOUTS)
+    weights = [0x10000] * 1000
+    if case == "firstn3":
+        return cm, 0, 3, weights
+    if case == "indep11":
+        return cm, 1, 11, weights
+    rng = np.random.default_rng(3200)
+    for b in cm.buckets.values():
+        # some under 2^17, so that the quotient passes 32 bits
+        b.item_weights = [int(rng.integers(1, 1 << 17)) if rng.random() < .3
+                          else int(rng.integers(1 << 15, 1 << 24))
+                          for _ in b.items]
+    for osd in rng.choice(1000, size=50, replace=False):
+        weights[int(osd)] = 0x8000
+    for osd in rng.choice(1000, size=20, replace=False):
+        weights[int(osd)] = 0
+    if case == "weighted_choose_args":
+        root = cm.buckets[-1]
+        cm.choose_args = {-1: {"weight_set": [
+            [int(rng.integers(1, 1 << 20)) for _ in root.items],
+            [int(rng.integers(1 << 12, 1 << 26)) for _ in root.items]]}}
+    return cm, 0, 3, weights
+
+
+@pytest.mark.parametrize("case", ["weighted", "weighted_choose_args"])
+def test_cell_tree_weighted_lane_exact(case):
+    """The cell's tree with non-uniform item weights (a choose_args
+    weight-set of two positions on top), 5 % of the OSDs reweighted and
+    2 % out: 4,096 of the driver's seeds, lane for lane."""
+    cm, ruleno, numrep, weights = cell_tree(case)
+    xs = cell_pps(4096, 1)
+    got = VectorCrush(cm, ruleno).map_pgs(xs, numrep, weights)
+    assert np.array_equal(got, scalar_batch(cm, ruleno, xs, numrep, weights))

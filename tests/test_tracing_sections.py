@@ -227,9 +227,8 @@ def test_crush_program_carries_its_scopes():
     from ceph_tpu.crush.vectorized import VectorCrush
 
     vc = VectorCrush(build_two_level_map(4, 2), 0)
-    with jax.enable_x64(True):
-        text = vc.crush_firstn.lower(
-            vc, jnp.arange(8, dtype=jnp.int32), 2,
-            jnp.full((8,), 0x10000, jnp.int32)).as_text(debug_info=True)
+    text = vc.crush_firstn.lower(
+        vc, jnp.arange(8, dtype=jnp.int32), 2,
+        jnp.full((8,), 0x10000, jnp.int32)).as_text(debug_info=True)
     assert "module @jit_crush_firstn " in text
     assert "straw2_draw" in text

@@ -130,6 +130,10 @@ DEFAULT_SCHEMA: list[Option] = [
     Option("osd_max_backfills", OPT_INT, 2,
            "concurrent backfill reservations per OSD (local+remote)",
            min=1),
+    Option("osd_max_pg_log_entries", OPT_INT, 512,
+           "entries a PG's log keeps; a peer behind its tail is "
+           "backfilled by scan instead of recovered from the log",
+           min=1),
     Option("osd_max_scrubs", OPT_INT, 1,
            "concurrent scrub slots per OSD", min=1),
     Option("osd_client_message_size_cap", OPT_INT, 500 << 20,

@@ -35,7 +35,7 @@ from collections import deque
 # the host layers a section name starts with ("wire.decode"); the
 # benchmark's per-layer metrics read these prefixes letter for letter
 SECTION_LAYERS = ("client", "wire", "osd_op", "osd_read", "store",
-                  "batcher", "device_wait")
+                  "batcher", "device_wait", "recovery")
 
 
 class _NoSection:
@@ -145,6 +145,12 @@ class Tracer:
         cur = current_span.get()
         if cur is not None:
             return Span(self, name, cur.trace_id, cur.span_id, tags)
+        return Span(self, name, self.next_id(), None, tags)
+
+    def root(self, name: str, **tags) -> Span:
+        """A span with a trace id of its own, whatever span the task
+        has inherited: background work (a backfill push) is no part of
+        the client op whose task happened to start it."""
         return Span(self, name, self.next_id(), None, tags)
 
     def _done(self, span: Span) -> None:

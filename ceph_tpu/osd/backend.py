@@ -37,6 +37,9 @@ CRC_XATTR = "_crc"      # CRC32C of the stored shard bytes (the per-shard
                         # bytes don't match their claimed identity
 HIDDEN_XATTRS = frozenset({SIZE_XATTR, VER_XATTR, SHARD_XATTR,
                            CRC_XATTR})               # never client-visible
+# the span of a gather that rebuilds a shard (read_recovery_payload);
+# a client read's is "ec.gather"
+RECOVER_GATHER = "ec.recover_gather"
 
 
 def shard_crc(data) -> int:
@@ -493,6 +496,9 @@ class ECBackend(PGBackend):
         if self.perf_pipeline is not None:
             self.perf_pipeline.inc(key, by)
 
+    def _tracer(self):
+        return tracing.get_tracer(f"osd.{self.osd.whoami}")
+
     @property
     def batcher(self):
         """The OSD-wide codec aggregation stage (None in bare tests)."""
@@ -612,10 +618,16 @@ class ECBackend(PGBackend):
                        span=None) -> set[int]:
         """``_verify_entries`` for one batch of a gather; under the
         ``osd_read.verify`` section when the gather serves a client
-        read (``span`` is its ``ec.gather`` span)."""
+        read (``span`` is its ``ec.gather`` span), under
+        ``recovery.payload`` when it rebuilds a shard (its
+        ``ec.recover_gather`` span)."""
         if span is None:
             return self._verify_entries(entries, rng, out, failed,
                                         relabeled)
+        if span.name == RECOVER_GATHER:
+            with tracing.section("recovery.payload"):
+                return self._verify_entries(entries, rng, out, failed,
+                                            relabeled)
         with tracing.section("osd_read.verify"):
             return self._verify_entries(entries, rng, out, failed,
                                         relabeled)
@@ -857,7 +869,8 @@ class ECBackend(PGBackend):
                              need_shards: set[int] | None = None,
                              rng: tuple[int, int] | None = None,
                              exclude: set[int] | None = None,
-                             served: bool = False
+                             served: bool = False,
+                             recovering: bool = False
                              ) -> tuple[dict[int, np.ndarray], int]:
         """Read enough CONSISTENT shard buffers to decode.
 
@@ -874,10 +887,19 @@ class ECBackend(PGBackend):
         verified sufficient set is in hand; tags: sub-reads asked for,
         of which hedges, shards rejected), and its verify passes under
         the ``osd_read.verify`` section.  A write's look at the old
-        content and recovery's gathers carry neither.
+        content carries neither; ``read_recovery_payload``'s gather
+        (``recovering``) runs under an ``ec.recover_gather`` span with
+        the same tags and ``excluded``, its verify passes under
+        ``recovery.payload``.
         """
-        span = tracing.child_span("ec.gather", oid=oid, asked=0, hedged=0,
-                                  rejected=0) if served else None
+        span = None
+        if served:
+            span = tracing.child_span("ec.gather", oid=oid, asked=0,
+                                      hedged=0, rejected=0)
+        elif recovering:
+            span = self._tracer().start(
+                RECOVER_GATHER, oid=oid, asked=0, hedged=0, rejected=0,
+                excluded=sorted(exclude or ()))
         try:
             return await self._gather_rounds(oid, need_shards, rng,
                                              exclude, span)
@@ -1687,10 +1709,17 @@ class ECBackend(PGBackend):
             # reply, and the "recovery" pushed a remove instead of a
             # reconstruction (the shard stayed lost forever)
             bufs, size, ver = await self._gather_shards(
-                oid, need_shards={shard}, exclude={int(shard)})
+                oid, need_shards={shard}, exclude={int(shard)},
+                recovering=True)
             self._rcount("repair_bytes_read",
                          sum(len(b) for b in bufs.values()))
-            if len(bufs) < self.sinfo.k:
+            if shard in bufs:
+                # the wanted shard itself, whole and verified under its
+                # write-time label, on a survivor that now serves
+                # another position (a remap moved it): copied, nothing
+                # decoded
+                self._rcount("repair_relabeled_copies")
+            elif len(bufs) < self.sinfo.k:
                 # a layered plan (the LRC local group) read fewer than
                 # k chunks: the locality savings, counted
                 self._rcount("repair_local_repairs")
@@ -1708,9 +1737,13 @@ class ECBackend(PGBackend):
                 # recovery/backfill pushes for the same down-shard
                 # pattern share one decode_batch launch
                 self._count("reconstructions")
-                decoded = await self.sinfo.decode_async(
-                    self.codec, bufs, want={shard},
-                    batcher=self.batcher)
+                span = self._tracer().start("ec.recover_decode", oid=oid)
+                try:
+                    decoded = await self.sinfo.decode_async(
+                        self.codec, bufs, want={shard},
+                        batcher=self.batcher, recovering=True)
+                finally:
+                    span.finish()
                 buf = decoded[shard]
         # the pushed shard must carry the version stamp (an unstamped
         # recovered shard would read as (0,0) and be rejected as stale
@@ -1718,13 +1751,15 @@ class ECBackend(PGBackend):
         # shard label + CRC travel in the xattrs so the applied copy is
         # self-describing, and again at the payload top level so the
         # receiver can verify BEFORE applying anything
-        raw = buf.tobytes()
+        with tracing.section("recovery.payload"):
+            raw = buf.tobytes()
+            crc = shard_crc(raw)
         self._rcount("repair_bytes_shipped", len(raw))
         return {"data": raw,
                 "xattrs": {SIZE_XATTR: str(size).encode(),
                            VER_XATTR: f"{ver[0]},{ver[1]}".encode(),
                            SHARD_XATTR: str(int(shard)).encode(),
-                           CRC_XATTR: str(shard_crc(raw)).encode()},
+                           CRC_XATTR: str(crc).encode()},
                 "omap": {},
                 "shard": int(shard)}
 

@@ -59,7 +59,10 @@ batches launched, a stripes-per-batch histogram, padding waste, and
 flush-reason counts, so the bench can report achieved batch sizes.
 Three wait counters, integer microseconds summed over launches, say
 where a launch's time goes: ``queue_wait_us`` (a group's first
-submission to its dispatch), ``overlap_us`` (dispatch return to
+submission to its dispatch; also by kind, ``<kind>_queue_wait_us``, as
+``stripes`` is by ``<kind>_stripes`` and ``batches`` by
+``<kind>_launches``: a window that mixes clients' encodes with a
+repair's decodes reads each kind's own), ``overlap_us`` (dispatch return to
 completion entry: the launch is in flight while the loop does other
 work) and ``materialize_us`` (the ``np.asarray`` that blocks the
 loop's thread until the device is done).
@@ -461,8 +464,9 @@ class CodecBatcher:
         the event loop while the device works.  Returns (out, crcs);
         ``_complete`` pays the single asarray."""
         if self.perf is not None:
-            self.perf.inc("queue_wait_us", (time.perf_counter_ns()
-                                            - st.grp.t_first) // 1000)
+            waited = (time.perf_counter_ns() - st.grp.t_first) // 1000
+            self.perf.inc("queue_wait_us", waited)
+            self.perf.inc(f"{st.grp.kind}_queue_wait_us", waited)
         with section("batcher.dispatch"):
             handle = self._dispatch_launch(st)
         st.t_dispatched = time.perf_counter_ns()
@@ -532,6 +536,7 @@ class CodecBatcher:
             self.perf.inc("batches")
             self.perf.inc(f"{grp.kind}_launches")
             self.perf.inc("stripes", st.total)
+            self.perf.inc(f"{grp.kind}_stripes", st.total)
             self.perf.inc("ops_coalesced", len(items))
             self.perf.inc("pad_waste_bytes",
                           st.b * st.batch.shape[1] * lane - st.payload)

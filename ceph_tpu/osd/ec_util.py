@@ -13,6 +13,8 @@ from typing import Mapping
 
 import numpy as np
 
+from ..common.tracing import section
+
 
 def parse_stripe_unit(codec, value) -> int:
     """Validate a profile's stripe_unit (OSDMonitor.cc:7782-7813
@@ -194,11 +196,14 @@ class StripeInfo:
     async def decode_async(self, codec,
                            shard_bufs: Mapping[int, np.ndarray],
                            want: set[int] | None = None,
-                           batcher=None) -> dict[int, np.ndarray]:
+                           batcher=None, recovering: bool = False
+                           ) -> dict[int, np.ndarray]:
         """Batched analog of decode(): all stripes' reconstructions in
         one launch, grouped in the batcher by erasure
         signature (the DecodeTableCache keying) so concurrent recovery
-        reads with the same down-shard pattern coalesce."""
+        reads with the same down-shard pattern coalesce.
+        ``recovering`` (a shard rebuilt for a peer) puts the stack of
+        the survivors under the ``recovery.payload`` section."""
         from ..gf.matrices import decode_index_for
         want = (set(self.data_positions(codec)) if want is None
                 else set(want))
@@ -255,10 +260,18 @@ class StripeInfo:
             # canonical IOError
             return self.decode(codec, shard_bufs, want)
         decode_index = decode_index_for(k, set(erasures))
-        survivors = np.stack(
-            # lint: disable=device-path-host-sync -- the single input marshal: network/cache-resident buffers stacked once for the launch
-            [np.asarray(shard_bufs[i], dtype=np.uint8).reshape(n, cs)
-             for i in decode_index], axis=1)          # (n, k, cs)
+
+        def stack() -> np.ndarray:
+            return np.stack(
+                # lint: disable=device-path-host-sync -- the single input marshal: network/cache-resident buffers stacked once for the launch
+                [np.asarray(shard_bufs[i], dtype=np.uint8).reshape(n, cs)
+                 for i in decode_index], axis=1)          # (n, k, cs)
+
+        if recovering:
+            with section("recovery.payload"):
+                survivors = stack()
+        else:
+            survivors = stack()
         rec = await batcher.decode(codec, tuple(erasures), survivors)
         out: dict[int, np.ndarray] = {}
         for i in want:

@@ -13,6 +13,7 @@ from __future__ import annotations
 import asyncio
 import json
 
+from ..common import tracing
 from ..msg import Message
 from ..os.transaction import Transaction
 from .backend import (
@@ -27,6 +28,7 @@ from .types import (
 )
 
 LOG_CAP = 512           # entries kept in the in-memory/persisted log
+                        # unless osd_max_pg_log_entries says otherwise
 SCAN_BATCH = 128        # objects per pg_scan page / backfill batch
 
 
@@ -110,6 +112,12 @@ class PG:
         # full rewrite after wholesale log surgery (peering merges).
         self._log_keys: set[str] = set()
         self._log_dirty = False
+        # how far back the log reaches decides how a new member is
+        # repaired: a peer whose last_update is behind the tail is
+        # backfilled by scan, one whose log overlaps is recovered entry
+        # by entry under the PG's lock (snapshot, never read per write)
+        self._log_cap = max(1, int(getattr(self.osd, "config", {}).get(
+            "osd_max_pg_log_entries", LOG_CAP)))
         self._legacy_log_key = False
         if not self.osd.store.collection_exists(self.coll):
             txn = Transaction()
@@ -243,8 +251,8 @@ class PG:
             self.log.add(entry)
             if entry.reqid is not None:
                 self._completed_reqids[tuple(entry.reqid)] = entry.version
-            if len(self.log.entries) > LOG_CAP:
-                self.log.trim(self.log.entries[-LOG_CAP].version)
+            if len(self.log.entries) > self._log_cap:
+                self.log.trim(self.log.entries[-self._log_cap].version)
                 self._reindex_reqids()
             self.info.last_update = entry.version
             self.info.log_tail = self.log.tail
@@ -352,8 +360,10 @@ class PG:
             "osd", 1,
             f"pg {self.pgid}: osd.{self.whoami} remapped shard "
             f"{self.shard_id} -> {pos}; re-recovering local objects")
-        for oid, ver in self.object_vers().items():
+        moved = self.object_vers()
+        for oid, ver in moved.items():
             self.missing.add(oid, need=EVersion(*ver), have=ZERO)
+        self._count_recovery("backfill_positions_moved", len(moved))
         self.shard_id = pos
         self.persist_meta()
 
@@ -597,14 +607,15 @@ class PG:
         from .backend import VER_XATTR, ver_decode
         # +1 as the exhaustion probe; META_OID may occupy one slot
         from .snaps import INTERNAL_OIDS
-        names = [o for o in self.osd.store.list_objects_range(
-            self.coll, begin, limit + 2)
-            if o != META_OID and o not in INTERNAL_OIDS]
-        batch = names[:limit]
-        out = {oid: ver_decode(
-            self.osd.store.getattr(self.coll, oid, VER_XATTR))
-            for oid in batch}
-        return out, len(names) <= limit
+        with tracing.section("recovery.scan"):
+            names = [o for o in self.osd.store.list_objects_range(
+                self.coll, begin, limit + 2)
+                if o != META_OID and o not in INTERNAL_OIDS]
+            batch = names[:limit]
+            out = {oid: ver_decode(
+                self.osd.store.getattr(self.coll, oid, VER_XATTR))
+                for oid in batch}
+            return out, len(names) <= limit
 
     async def _fetch_scan_page(
             self, osd_id: int, begin: str,
@@ -1483,21 +1494,7 @@ class PG:
                     break
                 await self.osd.admit(OpClass.RECOVERY)
                 try:
-                    # lint: disable=await-under-lock -- log-based recovery deliberately blocks client ops for its round (the per-object interlock); whole-PG backfill runs OUTSIDE the lock below
-                    async with self.lock:
-                        for oid in list(self.missing.items):
-                            await self._recover_object(oid)
-                        if not self.missing:
-                            if not self.info.backfill_complete:
-                                self.info.backfill_complete = True
-                                self.info.last_backfill = ""
-                            self.info.last_complete = self.info.last_update
-                        for peer, ms in list(self.peer_missing.items()):
-                            if (not self.osd.osd_is_up(peer)
-                                    or peer in self.backfill_targets):
-                                continue
-                            for oid in list(ms.items):
-                                await self._push_object(peer, oid)
+                    await self._recover_from_log()
                     # backfill runs OUTSIDE the PG lock (it takes it
                     # per scan batch / payload read): client I/O to the
                     # PG proceeds between pushes instead of stalling for
@@ -1514,6 +1511,37 @@ class PG:
                     await asyncio.sleep(0.5)
         except asyncio.CancelledError:
             pass
+
+    async def _recover_from_log(self) -> None:
+        """One round of log-based recovery: pull what this OSD misses,
+        push what its acting peers miss.  The PG's lock is taken PER
+        OBJECT (the per-object interlock): a client op to the PG waits
+        for one object's pull or push, not for the round's.  An op
+        that reaches an object still missing recovers it itself, under
+        the same lock (``do_op``), and ``_recover_object`` /
+        ``_push_object`` return at once for an object that is no
+        longer missing.  Held across the whole round, the lock kept
+        clients of a PG with a few dozen 512 KiB shards to re-recover
+        waiting past the objecter's 30 s: writes failed ETIMEDOUT in a
+        cluster that was healing as it should (chip run, PR 33)."""
+        for oid in list(self.missing.items):
+            # lint: disable=await-under-lock -- log-based recovery deliberately blocks client ops to the PG for one object's pull (the per-object interlock)
+            async with self.lock:
+                await self._recover_object(oid)
+        async with self.lock:
+            if not self.missing:
+                if not self.info.backfill_complete:
+                    self.info.backfill_complete = True
+                    self.info.last_backfill = ""
+                self.info.last_complete = self.info.last_update
+        for peer, ms in list(self.peer_missing.items()):
+            for oid in list(ms.items):
+                if (not self.osd.osd_is_up(peer)
+                        or peer in self.backfill_targets):
+                    break
+                # lint: disable=await-under-lock -- log-based recovery deliberately blocks client ops to the PG for one object's push (the per-object interlock)
+                async with self.lock:
+                    await self._push_object(peer, oid)
 
     # -- incremental, cursor-driven backfill --------------------------------
     def should_send_to(self, peer: int, oid: str) -> bool:
@@ -1564,25 +1592,46 @@ class PG:
         (_apply_recovery_payload) -- a mislabeled or corrupt payload is
         rejected and retried, never silently installed."""
         from .backend import shard_crc
-        data = {"oid": oid,
-                "absent": payload.get("absent", False),
-                "crc": shard_crc(payload["data"]),
-                "xattrs": {k: v.hex()
-                           for k, v in payload["xattrs"].items()},
-                "omap": {k: v.hex()
-                         for k, v in payload["omap"].items()}}
-        if payload.get("shard") is not None:
-            data["shard"] = int(payload["shard"])
-        return data, [payload["data"]]
+        with tracing.section("recovery.payload"):
+            data = {"oid": oid,
+                    "absent": payload.get("absent", False),
+                    "crc": shard_crc(payload["data"]),
+                    "xattrs": {k: v.hex()
+                               for k, v in payload["xattrs"].items()},
+                    "omap": {k: v.hex()
+                             for k, v in payload["omap"].items()}}
+            if payload.get("shard") is not None:
+                data["shard"] = int(payload["shard"])
+            return data, [payload["data"]]
 
-    async def _backfill_push(self, peer: int, oid: str) -> bool:
+    async def _backfill_push(self, peer: int, oid: str,
+                             dirty: bool = False) -> bool:
         """Push one object (or its absence) to a backfill target with
-        the per-object interlock.  Returns True on ack."""
+        the per-object interlock.  Returns True on ack.
+
+        Span ``pg.backfill_push`` (a root: no client op is its parent;
+        ``dirty`` marks a push made because a client write was skipped
+        past the cursor) with the children ``ec.recover_gather`` and
+        ``ec.recover_decode`` (``read_recovery_payload``) and
+        ``pg.push`` (the ``pg_push`` sent until its ack)."""
         bi = self.backfill_info[peer]
         try:
             shard = self._shard_of(peer)
         except ValueError:
             return False           # peer left the acting set; re-peered
+        self._count_recovery("backfill_pushes")
+        if dirty:
+            self._count_recovery("backfill_dirty_pushes")
+        span = tracing.get_tracer(f"osd.{self.whoami}").root(
+            "pg.backfill_push", pgid=self.pgid, oid=oid, shard=shard,
+            dirty=dirty).activate()
+        try:
+            return await self._backfill_push_traced(bi, peer, oid, shard)
+        finally:
+            span.finish()
+
+    async def _backfill_push_traced(self, bi: dict, peer: int, oid: str,
+                                    shard: int) -> bool:
         ev = asyncio.Event()
         try:
             # the lock is held ONLY to mark the interlock: no write is
@@ -1596,9 +1645,13 @@ class PG:
                 oid, shard)
             data, segs = self._push_payload(oid, payload)
             data["pgid"] = self.pgid
-            replies = await self.osd.fanout_and_wait(
-                [(peer, "pg_push", data, segs)],
-                collect=True, timeout=10)
+            push = tracing.child_span("pg.push", peer=peer)
+            try:
+                replies = await self.osd.fanout_and_wait(
+                    [(peer, "pg_push", data, segs)],
+                    collect=True, timeout=10)
+            finally:
+                tracing.finish(push)
             if not replies or replies[0].data.get("err"):
                 return False
             bi["pushed"].add(oid)
@@ -1625,15 +1678,17 @@ class PG:
                 peer, bi["cursor"], SCAN_BATCH)
             # compare only below the lowest exhausted bound; names above
             # it belong to the next batch
-            bounds = ([] if local_done else [max(local)]) + \
-                     ([] if remote_done else [max(remote)])
-            bound = min(bounds) if bounds else None
-            work_l = {o: v for o, v in local.items()
-                      if bound is None or o <= bound}
-            work_r = {o: v for o, v in remote.items()
-                      if bound is None or o <= bound}
-            todo = [o for o, v in work_l.items() if work_r.get(o) != v]
-            todo += [o for o in work_r if o not in work_l]
+            with tracing.section("recovery.scan"):
+                bounds = ([] if local_done else [max(local)]) + \
+                         ([] if remote_done else [max(remote)])
+                bound = min(bounds) if bounds else None
+                work_l = {o: v for o, v in local.items()
+                          if bound is None or o <= bound}
+                work_r = {o: v for o, v in remote.items()
+                          if bound is None or o <= bound}
+                todo = [o for o, v in work_l.items()
+                        if work_r.get(o) != v]
+                todo += [o for o in work_r if o not in work_l]
             for oid in sorted(todo):
                 if not await self._backfill_push(peer, oid):
                     raise asyncio.TimeoutError(
@@ -1657,7 +1712,8 @@ class PG:
                 if not redo:
                     break
                 for oid in redo:
-                    if not await self._backfill_push(peer, oid):
+                    if not await self._backfill_push(peer, oid,
+                                                     dirty=True):
                         raise asyncio.TimeoutError(
                             f"backfill dirty push {oid} to osd.{peer} "
                             f"failed")
@@ -1830,25 +1886,35 @@ class PG:
         if pc is not None:
             pc.inc(key)
 
+    def _count_recovery(self, key: str, by: int = 1) -> None:
+        """The ``ec_recovery`` set of an erasure pool's backend (a
+        replicated pool has none)."""
+        pc = getattr(self.backend, "perf_recovery", None)
+        if pc is not None:
+            pc.inc(key, by)
+
     def _apply_recovery_payload(self, oid: str, data: dict,
                                 segments: list[bytes]) -> None:
-        self._verify_recovery_payload(oid, data, segments)
-        self.backend.invalidate_extents(oid)
-        txn = Transaction()
-        if data.get("absent"):
-            txn.remove(self.coll, oid)
-        else:
-            buf = segments[0] if segments else b""
-            txn.remove(self.coll, oid)
-            txn.touch(self.coll, oid)
-            txn.write(self.coll, oid, 0, buf)
-            for k, v in data.get("xattrs", {}).items():
-                txn.setattr(self.coll, oid, k, bytes.fromhex(v))
-            omap = {k: bytes.fromhex(v)
-                    for k, v in data.get("omap", {}).items()}
-            if omap:
-                txn.omap_setkeys(self.coll, oid, omap)
-        self.osd.store.queue_transaction(txn)
+        """Section ``recovery.apply``: the verify (CRC32C of the
+        payload, its label) and the transaction that installs it."""
+        with tracing.section("recovery.apply"):
+            self._verify_recovery_payload(oid, data, segments)
+            self.backend.invalidate_extents(oid)
+            txn = Transaction()
+            if data.get("absent"):
+                txn.remove(self.coll, oid)
+            else:
+                buf = segments[0] if segments else b""
+                txn.remove(self.coll, oid)
+                txn.touch(self.coll, oid)
+                txn.write(self.coll, oid, 0, buf)
+                for k, v in data.get("xattrs", {}).items():
+                    txn.setattr(self.coll, oid, k, bytes.fromhex(v))
+                omap = {k: bytes.fromhex(v)
+                        for k, v in data.get("omap", {}).items()}
+                if omap:
+                    txn.omap_setkeys(self.coll, oid, omap)
+            self.osd.store.queue_transaction(txn)
         # an applied EC shard re-pins the PG identity (first write on a
         # fresh replica may arrive via recovery rather than a sub-write)
         if data.get("shard") is not None and self.shard_id is None:

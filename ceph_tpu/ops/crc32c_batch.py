@@ -16,13 +16,15 @@ amortized, the XOR/CRC side-path dominates):
   what ``native._crc32c_py`` now delegates to).
 
 * GF(2) register algebra (``crc32c_zeros`` / ``crc32c_combine`` /
-  ``crc32c_strip_zeros`` / ``fold_chunk_crcs``): advancing a CRC over
-  n zero bytes is multiplication by the 32x32 bit-matrix M^n (the same
-  x^(8n) mod P math Ceph's crc32c combine uses), which makes CRC
-  embarrassingly batch-parallel: ragged buffers are zero-padded,
-  checksummed in lockstep, and un-padded by the INVERSE matrix; chunk
+  ``crc32c_strip_zeros`` / ``fold_chunk_crcs`` / ``crc32c_patch``):
+  advancing a CRC over n zero bytes is multiplication by the 32x32
+  bit-matrix M^n (the same x^(8n) mod P math Ceph's crc32c combine
+  uses), which makes CRC embarrassingly batch-parallel: ragged
+  buffers are zero-padded, checksummed in lockstep, and un-padded by
+  the INVERSE matrix; chunk
   CRCs from a device launch fold into whole-shard CRCs without
-  re-reading a byte.
+  re-reading a byte, and a ranged overwrite updates a shard's CRC
+  from the bytes it changes.
 
 * ``crc32c_device_chunks``: the JAX kernel variant, the same algebra
   on the device: every 512-byte segment's register is one GF(2)
@@ -149,10 +151,10 @@ def _inv_zeros_pow2(b: int) -> np.ndarray:
     return _mat_mul(m, m)
 
 
-@functools.lru_cache(maxsize=256)
+@functools.lru_cache(maxsize=1024)
 def _zeros_matrix(n: int) -> np.ndarray:
     """M^n via the binary ladder (few distinct n recur: segment and
-    chunk lengths)."""
+    chunk lengths, and the chunk-aligned tails of ``crc32c_patch``)."""
     assert n >= 0
     out = None
     b = 0
@@ -170,8 +172,16 @@ def _zeros_matrix(n: int) -> np.ndarray:
 def crc32c_zeros(crc, n: int):
     """Advance CRC register(s) over ``n`` zero bytes (raw register
     semantics: equivalent to ``native.crc32c(b"\\x00" * n, crc)``)."""
-    out = _mat_apply(_zeros_matrix(int(n)), crc)
-    return int(out) if np.ndim(crc) == 0 else out
+    mat = _zeros_matrix(int(n))
+    if np.ndim(crc) != 0:
+        return _mat_apply(mat, crc)
+    # one register: a pass over its set bits costs less than
+    # marshaling it through _mat_apply's arrays
+    reg, out = int(crc), 0
+    for bit, col in enumerate(mat.tolist()):
+        if reg >> bit & 1:
+            out ^= col
+    return out
 
 
 def crc32c_combine(crc_a, crc_b, len_b: int):
@@ -183,6 +193,26 @@ def crc32c_combine(crc_a, crc_b, len_b: int):
         ^ np.asarray(crc_b, np.uint32)
     return int(out) if np.ndim(crc_a) == 0 and np.ndim(crc_b) == 0 \
         else out
+
+
+def crc32c_patch(crc: int, patches) -> int:
+    """CRC of a buffer after disjoint ranges of it were replaced in
+    place, from its CRC before (any seed, raw register) without
+    touching the rest: the register is affine in the data, so
+    replacing ``old`` by ``new`` with ``tail`` bytes after the range
+    XORs in the register run from 0 over ``old ^ new`` and advanced
+    over ``tail`` zero bytes.  ``patches``: (old, new, tail) with
+    ``old`` and ``new`` bytes-like of one length.  One range is the
+    rule (a sub-write's chunk), so each is one scalar library call and
+    one scalar ``crc32c_zeros``: the batched entry costs more in
+    marshaling than 4 KiB cost to hash."""
+    crc = int(crc)
+    for old, new, tail in patches:
+        reg = native.crc32c(np.bitwise_xor(
+            np.frombuffer(old, np.uint8),
+            np.frombuffer(new, np.uint8)).tobytes(), 0)
+        crc ^= crc32c_zeros(reg, tail)
+    return crc
 
 
 def crc32c_strip_zeros(crcs, nzeros):

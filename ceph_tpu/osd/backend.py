@@ -35,8 +35,14 @@ SHARD_XATTR = "_shard"  # WRITE-TIME-PINNED shard id of the stored bytes
 CRC_XATTR = "_crc"      # CRC32C of the stored shard bytes (the per-shard
                         # hashinfo digest): rejects payloads/replies whose
                         # bytes don't match their claimed identity
+CRC_ALG_XATTR = "_crc_alg"  # CRC_ALG beside every ``_crc`` that shard_crc
+                            # (or a codec launch) computed: a tag without
+                            # it may be a pre-unification zlib.crc32, which
+                            # still verifies (shard_crc_matches) but which
+                            # CRC32C's linearity cannot update (_plan_stamp)
+CRC_ALG = b"crc32c"
 HIDDEN_XATTRS = frozenset({SIZE_XATTR, VER_XATTR, SHARD_XATTR,
-                           CRC_XATTR})               # never client-visible
+                           CRC_XATTR, CRC_ALG_XATTR})  # never client-visible
 # the span of a gather that rebuilds a shard (read_recovery_payload);
 # a client read's is "ec.gather"
 RECOVER_GATHER = "ec.recover_gather"
@@ -74,6 +80,19 @@ def shard_crc_matches(data, tag, precomputed: int | None = None) -> bool:
         PERF.inc("legacy_crc_tags")
         return True
     return False
+
+
+def crc_tag(crc: int) -> dict[str, bytes]:
+    """The xattrs that tag a shard with the CRC32C ``crc`` of its
+    bytes: ``_crc`` and, beside it, the polynomial's marker."""
+    return {CRC_XATTR: str(int(crc)).encode(), CRC_ALG_XATTR: CRC_ALG}
+
+
+def _patched(arr: np.ndarray, writes, segs) -> np.ndarray:
+    """``arr`` with a ranged sub-write's segments written into it."""
+    for (off, ln), seg in zip(writes, segs):
+        arr[off:off + ln] = np.frombuffer(seg, np.uint8)
+    return arr
 
 
 def ver_encode(version) -> bytes:
@@ -415,7 +434,17 @@ class ECBackend(PGBackend):
     RANGED per-shard sub-writes — write amplification is
     O(touched stripes), not O(object)
     (tests/test_ec_rmw.py pins both the byte movement and this
-    docstring's claim).
+    docstring's claim).  The shard side pays in the same proportion
+    (apply_sub_write, _plan_stamp): a version-only sub-write leaves
+    the shard's bytes, resident copy and ``_crc`` alone, one that
+    changes bytes in place updates ``_crc`` by CRC32C's linearity from
+    the bytes it changes, both in the sub-write's one transaction; a
+    shard is re-hashed whole (_stamp_identity) only where its length
+    changes (append, growth, truncate), where no ``_crc`` of known
+    polynomial is stored (``_crc_alg``: a pre-unification zlib tag
+    cannot be updated), or where the old bytes of a range cannot be had
+    (``ec_pipeline`` counts ``rmw_stamps_kept`` / ``_patched`` /
+    ``_rehashed``; tests/test_ec_rmw_stamp.py).
 
     Codec launches go through the per-OSD CodecBatcher
     (osd.codec_batcher): all stripes of an op share one launch, and
@@ -1562,35 +1591,48 @@ class ECBackend(PGBackend):
         if shard is not None and self.pg.shard_id is None:
             self.pg.shard_id = shard
         # final shard content for the device-resident cache: full-shard
-        # writes hand their payload straight through; ranged RMW writes
-        # patch the PRE-txn resident copy (captured before the store's
-        # coherence invalidation fires) so the identity stamp never
-        # reads the shard back from the store
+        # writes hand their payload straight through; a ranged RMW
+        # write in place updates the resident copy and ``_crc`` from
+        # the bytes it changes (_plan_stamp) and needs no second
+        # transaction; one that cannot patches the PRE-txn resident
+        # copy (captured before the store's coherence invalidation
+        # fires) so the identity stamp never reads the shard back
         content = size = vtuple = None
+        stamp = pre = None
         if w.get("remove"):
             txn.remove(self.coll, oid)
         elif w.get("writes") is not None:
             # partial-stripe RMW: ranged chunk writes + final length
             pre = self.dcache.get(self.coll, oid) \
                 if self.dcache is not None else None
+            vtuple = (entry.version.epoch, entry.version.version)
+            with tracing.section("osd_op.stamp"):
+                stamp = self._plan_stamp(oid, shard, pre, w, segs)
             txn.touch(self.coll, oid)
             for i, (off, ln) in enumerate(w["writes"]):
                 buf = segs[i] if i < len(segs) else b""
                 assert len(buf) == ln, (len(buf), ln)
                 txn.write(self.coll, oid, off, buf)
-            txn.truncate(self.coll, oid, w["shard_len"])
+            if stamp is None:
+                self._pcount("rmw_stamps_rehashed")
+                txn.truncate(self.coll, oid, w["shard_len"])
             txn.setattr(self.coll, oid, SIZE_XATTR,
                         str(w["size"]).encode())
             txn.setattr(self.coll, oid, VER_XATTR,
                         ver_encode(entry.version))
-            if pre is not None:
+            if stamp is not None:
+                crc, label = stamp
+                if label is not None:
+                    txn.setattr(self.coll, oid, SHARD_XATTR,
+                                str(int(label)).encode())
+                if w["writes"]:
+                    txn.setattr(self.coll, oid, CRC_XATTR,
+                                str(crc).encode())
+            elif pre is not None:
                 arr = np.zeros(w["shard_len"], np.uint8)
                 n = min(pre.buf.size, w["shard_len"])
                 arr[:n] = pre.buf[:n]
-                for (off, ln), buf in zip(w["writes"], segs):
-                    arr[off:off + ln] = np.frombuffer(buf, np.uint8)
-                content, size = arr, w["size"]
-                vtuple = (entry.version.epoch, entry.version.version)
+                content, size = _patched(arr, w["writes"], segs), w["size"]
         elif w.get("touch"):
             # create-only / attr-only: never rewrite shard content
             txn.touch(self.coll, oid)
@@ -1613,22 +1655,118 @@ class ECBackend(PGBackend):
         apply_mutations(txn, self.coll, oid, attr_muts)
         self.pg.append_log_and_meta(txn, entry)
         self._queue_txn_traced(txn, oid)
-        if not w.get("remove"):
+        if stamp is not None:
+            # the transaction carried the whole identity; the store's
+            # coherence invalidation dropped the resident entry on it
+            if pre is not None:
+                crc, label = stamp
+                buf = pre.buf
+                if w["writes"]:
+                    # one copy stays, the hash does not: _local_entry
+                    # and device_view hand out the resident buffer
+                    # itself (a gather's merge, a decode's stack, an
+                    # upload), and patching it in place would change
+                    # bytes under a reader that took them at the old
+                    # version
+                    buf = _patched(buf.copy(), w["writes"], segs)
+                self.dcache.put(
+                    self.coll, oid, buf, size=w["size"], ver=vtuple,
+                    shard=pre.shard if label is None else label, crc=crc)
+        elif not w.get("remove"):
             self._stamp_identity(oid, shard, crc=w.get("crc"),
                                  content=content, size=size,
                                  ver=vtuple)
+
+    def _plan_stamp(self, oid: str, shard: int | None, pre,
+                    w: dict, segs: list[bytes]):
+        """What a ranged sub-write in place does to the shard's
+        identity, worked out BEFORE its transaction so that the tag
+        rides in it: ``(crc, label)``, ``label`` None where the stored
+        ``_shard`` already names ``shard``; or None where the shard
+        must be re-hashed whole after the transaction
+        (_stamp_identity), because linearity has nothing sound to
+        stand on: the shard's length changes (growth past the old end,
+        ``truncate``), no ``_crc`` is stored or its polynomial is
+        not known to be CRC32C (``_crc_alg`` absent: the tag may be a
+        pre-unification zlib.crc32, and a CRC32C delta XORed into
+        that matches neither), a range's old bytes cannot be read,
+        ranges overlap.
+
+        A version-only sub-write (no range) changes neither bytes nor
+        ``_crc``: nothing is read.  One that changes bytes updates
+        ``_crc`` by CRC32C's linearity (``crc32c_patch``) from the old
+        bytes of its ranges alone, taken from the resident copy
+        ``pre`` or by a ranged ``store.read``.  Counted in
+        ``ec_pipeline``: ``rmw_stamps_kept`` / ``rmw_stamps_patched``
+        here, ``rmw_stamps_rehashed`` by the caller on None."""
+        length = int(w["shard_len"])
+        if self.store.getattr(self.coll, oid, CRC_ALG_XATTR) != CRC_ALG:
+            # a tag of unknown polynomial (pre-unification zlib.crc32,
+            # which a resident entry filled from the store carries
+            # too): the re-hash stamps a CRC32C over it, as it always
+            # did
+            return None
+        if pre is not None:
+            old_len, crc, label = pre.buf.size, pre.crc, pre.shard
+        else:
+            st = self.store.stat(self.coll, oid)
+            if st is None:
+                return None
+            old_len = st["size"]
+            raw = self.store.getattr(self.coll, oid, CRC_XATTR)
+            crc = int(raw) if raw is not None else None
+            raw = self.store.getattr(self.coll, oid, SHARD_XATTR)
+            label = int(raw) if raw is not None else None
+        if old_len != length or crc is None:
+            return None
+        label = None if shard is None or label == int(shard) \
+            else int(shard)
+        if not w["writes"]:
+            self._pcount("rmw_stamps_kept")
+            return crc, label
+        end, patches = 0, []
+        for (off, ln), new in sorted(zip(w["writes"], segs),
+                                     key=lambda ws: tuple(ws[0])):
+            if off < end or off + ln > length or len(new) != ln:
+                return None
+            end = off + ln
+            if pre is not None:
+                old = pre.buf[off:end]
+            else:
+                with tracing.section("store.read"):
+                    try:
+                        old = self.store.read(self.coll, oid, off, ln)
+                    except OSError:
+                        return None
+                if self.dcache is not None:
+                    self.dcache.note_host_read(len(old))
+                if len(old) != ln:
+                    return None
+            patches.append((old, new, length - end))
+        from ..ops.crc32c_batch import crc32c_patch
+        self._pcount("rmw_stamps_patched")
+        return crc32c_patch(crc, patches), label
 
     def _stamp_identity(self, oid: str, shard: int | None,
                         crc: int | None = None, content=None,
                         size: int | None = None,
                         ver: tuple | None = None) -> None:
         """Post-commit identity tag: shard label + CRC of the FINAL
-        shard content.  Full-shard writes pass the ``crc`` the codec
-        launch already computed (no read-back, no re-hash); ranged RMW
-        writes pass the patched resident ``content`` (no store
-        read-back) or, with no resident copy, read back from the store
-        after the txn applied (queue_transaction is synchronous, no
-        interleaving await) -- still through the batched kernel.
+        shard content, for the writes that do not bring their tag in
+        their own transaction.  Full-shard writes pass the ``crc`` the
+        codec launch already computed (no read-back, no re-hash).  A
+        ranged RMW write comes here only where _plan_stamp found
+        nothing sound to update ``_crc`` from (the shard's length
+        changes, no ``_crc`` of known polynomial stored, old bytes
+        unreadable, overlapping ranges; counted
+        ``rmw_stamps_rehashed``): the shard is then
+        RE-HASHED WHOLE, from the patched resident ``content`` (no
+        store read-back) or, with no resident copy, read back from the
+        store after the txn applied (queue_transaction is synchronous,
+        no interleaving await) -- still through the batched kernel.
+        Every other ranged write never re-hashes: a version-only one
+        leaves bytes and ``_crc`` as stored, one that changes bytes in
+        place updates ``_crc`` from those bytes alone.
 
         When the final content is in hand it becomes the cache entry
         for ``(coll, oid)`` -- the write's encoded bytes flow straight
@@ -1636,7 +1774,9 @@ class ECBackend(PGBackend):
         the store.
 
         Section ``osd_op.stamp``: the read-back and the CRC where the
-        write handed none down, and building the tag; the tag's own
+        write handed none down, and building the tag (and, in
+        apply_sub_write, _plan_stamp: the ranged read of old bytes
+        and the CRC update); the tag's own
         transaction stays the store's (``store.queue_transaction``),
         as every other transaction is."""
         with tracing.section("osd_op.stamp"):
@@ -1654,8 +1794,8 @@ class ECBackend(PGBackend):
             if shard is not None:
                 txn.setattr(self.coll, oid, SHARD_XATTR,
                             str(int(shard)).encode())
-            txn.setattr(self.coll, oid, CRC_XATTR,
-                        str(int(crc)).encode())
+            for name, val in crc_tag(crc).items():
+                txn.setattr(self.coll, oid, name, val)
             with tracing.section("store.queue_transaction"):
                 self.store.queue_transaction(txn)
             if self.dcache is not None and content is not None \
@@ -1759,7 +1899,7 @@ class ECBackend(PGBackend):
                 "xattrs": {SIZE_XATTR: str(size).encode(),
                            VER_XATTR: f"{ver[0]},{ver[1]}".encode(),
                            SHARD_XATTR: str(int(shard)).encode(),
-                           CRC_XATTR: str(crc).encode()},
+                           **crc_tag(crc)},
                 "omap": {},
                 "shard": int(shard)}
 

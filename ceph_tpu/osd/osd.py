@@ -154,7 +154,9 @@ class OSD:
         # cost (rmw_stripes_read: stripes of stored content it asked
         # for; rmw_stripes_cached: of those, served by the ExtentCache;
         # rmw_subwrites_empty: sub-writes that carried the version
-        # stamp alone, to data shards it left unchanged).  Pipeline
+        # stamp alone, to data shards it left unchanged) and what a
+        # ranged sub-write applied here did to its shard's ``_crc``
+        # (rmw_stamps_kept / _patched / _rehashed).  Pipeline
         # knobs are SNAPSHOT at construction.
         self.perf_pipeline = self.perf.create("ec_pipeline")
         for key in ("staged_batches", "inflight_overlap_windows",
@@ -162,7 +164,9 @@ class OSD:
                     "commit_overlap_ms", "coalesced_subops",
                     "flush_windows", "write_old_gathers",
                     "writes_blind", "rmw_stripes_read",
-                    "rmw_stripes_cached", "rmw_subwrites_empty"):
+                    "rmw_stripes_cached", "rmw_subwrites_empty",
+                    "rmw_stamps_kept", "rmw_stamps_patched",
+                    "rmw_stamps_rehashed"):
             self.perf_pipeline.inc(key, 0)    # visible even when idle
         self._pipeline_flush_window = float(
             self.config.get("osd_pipeline_flush_window", 0.002))

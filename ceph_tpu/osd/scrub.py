@@ -207,7 +207,7 @@ async def scrub_ec(pg, repair: bool = False) -> ScrubResult:
     oids = [o for o in pg.osd.store.list_objects(pg.coll)
             if o != META_OID]
     res.objects_scrubbed = len(oids)
-    from .backend import (CRC_XATTR, SHARD_XATTR, VER_XATTR, shard_crc,
+    from .backend import (SHARD_XATTR, VER_XATTR, crc_tag, shard_crc,
                           shard_crc_matches)
     for oid in oids:
         # fetch every stored shard + its write-time identity tags
@@ -288,9 +288,9 @@ async def scrub_ec(pg, repair: bool = False) -> ScrubResult:
                                        .encode().hex(),
                                    SHARD_XATTR:
                                        str(shard).encode().hex(),
-                                   CRC_XATTR:
-                                       str(shard_crc(blob))
-                                       .encode().hex()},
+                                   **{name: val.hex() for name, val
+                                      in crc_tag(shard_crc(blob))
+                                      .items()}},
                                "omap": {}}
                     if osd_id == pg.whoami:
                         pg._apply_recovery_payload(oid, payload,

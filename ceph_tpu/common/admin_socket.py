@@ -79,9 +79,16 @@ class AdminSocket:
         return {"name": "ceph-tpu", "version": "0.1"}
 
 
+REPLY_MAX = 64 << 20
+
+
 async def admin_command(path: str, prefix: str, **kwargs) -> object:
     """Client side (`ceph daemon` analog): one command, one reply."""
-    reader, writer = await asyncio.open_unix_connection(path)
+    # one reply is one line, and a full ring (``dump_loop``'s 600
+    # seconds, ``dump_tracing``'s 2048 spans) is far over the stream
+    # reader's default 64 KiB a line
+    reader, writer = await asyncio.open_unix_connection(path,
+                                                        limit=REPLY_MAX)
     try:
         req = {"prefix": prefix, **kwargs}
         writer.write(json.dumps(req).encode() + b"\n")

@@ -153,6 +153,7 @@ class SimCluster:
         only (histogram/avg dict entries are skipped — use
         ``perf_dump`` for the full structures)."""
         out: dict[str, int | float] = {}
+        counted = set()
         for osd in self.osds:
             # a killed-but-not-yet-revived OSD still sits in the list;
             # counting its frozen lifetime counters makes phase deltas
@@ -161,8 +162,11 @@ class SimCluster:
             if osd.is_stopped():
                 continue
             pc = osd.perf.get(which)
-            if pc is None:
+            # a process-wide set (datapath, loop) is one object that
+            # every OSD adopted: it counts once
+            if pc is None or id(pc) in counted:
                 continue
+            counted.add(id(pc))
             for key, val in pc.dump().items():
                 if isinstance(val, (int, float)):
                     out[key] = out.get(key, 0) + val

@@ -18,6 +18,7 @@ import time
 from collections import defaultdict
 
 from ..common import AdminSocket, PerfCountersCollection
+from ..common.tracing import LOOP_PERF
 from ..msg import Message, Messenger
 from ..crush.types import (
     Bucket, CrushMap, CRUSH_BUCKET_STRAW2,
@@ -122,6 +123,7 @@ class Monitor:
         self.perf = PerfCountersCollection()
         self.perf_paxos = self.perf.create("paxos")
         self.perf.adopt(self.msgr.perf)
+        self.perf.adopt(LOOP_PERF)       # the loop the daemons share
         self.admin_socket: AdminSocket | None = None
         self._admin_socket_path = admin_socket_path
         # the other PaxosServices (auth/config/log/health) ride the

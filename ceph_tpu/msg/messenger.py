@@ -22,7 +22,7 @@ from typing import Awaitable, Callable
 
 from ..common.perf import PerfCounters
 from ..common.throttle import injector as _fault
-from ..common.tracing import section
+from ..common.tracing import install_loop_probe, section
 from .message import (COMP_MAGIC, MAGIC, OFFLOAD_THRESHOLD, SEC_MAGIC,
                       FrameReader, Message, decrypt_frame, unwrap_frame,
                       wrap_frame)
@@ -654,8 +654,16 @@ class Messenger:
         self._rx_spare: list[bytearray] = []     # see FrameReader.spare
 
     # -- server -------------------------------------------------------------
+    def _loop(self) -> asyncio.AbstractEventLoop:
+        """The running loop, with the tracing module's probe on it
+        before this messenger opens a socket there (the first
+        messenger of a loop installs it; see ``install_loop_probe``)."""
+        loop = asyncio.get_running_loop()
+        install_loop_probe(loop)
+        return loop
+
     async def bind(self, host: str = "127.0.0.1", port: int = 0) -> tuple[str, int]:
-        self._server = await asyncio.get_event_loop().create_server(
+        self._server = await self._loop().create_server(
             lambda: FrameProtocol(self, self._on_accept), host, port)
         self.addr = self._server.sockets[0].getsockname()[:2]
         return self.addr
@@ -915,7 +923,7 @@ class Messenger:
         """A new socket to ``addr`` with the client's handshake done:
         its protocol and what the handshake returned.  The socket is
         closed again if the handshake fails."""
-        _, proto = await asyncio.get_event_loop().create_connection(
+        _, proto = await self._loop().create_connection(
             lambda: FrameProtocol(self), addr[0], addr[1])
         try:
             return proto, await self._handshake_client(proto, peer_name)

@@ -1653,7 +1653,8 @@ class ECBackend(PGBackend):
                 content, size = buf, w["size"]
                 vtuple = (entry.version.epoch, entry.version.version)
         apply_mutations(txn, self.coll, oid, attr_muts)
-        self.pg.append_log_and_meta(txn, entry)
+        with tracing.section("osd_op.log_meta"):
+            self.pg.append_log_and_meta(txn, entry)
         self._queue_txn_traced(txn, oid)
         if stamp is not None:
             # the transaction carried the whole identity; the store's

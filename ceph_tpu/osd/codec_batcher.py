@@ -353,8 +353,6 @@ class CodecBatcher:
             # which under a saturated loop is pure latency.
             if self._staged or self._groups:
                 await asyncio.sleep(0)
-            if self._staged:
-                self._pcount("inflight_overlap_windows")
             try:
                 self._complete(st, handle)
             except Exception as e:

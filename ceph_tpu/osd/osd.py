@@ -16,7 +16,7 @@ import uuid as uuid_mod
 
 from ..common import AdminSocket, ConfigProxy, PerfCountersCollection, \
     make_task_tracker
-from ..common.tracing import get_tracer, section
+from ..common.tracing import LOOP_PERF, dump_loop, get_tracer, section
 from ..mon.osdmap import OSDMap, Incremental
 from ..msg import Message, Messenger
 from ..os.store import MemStore, make_default_store
@@ -159,8 +159,7 @@ class OSD:
         # (rmw_stamps_kept / _patched / _rehashed).  Pipeline
         # knobs are SNAPSHOT at construction.
         self.perf_pipeline = self.perf.create("ec_pipeline")
-        for key in ("staged_batches", "inflight_overlap_windows",
-                    "stage_stalls", "overlapped_commits",
+        for key in ("staged_batches", "stage_stalls", "overlapped_commits",
                     "commit_overlap_ms", "coalesced_subops",
                     "flush_windows", "write_old_gathers",
                     "writes_blind", "rmw_stripes_read",
@@ -193,6 +192,9 @@ class OSD:
         self.shard_cache = DeviceShardCache.from_config(self.config)
         self.store.attach_shard_cache(self.shard_cache)
         self.perf.adopt(_datapath_perf)
+        # the event loop's phase totals (common/tracing.py): one set a
+        # process, like datapath, since all daemons share the loop
+        self.perf.adopt(LOOP_PERF)
         # straggler-tolerant hedged gathers (osd/hedged_gather.py):
         # ONE engine + per-peer latency EWMA per daemon -- every
         # ECBackend, scrub collection and recovery pull on this OSD
@@ -370,6 +372,13 @@ class OSD:
         sock.register("dump_tracing",
                       "finished trace spans (optionally one trace_id)",
                       dump_tracing)
+
+        async def loop_phases(req):
+            return dump_loop()
+
+        sock.register("dump_loop",
+                      "the event loop's seconds and its long phases",
+                      loop_phases)
         sock.register("config show", "all config values", config_show)
         sock.register("scrub", "scrub a pg: {pgid, repair}", scrub_cmd)
         sock.register("config get", "describe one option", config_get)

@@ -1639,7 +1639,12 @@ class PG:
             # their whole submit), and later writers wait on the event.
             # The payload read itself -- a remote shard fanout for EC
             # pools -- runs without the lock so client I/O proceeds.
+            # A write that has left the lock may still have its commit
+            # in flight (the staged sub-writes ship from the pipe's
+            # workers): the read would find the object on fewer than k
+            # shards, fail EIO and restart the PG's whole backfill.
             async with self.lock:
+                await self._yield_to_commits(oid)
                 bi["inflight"][oid] = ev
             payload = await self.backend.read_recovery_payload(
                 oid, shard)

@@ -327,6 +327,49 @@ def test_a_client_write_waits_for_one_objects_push_not_for_the_round():
     assert 1 <= res["at_ack"] <= res["pushes"] // 2
 
 
+async def _push_beside_a_commit_in_flight() -> dict:
+    """A write has left the PG's lock and its commit is still on its
+    way to the peers: a push of that object must not read it yet (it
+    would find fewer than k shards, fail EIO and restart the PG's
+    backfill), and must not hold the lock while it waits."""
+    size = 2 * UNIT
+    cluster, rados, ioctx = await _pool(3, K2M1, 1)
+    try:
+        await ioctx.write_full("obj-0", payload(0, size))
+        pg = next(pg for o in cluster.osds for pg in o.pgs.values()
+                  if pg.is_primary())
+        peer = next(o for o in pg.acting if o != pg.whoami)
+        commit = asyncio.get_running_loop().create_future()
+        pg._obj_commits["obj-0"] = commit
+        bi = {"inflight": {}, "pushed": set()}
+        read, reads = pg.backend.read_recovery_payload, []
+
+        async def reading(oid, shard):
+            reads.append(commit.done())
+            return await read(oid, shard)
+
+        pg.backend.read_recovery_payload = reading
+        push = asyncio.ensure_future(pg._backfill_push_traced(
+            bi, peer, "obj-0", pg._shard_of(peer)))
+        await asyncio.sleep(0.05)
+        waiting = {"reads": list(reads), "marked": "obj-0" in bi["inflight"],
+                   "locked": pg.lock.locked()}
+        commit.set_result(None)
+        acked = await asyncio.wait_for(push, 10)
+        return {"waiting": waiting, "reads": reads, "acked": acked,
+                "pushed": bi["pushed"], "inflight": bi["inflight"]}
+    finally:
+        await rados.shutdown()
+        await cluster.stop()
+
+
+def test_a_push_reads_its_object_after_the_commit_in_flight():
+    res = asyncio.run(_push_beside_a_commit_in_flight())
+    assert res["waiting"] == {"reads": [], "marked": False, "locked": False}
+    assert res["reads"] == [True] and res["acked"]
+    assert res["pushed"] == {"obj-0"} and res["inflight"] == {}
+
+
 # -- every position of the k=8,m=3 pool --------------------------------------
 
 async def _rebuild_every_position() -> dict:

@@ -20,9 +20,10 @@ import pytest
 from ceph_tpu.common import tracing
 
 ROOT = Path(__file__).resolve().parents[1]
+# tracing.py among them: the event loop's own three sections are there
 SECTIONED = sorted(
     str(p.relative_to(ROOT)) for p in (ROOT / "ceph_tpu").rglob("*.py")
-    if "section(" in p.read_text() and p.name != "tracing.py")
+    if "section(" in p.read_text())
 
 
 def _is_section(item: ast.withitem) -> bool:
@@ -68,7 +69,24 @@ def section_faults(source: str) -> list[str]:
 
 
 def test_sections_are_placed():
-    assert len(SECTIONED) >= 8, SECTIONED
+    assert len(SECTIONED) >= 9, SECTIONED
+    assert "ceph_tpu/common/tracing.py" in SECTIONED
+
+
+def test_the_loop_layer_has_its_three_sections_and_no_others():
+    """``loop`` is a layer like the others, and its sections are the
+    probe's: nothing else in the program opens a ``loop.*`` section."""
+    assert "loop" in tracing.SECTION_LAYERS
+    found = {}
+    for path in SECTIONED:
+        for node in ast.walk(ast.parse((ROOT / path).read_text())):
+            if isinstance(node, ast.With):
+                for i in node.items:
+                    if _is_section(i) and i.context_expr.args[0].value \
+                            .startswith("loop."):
+                        found[i.context_expr.args[0].value] = path
+    assert found == {name: "ceph_tpu/common/tracing.py" for name in (
+        "loop.select", "loop.read_ready", "loop.write_ready")}
 
 
 @pytest.mark.parametrize("path", SECTIONED)

@@ -8,8 +8,9 @@ an exact quotient by the item weight and a lexicographic argmin, all in
 32-bit limbs (the TPU has no 64-bit integers, and the program holds no
 64-bit type whether or not an embedding process runs jax with x64 on);
 the firstn/indep retry loops become bounded `lax.while_loop`s with
-per-lane masks -- decision-identical to the scalar mapper
-(ceph_tpu/crush/mapper.py), which is itself pinned to mapper.c.
+per-lane masks (firstn's, in a long launch, over the lanes still
+unplaced alone: RETRY_MIN_LANES) -- decision-identical to the scalar
+mapper (ceph_tpu/crush/mapper.py), which is itself pinned to mapper.c.
 
 Supported map shape for the fused path: uniform-depth straw2
 hierarchies of ANY depth (root->osds up through root->row->rack->host->
@@ -52,6 +53,18 @@ CRUSH_HASH_SEED = np.uint32(1315423911)
 # from the 64-bit program (PR 21: a 2M-lane launch aborted the v5e
 # runtime); nothing has measured the 32-bit program beyond it.
 MAX_LANES = 1 << 17
+
+# crush_firstn retries at the width of what is left to retry: a launch
+# of RETRY_MIN_LANES lanes or more tries a replica at full width until
+# the lanes still unplaced fit lanes // RETRY_NARROW (all weights in, a
+# replica collides with an earlier one's bucket in one to three lanes of
+# a hundred: one pass; many OSDs out or few failure domains: more) and
+# finishes them compacted to that width.  A shorter launch keeps the one
+# full-width loop: at 4,096 lanes the narrow stage still saves a fifth
+# of a call on the chip (PERF.md section 6, PR 37); a few dozen narrow
+# lanes save nothing and are a second loop body to compile.
+RETRY_MIN_LANES = 1 << 12
+RETRY_NARROW = 16
 
 
 def _u32(v):
@@ -381,6 +394,36 @@ def _rule_shape(crush_map: CrushMap, ruleno: int):
     return root_id, firstn, leaf, choose_tries, leaf_tries, choose_type
 
 
+def _narrow(left, width, lanes, state, loop):
+    """``loop(lanes, state)`` -> state over the lanes of ``left``
+    alone, compacted to ``width`` lanes (at most that many are left),
+    and not run where none is: the count is read on the device, both
+    ways are in the one program.  lanes and state: tuples of (L,) int32
+    rows, what the loop reads of a lane and what it changes; a lane
+    outside ``left`` passes through the loop unchanged."""
+    n = len(lanes)
+    # items lead, (rows, L): one gather lands a lane's rows as (rows, width)
+    rows = jnp.stack(lanes + state)
+
+    def run():
+        idx = _first_lanes(left, width)
+        sub = rows[:, idx]
+        got = loop(tuple(sub[:n]), tuple(sub[n:]))
+        return rows[n:].at[:, idx].set(jnp.stack(got), unique_indices=True)
+
+    return tuple(jax.lax.cond(jnp.any(left), run, lambda: rows[n:]))
+
+
+def _first_lanes(left, width):
+    """The lanes of ``left`` in order, then lanes outside it, ``width``
+    in all and each once: the pad lanes are real ones that a loop
+    passes through unchanged, so nothing marks or drops them."""
+    L = left.shape[0]
+    lane = jnp.arange(L, dtype=jnp.int32)
+    first = jax.lax.sort(jnp.where(left, lane, lane + np.int32(L)))[:width]
+    return jnp.where(first >= L, first - np.int32(L), first)
+
+
 class VectorCrush:
     """Bulk mapper for one (map, rule) pair, any uniform depth."""
 
@@ -418,6 +461,10 @@ class VectorCrush:
         if not self.stable or self.vary_r != 1:
             # scalar fallback covers other tunable profiles
             raise ValueError("fused path implements jewel tunables")
+        # running totals of map_pgs: device launches, and crush_firstn's
+        # two counts: lanes finished by a narrow retry loop, full-width
+        # passes after a replica's first
+        self.launches = self.retry_lanes = self.wide_retries = 0
 
     def _tables(self):
         """Per level one int32 table (4 * N, P, B), all that a choice
@@ -498,71 +545,116 @@ class VectorCrush:
         return osd, found
 
     # -- firstn -------------------------------------------------------------
-    @partial(jax.jit, static_argnames=("self", "numrep"))
-    def crush_firstn(self, xs: jnp.ndarray, numrep: int,
-                   osd_weights: jnp.ndarray) -> jnp.ndarray:
-        cm = self.cm
-        tables = self._tables()
-        L = xs.shape[0]
+    def _firstn_try(self, tables, rep, numrep, osd_weights, lanes, state):
+        """Replica ``rep``'s next try in every lane that holds no osd
+        yet: the body of the retry loop, one for all replicas (``rep``
+        is traced) and at the width of the rows it is given.  lanes:
+        (xs, placed, the osd column of each replica slot, its bucket
+        column), ``_NONE``, which no candidate equals, in the slots
+        not placed yet; state: (ftotal, bucket, osd), the osd ``_NONE``
+        until a try is accepted."""
+        xs, placed = lanes[:2]
+        out, out_sel = lanes[2:2 + numrep], lanes[2 + numrep:]
+        ftotal, sel, osd = state
+        done = osd != _NONE
+        r = rep + ftotal
         # chooseleaf targets the last bucket level; plain choose (no
         # leaf recursion) targets the device level
-        bucket_levels = cm.n_levels - 1 if self.leaf else cm.n_levels
-        # one column per replica slot placed so far: osd, chosen bucket
-        out, out_sel = [], []
-        # per-lane count of PLACED replicas: the scalar engine's
-        # outpos, which is the choose_args weight-set position (a lane
-        # whose earlier slot exhausted its tries keeps drawing later
-        # slots at the unadvanced position, exactly as mapper.c does)
-        placed = jnp.zeros((L,), jnp.int32)
+        cand_sel = self._descend(
+            tables, xs, r, placed,
+            self.cm.n_levels - 1 if self.leaf else self.cm.n_levels)
+        collide = jnp.zeros(xs.shape, bool)
+        for prev in out_sel:
+            collide |= prev == cand_sel
+        if self.leaf:
+            # vary_r=1: sub_r = r >> 0 = r
+            cand_osd, found = self._leaf_descend(
+                tables, xs, cand_sel, r, rep, numrep, osd_weights, out,
+                placed)
+            reject = ~found
+        else:
+            cand_osd = cand_sel
+            reject = is_out_jnp(osd_weights, cand_osd, xs)
+            for prev in out:
+                reject |= prev == cand_osd
+        ok = ~done & ~collide & ~reject
+        return (jnp.where(done | ok, ftotal, ftotal + 1),
+                jnp.where(ok, cand_sel, sel), jnp.where(ok, cand_osd, osd))
 
-        for rep in range(numrep):
-            def cond(state):
-                ftotal, done, _, _ = state
-                return jnp.any(~done & (ftotal < self.choose_tries))
+    @partial(jax.jit, static_argnames=("self", "numrep"))
+    def crush_firstn(self, xs: jnp.ndarray, numrep: int,
+                     osd_weights: jnp.ndarray):
+        """(osd ids (lanes, numrep), int32 [lanes finished by a narrow
+        retry loop, full-width passes after a replica's first])."""
+        tables = self._tables()
+        L = xs.shape[0]
+        width = L // RETRY_NARROW if L >= RETRY_MIN_LANES else 0
 
-            def body(state):
-                ftotal, done, sel, osd = state
-                r = rep + ftotal
-                cand_sel = self._descend(tables, xs, r, placed,
-                                         bucket_levels)
-                collide = jnp.zeros((L,), bool)
-                for prev in out_sel:
-                    collide |= prev == cand_sel
-                if self.leaf:
-                    # vary_r=1: sub_r = r >> 0 = r
-                    cand_osd, found = self._leaf_descend(
-                        tables, xs, cand_sel, r, rep, numrep,
-                        osd_weights, out, placed)
-                    reject = ~found
-                else:
-                    cand_osd = cand_sel
-                    reject = is_out_jnp(osd_weights, cand_osd, xs)
-                    for prev in out:
-                        reject |= prev == cand_osd
-                ok = ~done & ~collide & ~reject
-                sel = jnp.where(ok, cand_sel, sel)
-                osd = jnp.where(ok, cand_osd, osd)
-                newdone = done | ok
-                ftotal = jnp.where(~newdone, ftotal + 1, ftotal)
-                return ftotal, newdone, sel, osd
+        def left(state):
+            ftotal, _, osd = state
+            return (osd == _NONE) & (ftotal < self.choose_tries)
 
-            init = (jnp.zeros((L,), jnp.int32), jnp.zeros((L,), bool),
-                    jnp.full((L,), _NONE), jnp.full((L,), _NONE))
-            _, done, sel, osd = jax.lax.while_loop(cond, body, init)
-            out.append(jnp.where(done, osd, _NONE))
-            out_sel.append(jnp.where(done, sel, _NONE))
-            placed = placed + done.astype(jnp.int32)
+        def tries(one_try, lanes, state, fit):
+            """Passes of ``one_try`` until at most ``fit`` lanes are
+            left: (passes made, state)."""
+            return jax.lax.while_loop(
+                lambda st: jnp.sum(left(st[1]), dtype=jnp.int32) > fit,
+                lambda st: (st[0] + 1, one_try(lanes, st[1])),
+                (jnp.int32(0), state))
+
+        def replica(carry, rep):
+            # out, out_sel: one column per replica slot, osd and chosen
+            # bucket.  placed: per-lane count of PLACED replicas, the
+            # scalar engine's outpos, which is the choose_args
+            # weight-set position (a lane whose earlier slot exhausted
+            # its tries keeps drawing later slots at the unadvanced
+            # position, exactly as mapper.c does)
+            out, out_sel, placed, retry_lanes, wide_retries = carry
+            one_try = partial(self._firstn_try, tables, rep, numrep,
+                              osd_weights)
+            lanes = (xs, placed, *out, *out_sel)
+            state = (jnp.zeros((L,), jnp.int32), jnp.full((L,), _NONE),
+                     jnp.full((L,), _NONE))
+            passes, state = tries(one_try, lanes, state, width)
+            wide_retries += passes - 1
+            if width:
+                # metadata only, as straw2_draw: what a call spends on
+                # the lanes its full-width passes left
+                with jax.named_scope("crush_retry"):
+                    todo = left(state)
+                    retry_lanes += jnp.sum(todo, dtype=jnp.int32)
+                    state = _narrow(
+                        todo, width, lanes, state,
+                        lambda *rows: tries(one_try, *rows, 0)[1])
+            _, sel, osd = state
+            return (tuple(jnp.where(rep == i, osd, o)
+                          for i, o in enumerate(out)),
+                    tuple(jnp.where(rep == i, sel, o)
+                          for i, o in enumerate(out_sel)),
+                    placed + (osd != _NONE).astype(jnp.int32),
+                    retry_lanes, wide_retries), None
+
+        # one body for all replicas: the program's size and its compile
+        # time do not grow with numrep.  The columns are a tuple, each
+        # updated by a select: a stacked (numrep, L) array costs the
+        # chip 1.5 ms a launch in row slices and dynamic updates
+        unplaced = (jnp.full((L,), _NONE),) * numrep
+        (out, _, _, retry_lanes, wide_retries), _ = jax.lax.scan(
+            replica,
+            (unplaced, unplaced, jnp.zeros((L,), jnp.int32), jnp.int32(0),
+             jnp.int32(0)), jnp.arange(numrep, dtype=jnp.int32))
         # scalar firstn COMPACTS (an exhausted slot leaves no hole):
         # shift placed entries left, NONE-pad the tail
         out = jnp.stack(out, axis=1)
-        return jax.lax.sort(
+        ids = jax.lax.sort(
             ((out == _NONE).astype(jnp.int32), out),
             dimension=1, is_stable=True, num_keys=1)[1]
+        return ids, jnp.stack([retry_lanes, wide_retries])
 
     # -- indep --------------------------------------------------------------
     @partial(jax.jit, static_argnames=("self", "numrep"))
     def crush_indep(self, xs: jnp.ndarray, numrep: int,
-                  osd_weights: jnp.ndarray) -> jnp.ndarray:
+                    osd_weights: jnp.ndarray):
         cm = self.cm
         tables = self._tables()
         L = xs.shape[0]
@@ -605,7 +697,17 @@ class VectorCrush:
         _, _, out_o = jax.lax.while_loop(cond, body,
                                          (jnp.int32(0), undef, undef))
         out_o = jnp.stack(out_o, axis=1)
-        return jnp.where(out_o == UNDEF, _NONE, out_o)
+        # one loop over every slot of every lane: no retry of its own
+        # to count (crush_firstn's second result)
+        return (jnp.where(out_o == UNDEF, _NONE, out_o),
+                jnp.zeros((2,), jnp.int32))
+
+    def totals(self) -> dict[str, int]:
+        """The running totals under their names in the monitor's
+        ``placement_cache`` set (mon/pg_mapping.py)."""
+        return {"fused_launches": self.launches,
+                "retry_lanes": self.retry_lanes,
+                "wide_retries": self.wide_retries}
 
     def map_pgs(self, xs, numrep: int, osd_weights) -> np.ndarray:
         """Map every placement seed in ``xs``: (len(xs), numrep) osd
@@ -630,5 +732,10 @@ class VectorCrush:
             # on the host
             with section("device_wait.crush"):
                 # lint: disable=device-path-host-sync -- one materialization per bounded launch of the bulk map
-                out.append(np.asarray(fn(jnp.asarray(part), numrep, w)))
+                ids, (lanes, wide) = jax.device_get(
+                    fn(jnp.asarray(part), numrep, w))
+            out.append(ids)
+            self.launches += 1
+            self.retry_lanes += int(lanes)
+            self.wide_retries += int(wide)
         return np.concatenate(out)[:n]

@@ -316,7 +316,8 @@ class OSDMap:
     @property
     def placement_perf(self):
         """This map's 'placement_cache' counter set (bulk_recomputes,
-        fused/scalar pools, recompute time, lookups, delta_pgs).
+        fused/scalar pools, fused_launches with their retry_lanes and
+        wide_retries, recompute time, lookups, delta_pgs).
         Daemons adopt it into their PerfCountersCollection so `perf
         dump` and the chaos driver see it."""
         if self._placement_perf is None:

@@ -91,8 +91,8 @@ def _vector_crush_for(crush_map, ruleno: int):
 
 
 def bulk_crush(crush_map, ruleno: int, xs, numrep: int, weights,
-               fused: str = "auto",
-               min_lanes: int | None = None) -> tuple[np.ndarray, bool]:
+               fused: str = "auto", min_lanes: int | None = None,
+               perf=None) -> tuple[np.ndarray, bool]:
     """Map every x in ``xs`` through one rule: (rows, used_fused).
 
     rows is (len(xs), numrep) int64 with CRUSH_ITEM_NONE holes -- the
@@ -102,6 +102,8 @@ def bulk_crush(crush_map, ruleno: int, xs, numrep: int, weights,
     it (raising if the shape cannot compile); 'never' is the pure
     scalar sweep.  crushtool --test and the placement cache both ride
     this helper so offline what-ifs exercise the exact production path.
+    ``perf`` takes what the fused launches add to the mapper's running
+    totals: ``fused_launches``, ``retry_lanes``, ``wide_retries``.
     """
     xs = np.asarray(xs, dtype=np.int64)
     lanes = int(xs.shape[0])
@@ -119,8 +121,12 @@ def bulk_crush(crush_map, ruleno: int, xs, numrep: int, weights,
                              and (warm or lanes >= threshold)):
         try:
             vc = _vector_crush_for(crush_map, ruleno)
+            before = vc.totals()
             rows = np.asarray(vc.map_pgs(xs, numrep, list(weights)),
                               dtype=np.int64)
+            if perf is not None:
+                for name, total in vc.totals().items():
+                    perf.inc(name, total - before[name])
             return rows, True
         except ValueError:
             if fused == "always":
@@ -183,7 +189,7 @@ class PGMapping:
             pps = pool_pps(pool)
             rows, used_fused = bulk_crush(
                 osdmap.crush, pool.crush_rule, pps, pool.size, weights,
-                fused=fused, min_lanes=min_lanes)
+                fused=fused, min_lanes=min_lanes, perf=perf)
             if used_fused:
                 pm.fused_pools += 1
             else:

@@ -278,16 +278,33 @@ def _wide_avals(jaxpr):
     return found
 
 
+def _primitives(jaxpr) -> set:
+    import jax
+
+    names = {eqn.primitive.name for eqn in jaxpr.eqns}
+    for eqn in jaxpr.eqns:
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            names |= _primitives(sub)
+    return names
+
+
 @pytest.mark.parametrize("x64", [False, True], ids=["x64_off", "x64_on"])
-@pytest.mark.parametrize("ruleno", [0, 1], ids=["firstn", "indep"])
-def test_no_64_bit_type_reaches_the_device(ruleno, x64, monkeypatch):
+@pytest.mark.parametrize("ruleno,narrow", [(0, False), (1, False), (0, True)],
+                         ids=["firstn", "indep", "firstn_narrow"])
+def test_no_64_bit_type_reaches_the_device(ruleno, narrow, x64, monkeypatch):
     """The mapper's program holds no 64-bit type whether an embedding
     process has jax's x64 flag on or off, maps like the scalar engine
-    under both, and map_pgs never touches the flag."""
+    under both, and map_pgs never touches the flag; ``narrow``: the
+    program of a long launch, with the compaction of the lanes left,
+    their gather, the narrow loop and the scatter back."""
     import jax
     import jax.numpy as jnp
+    import ceph_tpu.crush.vectorized as V
     from ceph_tpu.crush.builder import build_hierarchy
 
+    if narrow:
+        monkeypatch.setattr(V, "RETRY_MIN_LANES", 128)
+        monkeypatch.setattr(V, "RETRY_NARROW", 2)
     cm = build_hierarchy([3, 3, 4])
     weights = [0x10000] * 36
     weights[5], weights[20] = 0, 0x8000
@@ -300,9 +317,12 @@ def test_no_64_bit_type_reaches_the_device(ruleno, x64, monkeypatch):
             jnp.asarray(xs), jnp.asarray(weights, jnp.int32))
         assert _wide_avals(jaxpr.jaxpr) == []
         assert len(jaxpr.jaxpr.eqns) > 0
+        # the narrow stage is the program's one conditional
+        assert ("cond" in _primitives(jaxpr.jaxpr)) == narrow
         # no way into the flag from the mapper
         monkeypatch.setattr(jax, "enable_x64", None)
         assert np.array_equal(vc.map_pgs(xs, 3, weights), want)
+    assert (vc.retry_lanes > 0) == narrow
 
 
 # -- the benchmark cell's own widths ----------------------------------------
@@ -330,6 +350,13 @@ def cell_tree(case: str):
         return cm, 0, 3, weights
     if case == "indep11":
         return cm, 1, 11, weights
+    if case == "one_replica":
+        return cm, 0, 1, weights
+    if case == "third_out":
+        for osd in np.random.default_rng(37).choice(1000, size=333,
+                                                    replace=False):
+            weights[int(osd)] = 0
+        return cm, 0, 3, weights
     rng = np.random.default_rng(3200)
     for b in cm.buckets.values():
         # some under 2^17, so that the quotient passes 32 bits
@@ -357,3 +384,81 @@ def test_cell_tree_weighted_lane_exact(case):
     xs = cell_pps(4096, 1)
     got = VectorCrush(cm, ruleno).map_pgs(xs, numrep, weights)
     assert np.array_equal(got, scalar_batch(cm, ruleno, xs, numrep, weights))
+
+
+# -- the retry loop at the width of what is left ----------------------------
+
+def small_tree(case: str):
+    """(map, ruleno, numrep, osd weights): the map of
+    test_firstn_exhausted_slot_compacts_like_scalar (two OSDs in, three
+    weight-set positions: most lanes run out of tries in some slot and
+    draw the later ones at the unadvanced position) or of
+    test_choose_args_weight_set_scalar_and_vector."""
+    from ceph_tpu.crush.builder import build_hierarchy
+
+    if case == "exhausting":
+        cm = build_hierarchy([3, 3])
+        cm.choose_args = {-1: {"weight_set": [
+            [0x10000, 0x20000, 0x30000],
+            [0x30000, 0x10000, 0x20000],
+            [0x20000, 0x30000, 0x10000]]}}
+        weights = [0] * 9
+        weights[2] = weights[7] = 0x10000
+        return cm, 0, 3, weights
+    cm = build_hierarchy([4, 4, 4])
+    cm.choose_args = {-1: {"weight_set": [
+        [0, 0x40000, 0x40000, 0x40000],
+        [0x40000, 0x40000, 0x40000, 0x80000]]}}
+    return cm, 0, 3, [0x10000] * 64
+
+
+# tree, lanes, RETRY_NARROW (None: the module's own widths, which these
+# lane counts are under), whether a narrow loop finished any lane (None:
+# as the last full-width pass happens to leave it), whether a replica
+# made a full-width pass after its first
+RETRY_CASES = {
+    # the cell's tree, all weights in: replicas 1 and 2 leave 1-4 lanes
+    # of a hundred, which fit a sixteenth
+    "cell-narrow": ("firstn3", 1024, 16, True, False),
+    "cell-wide": ("firstn3", 1024, 128, None, True),
+    "cell-below_threshold": ("firstn3", 1024, None, False, True),
+    # a third of the OSDs out: what a first try leaves overflows a
+    # sixteenth, so full-width passes go on until it fits
+    "third_out-wide": ("third_out", 1024, 16, None, True),
+    "third_out-below_threshold": ("third_out", 1024, None, False, True),
+    # every lane fits (RETRY_NARROW 1): the lanes that run out of tries
+    # do so inside the narrow loop, at their own weight-set position
+    "exhausting-narrow": ("exhausting", 128, 1, True, False),
+    "exhausting-wide": ("exhausting", 128, 16, None, True),
+    "choose_args-narrow": ("choose_args", 512, 2, True, False),
+    "choose_args-wide": ("choose_args", 512, 64, None, True),
+    # no lane is left by the one replica's first try: no retry runs
+    "one_replica-narrow": ("one_replica", 1024, 16, False, False),
+}
+
+
+@pytest.mark.parametrize("case", list(RETRY_CASES))
+def test_retry_at_the_width_of_what_is_left(case, monkeypatch):
+    """crush_firstn over a launch long enough to finish each replica's
+    leftover lanes in a narrow loop: lane for lane the scalar mapper's
+    result whichever width ran, the mapper's totals saying which did."""
+    import ceph_tpu.crush.vectorized as V
+
+    tree, lanes, narrow, narrow_ran, wide_ran = RETRY_CASES[case]
+    if narrow is not None:
+        monkeypatch.setattr(V, "RETRY_MIN_LANES", lanes)
+        monkeypatch.setattr(V, "RETRY_NARROW", narrow)
+    cm, ruleno, numrep, weights = (
+        small_tree(tree) if tree in ("exhausting", "choose_args")
+        else cell_tree(tree))
+    xs = cell_pps(lanes, 2)
+    vc = VectorCrush(cm, ruleno)
+    got = vc.map_pgs(xs, numrep, weights)
+    assert np.array_equal(got, scalar_batch(cm, ruleno, xs, numrep, weights))
+    assert vc.launches == 1
+    if narrow_ran is not None:
+        assert (vc.retry_lanes > 0) == narrow_ran, vc.totals()
+    assert (vc.wide_retries > 0) == wide_ran, vc.totals()
+    if tree == "exhausting":
+        # slots were left unplaced, in lanes that placed a later one
+        assert (got == CRUSH_ITEM_NONE).any()

@@ -10,6 +10,7 @@ from __future__ import annotations
 
 import ast
 import asyncio
+import re
 import subprocess
 import sys
 from pathlib import Path
@@ -238,15 +239,24 @@ def test_mesh_programs_carry_their_own_names(call, name):
     del jax
 
 
-def test_crush_program_carries_its_scopes():
+@pytest.mark.parametrize("lanes", [8, 64], ids=["short", "long"])
+def test_crush_program_carries_its_scopes(lanes, monkeypatch):
+    """``straw2_draw`` in every launch; ``crush_retry`` around the narrow
+    stage (the count of the lanes left, their compaction, the narrow
+    loop with its draws, the scatter back) of a launch long enough to
+    have one."""
     import jax
     import jax.numpy as jnp
+    import ceph_tpu.crush.vectorized as V
     from ceph_tpu.crush.builder import build_two_level_map
-    from ceph_tpu.crush.vectorized import VectorCrush
 
-    vc = VectorCrush(build_two_level_map(4, 2), 0)
+    monkeypatch.setattr(V, "RETRY_MIN_LANES", 64)
+    vc = V.VectorCrush(build_two_level_map(4, 2), 0)
     text = vc.crush_firstn.lower(
-        vc, jnp.arange(8, dtype=jnp.int32), 2,
+        vc, jnp.arange(lanes, dtype=jnp.int32), 2,
         jnp.full((8,), 0x10000, jnp.int32)).as_text(debug_info=True)
     assert "module @jit_crush_firstn " in text
     assert "straw2_draw" in text
+    # the narrow loop's draws nest inside it
+    assert bool(re.search(r'crush_retry/[^"]*straw2_draw', text)) \
+        == (lanes >= 64)

@@ -38,17 +38,18 @@ ROOTS = (
     "CodecBatcher.encode",
     "CodecBatcher.decode",
     "CodecBatcher.rmw",
+    "CodecBatcher.digest",
     "CodecBatcher._submit",
     "CodecBatcher._run_batch",
     "MeshCodec.encode",
     "MeshCodec.decode",
     "MeshCodec.rmw",
+    "MeshCodec.digest",
     "StripeInfo.encode_async",
     "StripeInfo.decode_async",
     "StripeInfo.reconstruct_logical_async",
     "ECBackend._fetch_shards",
     "ECBackend._gather_shards",
-    "ECBackend.collect_shard_states",
     # the recovery repair path (runs per rebuilt shard: fragment
     # pulls + full gathers) and the flat codec launch entry points --
     # the osd_ec_repair_fragments_enabled gate is snapshot at

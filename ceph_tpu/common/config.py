@@ -75,8 +75,10 @@ DEFAULT_SCHEMA: list[Option] = [
     Option("osd_recovery_max_active", OPT_INT, 3,
            "max concurrent recovery ops per OSD", min=1),
     Option("osd_client_op_priority", OPT_INT, 63, "client op priority"),
-    Option("osd_scrub_interval", OPT_FLOAT, 60.0,
-           "seconds between periodic scrubs", min=0.0),
+    Option("osd_scrub_interval", OPT_FLOAT, 0.0,
+           "seconds after a PG's last scrub until its primary "
+           "schedules the next; 0 = scheduled scrubs off unless set",
+           min=0.0),
     Option("mon_osd_min_down_reporters", OPT_INT, 2,
            "distinct reporters before marking an osd down", min=1),
     Option("mon_osd_down_out_interval", OPT_FLOAT, 600.0,
@@ -142,6 +144,9 @@ DEFAULT_SCHEMA: list[Option] = [
     Option("osd_op_complaint_time", OPT_FLOAT, 30.0,
            "seconds in flight before an op is complained about",
            min=0.1),
+    Option("osd_scrub_chunk_max", OPT_INT, 25,
+           "most object names a scrub compares at a time; writes to "
+           "names inside the chunk's range wait for it", min=1),
     Option("osd_scrub_auto_repair", OPT_BOOL, True,
            "repair scrub-detected inconsistencies automatically"),
     Option("osd_ec_batch_enabled", OPT_BOOL, True,

@@ -30,20 +30,25 @@ class AsyncReserver:
             fut.set_result(True)
 
     async def request(self, item, prio: int = 0,
-                      timeout: float | None = None) -> None:
-        """Wait for a slot.  Re-requesting a granted item is a no-op."""
+                      timeout: float | None = None,
+                      lease: float | None = None) -> None:
+        """Wait for a slot, first come first served within a priority.
+        Re-requesting a granted item is a no-op.  ``lease`` bounds the
+        grant's lifetime as in ``get_or_fail``."""
         self._purge_leases()    # a crashed remote holder's expired
-        if item in self.granted:  # lease must not starve local waiters
-            return
-        fut = asyncio.get_event_loop().create_future()
-        heapq.heappush(self._queue, (-prio, self._seq, item, fut))
-        self._seq += 1
-        self._do_grants()
-        try:
-            await asyncio.wait_for(fut, timeout)
-        except asyncio.TimeoutError:
-            self.cancel(item)
-            raise
+        if item not in self.granted:  # lease must not starve waiters
+            fut = asyncio.get_event_loop().create_future()
+            heapq.heappush(self._queue, (-prio, self._seq, item, fut))
+            self._seq += 1
+            self._do_grants()
+            try:
+                await asyncio.wait_for(fut, timeout)
+            except asyncio.TimeoutError:
+                self.cancel(item)
+                raise
+        if lease is not None:
+            import time
+            self._leases[item] = time.monotonic() + lease
 
     def get_or_fail(self, item, lease: float | None = None) -> bool:
         """Immediate grant or False -- never queues (the remote-

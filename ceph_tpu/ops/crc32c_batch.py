@@ -565,36 +565,63 @@ def crc32c_device_chunks(x):
     return out
 
 
-def crc32c_resident(buf) -> int:
-    """Whole-buffer CRC32C of a RESIDENT shard buffer as ONE device
-    launch: the buffer splits into equal power-of-two chunks whose CRCs
-    come back from the device kernel, the GF(2) fold combines them,
-    and the inverse matrix strips the zero padding -- no host-side
-    pass over the payload bytes.  This is how scrub re-verifies a
-    cache-resident shard against its write-time tag without ever
-    re-materializing it through the store."""
-    # lint: disable=device-path-host-sync -- input view of an already-resident buffer, not a transfer
-    arr = np.ascontiguousarray(
-        np.frombuffer(buf, np.uint8) if isinstance(
-            buf, (bytes, bytearray, memoryview))
-        else np.asarray(buf, np.uint8).reshape(-1))
-    n = arr.size
-    if n == 0:
-        return SEED
-    # up to 256 chunks: the device kernel is parallel over every byte
-    # of every chunk, so the chunk count does not set its speed; it
-    # bounds the host fold below, a linear scan of the chunk registers
-    chunk = max(64, _next_pow2(-(-n // 256)))
-    pad = (-n) % chunk
-    if pad:
-        padded = np.zeros(n + pad, np.uint8)
-        padded[:n] = arr
-        arr = padded
-    rows = arr.reshape(-1, chunk)
-    # lint: disable=device-path-host-sync -- the single post-launch materialization of the chunk CRCs
-    crcs = np.asarray(crc32c_device_chunks(rows), np.uint32)
-    out = np.asarray(fold_chunk_crcs(crcs, chunk), np.uint32).reshape(1)
-    if pad:
-        out = crc32c_strip_zeros(out, pad)
-    PERF.inc("resident_crcs")
-    return int(out[0])
+# The digest program's name.  A trace finds a deep scrub's launches
+# under it (``jit_crc32c_shards``), apart from the ``crc32c_chunks``
+# inlined in the encode that made the parity; its operations stay
+# under the ``crc32c`` scope.
+DIGEST_PROGRAM = "crc32c_shards"
+# Shortest lane a digest row is padded to: lanes are powers of two, so
+# a launch's shape is one of a few whatever lengths a PG's shards have.
+DIGEST_MIN_LANE = 4096
+
+
+def digest_lane(n: int) -> int:
+    """The power-of-two lane a digest launch gives a buffer of ``n``
+    bytes."""
+    return max(DIGEST_MIN_LANE, _next_pow2(n))
+
+
+def digest_rows(bufs, lane: int | None = None) -> np.ndarray:
+    """Whole buffers as the rows of one digest launch: (len(bufs),
+    lane) uint8, each buffer at the END of its row.  Zero bytes in
+    front of a message leave its zero-seed register as it is, so
+    ragged buffers share a launch and ``digest_finish`` needs only
+    their lengths."""
+    views = [np.frombuffer(b, np.uint8) if isinstance(
+        b, (bytes, bytearray, memoryview))
+        else np.asarray(b, np.uint8).reshape(-1) for b in bufs]
+    if lane is None:
+        lane = digest_lane(max((v.size for v in views), default=0))
+    rows = np.zeros((len(views), lane), np.uint8)
+    for row, v in zip(rows, views):
+        if v.size:
+            row[lane - v.size:] = v
+    return rows
+
+
+def crc32c_shards_traced(x):
+    """(B, lane) uint8 rows -> (B,) uint32 ZERO-SEED registers, the
+    body of the digest program: the chunk kernel over whole rows with
+    its seed constant taken out again, so a row's front padding costs
+    nothing and the true length enters on the host
+    (``digest_finish``)."""
+    import jax
+    import jax.numpy as jnp
+    lane = x.shape[-1]
+    with jax.named_scope("crc32c"):
+        return _crc_chunks_compiled(lane)(x) ^ jnp.uint32(
+            crc32c_zeros(SEED, lane))
+
+
+def digest_finish(regs, lengths) -> np.ndarray:
+    """Zero-seed registers of front-padded rows -> the buffers'
+    CRC32C (default seed): the seed advanced over each buffer's own
+    length, XORed in."""
+    # lint: disable=device-path-host-sync -- (n,) uint32 registers the batcher has already materialized, not batch payload
+    regs = np.asarray(regs, np.uint32).reshape(-1)
+    # lint: disable=device-path-host-sync -- (n,) length vector for the seed fold, not batch payload
+    lengths = np.asarray(lengths, np.int64).reshape(-1)
+    out = regs.copy()
+    for n in np.unique(lengths):
+        out[lengths == n] ^= np.uint32(crc32c_zeros(SEED, int(n)))
+    return out

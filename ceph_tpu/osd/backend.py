@@ -1056,65 +1056,6 @@ class ECBackend(PGBackend):
             return b""
         return self.sinfo.interleave_logical(self.codec, shards)[:size]
 
-    async def collect_shard_states(self, oid: str
-                                   ) -> tuple[list[tuple], int]:
-        """Every up acting shard's stored state for scrub: a list of
-        (shard, buf, label, crc, ver, trusted) plus the count of up
-        acting shards.
-
-        One PARALLEL gather through the HedgedGather sub-read
-        machinery (scrub used to round-trip each shard serially, so a
-        deep scrub of a wide stripe paid k+m sequential RTTs); every
-        reply feeds the same per-peer latency EWMA the hedge timer
-        draws from.  No hedging applies -- scrub wants EVERY stored
-        shard, not a sufficient subset -- but a straggler is bounded
-        by the read deadline instead of stalling the whole scrub: a
-        missing shard simply falls out to the reconstruct path."""
-        pg = self.pg
-        stored: list[tuple] = []
-        remote: dict[int, int] = {}
-        n_acting = 0
-        for shard, osd_id in enumerate(pg.acting):
-            if osd_id < 0 or not self.osd.osd_is_up(osd_id):
-                continue
-            n_acting += 1
-            if osd_id == self.osd.whoami:
-                buf, _, over, label, crc, cached = \
-                    self._local_entry(oid)
-                stored.append((shard, buf, label, crc, tuple(over),
-                               cached))
-            else:
-                remote[shard] = osd_id
-        if remote:
-            payload = {"pgid": pg.pgid, "oid": oid}
-            collected: dict[int, object] = {}
-            if self.hedger is not None:
-                def on_reply(s, msg):
-                    if msg is not None:
-                        collected[s] = msg
-                await self.hedger.gather_shards(
-                    {s: (o, "ec_subop_read",
-                         {**payload, "shard": s})
-                     for s, o in remote.items()},
-                    on_reply=on_reply, timeout=self._read_timeout)
-            else:
-                replies = await self.osd.fanout_and_wait(
-                    [(o, "ec_subop_read", {**payload, "shard": s}, [])
-                     for s, o in remote.items()],
-                    collect=True, timeout=self._read_timeout)
-                for rep in replies:
-                    s = rep.data.get("req_shard", rep.data.get("shard"))
-                    if s in remote:
-                        collected[s] = rep
-            for s, rep in sorted(collected.items()):
-                raw = rep.segments[0] if rep.segments else b""
-                stored.append((s, raw, rep.data.get("shard"),
-                               rep.data.get("crc"),
-                               tuple(rep.data.get("ver", (0, 0))),
-                               False))
-        stored.sort(key=lambda e: e[0])
-        return stored, n_acting
-
     # -- write path ---------------------------------------------------------
     async def submit_transaction(self, entry, muts, old_size=None) -> None:
         """New logical content -> k+m shard writes.

@@ -62,9 +62,10 @@ where a launch's time goes: ``queue_wait_us`` (a group's first
 submission to its dispatch; also by kind, ``<kind>_queue_wait_us``, as
 ``stripes`` is by ``<kind>_stripes`` and ``batches`` by
 ``<kind>_launches``: a window that mixes clients' encodes with a
-repair's decodes reads each kind's own), ``overlap_us`` (dispatch return to
-completion entry: the launch is in flight while the loop does other
-work) and ``materialize_us`` (the ``np.asarray`` that blocks the
+repair's decodes or a scrub's digests reads each kind's own; a
+digest's stripes are its rows, one whole shard each), ``overlap_us``
+(dispatch return to completion entry: the launch is in flight while
+the loop does other work) and ``materialize_us`` (the ``np.asarray`` that blocks the
 loop's thread until the device is done).
 Pipeline occupancy (staged batches, overlap windows, staging-full
 stalls) lands in the OSD-wide "ec_pipeline" set.
@@ -102,8 +103,8 @@ class _Group:
 
     def __init__(self, codec, kind: str, extra: tuple) -> None:
         self.codec = codec
-        self.kind = kind                 # "encode" | "decode"
-        self.extra = extra               # decode: erasure tuple
+        self.kind = kind     # "encode" | "decode" | "rmw" | "digest"
+        self.extra = extra   # decode: erasure tuple; digest: (lane,)
         self.items: list[tuple[np.ndarray, asyncio.Future, bool]] = []
         self.n_stripes = 0
         self.task: asyncio.Task | None = None
@@ -143,10 +144,10 @@ class CodecBatcher:
     codec.decode.
 
     ``engine`` launches the batches: ``supports(codec)``,
-    ``pad_batch(total)`` and ``encode`` / ``decode`` / ``rmw`` with
-    MeshCodec's signatures.  Left out, it is a MeshCodec over every
-    visible device, built on first use (a replicated-only OSD never
-    pays the jax import).
+    ``pad_batch(total)`` and ``encode`` / ``decode`` / ``rmw`` /
+    ``digest`` with MeshCodec's signatures.  Left out, it is a
+    MeshCodec over every visible device, built on first use (a
+    replicated-only OSD never pays the jax import).
     """
 
     def __init__(self, *, max_batch: int = 64,
@@ -246,6 +247,22 @@ class CodecBatcher:
         return await self._submit("rmw", codec, delta, (),
                                   old=old_parity)
 
+    async def digest(self, rows: np.ndarray, lengths) -> np.ndarray:
+        """CRC32C (default seed) of whole buffers, all of them in ONE
+        launch of the engine's digest program: a deep scrub's resident
+        shards (osd/scrub.py).  ``rows`` is (n, lane) as
+        ``ops/crc32c_batch.digest_rows`` lays buffers out (each at the
+        end of a power-of-two row), ``lengths`` the buffers' own
+        lengths, folded in here on the host; -> (n,) uint32.  A kind
+        of its own beside encode, decode and rmw: ``digest_launches``,
+        ``digest_stripes`` (rows) and ``digest_queue_wait_us`` count
+        it, the staged driver and the ``device_wait`` section carry
+        it, and concurrent submissions of one lane share a launch."""
+        from ..ops.crc32c_batch import digest_finish
+        regs = await self._submit("digest", None, rows[:, None, :],
+                                  (rows.shape[1],))
+        return digest_finish(regs, lengths)
+
     def note_fallback(self) -> None:
         """A caller took the per-op path for a non-batch codec."""
         if self.perf is not None:
@@ -262,7 +279,8 @@ class CodecBatcher:
                       extra: tuple, want_crc: bool = False, old=None):
         arr = np.ascontiguousarray(arr, dtype=np.uint8)
         assert arr.ndim == 3, arr.shape
-        key = codec_signature(codec, kind, extra)
+        key = (kind, extra) if codec is None \
+            else codec_signature(codec, kind, extra)
         grp = self._groups.get(key)
         if grp is None:
             grp = self._groups[key] = _Group(codec, kind, extra)
@@ -476,7 +494,10 @@ class CodecBatcher:
         (``_fail``): it is never retried another way."""
         grp, engine = st.grp, self.engine
         crcs = None
-        if grp.kind == "rmw":
+        if grp.kind == "digest":
+            out = engine.digest(st.batch.reshape(st.b, st.lane),
+                                out_np=False)
+        elif grp.kind == "rmw":
             out = engine.rmw(grp.codec, st.old_batch, st.batch,
                              out_np=False)
         elif grp.kind == "decode":
@@ -516,7 +537,8 @@ class CodecBatcher:
         for a, fut, w, _ in items:
             n, _, l = a.shape
             if not fut.done():
-                res = out[row:row + n, :, :l]
+                res = out[row:row + n] if grp.kind == "digest" \
+                    else out[row:row + n, :, :l]
                 if w:
                     item_crcs = crcs[row:row + n]
                     if l < lane:

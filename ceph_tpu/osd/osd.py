@@ -16,12 +16,17 @@ import uuid as uuid_mod
 
 from ..common import AdminSocket, ConfigProxy, PerfCountersCollection, \
     make_task_tracker
+from ..common import tracing
 from ..common.tracing import LOOP_PERF, dump_loop, get_tracer, section
 from ..mon.osdmap import OSDMap, Incremental
 from ..msg import Message, Messenger
 from ..os.store import MemStore, make_default_store
 from .pg import PG, WRITE_OPS
 from .scheduler import MClockScheduler, OpClass
+from .scrub import ScrubAborted
+
+# longest a scrub waits for one acting member's slot, its own included
+SCRUB_RESERVE_WAIT = 30.0
 
 
 class OSD:
@@ -111,7 +116,13 @@ class OSD:
         self.scrub_reserver = AsyncReserver(
             int(self.config.get("osd_max_scrubs", 1)))
         self._scrub_stamps: dict[str, float] = {}
+        # when a scrub of the PG last held all its slots and began
+        self._scrub_tried: dict[str, float] = {}
         self._scrubbing: set[str] = set()
+        # the last deep scrub of each PG this OSD led, scheduled or
+        # asked for (ScrubResult.to_dict): what it found and repaired;
+        # the admin socket's ``list_inconsistent_obj`` returns it
+        self.scrub_results: dict[str, dict] = {}
         self._sched_event = asyncio.Event()
         self._tid = itertools.count(1)
         self._waiters: dict[int, asyncio.Future] = {}
@@ -123,6 +134,16 @@ class OSD:
         # observability (src/common/perf_counters + TrackedOp analog)
         self.perf = PerfCountersCollection()
         self.perf_osd = self.perf.create("osd")
+        # deep scrub (osd/scrub.py): chunks and objects compared,
+        # bytes digested by route, bytes of scrub maps received,
+        # errors and repairs, writes that waited for a chunk,
+        # reservations a busy peer rejected
+        self.perf_scrub = self.perf.create("scrub")
+        for key in ("chunks", "objects", "bytes_digested_host",
+                    "bytes_digested_device", "map_bytes", "errors_found",
+                    "shards_repaired", "writes_blocked",
+                    "reserve_rejects"):
+            self.perf_scrub.inc(key, 0)       # visible even when idle
         # dmClock admission with its own perf set: per-class queue
         # depth gauges + dispatch counters, so a load harness can
         # report client-vs-recovery QoS behavior instead of inferring
@@ -302,8 +323,7 @@ class OSD:
             pgid = req.get("pgid")
             if not pgid or pgid not in self.pgs:
                 return {"err": f"no such pg {pgid!r}"}
-            pg = self.pgs[pgid]
-            if not pg.is_primary():
+            if not self.pgs[pgid].is_primary():
                 return {"err": f"osd.{self.whoami} is not primary "
                                f"for {pgid}"}
             if pgid in self._scrubbing:
@@ -312,24 +332,25 @@ class OSD:
             # ones -- osd_max_scrubs must bound BOTH
             self._scrubbing.add(pgid)
             try:
-                await self.scrub_reserver.request(pgid, timeout=30)
-                # the slot wait suspended: the PG may have been
-                # replaced or re-targeted by an epoch change -- scrub
-                # the current object, not the pre-wait snapshot
-                pg = self.pgs.get(pgid)
-                if pg is None or not pg.is_primary():
-                    return {"err": f"pg {pgid} moved while waiting "
-                                   f"for a scrub slot"}
-                from .scrub import scrub_pg
-                res = await scrub_pg(pg,
-                                     repair=bool(req.get("repair")))
-                self._scrub_stamps[pgid] = time.monotonic()
-                return res.to_dict()
+                res = await self._scrub_reserved(
+                    pgid, repair=bool(req.get("repair")), remote=False)
             except asyncio.TimeoutError:
                 return {"err": "scrub slots busy; try again"}
+            except (ConnectionError, OSError, ScrubAborted) as e:
+                return {"err": f"scrub of {pgid} did not finish: {e}"}
             finally:
-                self.scrub_reserver.release(pgid)
                 self._scrubbing.discard(pgid)
+            if res is None:
+                return {"err": f"scrub slots busy, or pg {pgid} moved "
+                               f"while waiting for one; try again"}
+            return res.to_dict()
+
+        async def list_inconsistent(req):
+            pgid = (req or {}).get("pgid")
+            if pgid is None:
+                return dict(self.scrub_results)
+            return self.scrub_results.get(
+                pgid, {"err": f"no scrub of {pgid!r} kept here"})
 
         async def status(req):
             return {"whoami": self.whoami, "epoch": self.osdmap.epoch,
@@ -381,6 +402,9 @@ class OSD:
                       loop_phases)
         sock.register("config show", "all config values", config_show)
         sock.register("scrub", "scrub a pg: {pgid, repair}", scrub_cmd)
+        sock.register("list_inconsistent_obj",
+                      "the last deep scrub a pg's primary kept: {pgid}",
+                      list_inconsistent)
         sock.register("config get", "describe one option", config_get)
         sock.register("config set", "set option (name=..., value=...)",
                       config_set)
@@ -833,6 +857,17 @@ class OSD:
             except (ConnectionError, OSError) as e:
                 on_error(e)
         return futs
+
+    def drop_staged(self, futs) -> None:
+        """Give up the (tid, future) reply waiters of a staged fan-out
+        nobody will await (the stager was cancelled or failed between
+        staging and waiting)."""
+        for tid, fut in futs:
+            self._waiters.pop(tid, None)
+            if fut.done() and not fut.cancelled():
+                fut.exception()         # consumed: nobody reports it
+            else:
+                fut.cancel()
 
     async def await_staged(self, futs, collect: bool = False,
                            timeout: float = 10):
@@ -1374,6 +1409,10 @@ class OSD:
         interval = float(self.config.get("osd_scrub_interval", 0))
         if interval <= 0:       # scheduling off unless configured
             return
+        # one attempt a slot in flight: an attempt waits its turn at the
+        # acting members and is not given up at the next tick
+        if len(self._scrubbing) >= self.scrub_reserver.max_allowed:
+            return
         import random
         due = []
         for pgid, pg in self.pgs.items():
@@ -1387,10 +1426,16 @@ class OSD:
             due.append(pgid)
         if not due:
             return
-        # ONE scrub kick per tick, randomly chosen: launching every due
-        # PG at once makes all primaries collide on the replicas'
-        # single scrub slots in lockstep, tick after tick
-        pgid = random.choice(due)
+        # ONE scrub kick per tick: launching every due PG at once makes
+        # all primaries collide on the replicas' single scrub slots,
+        # tick after tick.  The PG whose last scrub began longest ago
+        # (finished or aborted; never comes first), a random one among
+        # equals: every PG an OSD leads comes round in as few turns as
+        # the OSD gets, and one that keeps aborting does not hold the
+        # others back
+        oldest = min(self._scrub_tried.get(p, 0.0) for p in due)
+        pgid = random.choice([p for p in due if self._scrub_tried.get(
+            p, 0.0) == oldest])
         self._scrubbing.add(pgid)
         self._track(asyncio.ensure_future(
             self._run_scheduled_scrub(pgid)))
@@ -1399,80 +1444,204 @@ class OSD:
         """One reserved scrub: local slot + a slot on every acting
         replica, then the scrub itself (repair on by default, the
         osd_scrub_auto_repair discipline)."""
-        pg = self.pgs.get(pgid)
-        granted_remote: list[int] = []
-        got_local = False
         try:
-            if pg is None or not pg.is_primary():
-                return
-            await self.scrub_reserver.request(pgid, timeout=30)
-            got_local = True
-            # the slot wait suspended: re-read the PG, an epoch
-            # change may have replaced or deposed it meanwhile
-            pg = self.pgs.get(pgid)
-            if pg is None or not pg.is_primary():
-                return
-            peers = [o for o in pg.acting_peers() if self.osd_is_up(o)]
-            for o in peers:
-                replies = await self.fanout_and_wait(
-                    [(o, "scrub_reserve", {"pgid": pgid}, [])],
-                    collect=True, timeout=10)
-                if not replies or not replies[0].data.get("granted"):
-                    return          # replica busy; retried next tick
-                granted_remote.append(o)
-            from .scrub import scrub_pg
-            # the replica handshakes suspended too
-            pg = self.pgs.get(pgid)
-            if pg is None or not pg.is_primary():
-                return
-            res = await scrub_pg(pg, repair=bool(
+            res = await self._scrub_reserved(pgid, repair=bool(
                 self.config.get("osd_scrub_auto_repair", True)))
-            self._scrub_stamps[pgid] = time.monotonic()
-            self.perf_osd.inc("scrubs")
-            if not res.clean:
-                self.perf_osd.inc("scrub_repairs", len(res.repaired))
-        except (ConnectionError, OSError, asyncio.TimeoutError):
+            if res is not None:
+                self.perf_osd.inc("scrubs")
+                if not res.clean:
+                    self.perf_osd.inc("scrub_repairs",
+                                      len(res.repaired))
+        except (ConnectionError, OSError, asyncio.TimeoutError,
+                ScrubAborted):
             pass                    # retried next tick
         finally:
-            if got_local:
-                self.scrub_reserver.release(pgid)
-            for o in granted_remote:
-                try:
-                    await self.fanout_and_wait(
-                        [(o, "scrub_release", {"pgid": pgid}, [])],
-                        collect=True, timeout=5)
-                except (ConnectionError, OSError,
-                        asyncio.TimeoutError):
-                    pass
             self._scrubbing.discard(pgid)
 
+    async def _scrub_reserved(self, pgid: str, repair: bool,
+                              remote: bool = True):
+        """Take the scrub slots, scrub, give them back.  Returns the
+        ScrubResult, kept in ``scrub_results``, or None where the PG
+        moved or a replica's slot was busy.  Span ``pg.scrub`` (a root;
+        tags ``pgid``, then ``chunks``, ``objects``, ``errors``) with
+        ``scrub.reserve`` (the local slot and, with ``remote``, one on
+        every up acting member) and the ``scrub.chunk`` spans of
+        ``scrub_pg`` under it."""
+        from .scrub import scrub_pg
+        members: list[int] = []
+        root = get_tracer(f"osd.{self.whoami}").root(
+            "pg.scrub", pgid=pgid).activate()
+        try:
+            span = tracing.child_span("scrub.reserve")
+            try:
+                pg = self.pgs.get(pgid)
+                if pg is None or not pg.is_primary():
+                    return None
+                members = sorted([self.whoami] + [
+                    o for o in pg.acting_peers()
+                    if remote and self.osd_is_up(o)])
+                if not await self._scrub_slots(pgid, members):
+                    self.perf_scrub.inc("reserve_rejects")
+                    return None         # retried at a later tick
+            finally:
+                tracing.finish(span)
+            # the slot waits suspended: re-read the PG, an epoch change
+            # may have replaced or deposed it meanwhile
+            pg = self.pgs.get(pgid)
+            if pg is None or not pg.is_primary():
+                return None
+            self._scrub_tried[pgid] = time.monotonic()
+            res = await scrub_pg(pg, repair=repair)
+            self._scrub_stamps[pgid] = time.monotonic()
+            self.scrub_results[pgid] = res.to_dict()
+            root.tags.update(chunks=res.chunks,
+                             objects=res.objects_scrubbed,
+                             errors=len(res.errors))
+            return res
+        finally:
+            root.finish()
+            await self._scrub_give_back(pgid, members)
+
+    async def _scrub_slots(self, pgid: str, members: list[int]) -> bool:
+        """A scrub slot on every OSD of ``members`` (ascending ids,
+        this OSD's own among them), or False.
+
+        Held while waiting is only ever a PREFIX of ``members``: all
+        that are left are asked at once for a slot if one is free (one
+        round trip when nobody contends); what was granted beyond the
+        first busy member is given back, and that member's slot is
+        waited for in its queue, first come, first served, for at most
+        SCRUB_RESERVE_WAIT; then the rest are asked again.  Everybody
+        waits only for a higher OSD than any it holds, so no two scrubs
+        wait for each other, and primaries that contend (with PGs as
+        wide as the cluster every two scrubs do) queue at the lowest
+        OSD they share and take turns.  (Own slot first, then each
+        peer asked once, busy or not: primaries that tick together
+        refuse each other tick after tick, and the one that ticks
+        after a scrub's end starves the rest.)"""
+        held = 0
+        while held < len(members):
+            rest = members[held:]
+            got = await self._scrub_ask(pgid, rest, wait=False)
+            n = got.index(False) if False in got else len(rest)
+            await self._scrub_give_back(
+                pgid, [o for o, g in zip(rest[n + 1:], got[n + 1:]) if g])
+            held += n
+            if n < len(rest):
+                if not (await self._scrub_ask(pgid, [rest[n]],
+                                              wait=True))[0]:
+                    return False
+                held += 1
+        return True
+
+    async def _scrub_ask(self, pgid: str, osds: list[int],
+                         wait: bool) -> list[bool]:
+        """Ask each of ``osds`` for a scrub slot, all at once: one that
+        is free now, or with ``wait`` the next to come free."""
+        granted = dict.fromkeys(osds, False)
+        try:
+            replies = await self.fanout_and_wait(
+                [(o, "scrub_reserve", {"pgid": pgid, "wait": wait}, [])
+                 for o in osds if o != self.whoami], collect=True,
+                timeout=SCRUB_RESERVE_WAIT + 5 if wait else 10)
+            for rep in replies:
+                granted[rep.data["from_osd"]] = bool(
+                    rep.data.get("granted"))
+            if self.whoami in granted:
+                if wait:
+                    await self.scrub_reserver.request(
+                        pgid, timeout=SCRUB_RESERVE_WAIT)
+                granted[self.whoami] = wait \
+                    or self.scrub_reserver.get_or_fail(pgid)
+        except asyncio.TimeoutError:
+            pass            # whoever did not answer did not grant
+        return [granted[o] for o in osds]
+
+    async def _scrub_give_back(self, pgid: str, osds: list[int]) -> None:
+        """Release the slots held on ``osds`` and take back what is
+        still asked of them; harmless where neither is."""
+        if self.whoami in osds:
+            self.scrub_reserver.cancel(pgid)
+        peers = [o for o in osds if o != self.whoami]
+        if peers:
+            try:
+                await self.fanout_and_wait(
+                    [(o, "scrub_release", {"pgid": pgid}, [])
+                     for o in peers], collect=True, timeout=5)
+            except (ConnectionError, OSError, asyncio.TimeoutError):
+                pass                # the peer's lease runs out
+
+    def scrubs_running(self) -> list[str]:
+        """PGs this OSD leads that have a scrub chunk open right now."""
+        return [pgid for pgid, pg in self.pgs.items()
+                if pg._scrub_range is not None]
+
     async def _h_pg_scrub_map_req(self, conn, msg) -> None:
-        """Replica side of a scrub round: digest every local object
-        (scrub_backend.cc building the replica scrub map)."""
-        from .scrub import build_scrub_map
+        """An acting member's side of a scrub chunk: digest the local
+        copies of the objects in the asked range (the whole PG where
+        none is given) and answer with the map alone, one JSON
+        segment: for an erasure PG each shard object's length,
+        version, label, ``_crc`` and recomputed CRC32C
+        (``build_shard_map``, resident shards through the batcher's
+        digest launch), for a replicated one its digests
+        (``build_scrub_map``).  The primary has drained the range's
+        writes and holds new ones back, so no lock is taken here."""
+        import json
+        from .backend import ECBackend
+        from .scrub import build_scrub_map, build_shard_map
         pg = self._get_pg(msg.data["pgid"])
-        smap = await build_scrub_map(self.store, pg.coll) if pg else {}
-        await conn.send(Message("pg_scrub_map", {
-            "pgid": msg.data["pgid"], "map": smap,
-            "from_osd": self.whoami, "tid": msg.data.get("tid")}))
+        begin, end = msg.data.get("begin", ""), msg.data.get("end")
+        if pg is None:
+            smap = None
+        elif isinstance(pg.backend, ECBackend):
+            smap = await build_shard_map(
+                self.store, pg.coll, begin, end,
+                batcher=self.codec_batcher, perf=self.perf_scrub)
+        else:
+            smap = await build_scrub_map(self.store, pg.coll,
+                                         begin=begin, end=end)
+        data = {"pgid": msg.data["pgid"], "from_osd": self.whoami,
+                "tid": msg.data.get("tid")}
+        if smap is None:
+            data["err"] = "ENOENT"
+        await conn.send(Message(
+            "pg_scrub_map", data,
+            segments=[] if smap is None
+            else [json.dumps(smap, separators=(",", ":")).encode()]))
 
     async def _h_pg_scrub_map(self, conn, msg) -> None:
         self._resolve_tid(msg)
 
     async def _h_scrub_reserve(self, conn, msg) -> None:
         """Remote scrub slot (the scrubber's replica reservations --
-        a replica scrubs for at most osd_max_scrubs PGs at once)."""
-        granted = self.scrub_reserver.get_or_fail(
-            msg.data["pgid"], lease=120.0)
+        a replica scrubs for at most osd_max_scrubs PGs at once): one
+        that is free now or none, or with ``wait`` a place in this
+        OSD's queue.  A primary that gives up meanwhile sends
+        ``scrub_release``, which takes the request out of the queue
+        and ends this handler."""
+        pgid = msg.data["pgid"]
+        if not msg.data.get("wait"):
+            granted = self.scrub_reserver.get_or_fail(pgid, lease=120.0)
+        else:
+            try:
+                await self.scrub_reserver.request(
+                    pgid, timeout=SCRUB_RESERVE_WAIT, lease=120.0)
+                granted = True
+            except asyncio.TimeoutError:
+                granted = False
+            except asyncio.CancelledError:
+                if asyncio.current_task().cancelling():
+                    raise           # this task, not the queued request
+                return
         await conn.send(Message("scrub_reserve_reply", {
-            "pgid": msg.data["pgid"], "granted": granted,
+            "pgid": pgid, "granted": granted,
             "from_osd": self.whoami, "tid": msg.data.get("tid")}))
 
     async def _h_scrub_reserve_reply(self, conn, msg) -> None:
         self._resolve_tid(msg)
 
     async def _h_scrub_release(self, conn, msg) -> None:
-        self.scrub_reserver.release(msg.data["pgid"])
+        self.scrub_reserver.cancel(msg.data["pgid"])
         await conn.send(Message("scrub_release_ack", {
             "pgid": msg.data["pgid"], "from_osd": self.whoami,
             "tid": msg.data.get("tid")}))

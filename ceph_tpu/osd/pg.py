@@ -89,6 +89,12 @@ class PG:
         # PG lock; ordering per (PG, object) is preserved by chaining
         # commits per oid and gating the next op on the chain head.
         self._obj_commits: dict[str, asyncio.Task] = {}
+        # the chunk a scrub is comparing (osd/scrub.py): its name range
+        # (begin exclusive, end inclusive or None for the collection's
+        # end), the event that opens it again, the writes that waited
+        self._scrub_range: tuple[str, str | None] | None = None
+        self._scrub_gate: asyncio.Event | None = None
+        self._scrub_blocked = 0
         self._recovery_task: asyncio.Task | None = None
         self._peering_task: asyncio.Task | None = None
         self._completed_reqids: dict[tuple[str, int], EVersion] = {}
@@ -750,6 +756,10 @@ class PG:
             # still in flight (the pipelined spine overlaps commits
             # ACROSS objects, never within one)
             await self._yield_to_commits(oid)
+            if self._scrub_range is not None \
+                    and any(op["op"] not in READ_OPS for op in ops):
+                await self._yield_to_scrub(oid)
+                await self._yield_to_commits(oid)
             if self.state != "active" or not self.is_primary():
                 return ({"err": "ENOTPRIMARY", "state": self.state}, [])
             if reqid is not None and reqid in self._completed_reqids:
@@ -983,6 +993,66 @@ class PG:
                    if not t.done()]
         if pending:
             await asyncio.wait(pending)
+
+    # -- scrub chunks (osd/scrub.py) -----------------------------------------
+    def scrub_blocks(self, oid: str) -> bool:
+        """``oid`` lies in the range of the chunk being scrubbed."""
+        rng = self._scrub_range
+        return rng is not None and oid > rng[0] \
+            and (rng[1] is None or oid <= rng[1])
+
+    async def _yield_to_scrub(self, oid: str) -> None:
+        """A write to a name inside the scrubbing chunk's range waits
+        until the chunk is compared.  Entered and left with the PG's
+        lock HELD and released around the wait, as
+        ``_yield_to_commits``: writes to every other name go on."""
+        counted = False
+        while self.scrub_blocks(oid):
+            if not counted:
+                counted = True
+                self._scrub_blocked += 1
+                perf = getattr(self.osd, "perf_scrub", None)
+                if perf is not None:
+                    perf.inc("writes_blocked")
+            gate = self._scrub_gate
+            self.lock.release()
+            try:
+                await gate.wait()
+            finally:
+                await self.lock.acquire()
+
+    async def scrub_chunk_begin(self, begin: str, limit: int
+                                ) -> tuple[list[str], str | None]:
+        """Open the next chunk of a scrub: list up to ``limit`` names
+        after ``begin``, mark their range (``begin`` exclusive to the
+        chunk's last name; to the end of the collection for the last
+        chunk) and wait until the writes in flight inside it have
+        committed on every shard.  The lock is held for the listing
+        and the mark alone: a writer holds it for its whole submit, so
+        a write to the range either has its commit chained here
+        already or will find the mark.  Returns the names and the
+        range's end."""
+        from .scrub import next_chunk
+        async with self.lock:
+            with tracing.section("scrub.list"):
+                names, end = next_chunk(self.osd.store, self.coll,
+                                        begin, limit)
+            self._scrub_range = (begin, end)
+            self._scrub_gate = asyncio.Event()
+            self._scrub_blocked = 0
+        pending = [t for oid, t in self._obj_commits.items()
+                   if not t.done() and self.scrub_blocks(oid)]
+        if pending:
+            await asyncio.wait(pending)
+        return names, end
+
+    def scrub_chunk_end(self) -> int:
+        """Close the chunk: its range is open to writes again.
+        Returns how many writes waited for it."""
+        self._scrub_range = None
+        if self._scrub_gate is not None:
+            self._scrub_gate.set()
+        return self._scrub_blocked
 
     # -- pending-write overlay (in-order read-after-write) -------------------
     async def _make_overlay(self, oid: str) -> dict:

@@ -38,7 +38,9 @@ Shape of the thing:
     real 8-way SPMD program on CPU (tests/test_mesh_codec.py).
 
 There is one program family: the dense bit-matmul with the coefficient
-matrix as an operand.  What the tests byte-check on the CPU is the
+matrix as an operand (encode, decode, rmw), and beside it the digest
+program a deep scrub launches: whole resident shards in, one CRC32C
+register a row out, no codec (``digest``).  What the tests byte-check on the CPU is the
 program the chip runs.  The mesh holds no config object and reads no
 environment variable.
 """
@@ -91,14 +93,14 @@ def _w_device(mesh: Mesh, mat_bytes: bytes, r: int, k: int):
 DECODE_PROGRAM = "ec_decode_rows"
 
 
-def _jit_as(name: str, fn, donate_argnums: tuple):
+def _jit_as(name: str, fn, donate_argnums: tuple, **jit_kw):
     """``jax.jit`` under a program name of its own: the profiler's
     trace and the compile cache call the program ``jit_<name>``, so
-    encode, decode and rmw launches stand apart in a trace.  The
-    stripe buffers at ``donate_argnums`` are donated -- consumed by
-    the launch, never read again."""
+    encode, decode, rmw and digest launches stand apart in a trace.
+    The stripe buffers at ``donate_argnums`` are donated -- consumed
+    by the launch, never read again."""
     fn.__name__ = fn.__qualname__ = name
-    return jax.jit(fn, donate_argnums=donate_argnums)
+    return jax.jit(fn, donate_argnums=donate_argnums, **jit_kw)
 
 
 def _host(out):
@@ -165,6 +167,22 @@ def _compiled_rmw(mesh: Mesh, b: int, m: int, k: int, lane: int):
     return _jit_as("ec_rmw", sharded, (1, 2))
 
 
+@functools.lru_cache(maxsize=64)
+def _compiled_digest(mesh: Mesh, b: int, lane: int):
+    """The digest program: (B, lane) whole-shard rows -> (B,) uint32
+    zero-seed CRC32C registers (ops/crc32c_batch.crc32c_shards_traced).
+    Rows are independent, so the batch axis shards over 'stripe' like
+    every other launch and no collective runs.  Nothing is donated: no
+    output has the rows' shape, and the caller keeps no reference."""
+    from ..ops.crc32c_batch import DIGEST_PROGRAM, crc32c_shards_traced
+    def fn(x):
+        return crc32c_shards_traced(x)
+
+    return _jit_as(DIGEST_PROGRAM, fn, (),
+                   in_shardings=NamedSharding(mesh, P("stripe", None)),
+                   out_shardings=NamedSharding(mesh, P("stripe")))
+
+
 @functools.lru_cache(maxsize=256)
 def _decode_matrix_cached(mat_bytes: bytes, rows: int, k_total: int,
                           k: int, erasures: tuple) -> np.ndarray:
@@ -179,7 +197,7 @@ def _decode_matrix_cached(mat_bytes: bytes, rows: int, k_total: int,
 
 def clear_mesh_cache() -> None:
     for fn in (_shared_mesh, _w_device, _compiled_apply, _compiled_rmw,
-               _decode_matrix_cached):
+               _compiled_digest, _decode_matrix_cached):
         fn.cache_clear()
 
 
@@ -321,6 +339,21 @@ class MeshCodec:
             matrix = _decode_matrix_cached(enc.tobytes(), *enc.shape,
                                            codec.k, erasures)
         out = self._apply(DECODE_PROGRAM, matrix, batch, False)
+        return _host(out) if out_np else out
+
+    def digest(self, rows: np.ndarray, out_np: bool = True):
+        """(B, lane) whole buffers, each at the end of its row
+        (ops/crc32c_batch.digest_rows) -> (B,) uint32 zero-seed
+        CRC32C registers in one launch of the digest program; the
+        caller folds each buffer's length in (``digest_finish``).  No
+        codec: a checksum has no coefficients."""
+        b, lane = rows.shape
+        assert b % self.n_devices == 0, (b, self.n_devices)
+        fn = _compiled_digest(self.mesh, b, lane)
+        out = fn(jax.device_put(
+            np.ascontiguousarray(rows, np.uint8),
+            NamedSharding(self.mesh, P("stripe", None))))
+        self._count(b)
         return _host(out) if out_np else out
 
     def rmw(self, codec, old_parity: np.ndarray,

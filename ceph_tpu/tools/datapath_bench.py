@@ -163,8 +163,8 @@ class _Rig:
         that produced the parity, so verifying every resident shard's
         CRC against its tag in ONE batched pass attests the parity
         relationship transitively -- zero store reads, zero re-encode
-        (the scrub_ec fast path).  Baseline: the pre-cache deep scrub
-        -- read every shard back through the store, reconstruct the
+        (the deep scrub's fast path, osd/scrub.py).  Baseline: the
+        pre-cache deep scrub -- read every shard back through the store, reconstruct the
         logical object, RE-ENCODE it, byte-compare every stored shard
         against the canonical encode."""
         if self.cached:

@@ -488,3 +488,42 @@ def test_mon_rejects_bad_stripe_unit():
         finally:
             await c.stop()
     run(main())
+
+
+def test_digest_is_a_kind_of_its_own_with_its_own_counters():
+    """A scrub's digest launch beside an encode: each kind counts its
+    launches, rows and queue wait; submissions of one lane share a
+    launch, another lane takes its own."""
+    from ceph_tpu import native
+    from ceph_tpu.common.perf import PerfCounters
+    from ceph_tpu.ec import registry
+    from ceph_tpu.ops.crc32c_batch import digest_rows
+
+    codec = registry().factory("tpu", {"k": "2", "m": "1",
+                                       "technique": "reed_sol_van"})
+    perf = PerfCounters("ec_batch")
+    batcher = CodecBatcher(perf=perf)
+    rng = np.random.default_rng(21)
+    short = [rng.bytes(n) for n in (100, 4096, 3000)]
+    long = [rng.bytes(70000)]
+
+    async def main():
+        out = await asyncio.gather(
+            batcher.encode(codec, rng.integers(0, 256, (2, 2, 256),
+                                               np.uint8), with_crc=True),
+            batcher.digest(digest_rows(short[:2]),
+                           [len(b) for b in short[:2]]),
+            batcher.digest(digest_rows(short[2:]), [len(short[2])]),
+            batcher.digest(digest_rows(long), [len(long[0])]))
+        batcher.close()
+        return out
+
+    _, a, b, c = asyncio.run(main())
+    assert [int(x) for x in list(a) + list(b) + list(c)] == [
+        native.crc32c(buf) for buf in short + long]
+    dump = perf.dump()
+    assert dump["digest_launches"] == 2 and dump["encode_launches"] == 1
+    assert dump["digest_stripes"] == 4 and dump["encode_stripes"] == 2
+    assert dump["batches"] == 3 and dump["stripes"] == 6
+    assert dump["digest_queue_wait_us"] + dump["encode_queue_wait_us"] \
+        == dump["queue_wait_us"]

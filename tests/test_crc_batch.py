@@ -264,14 +264,23 @@ def test_mesh_encode_with_crc_store_shapes_match_host_rehash(
 
 @pytest.mark.parametrize("n", (0, 1, 63, 64, 1000, 64 * 256 + 17,
                                1_000_003))
-def test_crc32c_resident_matches_scalar_on_ragged_buffers(n):
-    """Whole-buffer CRC of a resident shard: buffers that are not a
-    multiple of the chunk the launch splits them into."""
+def test_digest_launch_matches_scalar_on_ragged_buffers(n):
+    """Whole-buffer CRC of resident shards through the digest program
+    (a deep scrub's device route): buffers of any length sit at the
+    end of a power-of-two row, ragged ones share a launch, and the
+    host folds each one's own length in."""
+    from ceph_tpu.parallel.mesh_codec import MeshCodec
     rng = np.random.default_rng(15)
-    buf = rng.integers(0, 256, n, dtype=np.uint8)
-    assert cb.crc32c_resident(buf) == native.crc32c(buf.tobytes())
-    assert cb.crc32c_resident(buf.tobytes()) == native.crc32c(
-        buf.tobytes())
+    bufs = [rng.integers(0, 256, n, dtype=np.uint8),
+            rng.integers(0, 256, n // 3, dtype=np.uint8).tobytes()]
+    rows = cb.digest_rows(bufs)
+    assert rows.shape == (2, cb.digest_lane(n))
+    mesh = MeshCodec()
+    padded = np.zeros((mesh.pad_batch(2), rows.shape[1]), np.uint8)
+    padded[:2] = rows
+    got = cb.digest_finish(mesh.digest(padded)[:2], [n, n // 3])
+    assert [int(c) for c in got] == [
+        native.crc32c(bytes(b)) for b in bufs]
 
 
 def test_fused_encode_crc_byte_identity_vs_host_recompute():

@@ -490,9 +490,11 @@ def test_exhaustion_surfaces_eio_with_bounded_subreads():
     run(main())
 
 
-def test_scrub_collects_shards_in_parallel_and_stays_clean():
-    """Scrub shard collection rides the hedged sub-read machinery (one
-    parallel gather) and still verifies a healthy PG clean."""
+def test_scrub_collects_maps_not_shards_and_stays_clean():
+    """An erasure scrub is shard-local: every OSD digests its own
+    shard and only the maps travel, so a healthy PG's scrub makes no
+    sub-read at all (it used to pull every remote shard to the
+    primary through the hedged sub-read machinery)."""
     async def main():
         c = await make_hedged_cluster()
         try:
@@ -501,13 +503,13 @@ def test_scrub_collects_shards_in_parallel_and_stays_clean():
             await c.osd_op("ecpool", "sc", [
                 {"op": "write", "off": 0, "data": data}])
             pgid, primary, _ = c.target_for("ecpool", "sc")
-            pg = next(o for o in c.osds
-                      if o.whoami == primary).pgs[pgid]
+            prim = next(o for o in c.osds if o.whoami == primary)
             sub0 = _hedge_counters(c, "subreads")
-            res = await scrub_pg(pg, repair=False)
-            assert res.clean
-            assert _hedge_counters(c, "subreads") > sub0, \
-                "scrub collection did not ride the hedged sub-reads"
+            res = await scrub_pg(prim.pgs[pgid], repair=False)
+            assert res.clean and res.objects_scrubbed == 1
+            assert _hedge_counters(c, "subreads") == sub0, \
+                "a healthy PG's scrub read shards from its peers"
+            assert prim.perf_scrub.get("map_bytes") > 0
         finally:
             await c.stop()
     run(main())

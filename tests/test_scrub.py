@@ -82,16 +82,22 @@ def test_ec_scrub_reencode_check_and_repair():
             await io.write_full("obj", payload)
             pgid, prim = find_pg(osds, io.pool_id, "obj", rados)
             pg = prim.pgs[pgid]
-            # rot one SHARD; the re-encode comparison must find it
+            # rot one SHARD: its OSD's own digest disagrees with its
+            # tag (no shard travels), the shard rebuilt from the two
+            # others says it is the bytes that are wrong, and the
+            # rebuilt shard is pushed back
             shard_osd = next(o for o in osds
                              if o.whoami in pg.acting
                              and o.whoami != prim.whoami)
             corrupt(shard_osd, f"pg_{pgid}", "obj", b"\xff" * 16)
             res = await scrub_pg(pg, repair=True)
             assert not res.clean
-            assert res.inconsistent["obj"]["bad_shards"] == \
-                [pg.acting.index(shard_osd.whoami)]
+            bad = pg.acting.index(shard_osd.whoami)
+            assert res.inconsistent["obj"]["bad_shards"] == [bad]
+            assert res.errors == [("obj", bad, "bytes")]
             assert res.repaired == ["obj"]
+            assert res.shards_repaired == [("obj", bad)]
+            assert prim.perf_scrub.get("map_bytes") > 0
             assert await io.read("obj") == payload
             res = await scrub_pg(pg, repair=False)
             assert res.clean

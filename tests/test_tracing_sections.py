@@ -239,6 +239,44 @@ def test_mesh_programs_carry_their_own_names(call, name):
     del jax
 
 
+def test_digest_program_carries_its_own_name_under_the_crc_scope():
+    """A deep scrub's launch is ``jit_crc32c_shards`` in a trace, not
+    the ``crc32c_chunks`` an encode inlines, and its operations stand
+    under the ``crc32c`` scope."""
+    import jax.numpy as jnp
+    from ceph_tpu.ops.crc32c_batch import DIGEST_PROGRAM
+    from ceph_tpu.parallel import mesh_codec as mc
+
+    fn = mc._compiled_digest(mc._shared_mesh(1), 2, 4096)
+    text = fn.lower(jnp.zeros((2, 4096), jnp.uint8)).as_text(
+        debug_info=True)
+    assert DIGEST_PROGRAM == "crc32c_shards"
+    assert f"module @jit_{DIGEST_PROGRAM} " in text
+    assert "crc32c" in text.replace(DIGEST_PROGRAM, "")
+    assert "gf_encode" not in text and "gf_decode" not in text
+
+
+def test_the_scrub_layer_has_its_five_sections():
+    """``scrub.list``, ``.digest_host``, ``.digest_device``,
+    ``.compare`` and ``.repair``, in the scrubber and where the PG
+    lists a chunk under its lock; nothing else opens a ``scrub.*``."""
+    assert "scrub" in tracing.SECTION_LAYERS
+    found: dict[str, set] = {}
+    for path in SECTIONED:
+        for node in ast.walk(ast.parse((ROOT / path).read_text())):
+            if isinstance(node, ast.With):
+                for i in node.items:
+                    if _is_section(i) and i.context_expr.args[0].value \
+                            .startswith("scrub."):
+                        found.setdefault(i.context_expr.args[0].value,
+                                         set()).add(path)
+    assert sorted(found) == ["scrub.compare", "scrub.digest_device",
+                             "scrub.digest_host", "scrub.list",
+                             "scrub.repair"]
+    assert set().union(*found.values()) == {"ceph_tpu/osd/scrub.py",
+                                            "ceph_tpu/osd/pg.py"}
+
+
 @pytest.mark.parametrize("lanes", [8, 64], ids=["short", "long"])
 def test_crush_program_carries_its_scopes(lanes, monkeypatch):
     """``straw2_draw`` in every launch; ``crush_retry`` around the narrow

@@ -14,11 +14,16 @@ hands meta and segments to the socket as a list (``encode_parts``),
 the receiver reads into the frame's own buffers (``FrameReader``) and
 copies each segment out once (``decode_parts``).
 
-meta envelope (denc, struct_v 1):
+meta envelope (denc, struct_v 2, compat 1):
   string t | u64 seq | string from | u8 kind | blob payload |
-  list<u32> seg_lens
+  list<u32> seg_lens | u64 ack_seq | u8 flags
 where kind selects the payload codec: 0 generic value, 1 json
-(escape hatch), 2 typed (wire_types.WIRE_CODECS[t]).
+(escape hatch), 2 typed (wire_types.WIRE_CODECS[t]).  ``ack_seq`` and
+``flags`` came with struct_v 2 (ceph_msg_header2's ``ack_seq``): the
+highest seq the sender has received on this connection, and
+``FLAG_ACK_NOW``, set by a sender that is short of flow-control
+window.  They stand last, so a v1 reader skips them and a v1
+envelope reads as 0 / 0.
 """
 
 from __future__ import annotations
@@ -44,6 +49,8 @@ KIND_VALUE = 0
 KIND_JSON = 1
 KIND_TYPED = 2
 
+FLAG_ACK_NOW = 0x01      # the sender wants this frame confirmed at once
+
 
 @dataclass
 class Message:
@@ -52,6 +59,9 @@ class Message:
     segments: list[bytes] = field(default_factory=list)
     seq: int = 0
     from_name: str = ""
+    # stamped by the connection a frame leaves on, each time it leaves
+    ack_seq: int = 0
+    flags: int = 0
 
     def encode(self) -> bytes:
         """The frame as one buffer: for a connection that compresses
@@ -93,13 +103,15 @@ class Message:
             payload = Encoder()
             payload.blob(blob)
         enc = Encoder()
-        enc.start(1, 1)
+        enc.start(2, 1)
         enc.string(self.type)
         enc.u64(self.seq)
         enc.string(self.from_name)
         enc.u8(kind)
         enc.blob(payload.bytes())
         enc.list([len(s) for s in self.segments], Encoder.u32)
+        enc.u64(self.ack_seq)
+        enc.u8(self.flags)
         enc.finish()
         mb = enc.bytes()
         parts = [MAGIC + struct.pack("<I", len(mb))]
@@ -165,7 +177,8 @@ class Message:
                 got = crc32c(view, got)
             if (got & 0xFFFFFFFF) != crc:
                 raise ValueError("frame crc mismatch")
-        mtype, seq, from_name, data, seg_lens = _decode_meta(head[8:off])
+        mtype, seq, from_name, data, seg_lens, ack_seq, flags = \
+            _decode_meta(head[8:off])
         if off + sum(seg_lens) + 4 != total:
             raise ValueError("segment lengths do not fill the frame")
         segments = []
@@ -173,7 +186,8 @@ class Message:
             segments.append(b"".join(_span(head, rest, off, off + ln)))
             off += ln
         return cls(type=mtype, data=data, segments=segments,
-                   seq=seq, from_name=from_name)
+                   seq=seq, from_name=from_name, ack_seq=ack_seq,
+                   flags=flags)
 
 
 _NO_BYTES = memoryview(b"")
@@ -193,13 +207,17 @@ def _span(head: memoryview, rest: memoryview, a: int,
 
 def _decode_meta(mb) -> tuple:
     dec = Decoder(mb)
-    dec.start(1)
+    struct_v = dec.start(2)
     mtype = dec.string()
     seq = dec.u64()
     from_name = dec.string()
     kind = dec.u8()
     payload = dec.blob()
     seg_lens = dec.list(Decoder.u32)
+    ack_seq = flags = 0
+    if struct_v >= 2:
+        ack_seq = dec.u64()
+        flags = dec.u8()
     dec.finish()
     if kind == KIND_TYPED:
         codec = WIRE_CODECS.get(mtype)
@@ -212,7 +230,7 @@ def _decode_meta(mb) -> tuple:
         data = json.loads(Decoder(payload).blob())
     else:
         raise ValueError(f"bad meta kind {kind}")
-    return mtype, seq, from_name, data, seg_lens
+    return mtype, seq, from_name, data, seg_lens, ack_seq, flags
 
 
 COMP_MAGIC = b"CTvC"     # on-wire compressed frame (compression_onwire)
@@ -453,7 +471,7 @@ def _meta_seg_lens(mb: memoryview) -> list[int]:
     """Just the segment lengths from a meta envelope (what
     ``frame_need`` sizes the rest of the frame by)."""
     dec = Decoder(mb)
-    dec.start(1)
+    dec.start(2)
     dec.string()        # t
     dec.u64()           # seq
     dec.string()        # from

@@ -23,9 +23,9 @@ from typing import Awaitable, Callable
 from ..common.perf import PerfCounters
 from ..common.throttle import injector as _fault
 from ..common.tracing import install_loop_probe, section
-from .message import (COMP_MAGIC, MAGIC, OFFLOAD_THRESHOLD, SEC_MAGIC,
-                      FrameReader, Message, decrypt_frame, unwrap_frame,
-                      wrap_frame)
+from .message import (COMP_MAGIC, FLAG_ACK_NOW, MAGIC, OFFLOAD_THRESHOLD,
+                      SEC_MAGIC, FrameReader, Message, decrypt_frame,
+                      unwrap_frame, wrap_frame)
 
 Dispatcher = Callable[["Connection", Message], Awaitable[None]]
 
@@ -33,15 +33,26 @@ HELLO_MAGIC = b"CTHL"
 HELLO_ACCEPTS_TICKETS = 0x01     # server can validate cephx tickets
 HELLO_REQUIRES_TICKET = 0x02     # server will NACK ticketless peers
 
-# flow-control policy (src/msg/Policy.h throttler analog): receivers ack
-# delivered seqs every ack_every messages or ack_bytes payload bytes --
-# and on a short idle timer, so a sender whose window is smaller than
-# the peer's batching cadence still gets unblocked -- and senders block
+# flow-control policy (src/msg/Policy.h throttler analog): senders block
 # in send() once the unacked window exceeds the messenger's
-# max_unacked_msgs/max_unacked_bytes instead of growing without bound.
+# max_unacked_msgs/max_unacked_bytes instead of growing without bound,
+# and receivers confirm what was delivered.  The confirmation rides in
+# the envelope of every frame that leaves the connection anyway
+# (``ack_seq``: a reply, a ping, the next request; msgr2's
+# ceph_msg_header2.ack_seq); a frame of its own (ACK_TYPE) leaves only
+# when nothing else will: after ack_every frames or ack_bytes bytes
+# unconfirmed, for a frame whose sender says it is short of window
+# (FLAG_ACK_NOW), or when the connection has sent nothing for
+# ACK_IDLE_S.
 ACK_EVERY = 64
 ACK_BYTES = 8 << 20
-ACK_FLUSH_S = 0.2
+# What the idle deadline bounds is memory and replay, not a round trip
+# (nobody waits for an ack but a sender out of window, and it says so):
+# the frames a sender keeps referenced in ``unacked`` and would replay
+# after a reconnect, per connection at most ack_bytes.  It outlasts a
+# reply that is on its way and the gap between two heartbeats, so that
+# those carry the confirmation.
+ACK_IDLE_S = 1.0
 ACK_TYPE = "__ack"
 
 # per-peer sub-op coalescing (the PR-12 write pipeline): concurrent
@@ -226,7 +237,8 @@ class Connection:
         self.unacked: deque[tuple[Message, int]] = deque()  # (msg, nbytes)
         self.unacked_bytes = 0
         self.acked_seq = 0           # peer-confirmed delivery watermark
-        self._ack_pending_msgs = 0   # receive side: delivered since last ack
+        # receive side: delivered since a frame last left with in_seq
+        self._ack_pending_msgs = 0
         self._ack_pending_bytes = 0
         self.closed = False
         self.generation = 0          # bumped per successful reconnect
@@ -241,7 +253,12 @@ class Connection:
         self._reconnect_lock = asyncio.Lock()
         self._window_open = asyncio.Event()
         self._window_open.set()
-        self._ack_task: asyncio.Task | None = None
+        # the idle deadline: when the oldest delivery no frame has
+        # confirmed is due one of its own (loop time), and the one
+        # handle that looks; a frame that leaves resets the count and
+        # touches neither
+        self._ack_due = 0.0
+        self._ack_timer: asyncio.TimerHandle | None = None
 
     @property
     def writer(self) -> asyncio.Transport:
@@ -253,15 +270,19 @@ class Connection:
         return (len(self.unacked) >= m.max_unacked_msgs
                 or self.unacked_bytes >= m.max_unacked_bytes)
 
-    def _trim_acked(self, seq: int) -> None:
+    def _trim_acked(self, seq: int) -> bool:
+        """The peer has everything up to ``seq`` (cumulative and
+        monotonic: a confirmation that was lost is covered by the
+        next).  True if that was news."""
         if seq <= self.acked_seq:
-            return
+            return False
         self.acked_seq = seq
         while self.unacked and self.unacked[0][0].seq <= seq:
             _, nbytes = self.unacked.popleft()
             self.unacked_bytes -= nbytes
         if not self._window_full():
             self._window_open.set()
+        return True
 
     async def send(self, msg: Message) -> None:
         if msg.type == ACK_TYPE:
@@ -315,6 +336,7 @@ class Connection:
             self.out_seq += 1
             msg.seq = self.out_seq
             msg.from_name = self.messenger.name
+            self._stamp(msg, 1, sum(map(len, msg.segments)))
             wraps = self._wraps()
             if wraps:
                 buf = msg.encode()
@@ -361,8 +383,31 @@ class Connection:
         one buffer before it leaves."""
         return self.compressor is not None or self.aead_tx is not None
 
+    def _stamp(self, msg: Message, more_msgs: int = 0,
+               more_bytes: int = 0) -> None:
+        """``msg`` is about to be encoded for this connection: it
+        carries what we have received, so nothing is left for a frame
+        of its own to confirm; and it asks to be confirmed at once
+        where ``unacked`` (with what is about to join it) is at or
+        past half of the flow-control window, since no cadence of the
+        peer's knows our window."""
+        m = self.messenger
+        short = (2 * (len(self.unacked) + more_msgs) >= m.max_unacked_msgs
+                 or 2 * (self.unacked_bytes + more_bytes)
+                 >= m.max_unacked_bytes)
+        msg.flags = FLAG_ACK_NOW if short else 0
+        msg.ack_seq = self.in_seq
+        self._ack_pending_msgs = 0
+        self._ack_pending_bytes = 0
+
+    def _disarm_ack(self) -> None:
+        if self._ack_timer is not None:
+            self._ack_timer.cancel()
+            self._ack_timer = None
+
     def _frame_parts(self, msg: Message) -> list[bytes]:
-        """The buffers of one frame as this connection sends it."""
+        """The buffers of one frame as this connection sends it now."""
+        self._stamp(msg)
         if not self._wraps():
             return msg.encode_parts()
         self.messenger.perf.inc("tx_frames_joined")
@@ -374,36 +419,53 @@ class Connection:
         perf.inc("tx_bytes", sum(map(len, parts)))
         self.proto.write(parts)
 
-    def _note_delivered(self, nbytes: int) -> None:
-        """Receive side: count a delivery toward the ack cadence and
-        confirm immediately once the cadence is hit (a lost ack is
-        re-covered by the next one or the reconnect handshake)."""
+    def _note_delivered(self, nbytes: int, now: bool) -> None:
+        """Receive side: one more frame the next frame out will
+        confirm.  A frame of its own confirms it only at the cadence
+        that bounds what the sender retains, for a sender short of
+        window (``now``), or once nothing has left for ACK_IDLE_S (a
+        lost confirmation is covered by the next one or by the
+        reconnect handshake)."""
         self._ack_pending_msgs += 1
         self._ack_pending_bytes += nbytes
         if (self._ack_pending_msgs >= self.messenger.ack_every
                 or self._ack_pending_bytes >= self.messenger.ack_bytes):
-            self._flush_ack()
-        elif self._ack_task is None or self._ack_task.done():
-            # idle flush: a sender with a window smaller than our
-            # batching cadence must still see acks eventually
-            self._ack_task = asyncio.ensure_future(self._ack_flusher())
+            self._flush_ack("cadence")
+        elif now:
+            self._flush_ack("window")
+        elif self._ack_pending_msgs == 1:
+            loop = asyncio.get_running_loop()
+            self._ack_due = loop.time() + ACK_IDLE_S
+            if self._ack_timer is None:
+                self._ack_timer = loop.call_at(self._ack_due,
+                                               self._ack_idle)
 
-    def _flush_ack(self) -> None:
-        self._ack_pending_msgs = 0
-        self._ack_pending_bytes = 0
+    def _ack_idle(self) -> None:
+        """The handle fired: confirm what has waited out the deadline,
+        or look again when the oldest delivery still unconfirmed will
+        have (a frame that left meanwhile moved the deadline without
+        touching the handle: one timer a quiet second, not one a
+        frame)."""
+        self._ack_timer = None
+        if self.closed or not self._ack_pending_msgs:
+            return
+        loop = asyncio.get_running_loop()
+        if loop.time() >= self._ack_due:
+            self._flush_ack("idle")
+        else:
+            self._ack_timer = loop.call_at(self._ack_due, self._ack_idle)
+
+    def _flush_ack(self, why: str) -> None:
+        perf = self.messenger.perf
+        perf.inc("tx_acks")
+        perf.inc("tx_acks_" + why)
         ack = Message(ACK_TYPE, {"seq": self.in_seq})
         ack.from_name = self.messenger.name
         self._write_frame(self._frame_parts(ack))
 
-    async def _ack_flusher(self) -> None:
-        try:
-            await asyncio.sleep(ACK_FLUSH_S)
-            if not self.closed and self._ack_pending_msgs:
-                self._flush_ack()
-        except asyncio.CancelledError:
-            pass
-
     async def _resend_unacked(self) -> None:
+        """A reconnect's replay: each frame leaves with the watermark
+        and the window as they are now."""
         for msg, _ in list(self.unacked):
             self._write_frame(self._frame_parts(msg))
         await self.proto.drain()
@@ -411,8 +473,7 @@ class Connection:
     async def close(self) -> None:
         self.closed = True
         self._window_open.set()      # wake throttled senders to error out
-        if self._ack_task:
-            self._ack_task.cancel()
+        self._disarm_ack()
         self.writer.close()
 
 
@@ -648,8 +709,11 @@ class Messenger:
         # tx_frames, tx_frames_joined (sent as one buffer because the
         # connection compresses or encrypts), tx_bytes, rx_frames,
         # rx_bytes, rx_copied_bytes (bytes the receive path copied in
-        # user space: on a plain connection each segment once); a
-        # daemon adopts the set into its own collection
+        # user space: on a plain connection each segment once), tx_acks
+        # (ACK_TYPE frames sent: tx_acks_cadence + tx_acks_window +
+        # tx_acks_idle, by what made each leave), rx_acks_carried
+        # (frames other than those whose ack_seq advanced the peer's
+        # watermark); a daemon adopts the set into its own collection
         self.perf = PerfCounters("msgr")
         self._rx_spare: list[bytearray] = []     # see FrameReader.spare
 
@@ -1050,6 +1114,7 @@ class Messenger:
             except RuntimeError:      # event loop shutting down
                 pass
         conn.closed = True
+        conn._disarm_ack()
         # wake any sender blocked on the flow-control window so
         # it raises instead of hanging on a dead connection
         conn._window_open.set()
@@ -1058,15 +1123,21 @@ class Messenger:
                   nbytes: int) -> None:
         """One received frame of ``nbytes`` on the wire, synchronously:
         seq/ack accounting, delivery of the message(s) it carries."""
+        # every frame confirms, a replayed duplicate too: it left with
+        # the watermark of its replay
+        carried = conn._trim_acked(msg.ack_seq)
         if msg.type == ACK_TYPE:   # control frame, outside seq space
+            # (a v1 peer's says it in the payload alone)
             conn._trim_acked(int(msg.data.get("seq", 0)))
             return
+        if carried:
+            self.perf.inc("rx_acks_carried")
         if msg.seq <= conn.in_seq:
             return  # duplicate after resend
         conn.in_seq = msg.seq
         if not conn.outgoing:
             self._sessions[conn.peer_name] = msg.seq
-        conn._note_delivered(nbytes)
+        conn._note_delivered(nbytes, bool(msg.flags & FLAG_ACK_NOW))
         if msg.type == SUBOP_BATCH_TYPE:
             # one framed flush -> the staged sub-ops, delivered
             # in staging order (per-peer FIFO preserved)

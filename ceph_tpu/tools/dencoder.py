@@ -81,7 +81,9 @@ def _entry(cls, samples):
 def _samples_wire():
     """Wire frames: the denc meta envelope + typed hot-path codecs
     (msg/wire_types.py) must stay byte-stable -- a drift here breaks
-    rolling upgrades mid-flight, not just on-disk state."""
+    rolling upgrades mid-flight, not just on-disk state.  The
+    committed corpus keeps the struct_v 1 frames (``N.bin``, decode
+    compat) beside the struct_v 2 ones (``v2-N.bin``, byte-stable)."""
     from ..msg import Message
     m = Message("osd_op", {"pgid": "1.2a", "oid": "obj-7", "tid": 42,
                            "reqid": ["client.a:ffee", 7],
@@ -104,6 +106,11 @@ def _samples_wire():
     yield Message("paxos_begin", {"version": 7, "value": "v" * 20,
                                   "e": 2, "nested": {"a": [1, None],
                                                      "b": -1.5}})
+    # struct_v 2: a reply that confirms what its connection received
+    # and asks to be confirmed at once
+    r = Message("ec_subop_write_reply", {"tid": 6, "shard": 4})
+    r.seq, r.from_name, r.ack_seq, r.flags = 31, "osd.4", 57, 1
+    yield r
 
 
 def _wire_entry():
@@ -114,7 +121,8 @@ def _wire_entry():
         "dec": Message.decode,
         "dump": lambda m: {"t": m.type, "seq": m.seq,
                            "from": m.from_name, "data": m.data,
-                           "segs": [s.hex() for s in m.segments]},
+                           "segs": [s.hex() for s in m.segments],
+                           "ack_seq": m.ack_seq, "flags": m.flags},
         # frames start with 4-byte magic + u32 meta_len; the envelope
         # struct_v lives at offset 8 (default heuristic reads byte 0)
         "ver": lambda b: b[8:9],

@@ -212,8 +212,11 @@ def test_flow_control_send_raises_on_closed_conn():
         server.add_dispatcher(lambda c, m: asyncio.sleep(0))
         addr = await server.bind()
         conn = await client.connect(addr, "osd.4")
-        # fill the window (no acks: cadence is huge), then close the
-        # conn under a blocked sender: it must raise, not hang
+        # fill the window (no acks: the cadence is huge, and a receiver
+        # that reads nothing cannot be asked for one either), then
+        # close the conn under a blocked sender: it must raise, not hang
+        assert await _until(lambda: "client.g" in server.conns_in)
+        server.conns_in["client.g"].writer.pause_reading()
         await conn.send(Message("n", {"i": 0}))
         await conn.send(Message("n", {"i": 1}))
         blocked = asyncio.ensure_future(conn.send(Message("n", {"i": 2})))
@@ -479,3 +482,337 @@ def test_msgr_counters_say_how_frames_left_and_what_was_copied(opts,
         # the frame joined out of its two buffers, the frame out of the
         # decompressor, then the segments
         assert rx["rx_copied_bytes"] > seg_bytes
+
+
+# -- acks ride on frames that leave anyway (PR 39): a frame of its own
+# -- only at the cadence, for a sender short of window, or after silence
+
+def _echo_pair(monkeypatch, idle_s, *, server_opts=None, client_opts=None):
+    """A server that answers every ``ping`` with a ``pong`` on the
+    connection it came in on, a client that records the pongs, and the
+    idle deadline both run with."""
+    monkeypatch.setattr("ceph_tpu.msg.messenger.ACK_IDLE_S", idle_s)
+    server = Messenger("osd.20", **(server_opts or {}))
+    client = Messenger("client.q", **(client_opts or {}))
+    pongs = []
+
+    async def serve(conn, msg):
+        await conn.send(Message("pong", {"i": msg.data["i"]}))
+
+    async def collect(conn, msg):
+        pongs.append(msg.data["i"])
+
+    server.add_dispatcher(serve)
+    client.add_dispatcher(collect)
+    return server, client, pongs
+
+
+def _acks(m):
+    d = m.perf.dump()
+    return {k: d.get(k, 0) for k in ("tx_acks", "tx_acks_cadence",
+                                     "tx_acks_window", "tx_acks_idle",
+                                     "rx_acks_carried")}
+
+
+@pytest.mark.parametrize("opts", [
+    {}, {"compression": "zlib"}, {"secret": b"k", "secure": True}],
+    ids=["plain", "compressed", "secure"])
+def test_requests_and_replies_confirm_each_other_without_an_ack_frame(
+        monkeypatch, opts):
+    """A reply carries the request's confirmation and the next request
+    the reply's: both ``unacked`` queues are trimmed and no ``__ack``
+    leaves, on a plain and on a wrapped connection."""
+    async def main():
+        server, client, pongs = _echo_pair(monkeypatch, 60.0,
+                                           server_opts=opts,
+                                           client_opts=opts)
+        addr = await server.bind()
+        conn = await client.connect(addr, "osd.20")
+        pad = [bytes(3000)]              # over COMPRESS_THRESHOLD
+        for i in range(20):
+            await conn.send(Message("ping", {"i": i}, pad))
+            assert await _until(lambda: len(pongs) == i + 1)
+        back = server.conns_in["client.q"]
+        # the 20th pong confirmed the 20th ping; the pong itself waits
+        # for the next frame out (or the idle deadline, far off here)
+        state = (len(conn.unacked), conn.acked_seq, len(back.unacked),
+                 back.acked_seq, back._ack_pending_msgs,
+                 conn._ack_pending_msgs)
+        await conn.send(Message("ping", {"i": 20}, pad))
+        assert await _until(lambda: len(pongs) == 21)
+        state += (len(back.unacked), back.acked_seq)
+        acks = _acks(client), _acks(server)
+        frames = (client.perf.get("tx_frames"), server.perf.get("tx_frames"))
+        await client.shutdown()
+        await server.shutdown()
+        return state, acks, frames
+
+    state, (tx, rx), frames = run(main())
+    assert state == (0, 20, 1, 19, 0, 1, 1, 20)
+    assert tx["tx_acks"] == rx["tx_acks"] == 0
+    assert tx["rx_acks_carried"] == 21 and rx["rx_acks_carried"] == 20
+    assert frames == (21, 21)            # nothing but pings and pongs
+
+
+def test_a_one_way_stream_into_a_small_window_drains_by_the_window_flag(
+        monkeypatch):
+    """No reply ever leaves the receiver and its cadence (64) is four
+    windows long: a sender at half its window says so in the envelope
+    and is confirmed at once, long before the idle deadline."""
+    monkeypatch.setattr("ceph_tpu.msg.messenger.ACK_IDLE_S", 60.0)
+
+    async def main():
+        server, client, got = _pair("osd.21", "client.r",
+                                    client_opts={"max_unacked_msgs": 16})
+        addr = await server.bind()
+        conn = await client.connect(addr, "osd.21")
+        for i in range(200):
+            await asyncio.wait_for(conn.send(Message("n", {"i": i})), 10)
+            assert len(conn.unacked) <= 16
+        assert await _until(lambda: len(got) == 200)
+        # what is left unconfirmed is under half a window
+        assert await _until(lambda: len(conn.unacked) < 8, 5)
+        acks = _acks(server)
+        await client.shutdown()
+        await server.shutdown()
+        return [g[1] for g in got], acks
+
+    got, acks = run(main())
+    assert got == list(range(200))
+    assert acks["tx_acks_window"] > 0 and acks["tx_acks_idle"] == 0
+    assert acks["tx_acks"] == acks["tx_acks_window"] + acks["tx_acks_cadence"]
+
+
+def test_a_quiet_connection_is_confirmed_once_by_a_timer_and_no_task(
+        monkeypatch):
+    """Three frames, then silence: one ``__ack`` after the idle
+    deadline confirms all three; the deadline is a timer handle, never
+    a Task, and ``close`` cancels it."""
+    idle = 0.15
+    monkeypatch.setattr("ceph_tpu.msg.messenger.ACK_IDLE_S", idle)
+
+    async def main():
+        server, client, got = _pair("osd.22", "client.s")
+        addr = await server.bind()
+        conn = await client.connect(addr, "osd.22")
+        before = asyncio.all_tasks()
+        for i in range(3):
+            await conn.send(Message("n", {"i": i}))
+        assert await _until(lambda: len(got) == 3)
+        back = server.conns_in["client.s"]
+        assert isinstance(back._ack_timer, asyncio.TimerHandle)
+        assert len(conn.unacked) == 3 and server.perf.get("tx_acks") == 0
+        # only the three dispatch tasks came, and they are done
+        assert await _until(lambda: all(
+            t.done() for t in asyncio.all_tasks() - before), 5)
+        assert await _until(lambda: not conn.unacked, 5)
+        await asyncio.sleep(3 * idle)            # a second one would leave
+        acks = _acks(server)
+        assert back._ack_timer is None
+        # armed again by the next frame, cancelled by close()
+        await conn.send(Message("n", {"i": 3}))
+        assert await _until(lambda: len(got) == 4)
+        timer = back._ack_timer
+        await back.close()
+        assert timer.cancelled() and back._ack_timer is None
+        await asyncio.sleep(2 * idle)
+        acks_after = _acks(server)
+        await client.shutdown()
+        await server.shutdown()
+        others = asyncio.all_tasks() - {asyncio.current_task()}
+        assert await _until(lambda: all(t.done() for t in others), 5)
+        return acks, acks_after
+
+    acks, acks_after = run(main())
+    assert acks == acks_after
+    assert acks["tx_acks"] == acks["tx_acks_idle"] == 1
+
+
+def test_frames_that_keep_leaving_keep_the_deadline_away(monkeypatch):
+    """While pings and pongs follow each other faster than the idle
+    deadline nobody sends an ``__ack``, however long it goes on, and the
+    one handle of a connection is re-used, not re-made per frame; the
+    silence after it costs the one ack for the last pong."""
+    idle = 0.2
+
+    async def main():
+        server, client, pongs = _echo_pair(monkeypatch, idle)
+        addr = await server.bind()
+        conn = await client.connect(addr, "osd.20")
+        handles = set()
+        for i in range(12):                      # 0.6 s: three deadlines
+            await conn.send(Message("ping", {"i": i}))
+            assert await _until(lambda: len(pongs) == i + 1)
+            handles.add(conn._ack_timer)
+            await asyncio.sleep(0.05)
+        during = _acks(client), _acks(server)
+        assert await _until(lambda: client.perf.get("tx_acks") == 1, 5)
+        await asyncio.sleep(2 * idle)
+        after = _acks(client), _acks(server)
+        back = server.conns_in["client.q"]
+        state = (len(back.unacked), conn._ack_timer, back._ack_timer)
+        await client.shutdown()
+        await server.shutdown()
+        return during, after, len(handles), state
+
+    during, after, handles, state = run(main())
+    assert during[0]["tx_acks"] == during[1]["tx_acks"] == 0
+    assert handles <= 4                  # one a deadline, not one a frame
+    assert after[0]["tx_acks"] == after[0]["tx_acks_idle"] == 1
+    assert after[1]["tx_acks"] == 0
+    assert state == (0, None, None)
+
+
+def test_a_v1_peer_is_understood():
+    """An envelope of struct_v 1 has no ``ack_seq``: its frames confirm
+    nothing by themselves and its ``__ack`` says the seq in its
+    payload, which still trims."""
+    from ceph_tpu.common.denc import Encoder
+    from ceph_tpu.msg.messenger import ACK_TYPE
+
+    def v1_frame(mtype, seq, data):
+        payload = Encoder()
+        payload.value(data)
+        enc = Encoder()
+        enc.start(1, 1)
+        enc.string(mtype).u64(seq).string("osd.23").u8(0)
+        enc.blob(payload.bytes())
+        enc.list([], Encoder.u32)
+        enc.finish()
+        body = enc.bytes()
+        return (b"CTv3" + len(body).to_bytes(4, "little") + body
+                + (crc32c(body) & 0xFFFFFFFF).to_bytes(4, "little"))
+
+    old = Message.decode(v1_frame("n", 1, {"i": 0}))
+    assert (old.type, old.seq, old.data, old.ack_seq, old.flags) \
+        == ("n", 1, {"i": 0}, 0, 0)
+
+    async def main():
+        server, client, got = _pair("osd.23", "client.t")
+        addr = await server.bind()
+        conn = await client.connect(addr, "osd.23")
+        for i in range(3):
+            await conn.send(Message("n", {"i": i}))
+        assert await _until(lambda: len(got) == 3)
+        assert len(conn.unacked) == 3
+        # what a v1 osd.23 would send back on this socket
+        client._frame_in(conn, old, 64)
+        assert len(conn.unacked) == 3 and conn.in_seq == 1
+        client._frame_in(conn, Message.decode(
+            v1_frame(ACK_TYPE, 0, {"seq": 2})), 64)
+        left = [m.seq for m, _ in conn.unacked]
+        await client.shutdown()
+        await server.shutdown()
+        return left
+
+    assert run(main()) == [3]
+
+
+def test_a_replay_carries_the_watermark_of_now_and_a_duplicate_trims(
+        monkeypatch):
+    """A frame that is replayed after a reconnect is stamped again:
+    the receiver dedups it by seq and still takes its ``ack_seq``."""
+    async def main():
+        server, client, pongs = _echo_pair(monkeypatch, 60.0)
+        addr = await server.bind()
+        conn = await client.connect(addr, "osd.20")
+        for i in range(3):
+            await conn.send(Message("ping", {"i": i}))
+            assert await _until(lambda: len(pongs) == i + 1)
+        back = server.conns_in["client.q"]
+        # pongs 1-2 are confirmed by pings 1-2; pong 3 is not yet
+        assert [m.seq for m, _ in back.unacked] == [3]
+        first = Message("ping", {"i": 0})
+        first.seq, first.from_name = 1, "client.q"
+        stamped = []
+        for in_seq in (2, 3):
+            # what the client's replay of ping 1 would be: the same
+            # seq, the watermark of the moment it leaves again
+            conn.in_seq = in_seq
+            frame = b"".join(conn._frame_parts(first))
+            stamped.append(Message.decode(frame).ack_seq)
+        dup = Message.decode(frame)
+        delivered = len(pongs)
+        server._frame_in(back, dup, len(frame))
+        await asyncio.sleep(0.05)
+        state = (len(back.unacked), back.in_seq, len(pongs) - delivered)
+        await client.shutdown()
+        await server.shutdown()
+        return stamped, state
+
+    stamped, state = run(main())
+    assert stamped == [2, 3]
+    assert state == (0, 3, 0)            # trimmed, and not delivered again
+
+
+def test_a_reconnect_replays_with_the_flag_and_the_watermark(monkeypatch):
+    """The socket dies under a sender whose window is half full: the
+    replay on the new socket asks for its confirmation at once, and
+    everything arrives exactly once."""
+    monkeypatch.setattr("ceph_tpu.msg.messenger.ACK_IDLE_S", 60.0)
+
+    async def main():
+        server, client, got = _pair("osd.24", "client.u",
+                                    client_opts={"max_unacked_msgs": 32})
+        addr = await server.bind()
+        conn = await client.connect(addr, "osd.24")
+        for i in range(10):              # under half the window: no flag
+            await conn.send(Message("n", {"i": i}))
+        assert await _until(lambda: len(got) == 10)
+        assert len(conn.unacked) == 10 and server.perf.get("tx_acks") == 0
+        conn.writer.abort()
+        assert await _until(lambda: conn.generation == 1)
+        # the handshake's last_seq trimmed all ten: nothing to replay
+        assert not conn.unacked
+        server.conns_in["client.u"].writer.pause_reading()
+        for i in range(10, 26):
+            await conn.send(Message("n", {"i": i}))
+        conn.writer.abort()              # 16 of 32 in flight, unread
+        assert await _until(lambda: conn.generation == 2)
+        assert await _until(lambda: len(got) == 26)
+        assert await _until(lambda: not conn.unacked, 5)
+        acks = _acks(server)
+        await client.shutdown()
+        await server.shutdown()
+        return [g[1] for g in got], acks
+
+    got, acks = run(main())
+    assert got == list(range(26))
+    assert acks["tx_acks_window"] >= 1 and acks["tx_acks_idle"] == 0
+
+
+def test_a_lost_carrier_is_covered_by_the_next(monkeypatch):
+    """Two pongs never leave the server (a send fault) and the client
+    throws a third away after its frame was accounted (a receive
+    fault): the next pong's ``ack_seq`` says more than all of them
+    would have, and no ``__ack`` was needed."""
+    from ceph_tpu.common.faults import RECV, SEND, MessageFaultInjector
+
+    async def main():
+        drop_tx, drop_rx = MessageFaultInjector(1), MessageFaultInjector(2)
+        drop_tx.drop(mtype="pong", direction=SEND, count=2)
+        server, client, pongs = _echo_pair(
+            monkeypatch, 60.0, server_opts={"faults": drop_tx},
+            client_opts={"faults": drop_rx})
+        addr = await server.bind()
+        conn = await client.connect(addr, "osd.20")
+        for i in range(2):
+            await conn.send(Message("ping", {"i": i}))
+        await asyncio.sleep(0.1)
+        assert pongs == [] and len(conn.unacked) == 2
+        drop_rx.drop(mtype="pong", direction=RECV, count=1)
+        await conn.send(Message("ping", {"i": 2}))
+        assert await _until(lambda: not conn.unacked)
+        await conn.send(Message("ping", {"i": 3}))
+        assert await _until(lambda: pongs == [3])
+        acks = _acks(client), _acks(server)
+        stats = drop_tx.stats.get("dropped", 0), drop_rx.stats.get(
+            "dropped", 0)
+        await client.shutdown()
+        await server.shutdown()
+        return acks, stats
+
+    (tx, rx), stats = run(main())
+    assert stats == (2, 1)
+    assert tx["tx_acks"] == rx["tx_acks"] == 0
+    assert tx["rx_acks_carried"] == 2    # pong 2 (dropped above) and pong 3

@@ -1,6 +1,9 @@
 """The frame codec without a socket: ``Message.encode_parts`` against
 golden frames that the encoder of PR 27 produced (the bytes on the wire
-did not change when frames stopped being joined), and ``FrameReader``
+did not change when frames stopped being joined; since PR 39 the
+envelope is struct_v 2, which is those bytes with ``ack_seq`` and
+``flags`` appended inside the envelope: ``_v2`` / ``_v1`` spell the
+difference out, and the v1 frames still decode), and ``FrameReader``
 fed the same stream in every way a socket can cut it.
 """
 
@@ -133,6 +136,35 @@ def _case(name: str) -> Message:
     return m
 
 
+def _frame(meta: bytes, payload: bytes) -> bytes:
+    body = meta + payload
+    return (MAGIC + struct.pack("<I", len(meta)) + body
+            + struct.pack("<I", crc32c(body) & 0xFFFFFFFF))
+
+
+def _v2(v1: bytes, ack_seq: int = 0, flags: int = 0) -> bytes:
+    """The struct_v 2 frame of the message in the struct_v 1 frame
+    ``v1``: version byte 2, compat still 1, ``u64 ack_seq | u8 flags``
+    appended inside the envelope, the two lengths and the crc
+    following suit."""
+    (meta_len,) = struct.unpack_from("<I", v1, 4)
+    meta = v1[8:8 + meta_len]
+    assert meta[:2] == b"\x01\x01"
+    assert struct.unpack_from("<I", meta, 2) == (meta_len - 6,)
+    meta = (b"\x02\x01" + struct.pack("<I", meta_len - 6 + 9) + meta[6:]
+            + struct.pack("<QB", ack_seq, flags))
+    return _frame(meta, v1[8 + meta_len:-4])
+
+
+def _v1(v2: bytes) -> bytes:
+    """The other way: what PR 27's encoder gave for the same message."""
+    (meta_len,) = struct.unpack_from("<I", v2, 4)
+    meta = v2[8:8 + meta_len]
+    assert meta[:2] == b"\x02\x01"
+    meta = b"\x01\x01" + struct.pack("<I", meta_len - 6 - 9) + meta[6:-9]
+    return _frame(meta, v2[8 + meta_len:-4])
+
+
 def _by_hand(frame: bytes, segments: list[bytes]) -> bytes:
     """The layout spelled out, around the meta that ``frame`` carries:
     magic | u32 meta_len | meta | segments | u32 crc32c(meta + segments)."""
@@ -145,9 +177,10 @@ def _by_hand(frame: bytes, segments: list[bytes]) -> bytes:
 @pytest.mark.parametrize("name", sorted(CASES))
 def test_parts_join_to_the_golden_frame(name):
     m = _case(name)
-    golden = bytes.fromhex(GOLDEN[name])
+    golden = _v2(bytes.fromhex(GOLDEN[name]))
     parts = m.encode_parts()
     assert b"".join(parts) == golden == m.encode()
+    assert _v1(golden) == bytes.fromhex(GOLDEN[name])
     assert len(parts) == 1           # no long segment: one small buffer
     assert golden == _by_hand(golden, m.segments)
     # the payload codec the case is named for
@@ -157,13 +190,39 @@ def test_parts_join_to_the_golden_frame(name):
     assert Message.decode(golden) == m
 
 
+@pytest.mark.parametrize("name", sorted(CASES))
+def test_a_v1_envelope_still_decodes(name):
+    """A struct_v 1 frame (no ``ack_seq``, no ``flags``) is the same
+    message confirming nothing, to ``decode`` and to the reader, which
+    sizes the frame by the envelope's segment lengths."""
+    m = _case(name)
+    v1 = bytes.fromhex(GOLDEN[name])
+    got = Message.decode(v1)
+    assert got == m and got.ack_seq == 0 and got.flags == 0
+    fed: list[Message] = []
+    _feed(_reader(fed), v1 + m.encode() + v1, [3, len(v1) + 11])
+    assert fed == [m, m, m]
+
+
+@pytest.mark.parametrize("name", ["typed_one_segment", "json_escape"])
+def test_the_envelope_carries_ack_seq_and_flags(name):
+    m = _case(name)
+    m.ack_seq, m.flags = (1 << 40) + 5, 1
+    frame = m.encode()
+    assert frame == _v2(bytes.fromhex(GOLDEN[name]), (1 << 40) + 5, 1)
+    got = Message.decode(frame)
+    assert got == m and got.ack_seq == (1 << 40) + 5 and got.flags == 1
+    assert got != _case(name)
+
+
 @pytest.mark.parametrize("name", sorted(LONG_CASES))
 def test_a_long_segment_is_sent_as_the_object_it_is(name):
     m = _case(name)
     parts = m.encode_parts()
     frame = b"".join(parts)
-    assert (len(frame), hashlib.sha256(frame).hexdigest()) \
-        == GOLDEN_LONG[name]
+    v1 = _v1(frame)
+    assert (len(v1), hashlib.sha256(v1).hexdigest()) == GOLDEN_LONG[name]
+    assert frame == _v2(v1) and len(frame) == len(v1) + 9
     assert frame == m.encode() == _by_hand(frame, m.segments)
     long_segs = [s for s in m.segments if len(s) >= SCATTER_MIN]
     sent_as_is = [p for p in parts if any(p is s for s in long_segs)]

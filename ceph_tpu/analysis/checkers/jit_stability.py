@@ -8,8 +8,11 @@ static argument values.  Two project-shaped ways to defeat it:
   hazard the PR 3 placement cache exists to amortize);
 * jitting a method without marking ``self`` static: each tracer-typed
   ``self`` either fails (unhashable) or retraces per instance.  The
-  vectorized mapper's ``@partial(jax.jit,
-  static_argnames=("self", ...))`` is the sanctioned shape.
+  sanctioned shapes: ``@partial(jax.jit, static_argnames=("self",
+  ...))``, or a class registered as a pytree
+  (``@jax.tree_util.register_pytree_node_class``, as the vectorized
+  mapper is): its ``self`` flattens to arrays, which are operands, and
+  a hashable static part, which is jit's key.
 """
 
 from __future__ import annotations
@@ -84,6 +87,12 @@ class JitStability(Checker):
                       module: Module) -> Iterable[Finding]:
         params = [a.arg for a in fn.args.args]
         if not params or params[0] != "self":
+            return
+        cls = astutil.parent(fn)
+        if isinstance(cls, ast.ClassDef) and any(
+                (astutil.dotted(d) or "").endswith(
+                    "register_pytree_node_class")
+                for d in cls.decorator_list):
             return
         for dec in fn.decorator_list:
             target = dec.func if isinstance(dec, ast.Call) else dec

@@ -11,8 +11,8 @@ Scoped to the accelerator hot paths (``ops/`` and
 
 * functions decorated ``@jax.jit`` / ``@partial(jax.jit, ...)``
   (static_argnames/static_argnums are honored: branching on a static
-  arg is Python-level and fine, the vectorized mapper's
-  ``if self.leaf`` idiom);
+  arg is Python-level and fine; the vectorized mapper, whose ``self``
+  is a pytree, branches on its static ``Structure`` by name);
 * local functions passed by name to ``jax.jit(f)`` / ``pallas_call``;
 * kernel *builders* whose call result feeds ``pallas_call(...)`` --
   their nested ``def kernel(...)`` bodies are the traced code.

@@ -52,7 +52,8 @@ from .perf import PerfCounters
 # the host layers a section name starts with ("wire.decode"); the
 # benchmark's per-layer metrics read these prefixes letter for letter
 SECTION_LAYERS = ("client", "wire", "osd_op", "osd_read", "store",
-                  "batcher", "device_wait", "recovery", "scrub", "loop")
+                  "batcher", "device_wait", "recovery", "scrub", "loop",
+                  "placement")
 
 
 class _NoSection:

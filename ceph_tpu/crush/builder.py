@@ -3,7 +3,9 @@
 Covers what pool creation needs: flat and two-level straw2 hierarchies and
 the standard replicated / erasure rules (the same step sequences
 CrushWrapper::add_simple_rule emits, including the erasure rules'
-set_chooseleaf_tries 5 / set_choose_tries 100 preamble).
+set_chooseleaf_tries 5 / set_choose_tries 100 preamble), and what an
+operator's expansion needs: the ``osd crush`` commands as one function
+over a copy of the map (``crush_command``).
 """
 
 from __future__ import annotations
@@ -134,3 +136,61 @@ def erasure_rule(rule_id: int, root: int, choose_type: int,
         RuleStep(op, 0, choose_type),
         RuleStep(CRUSH_RULE_EMIT),
     ])
+
+
+def _weight(args: dict) -> int:
+    """A command's weight, a float as upstream takes it, in 16.16."""
+    w = int(round(float(args["weight"]) * 0x10000))
+    if w < 0:
+        raise ValueError(f"weight {args['weight']} is negative")
+    return w
+
+
+def _add_bucket(m: CrushMap, args: dict) -> None:
+    m.new_bucket(args["name"], args["type"])
+
+
+def _move(m: CrushMap, args: dict) -> None:
+    m.move_item(m.item_id(args["name"]), args["loc"])
+
+
+def _add(m: CrushMap, args: dict) -> None:
+    osd = m.item_id(args["name"])
+    if osd < 0:
+        raise ValueError(f"{args['name']} is not a device")
+    if m.holders(osd):
+        raise ValueError(f"{args['name']} is in the map already")
+    m.insert_item(osd, _weight(args), args["loc"])
+
+
+def _reweight(m: CrushMap, args: dict) -> None:
+    item = m.item_id(args["name"])
+    if not m.holders(item):
+        raise ValueError(f"{args['name']} is under no bucket")
+    m.adjust_item_weight(item, _weight(args))
+
+
+def _reweight_subtree(m: CrushMap, args: dict) -> None:
+    m.adjust_subtree_weight(m.item_id(args["name"]), _weight(args))
+
+
+# upstream's names (src/mon/MonCommands.h); ``loc`` is {type: name}
+CRUSH_COMMANDS = {
+    "osd crush add-bucket": _add_bucket,          # name, type
+    "osd crush move": _move,                      # name, loc
+    "osd crush add": _add,                        # name (osd.N), weight, loc
+    "osd crush reweight": _reweight,              # name, weight
+    "osd crush reweight-subtree": _reweight_subtree,
+}
+
+
+def crush_command(m: CrushMap, cmd: str, args: dict) -> CrushMap:
+    """The map after one ``osd crush`` command: a copy, edited, every
+    ancestor's weight the sum of its children again; the map handed in
+    is untouched (a served map is replaced wholesale).  The monitor
+    commits the result as ``Incremental.new_crush``; a script or a test
+    chains calls without one.  A name the map does not have, a type it
+    does not know or a weight below zero raises ``ValueError``."""
+    edited = m.copy()
+    CRUSH_COMMANDS[cmd](edited, args)
+    return edited

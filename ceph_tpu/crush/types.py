@@ -7,6 +7,7 @@ tunables (crush.h:374-395).  Weights are 16.16 fixed point throughout.
 
 from __future__ import annotations
 
+from copy import deepcopy
 from dataclasses import dataclass, field
 
 CRUSH_BUCKET_UNIFORM = 1
@@ -151,6 +152,130 @@ class CrushMap:
             if n == name:
                 return bid
         return None
+
+    # -- edits (CrushWrapper's: what the ``osd crush`` commands run) --------
+    # A served map is replaced wholesale, never edited in place (its
+    # compiled tables hang on the object): edit a ``copy()``.
+    def copy(self) -> "CrushMap":
+        """The map without what was derived from it (the compiled
+        tables and the structural key other modules hang on it)."""
+        m = CrushMap()
+        m.__dict__ = {k: deepcopy(v) for k, v in self.__dict__.items()
+                      if not k.startswith("_")}
+        return m
+
+    def item_id(self, name: str) -> int:
+        """``osd.<n>`` or a bucket's name; an unknown one is refused."""
+        if name.startswith("osd.") and name[4:].isdigit():
+            return int(name[4:])
+        bid = self.name_to_id(name)
+        if bid is None:
+            raise ValueError(f"no crush item {name!r}")
+        return bid
+
+    def holders(self, item: int) -> list[tuple[Bucket, int]]:
+        """(bucket, index) of every place ``item`` is held."""
+        return [(b, b.items.index(item)) for b in self.buckets.values()
+                if item in b.items]
+
+    def adjust_item_weight(self, item: int, weight: int) -> int:
+        """Set ``item``'s 16.16 weight in every bucket that holds it and
+        carry each such bucket's new sum up through its ancestors
+        (CrushWrapper::adjust_item_weight).  Returns the entries
+        changed."""
+        changed = 0
+        for b, i in self.holders(item):
+            if b.item_weights[i] != weight:
+                b.item_weights[i] = weight
+                b._tree_node_weights = b._list_sum_weights = None
+                changed += 1 + self.adjust_item_weight(b.id, b.weight)
+        return changed
+
+    def devices_under(self, item: int) -> list[int]:
+        if item >= 0:
+            return [item]
+        return [d for child in self.buckets[item].items
+                for d in self.devices_under(child)]
+
+    def adjust_subtree_weight(self, item: int, weight: int) -> int:
+        """Every device under ``item`` (or the device itself) weighs
+        ``weight``, the sums carried up to the roots
+        (CrushWrapper::adjust_subtree_weight)."""
+        return sum(self.adjust_item_weight(dev, weight)
+                   for dev in self.devices_under(item))
+
+    def new_bucket(self, name: str, type_name: str) -> int:
+        """An empty straw2 bucket under no parent, with the next free
+        id (CrushWrapper::add_bucket as ``osd crush add-bucket`` calls
+        it)."""
+        types = {n: t for t, n in self.type_names.items()}
+        if type_name not in types or types[type_name] == 0:
+            raise ValueError(f"no bucket type {type_name!r}")
+        if self.name_to_id(name) is not None:
+            raise ValueError(f"bucket {name!r} exists")
+        bid = min(self.buckets, default=0) - 1
+        self.add_bucket(Bucket(id=bid, type=types[type_name]), name)
+        return bid
+
+    def _parent_from(self, item: int, loc: dict[str, str]) -> Bucket:
+        """The bucket of ``loc`` ({type name: bucket name}) nearest
+        above ``item``: every name must exist, be of its type, and be
+        above the item's own type."""
+        types = {n: t for t, n in self.type_names.items()}
+        found = []
+        for type_name, name in loc.items():
+            bid = self.name_to_id(name)
+            if bid is None or type_name not in types \
+                    or self.buckets[bid].type != types[type_name]:
+                raise ValueError(f"no {type_name} named {name!r}")
+            found.append(self.buckets[bid])
+        found = [b for b in found if b.type > self.item_type(item)]
+        if not found:
+            raise ValueError(f"no location above the item in {loc}")
+        return min(found, key=lambda b: b.type)
+
+    def detach(self, item: int) -> int:
+        """Take ``item`` out of every bucket that holds it, the sums
+        carried up; returns the weight it had."""
+        weight = 0
+        for b, i in self.holders(item):
+            weight = b.item_weights[i]
+            del b.items[i], b.item_weights[i]
+            b._tree_node_weights = b._list_sum_weights = None
+            self.adjust_item_weight(b.id, b.weight)
+        return weight
+
+    def insert_item(self, item: int, weight: int, loc: dict[str, str]) -> None:
+        """``item`` under the nearest bucket of ``loc`` at ``weight``,
+        the sums carried up (CrushWrapper::insert_item, with the
+        location's buckets there already)."""
+        parent = self._parent_from(item, loc)
+        if item < 0 and parent.id in [item] + [
+                b.id for b in self._buckets_under(item)]:
+            raise ValueError("a bucket cannot move under itself")
+        if item in parent.items:
+            raise ValueError(f"item {item} is in {parent.id} already")
+        parent.items.append(item)
+        parent.item_weights.append(weight)
+        parent._tree_node_weights = parent._list_sum_weights = None
+        if item >= 0:
+            self.max_devices = max(self.max_devices, item + 1)
+        self.adjust_item_weight(parent.id, parent.weight)
+
+    def _buckets_under(self, bid: int) -> list[Bucket]:
+        out = []
+        for child in self.buckets[bid].items:
+            if child < 0:
+                out += [self.buckets[child]] + self._buckets_under(child)
+        return out
+
+    def move_item(self, item: int, loc: dict[str, str]) -> None:
+        """``osd crush move``: out of where it is, weight kept, into
+        ``loc`` (CrushWrapper::move_bucket)."""
+        self._parent_from(item, loc)              # refuse before detaching
+        held = self.detach(item)
+        self.insert_item(
+            item, self.buckets[item].weight if item < 0 else held, loc)
 
     def is_device(self, item_id: int) -> bool:
         return item_id >= 0

@@ -12,6 +12,16 @@ per-lane masks (firstn's, in a long launch, over the lanes still
 unplaced alone: RETRY_MIN_LANES) -- decision-identical to the scalar
 mapper (ceph_tpu/crush/mapper.py), which is itself pinned to mapper.c.
 
+A map's bucket tables (hashed ids, weights, reciprocal bits, children)
+are OPERANDS of the device program: a ``VectorCrush`` is a pytree whose
+leaves are the tables and whose static part (``Structure``) is what
+decides the program, so jit keys on the structure, the tables' shapes,
+``numrep`` and the lane count, not on the mapper instance.  An OSDMap
+epoch that changed weights alone -- ``osd crush reweight``, a rack
+raised step by step -- uploads its tables and launches the executable
+that is there, in this process and in the persistent cache; a change of
+structure compiles once.
+
 Supported map shape for the fused path: uniform-depth straw2
 hierarchies of ANY depth (root->osds up through root->row->rack->host->
 osd and deeper) with the standard replicated (chooseleaf firstn) /
@@ -24,6 +34,7 @@ from __future__ import annotations
 
 from dataclasses import dataclass
 from functools import partial
+from typing import NamedTuple
 
 import numpy as np
 
@@ -48,10 +59,22 @@ from .types import (
 
 CRUSH_HASH_SEED = np.uint32(1315423911)
 
+
+class FusedUnsupported(ValueError):
+    """The fused path declines a (map, rule): the scalar engine maps
+    it.  ``reason`` is one word, for a counter's name."""
+
+    def __init__(self, reason: str, message: str) -> None:
+        super().__init__(message)
+        self.reason = reason
+
+
 # lanes per device launch: bounds a launch's device memory and the number
-# of compiled shapes whatever size a caller hands in.  The value dates
-# from the 64-bit program (PR 21: a 2M-lane launch aborted the v5e
-# runtime); nothing has measured the 32-bit program beyond it.
+# of compiled shapes whatever size a caller hands in (a shape is a
+# program: the lane count is part of jit's key beside the structure).
+# The value dates from the 64-bit program (PR 21: a 2M-lane launch
+# aborted the v5e runtime); nothing has measured the 32-bit program,
+# whose tables are operands of a few hundred KB, beyond it.
 MAX_LANES = 1 << 17
 
 # crush_firstn retries at the width of what is left to retry: a launch
@@ -309,19 +332,27 @@ class CompiledMap:
             kinds = set()
             for b in cur:
                 if b.alg != CRUSH_BUCKET_STRAW2:
-                    raise ValueError("fused path requires straw2")
+                    raise FusedUnsupported(
+                        "bucket_alg", "fused path requires straw2")
+                if not b.items:
+                    # mapper.c rejects a choice that lands in one; a
+                    # table row has to point somewhere
+                    raise FusedUnsupported(
+                        "empty_bucket", "empty bucket under the root")
                 for i in b.items:
                     kinds.add(i < 0)
             if kinds == {True}:
                 levels.append([crush_map.buckets.get(i)
                                for b in cur for i in b.items])
                 if any(b is None for b in levels[-1]):
-                    raise ValueError("dangling bucket reference")
+                    raise FusedUnsupported(
+                        "dangling", "dangling bucket reference")
             elif kinds == {False}:
                 break                   # this level's items are osds
             else:
-                raise ValueError("mixed osd/bucket children "
-                                 "unsupported by the fused path")
+                raise FusedUnsupported(
+                    "mixed_children", "mixed osd/bucket children "
+                    "unsupported by the fused path")
         # dense row index per bucket id per level
         idx_of = [{b.id: j for j, b in enumerate(lv)} for lv in levels]
         child_ids, child_idx, weights, cw, bids = [], [], [], [], []
@@ -424,8 +455,38 @@ def _first_lanes(left, width):
     return jnp.where(first >= L, first - np.int32(L), first)
 
 
+# device programs traced so far (a jit cache miss of crush_firstn or
+# crush_indep): map_pgs reads it around a launch
+_programs_traced = 0
+
+
+class Structure(NamedTuple):
+    """What decides a mapper's device program besides ``numrep``, the
+    lane count and the shapes of its tables (buckets and items a level,
+    weight-set positions): the depth, the rule's mode and tries with the
+    tunables folded in (jewel's vary_r and stable are all the fused path
+    takes), and the two retry constants as they stood when the mapper
+    was made.  The static part of the ``VectorCrush`` pytree: compared
+    by value, so maps of one structure share an executable whatever
+    their weights."""
+
+    n_levels: int
+    firstn: bool
+    leaf: bool
+    choose_tries: int
+    recurse_tries: int
+    retry_min_lanes: int
+    retry_narrow: int
+
+
+@jax.tree_util.register_pytree_node_class
 class VectorCrush:
-    """Bulk mapper for one (map, rule) pair, any uniform depth."""
+    """Bulk mapper for one (map, rule) pair, any uniform depth: the
+    map's level tables on the device (uploaded once, here) and the
+    ``Structure`` of the program they are operands of.  As an argument
+    of ``crush_firstn`` / ``crush_indep`` the mapper flattens to its
+    tables; nothing of a bucket's weights reaches the program any other
+    way."""
 
     def __init__(self, crush_map: CrushMap, ruleno: int,
                  choose_args: dict | None = None) -> None:
@@ -434,53 +495,66 @@ class VectorCrush:
         self.cm = CompiledMap.from_map(crush_map, root_id, choose_args)
         # chooseleaf picks buckets at the LAST bucket level then
         # recurses to an osd; plain choose must name the device level
-        self.leaf = leaf
         if leaf:
             # only the tree under THIS rule's take root matters: a
             # second hierarchy's leaf parents must not veto the map
             if self.cm.leaf_parent_types != {choose_type}:
-                raise ValueError(
-                    "chooseleaf type must be the osd-parent level for "
-                    "the fused path")
+                raise FusedUnsupported(
+                    "leaf_type", "chooseleaf type must be the "
+                    "osd-parent level for the fused path")
         elif choose_type != 0:
-            raise ValueError("plain choose of a bucket type needs the "
-                             "scalar engine")
+            raise FusedUnsupported(
+                "choose_type", "plain choose of a bucket type needs "
+                "the scalar engine")
         t = crush_map.tunables
-        self.firstn = firstn
-        self.choose_tries = choose_tries
-        self.leaf_tries = leaf_tries
-        self.vary_r = t.chooseleaf_vary_r
-        self.stable = t.chooseleaf_stable
-        self.descend_once = t.chooseleaf_descend_once
-        if firstn:
-            self.recurse_tries = (leaf_tries if leaf_tries
-                                  else (1 if self.descend_once
-                                        else choose_tries))
-        else:
-            self.recurse_tries = leaf_tries if leaf_tries else 1
-        if not self.stable or self.vary_r != 1:
+        if not t.chooseleaf_stable or t.chooseleaf_vary_r != 1:
             # scalar fallback covers other tunable profiles
-            raise ValueError("fused path implements jewel tunables")
-        # running totals of map_pgs: device launches, and crush_firstn's
-        # two counts: lanes finished by a narrow retry loop, full-width
-        # passes after a replica's first
+            raise FusedUnsupported(
+                "tunables", "fused path implements jewel tunables")
+        if firstn:
+            recurse_tries = (leaf_tries if leaf_tries
+                             else (1 if t.chooseleaf_descend_once
+                                   else choose_tries))
+        else:
+            recurse_tries = leaf_tries if leaf_tries else 1
+        self.structure = Structure(
+            self.cm.n_levels, firstn, leaf, choose_tries, recurse_tries,
+            RETRY_MIN_LANES, RETRY_NARROW)
+        self.tables = self._tables()
+        # running totals of map_pgs: device launches; crush_firstn's two
+        # counts (lanes finished by a narrow retry loop, full-width
+        # passes after a replica's first); the passes crush_indep's loop
+        # made over every slot of every lane.  Apart from them: launches
+        # that had to trace their program first
         self.launches = self.retry_lanes = self.wide_retries = 0
+        self.indep_passes = self.programs_built = 0
 
-    def _tables(self):
-        """Per level one int32 table (4 * N, P, B), all that a choice
-        in a bucket needs: every item's hashed id, its weight at each
-        weight-set position, the bits of that weight's straw2_recip,
-        and its child (a row of the next level, or the osd).  Items
-        lead, so that one gather of a lane's bucket row lands all four
-        as (n, lanes)."""
+    def tree_flatten(self):
+        return self.tables, self.structure
+
+    @classmethod
+    def tree_unflatten(cls, structure, tables):
+        """The mapper a traced program sees: tables and structure,
+        no map and no totals."""
+        vc = object.__new__(cls)
+        vc.structure, vc.tables = structure, tuple(tables)
+        return vc
+
+    def _tables(self) -> tuple:
+        """Per level one int32 table (4 * N, P, B) on the device, all
+        that a choice in a bucket needs: every item's hashed id, its
+        weight at each weight-set position, the bits of that weight's
+        straw2_recip (built here, on the host), and its child (a row of
+        the next level, or the osd).  Items lead, so that one gather of
+        a lane's bucket row lands all four as (n, lanes)."""
         cm = self.cm
         per_pos = cm.cw if cm.cw is not None else \
             [t[None] for t in cm.weights]                # (P, B, N)
-        return [jnp.asarray(np.concatenate(
+        return tuple(jnp.asarray(np.concatenate(
             [np.broadcast_to(ids, w.shape), w,
              straw2_recip(w).view(np.int32), np.broadcast_to(idx, w.shape)],
             axis=2).transpose(2, 0, 1))
-            for ids, idx, w in zip(cm.child_ids, cm.child_idx, per_pos)]
+            for ids, idx, w in zip(cm.child_ids, cm.child_idx, per_pos))
 
     def _choose(self, tables, lvl, xs, cur, r, pos):
         """One straw2 choice per lane in bucket row ``cur`` of level
@@ -512,7 +586,7 @@ class VectorCrush:
         """chooseleaf recursion into the chosen last-level bucket:
         up to recurse_tries draws, rejecting out osds and (firstn)
         collisions with already-placed osds."""
-        lvl = self.cm.n_levels - 1
+        lvl = self.structure.n_levels - 1
         L = xs.shape[0]
 
         def cond(st):
@@ -520,11 +594,11 @@ class VectorCrush:
             # one shared try counter: a still-searching lane's personal
             # ftotal equals the iteration count (it either found and
             # froze, or rejected every round so far)
-            return jnp.any(~found) & (ft < self.recurse_tries)
+            return jnp.any(~found) & (ft < self.structure.recurse_tries)
 
         def body(st):
             ft, found, osd = st
-            if self.firstn:
+            if self.structure.firstn:
                 # leaf recursion: numrep=1, rep'=0 (stable), so
                 # r_leaf = sub_r + ftotal_leaf
                 r_leaf = (sub_r + ft).astype(jnp.int32)
@@ -562,11 +636,11 @@ class VectorCrush:
         # leaf recursion) targets the device level
         cand_sel = self._descend(
             tables, xs, r, placed,
-            self.cm.n_levels - 1 if self.leaf else self.cm.n_levels)
+            self.structure.n_levels - (1 if self.structure.leaf else 0))
         collide = jnp.zeros(xs.shape, bool)
         for prev in out_sel:
             collide |= prev == cand_sel
-        if self.leaf:
+        if self.structure.leaf:
             # vary_r=1: sub_r = r >> 0 = r
             cand_osd, found = self._leaf_descend(
                 tables, xs, cand_sel, r, rep, numrep, osd_weights, out,
@@ -581,18 +655,21 @@ class VectorCrush:
         return (jnp.where(done | ok, ftotal, ftotal + 1),
                 jnp.where(ok, cand_sel, sel), jnp.where(ok, cand_osd, osd))
 
-    @partial(jax.jit, static_argnames=("self", "numrep"))
+    @partial(jax.jit, static_argnames=("numrep",))
     def crush_firstn(self, xs: jnp.ndarray, numrep: int,
                      osd_weights: jnp.ndarray):
         """(osd ids (lanes, numrep), int32 [lanes finished by a narrow
         retry loop, full-width passes after a replica's first])."""
-        tables = self._tables()
+        global _programs_traced
+        _programs_traced += 1
+        tables, shape = self.tables, self.structure
         L = xs.shape[0]
-        width = L // RETRY_NARROW if L >= RETRY_MIN_LANES else 0
+        width = (L // shape.retry_narrow if L >= shape.retry_min_lanes
+                 else 0)
 
         def left(state):
             ftotal, _, osd = state
-            return (osd == _NONE) & (ftotal < self.choose_tries)
+            return (osd == _NONE) & (ftotal < self.structure.choose_tries)
 
         def tries(one_try, lanes, state, fit):
             """Passes of ``one_try`` until at most ``fit`` lanes are
@@ -652,18 +729,22 @@ class VectorCrush:
         return ids, jnp.stack([retry_lanes, wide_retries])
 
     # -- indep --------------------------------------------------------------
-    @partial(jax.jit, static_argnames=("self", "numrep"))
+    @partial(jax.jit, static_argnames=("numrep",))
     def crush_indep(self, xs: jnp.ndarray, numrep: int,
                     osd_weights: jnp.ndarray):
-        cm = self.cm
-        tables = self._tables()
+        """(osd ids (lanes, numrep), ``_NONE`` at its position where a
+        slot stayed unfilled; int32 [passes the loop made over every
+        slot of every lane, 0])."""
+        global _programs_traced
+        _programs_traced += 1
+        tables, shape = self.tables, self.structure
         L = xs.shape[0]
         UNDEF = jnp.int32(0x7FFFFFFE)
-        bucket_levels = cm.n_levels - 1 if self.leaf else cm.n_levels
+        bucket_levels = shape.n_levels - (1 if shape.leaf else 0)
 
         def cond(state):
             ftotal, out_h, _ = state
-            return (ftotal < self.choose_tries) & \
+            return (ftotal < shape.choose_tries) & \
                 jnp.any(jnp.stack(out_h) == UNDEF)
 
         def body(state):
@@ -681,7 +762,7 @@ class VectorCrush:
                 collide = jnp.zeros((L,), bool)
                 for col in out_h:
                     collide |= col == cand_sel
-                if self.leaf:
+                if shape.leaf:
                     osd, found = self._leaf_descend(
                         tables, xs, cand_sel, r, rep, numrep,
                         osd_weights, None, rep)
@@ -694,20 +775,22 @@ class VectorCrush:
             return ftotal + 1, tuple(out_h), tuple(out_o)
 
         undef = (jnp.full((L,), UNDEF, jnp.int32),) * numrep
-        _, _, out_o = jax.lax.while_loop(cond, body,
-                                         (jnp.int32(0), undef, undef))
+        # metadata only, as crush_retry: the one loop over every slot of
+        # every lane, which is all of the program but its last select
+        with jax.named_scope("crush_indep"):
+            passes, _, out_o = jax.lax.while_loop(
+                cond, body, (jnp.int32(0), undef, undef))
         out_o = jnp.stack(out_o, axis=1)
-        # one loop over every slot of every lane: no retry of its own
-        # to count (crush_firstn's second result)
         return (jnp.where(out_o == UNDEF, _NONE, out_o),
-                jnp.zeros((2,), jnp.int32))
+                jnp.stack([passes, jnp.int32(0)]))
 
     def totals(self) -> dict[str, int]:
         """The running totals under their names in the monitor's
         ``placement_cache`` set (mon/pg_mapping.py)."""
         return {"fused_launches": self.launches,
                 "retry_lanes": self.retry_lanes,
-                "wide_retries": self.wide_retries}
+                "wide_retries": self.wide_retries,
+                "indep_passes": self.indep_passes}
 
     def map_pgs(self, xs, numrep: int, osd_weights) -> np.ndarray:
         """Map every placement seed in ``xs``: (len(xs), numrep) osd
@@ -715,7 +798,7 @@ class VectorCrush:
         (longer inputs run as equal-sized launches, the tail padded),
         which bounds device memory and the number of compiled shapes
         whatever size a caller hands in."""
-        fn = self.crush_firstn if self.firstn else self.crush_indep
+        fn = self.crush_firstn if self.structure.firstn else self.crush_indep
         w = jnp.asarray(osd_weights, jnp.int32)
         # lint: disable=device-path-host-sync -- host-side input marshal of the seeds, no device array involved
         xs = np.asarray(xs).astype(np.int32)
@@ -728,14 +811,19 @@ class VectorCrush:
             ).reshape(-1, MAX_LANES)
         out = []
         for part in parts:
+            traced = _programs_traced
             # the calling thread, from the launch until its result is
             # on the host
             with section("device_wait.crush"):
                 # lint: disable=device-path-host-sync -- one materialization per bounded launch of the bulk map
-                ids, (lanes, wide) = jax.device_get(
+                ids, (first, second) = jax.device_get(
                     fn(jnp.asarray(part), numrep, w))
             out.append(ids)
             self.launches += 1
-            self.retry_lanes += int(lanes)
-            self.wide_retries += int(wide)
+            self.programs_built += _programs_traced - traced
+            if self.structure.firstn:
+                self.retry_lanes += int(first)
+                self.wide_retries += int(second)
+            else:
+                self.indep_passes += int(first)
         return np.concatenate(out)[:n]

@@ -23,7 +23,9 @@ from ..msg import Message, Messenger
 from ..crush.types import (
     Bucket, CrushMap, CRUSH_BUCKET_STRAW2,
 )
-from ..crush.builder import replicated_rule, erasure_rule
+from ..crush.builder import (
+    CRUSH_COMMANDS, crush_command, erasure_rule, replicated_rule,
+)
 from ..ec import registry as ec_registry
 from .osdmap import (
     OSDMap, Incremental, PoolSpec, crush_to_dict,
@@ -618,7 +620,14 @@ class Monitor:
         inc.new_uuids[osd_id] = uuid
         inc.new_hosts[osd_id] = host
         inc.new_max_osd = max(self.osdmap.max_osd, osd_id + 1)
-        inc.new_crush = self._build_crush_dict(extra_osd=(osd_id, host))
+        # an OSD that comes back where the map has it leaves the map as
+        # the operator's ``osd crush`` commands made it (upstream's
+        # osd_crush_update_on_start moves an OSD only when its location
+        # changed); a new OSD, or one on another host, rebuilds it
+        crush = self.osdmap.crush
+        if not any(crush.bucket_names.get(b.id) == host
+                   for b, _ in crush.holders(osd_id)):
+            inc.new_crush = self._build_crush_dict(extra_osd=(osd_id, host))
         await self.propose(inc)
         await conn.send(Message(
             "osd_boot_ack",
@@ -1253,6 +1262,15 @@ class Monitor:
             inc.new_weights[int(args["osd_id"])] = int(args["weight"])
             await self.propose(inc)
             return True
+        if cmd in CRUSH_COMMANDS:
+            # add-bucket, move, add, reweight, reweight-subtree: a new
+            # CRUSH map (every ancestor the sum of its children), so an
+            # epoch at which every consumer rebuilds its whole table
+            cm = crush_command(self.osdmap.crush, cmd, args)
+            inc = Incremental(epoch=0)
+            inc.new_crush = crush_to_dict(cm)
+            await self.propose(inc)
+            return {"epoch": self.osdmap.epoch}
         if cmd == "osd pool selfmanaged-snap create":
             # serialize allocation: two concurrent creates reading the
             # same snap_seq would hand out one id twice
@@ -1383,19 +1401,34 @@ class Monitor:
         return pid
 
     def _cmd_osd_tree(self):
-        tree = []
-        hosts = defaultdict(list)
-        for osd, info in self.osdmap.osds.items():
-            if info.host:
-                hosts[info.host].append(osd)
-        for host in sorted(hosts):
-            tree.append({"type": "host", "name": host})
-            for osd in sorted(hosts[host]):
-                info = self.osdmap.osds.get(osd)
-                tree.append({"type": "osd", "id": osd,
+        """The CRUSH hierarchy, depth first from every root: a row per
+        bucket (type, name, id, crush_weight, depth) and per OSD under
+        it (id, up, in, the reweight as ``weight``, crush_weight,
+        depth)."""
+        cm = self.osdmap.crush
+        held = {i for b in cm.buckets.values() for i in b.items}
+        tree: list[dict] = []
+
+        def walk(item: int, crush_weight: int, depth: int) -> None:
+            if item >= 0:
+                info = self.osdmap.osds.get(item)
+                tree.append({"type": "osd", "id": item,
                              "up": bool(info and info.up),
                              "in": bool(info and info.in_cluster),
-                             "weight": info.weight if info else 0})
+                             "weight": info.weight if info else 0,
+                             "crush_weight": crush_weight, "depth": depth})
+                return
+            b = cm.buckets[item]
+            tree.append({"type": cm.type_names.get(b.type, str(b.type)),
+                         "name": cm.bucket_names.get(item, str(item)),
+                         "id": item, "crush_weight": crush_weight,
+                         "depth": depth})
+            for child, w in zip(b.items, b.item_weights):
+                walk(child, w, depth + 1)
+
+        for root in sorted((b for b in cm.buckets if b not in held),
+                           reverse=True):
+            walk(root, cm.buckets[root].weight, 0)
         return tree
 
     # -- ticking (down->out aging) -----------------------------------------

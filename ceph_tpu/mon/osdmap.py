@@ -13,6 +13,7 @@ from __future__ import annotations
 from dataclasses import dataclass, field, asdict
 from typing import Any
 
+from ..common.tracing import section
 from ..crush import (
     CrushMap, crush_do_rule, ceph_str_hash_rjenkins, crush_hash32_2,
 )
@@ -219,6 +220,8 @@ def crush_to_dict(cm: CrushMap) -> dict:
         ],
         "tunables": asdict(cm.tunables),
         "max_devices": cm.max_devices,
+        # ``osd crush add-bucket <name> <type>`` names a type
+        "type_names": {str(t): n for t, n in cm.type_names.items()},
     }
 
 
@@ -233,6 +236,8 @@ def crush_from_dict(d: dict) -> CrushMap:
         cm.add_rule(Rule(rule_id=rd["rule_id"], type=rd["type"],
                          steps=[RuleStep(*s) for s in rd["steps"]]))
     cm.max_devices = max(cm.max_devices, d.get("max_devices", 0))
+    if "type_names" in d:
+        cm.type_names = {int(t): n for t, n in d["type_names"].items()}
     return cm
 
 
@@ -315,9 +320,13 @@ class OSDMap:
     # -- placement cache ----------------------------------------------------
     @property
     def placement_perf(self):
-        """This map's 'placement_cache' counter set (bulk_recomputes,
-        fused/scalar pools, fused_launches with their retry_lanes and
-        wide_retries, recompute time, lookups, delta_pgs).
+        """This map's 'placement_cache' counter set: bulk_recomputes,
+        fused/scalar pools, fused_launches with their retry_lanes,
+        wide_retries, indep_passes and programs_built (launches that
+        traced their program first: 0 across weight-only epochs),
+        fused_declined (and fused_declined_<reason>), the time of a
+        recompute and of its stages (launch, ingest) and of a delta,
+        lookups, delta_pgs.
         Daemons adopt it into their PerfCountersCollection so `perf
         dump` and the chaos driver see it."""
         if self._placement_perf is None:
@@ -447,6 +456,10 @@ class OSDMap:
 
     # -- mutation -----------------------------------------------------------
     def apply_incremental(self, inc: Incremental) -> None:
+        with section("placement.apply"):
+            self._apply(inc)
+
+    def _apply(self, inc: Incremental) -> None:
         assert inc.epoch == self.epoch + 1, (inc.epoch, self.epoch)
         self.epoch = inc.epoch
         if inc.new_max_osd is not None:

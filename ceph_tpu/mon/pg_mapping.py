@@ -26,67 +26,60 @@ import time
 
 import numpy as np
 
+from ..common.tracing import section
 from ..crush import crush_do_rule
 from ..crush.hashes import crush_hash32_2_np
 from ..crush.types import CRUSH_ITEM_NONE
 
-# below this many lanes (sum of pg_num over same-rule pools) the fused
-# JAX path is not worth its trace/compile cost -- the scalar sweep wins
-# on the small maps unit tests and the chaos smoke run.  Large maps
-# (the bench, real clusters) clear it easily.
+# below this many lanes (a pool's pg_num) the fused JAX path is not
+# worth its trace/compile cost -- the scalar sweep wins on the small
+# maps unit tests and the chaos smoke run.  Large maps (the bench, real
+# clusters) clear it easily.
 FUSED_MIN_LANES = int(os.environ.get("CEPH_TPU_PLACEMENT_FUSED_MIN",
                                      "2048"))
 
-
-# structurally-identical maps share ONE compiled instance process-wide
-# (bounded: stale structures age out).  An in-process cluster runs one
-# CrushMap object PER DAEMON, all deserialized from the same mon map;
-# without structural sharing each of 64 OSDs would pay its own
-# multi-second jit compile for byte-identical hierarchies.
-_VC_SHARED: dict[tuple, object] = {}
-_VC_SHARED_MAX = 8
+# (structure key, rule) pairs this process has launched fused: the
+# device program is jit's, keyed by a map's structure and not by its
+# weights (crush/vectorized.py), so a map of a launched structure --
+# the next weight step of an expansion, another daemon's copy of the
+# same map -- finds its executable there
+_FUSED_WARM: set[tuple[int, int]] = set()
 
 
-def _crush_digest(crush_map) -> str:
-    """Structural fingerprint of a CrushMap (buckets/rules/tunables/
-    choose_args), cached on the object (maps are replaced wholesale on
-    change, never mutated in place)."""
-    dig = crush_map.__dict__.get("_structure_digest")
-    if dig is None:
-        import hashlib
-        import json as _json
-        from .osdmap import crush_to_dict
-        # choose_args are baked into the compiled instance
-        # (CompiledMap.from_map falls back to map.choose_args) but are
-        # NOT part of crush_to_dict -- digest them explicitly
-        blob = _json.dumps(
-            {"crush": crush_to_dict(crush_map),
-             "choose_args": getattr(crush_map, "choose_args", None)},
-            sort_keys=True, default=str)
-        dig = hashlib.sha256(blob.encode()).hexdigest()
-        crush_map.__dict__["_structure_digest"] = dig
-    return dig
+def _structure_key(crush_map) -> int:
+    """What of a CrushMap decides the fused program and nothing of its
+    weights: buckets with their items, rules, tunables, the weight-set
+    positions.  A hash, cached on the object (maps are replaced
+    wholesale on change, never mutated in place)."""
+    key = crush_map.__dict__.get("_structure_key")
+    if key is None:
+        ca = getattr(crush_map, "choose_args", None) or {}
+        key = hash((
+            tuple((b.id, b.type, b.alg, tuple(b.items))
+                  for b in crush_map.buckets.values()),
+            tuple((r.rule_id, tuple((s.op, s.arg1, s.arg2)
+                                    for s in r.steps))
+                  for r in crush_map.rules.values()),
+            tuple(vars(crush_map.tunables).values()),
+            tuple((bid, len(arg.get("weight_set") or ()))
+                  for bid, arg in ca.items())))
+        crush_map.__dict__["_structure_key"] = key
+    return key
 
 
 def _vector_crush_for(crush_map, ruleno: int):
-    """Compiled VectorCrush for a (map, rule), shared two ways: per
-    CrushMap object (the jit stays warm across weight-only epochs),
-    and across structurally-identical maps process-wide (every daemon
-    of an in-process cluster deserializes its own copy of the same
-    map; one compile serves them all)."""
+    """This map's VectorCrush for a rule, kept on the CrushMap object:
+    its level tables, uploaded once.  A new map (every ``osd crush
+    reweight`` makes one) builds its own; the executable it launches is
+    shared process-wide by structure.  Only ``osd reweight`` / ``out``
+    leave the map object alone: they travel in the launch's
+    ``osd_weights`` operand."""
     cache = crush_map.__dict__.setdefault("_vc_cache", {})
     ca = getattr(crush_map, "choose_args", None)
     key = (ruleno, id(ca) if ca else None)
     if key not in cache:
-        shared_key = (_crush_digest(crush_map), ruleno)
-        vc = _VC_SHARED.get(shared_key)
-        if vc is None:
-            from ..crush.vectorized import VectorCrush
-            vc = VectorCrush(crush_map, ruleno)
-            while len(_VC_SHARED) >= _VC_SHARED_MAX:
-                _VC_SHARED.pop(next(iter(_VC_SHARED)))
-            _VC_SHARED[shared_key] = vc
-        cache[key] = vc
+        from ..crush.vectorized import VectorCrush
+        cache[key] = VectorCrush(crush_map, ruleno)
     return cache[key]
 
 
@@ -102,35 +95,46 @@ def bulk_crush(crush_map, ruleno: int, xs, numrep: int, weights,
     it (raising if the shape cannot compile); 'never' is the pure
     scalar sweep.  crushtool --test and the placement cache both ride
     this helper so offline what-ifs exercise the exact production path.
-    ``perf`` takes what the fused launches add to the mapper's running
-    totals: ``fused_launches``, ``retry_lanes``, ``wide_retries``.
+    ``perf`` takes the fused call's time (``launch``: tables and seeds
+    up, ids on the host), what it added to the mapper's running totals
+    (``VectorCrush.totals``), ``programs_built`` (launches that traced
+    their program first) and, where the engine declined the map,
+    ``fused_declined`` and ``fused_declined_<reason>``.
     """
+    from ..crush.vectorized import FusedUnsupported
+
     xs = np.asarray(xs, dtype=np.int64)
     lanes = int(xs.shape[0])
     threshold = FUSED_MIN_LANES if min_lanes is None else min_lanes
-    # a WARM VectorCrush for this (map, rule) makes the fused launch
-    # all but free -- the threshold only guards the one-time
-    # trace/compile cost, so it does not apply once that cost is sunk
-    # (the epoch-recompute path hits the same map object dozens of
-    # times during peering/recovery churn on a big cluster)
-    ca = getattr(crush_map, "choose_args", None)
-    warm = ((ruleno, id(ca) if ca else None)
-            in crush_map.__dict__.get("_vc_cache", {})
-            or (_crush_digest(crush_map), ruleno) in _VC_SHARED)
+    # a launched structure makes the fused launch all but free -- the
+    # threshold only guards the one-time trace/compile cost, so it does
+    # not apply once that cost is sunk (the epoch-recompute path hits
+    # one structure dozens of times during an expansion's weight steps
+    # and the peering churn after each)
+    warm = (_structure_key(crush_map), ruleno)
     if fused == "always" or (fused == "auto"
-                             and (warm or lanes >= threshold)):
+                             and (lanes >= threshold
+                                  or warm in _FUSED_WARM)):
         try:
-            vc = _vector_crush_for(crush_map, ruleno)
-            before = vc.totals()
-            rows = np.asarray(vc.map_pgs(xs, numrep, list(weights)),
-                              dtype=np.int64)
+            t0 = time.perf_counter()
+            with section("placement.launch"):
+                vc = _vector_crush_for(crush_map, ruleno)
+                before, built = vc.totals(), vc.programs_built
+                rows = np.asarray(vc.map_pgs(xs, numrep, list(weights)),
+                                  dtype=np.int64)
+            _FUSED_WARM.add(warm)
             if perf is not None:
+                perf.tinc("launch", time.perf_counter() - t0)
                 for name, total in vc.totals().items():
                     perf.inc(name, total - before[name])
+                perf.inc("programs_built", vc.programs_built - built)
             return rows, True
-        except ValueError:
+        except FusedUnsupported as e:
             if fused == "always":
                 raise
+            if perf is not None:
+                perf.inc("fused_declined")
+                perf.inc(f"fused_declined_{e.reason}")
     rows = np.full((lanes, numrep), CRUSH_ITEM_NONE, dtype=np.int64)
     for i, x in enumerate(xs):
         got = crush_do_rule(crush_map, ruleno, int(x), numrep,
@@ -185,8 +189,10 @@ class PGMapping:
         for o, info in osdmap.osds.items():
             if info.up and o < n:
                 live[o] = True
+        ingest = 0.0
         for pool_id, pool in osdmap.pools.items():
-            pps = pool_pps(pool)
+            with section("placement.pps"):
+                pps = pool_pps(pool)
             rows, used_fused = bulk_crush(
                 osdmap.crush, pool.crush_rule, pps, pool.size, weights,
                 fused=fused, min_lanes=min_lanes, perf=perf)
@@ -194,9 +200,13 @@ class PGMapping:
                 pm.fused_pools += 1
             else:
                 pm.scalar_pools += 1
-            pm._ingest_pool(osdmap, pool_id, pool, rows, live)
+            t1 = time.perf_counter()
+            with section("placement.ingest"):
+                pm._ingest_pool(osdmap, pool_id, pool, rows, live)
+            ingest += time.perf_counter() - t1
         dt = time.perf_counter() - t0
         if perf is not None:
+            perf.tinc("ingest", ingest)
             perf.inc("bulk_recomputes")
             perf.inc("fused_pools", pm.fused_pools)
             perf.inc("scalar_pools", pm.scalar_pools)
@@ -212,7 +222,10 @@ class PGMapping:
         """Raw CRUSH rows -> up/acting lists with the full OSDMap
         semantics applied in bulk (OSDMap.cc _apply_upmap,
         _raw_to_up_osds, pg_temp), vectorized where the data is dense
-        and per-entry only for the sparse override dicts."""
+        (the rows leave numpy as lists in one ``tolist``: a Python
+        loop over 24,576 rows cost an epoch 81-86 ms on the chip's
+        host, PERF.md section 6, PR 41) and per-entry only for the
+        sparse override dicts."""
         n_live = live.shape[0]
         # upmap rewrite first (it edits the RAW result): sparse dict,
         # touch only the pgs that carry items
@@ -234,11 +247,16 @@ class PGMapping:
         ok = np.zeros_like(valid)
         ok[valid] = live[rows[valid]]
         if pool.can_shift_osds():
-            up = [[int(o) for o in row[okr]]
-                  for row, okr in zip(rows, ok)]
+            # survivors first and in order (a stable sort on "dropped");
+            # the rows that lost one are cut to what they kept
+            kept = ok.sum(axis=1)
+            up = np.take_along_axis(
+                rows, np.argsort(~ok, axis=1, kind="stable"),
+                axis=1).tolist()
+            for pg in np.flatnonzero(kept < rows.shape[1]).tolist():
+                up[pg] = up[pg][:kept[pg]]
         else:
-            filt = np.where(ok, rows, -1)
-            up = [[int(o) for o in row] for row in filt]
+            up = np.where(ok, rows, -1).tolist()
         acting = list(up)           # shared rows until pg_temp overrides
         for pgid, temp in osdmap.pg_temp.items():
             if not pgid.startswith(prefix):
@@ -295,6 +313,15 @@ class PGMapping:
             # placement-neutral epochs (up_thru/blocklist-only) carry
             # the table object across generations: nothing moved
             return []
+        t0 = time.perf_counter()
+        with section("placement.delta"):
+            changed = self._diff(prev)
+        if perf is not None:
+            perf.tinc("delta", time.perf_counter() - t0)
+            perf.inc("delta_pgs", len(changed))
+        return changed
+
+    def _diff(self, prev: "PGMapping") -> list[tuple[int, int]]:
         changed: list[tuple[int, int]] = []
         pools = set(self._up) | set(prev._up)
         for pool_id in sorted(pools):
@@ -312,6 +339,4 @@ class PGMapping:
                         or cur_u[pg] != old_u[pg]
                         or cur_a[pg] != old_a[pg]):
                     changed.append((pool_id, pg))
-        if perf is not None:
-            perf.inc("delta_pgs", len(changed))
         return changed

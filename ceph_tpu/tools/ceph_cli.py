@@ -137,8 +137,10 @@ def _render(cmd: str, result) -> None:
     elif cmd == "osd tree":
         print(f"{'ID':>4} {'TYPE':<6} {'NAME':<12} {'STATUS':<8} WEIGHT")
         for row in result:
-            if row["type"] == "host":
-                print(f"{'':>4} {'host':<6} {row['name']:<12}")
+            if row["type"] != "osd":
+                print(f"{row['id']:>4} {row['type']:<6} "
+                      f"{'  ' * row['depth']}{row['name']:<12} {'':<8} "
+                      f"{row['crush_weight']/65536:.4f}")
             else:
                 status = "up" if row["up"] else "down"
                 print(f"{row['id']:>4} {'osd':<6} osd.{row['id']:<8} "

@@ -8,9 +8,10 @@ an exact quotient by the item weight and a lexicographic argmin, all in
 32-bit limbs (the TPU has no 64-bit integers, and the program holds no
 64-bit type whether or not an embedding process runs jax with x64 on);
 the firstn/indep retry loops become bounded `lax.while_loop`s with
-per-lane masks (firstn's, in a long launch, over the lanes still
-unplaced alone: RETRY_MIN_LANES) -- decision-identical to the scalar
-mapper (ceph_tpu/crush/mapper.py), which is itself pinned to mapper.c.
+per-lane masks (in a long launch over what is still unplaced alone,
+firstn's lanes and indep's (lane, slot) pairs: RETRY_MIN_LANES) --
+decision-identical to the scalar mapper (ceph_tpu/crush/mapper.py),
+which is itself pinned to mapper.c.
 
 A map's bucket tables (hashed ids, weights, reciprocal bits, children)
 are OPERANDS of the device program: a ``VectorCrush`` is a pytree whose
@@ -86,6 +87,10 @@ MAX_LANES = 1 << 17
 # full-width loop: at 4,096 lanes the narrow stage still saves a fifth
 # of a call on the chip (PERF.md section 6, PR 37); a few dozen narrow
 # lanes save nothing and are a second loop body to compile.
+# crush_indep takes both for its unit, the (lane, slot) pair: full-width
+# passes over every pair until those still undefined fit
+# numrep * lanes // RETRY_NARROW (eleven slots over a hundred hosts
+# leave a twentieth after the first), then these alone (PR 42).
 RETRY_MIN_LANES = 1 << 12
 RETRY_NARROW = 16
 
@@ -521,13 +526,16 @@ class VectorCrush:
             self.cm.n_levels, firstn, leaf, choose_tries, recurse_tries,
             RETRY_MIN_LANES, RETRY_NARROW)
         self.tables = self._tables()
-        # running totals of map_pgs: device launches; crush_firstn's two
-        # counts (lanes finished by a narrow retry loop, full-width
-        # passes after a replica's first); the passes crush_indep's loop
-        # made over every slot of every lane.  Apart from them: launches
-        # that had to trace their program first
+        # running totals of map_pgs: device launches; crush_firstn's
+        # lanes finished by a narrow retry loop; full-width passes after
+        # a replica's first (firstn) or a launch's first (indep, where
+        # the launch has a narrow stage); crush_indep's passes, at
+        # either width, and the (lane, slot) pairs handed to its narrow
+        # stage.  Apart from them: launches that had to trace their
+        # program first
         self.launches = self.retry_lanes = self.wide_retries = 0
-        self.indep_passes = self.programs_built = 0
+        self.indep_passes = self.indep_retry_pairs = 0
+        self.programs_built = 0
 
     def tree_flatten(self):
         return self.tables, self.structure
@@ -729,60 +737,124 @@ class VectorCrush:
         return ids, jnp.stack([retry_lanes, wide_retries])
 
     # -- indep --------------------------------------------------------------
+    def _indep_draw(self, tables, numrep, osd_weights, xs, rep, ftotal):
+        """The candidates of try ``ftotal``, one per row of ``xs``, for
+        slot ``rep`` (traced: a scalar, or a vector when the rows are
+        (lane, slot) pairs): (bucket, osd), the osd ``_NONE`` where the
+        leaf tries found none.  A function of the seed, the slot and
+        the try alone, of nothing placed so far (mapper.c hands the
+        leaf recursion one empty slot): whether a candidate is taken is
+        ``crush_indep``'s ``resolve``."""
+        shape = self.structure
+        r = rep + numrep * ftotal
+        # weight-set position is the top call's OUTPOS (0), not the
+        # replica slot (crush_choose_indep passes its own outpos down);
+        # the leaf recursion's outpos IS the slot, so _leaf_descend
+        # keeps rep
+        sel = self._descend(tables, xs, r, 0,
+                            shape.n_levels - (1 if shape.leaf else 0))
+        if shape.leaf:
+            osd, _ = self._leaf_descend(tables, xs, sel, r, rep, numrep,
+                                        osd_weights, None, rep)
+        else:
+            osd = jnp.where(is_out_jnp(osd_weights, sel, xs), _NONE, sel)
+        return sel, osd
+
     @partial(jax.jit, static_argnames=("numrep",))
     def crush_indep(self, xs: jnp.ndarray, numrep: int,
                     osd_weights: jnp.ndarray):
         """(osd ids (lanes, numrep), ``_NONE`` at its position where a
-        slot stayed unfilled; int32 [passes the loop made over every
-        slot of every lane, 0])."""
+        slot stayed unfilled; int32 [passes made (every try, at either
+        width), (lane, slot) pairs handed to the narrow stage,
+        full-width passes after the first of a launch that has one])."""
         global _programs_traced
         _programs_traced += 1
         tables, shape = self.tables, self.structure
         L = xs.shape[0]
         UNDEF = jnp.int32(0x7FFFFFFE)
-        bucket_levels = shape.n_levels - (1 if shape.leaf else 0)
+        pairs = numrep * L
+        width = (pairs // shape.retry_narrow if L >= shape.retry_min_lanes
+                 else 0)
+        draw = partial(self._indep_draw, tables, numrep, osd_weights)
 
-        def cond(state):
-            ftotal, out_h, _ = state
-            return (ftotal < shape.choose_tries) & \
-                jnp.any(jnp.stack(out_h) == UNDEF)
+        def undefined(out_h):
+            return jnp.stack(out_h) == UNDEF                # (numrep, L)
 
-        def body(state):
-            # one column per replica slot: chosen bucket, osd
+        def resolve(state, sel, osd):
+            """A pass's second half, at full width and in slot order:
+            slot ``rep`` takes its candidate (rows ``rep`` of ``sel``
+            and ``osd``) where it is undefined, an osd was found and no
+            column holds the bucket, the earlier slots' as this pass
+            left them.  Compares and selects alone."""
             ftotal, out_h, out_o = state
             out_h, out_o = list(out_h), list(out_o)
             for rep in range(numrep):
-                slot_undef = out_h[rep] == UNDEF
-                r = rep + numrep * ftotal
-                # weight-set position is the top call's OUTPOS (0),
-                # not the replica slot (crush_choose_indep passes its
-                # own outpos down); the leaf recursion's outpos IS the
-                # slot, so _leaf_descend keeps rep
-                cand_sel = self._descend(tables, xs, r, 0, bucket_levels)
                 collide = jnp.zeros((L,), bool)
                 for col in out_h:
-                    collide |= col == cand_sel
-                if shape.leaf:
-                    osd, found = self._leaf_descend(
-                        tables, xs, cand_sel, r, rep, numrep,
-                        osd_weights, None, rep)
-                else:
-                    osd = cand_sel
-                    found = ~is_out_jnp(osd_weights, osd, xs)
-                ok = slot_undef & ~collide & found
-                out_h[rep] = jnp.where(ok, cand_sel, out_h[rep])
-                out_o[rep] = jnp.where(ok, osd, out_o[rep])
+                    collide |= col == sel[rep]
+                ok = (out_h[rep] == UNDEF) & (osd[rep] != _NONE) & ~collide
+                out_h[rep] = jnp.where(ok, sel[rep], out_h[rep])
+                out_o[rep] = jnp.where(ok, osd[rep], out_o[rep])
             return ftotal + 1, tuple(out_h), tuple(out_o)
 
+        def tries(one_pass, state, fit):
+            """Passes until at most ``fit`` pairs are undefined."""
+            return jax.lax.while_loop(
+                lambda st: (st[0] < shape.choose_tries) & (jnp.sum(
+                    undefined(st[1]), dtype=jnp.int32) > fit),
+                one_pass, state)
+
+        def wide_pass(state):
+            """Every pair drawn, one body for all slots (``rep`` is
+            traced: the program's size and its compile time do not grow
+            with numrep).  A while_loop: a scan's body is lowered as a
+            function of its own, whose operations lose ``crush_indep``
+            from their names in the lowered text."""
+            def slot(carry):
+                rep, sel, osd = carry
+                s, o = draw(xs, rep, state[0])
+                return rep + 1, sel.at[rep].set(s), osd.at[rep].set(o)
+
+            none = jnp.zeros((numrep, L), jnp.int32)
+            _, sel, osd = jax.lax.while_loop(
+                lambda carry: carry[0] < numrep, slot,
+                (jnp.int32(0), none, none))
+            return resolve(state, sel, osd)
+
+        def narrow(state):
+            """The pairs still undefined (at most ``width``), drawn
+            alone until none is left or out of tries: compacted once,
+            a pair placed meanwhile is drawn on and ignored, as the pad
+            pairs are (real ones, placed before)."""
+            idx = _first_lanes(undefined(state[1]).reshape(-1), width)
+            rep, lane = jnp.divmod(idx, np.int32(L))
+            sub_xs = xs[lane]
+
+            def narrow_pass(st):
+                cand = jnp.stack(draw(sub_xs, rep, st[0]))
+                sel, osd = jnp.zeros((2, pairs), jnp.int32).at[:, idx].set(
+                    cand, unique_indices=True).reshape(2, numrep, L)
+                return resolve(st, sel, osd)
+
+            return tries(narrow_pass, state, 0)
+
         undef = (jnp.full((L,), UNDEF, jnp.int32),) * numrep
-        # metadata only, as crush_retry: the one loop over every slot of
-        # every lane, which is all of the program but its last select
+        retry_pairs = wide_retries = jnp.int32(0)
+        # metadata only, as crush_retry: the erasure rule's loops, which
+        # are all of the program but its last select
         with jax.named_scope("crush_indep"):
-            passes, _, out_o = jax.lax.while_loop(
-                cond, body, (jnp.int32(0), undef, undef))
+            state = tries(wide_pass, (jnp.int32(0), undef, undef), width)
+            if width:
+                wide_retries = jnp.maximum(state[0] - 1, 0)
+                with jax.named_scope("crush_retry"):
+                    retry_pairs = jnp.sum(undefined(state[1]),
+                                          dtype=jnp.int32)
+                    state = jax.lax.cond(retry_pairs > 0, narrow,
+                                         lambda st: st, state)
+        passes, _, out_o = state
         out_o = jnp.stack(out_o, axis=1)
         return (jnp.where(out_o == UNDEF, _NONE, out_o),
-                jnp.stack([passes, jnp.int32(0)]))
+                jnp.stack([passes, retry_pairs, wide_retries]))
 
     def totals(self) -> dict[str, int]:
         """The running totals under their names in the monitor's
@@ -790,7 +862,8 @@ class VectorCrush:
         return {"fused_launches": self.launches,
                 "retry_lanes": self.retry_lanes,
                 "wide_retries": self.wide_retries,
-                "indep_passes": self.indep_passes}
+                "indep_passes": self.indep_passes,
+                "indep_retry_pairs": self.indep_retry_pairs}
 
     def map_pgs(self, xs, numrep: int, osd_weights) -> np.ndarray:
         """Map every placement seed in ``xs``: (len(xs), numrep) osd
@@ -816,14 +889,17 @@ class VectorCrush:
             # on the host
             with section("device_wait.crush"):
                 # lint: disable=device-path-host-sync -- one materialization per bounded launch of the bulk map
-                ids, (first, second) = jax.device_get(
+                ids, counts = jax.device_get(
                     fn(jnp.asarray(part), numrep, w))
             out.append(ids)
             self.launches += 1
             self.programs_built += _programs_traced - traced
             if self.structure.firstn:
-                self.retry_lanes += int(first)
-                self.wide_retries += int(second)
+                retry_lanes, wide_retries = counts
+                self.retry_lanes += int(retry_lanes)
             else:
-                self.indep_passes += int(first)
+                passes, retry_pairs, wide_retries = counts
+                self.indep_passes += int(passes)
+                self.indep_retry_pairs += int(retry_pairs)
+            self.wide_retries += int(wide_retries)
         return np.concatenate(out)[:n]

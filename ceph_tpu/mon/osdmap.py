@@ -322,8 +322,12 @@ class OSDMap:
     def placement_perf(self):
         """This map's 'placement_cache' counter set: bulk_recomputes,
         fused/scalar pools, fused_launches with their retry_lanes,
-        wide_retries, indep_passes and programs_built (launches that
-        traced their program first: 0 across weight-only epochs),
+        wide_retries, indep_passes, indep_retry_pairs (the (lane, slot)
+        pairs an erasure rule's launches finished at the narrow width:
+        above 0 wherever the narrow stage engages, wide_retries saying
+        how often a first pass left more than it holds) and
+        programs_built (launches that traced their program first: 0
+        across weight-only epochs),
         fused_declined (and fused_declined_<reason>), the time of a
         recompute and of its stages (launch, ingest) and of a delta,
         lookups, delta_pgs.

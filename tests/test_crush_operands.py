@@ -129,12 +129,13 @@ def test_the_weights_reach_the_program_as_arguments_only():
     assert "262144" not in text
 
 
-def test_retry_constants_are_part_of_the_programs_key(monkeypatch):
+@pytest.mark.parametrize("ruleno", [0, 1], ids=["firstn", "indep"])
+def test_retry_constants_are_part_of_the_programs_key(ruleno, monkeypatch):
     cm = tree([3, 4])
-    wide = VectorCrush(cm, 0).structure
+    wide = VectorCrush(cm, ruleno).structure
     monkeypatch.setattr(V, "RETRY_MIN_LANES", 64)
     monkeypatch.setattr(V, "RETRY_NARROW", 4)
-    narrow = VectorCrush(cm, 0).structure
+    narrow = VectorCrush(cm, ruleno).structure
     assert (narrow.retry_min_lanes, narrow.retry_narrow) == (64, 4)
     assert narrow != wide and narrow._replace(
         retry_min_lanes=wide.retry_min_lanes,
@@ -147,12 +148,35 @@ def test_totals_count_indep_passes_and_leave_programs_built_apart():
     vc.map_pgs(np.arange(300), 3, [W] * 12)
     first = vc.totals()
     assert set(first) == {"fused_launches", "retry_lanes", "wide_retries",
-                          "indep_passes"}
+                          "indep_passes", "indep_retry_pairs"}
     assert first["fused_launches"] == 1 and first["indep_passes"] >= 1
     assert first["retry_lanes"] == first["wide_retries"] == 0
+    # 300 lanes are under the threshold: one loop, no narrow stage
+    assert first["indep_retry_pairs"] == 0
     vc.map_pgs(np.arange(300), 3, [W] * 12)
     assert vc.totals() == {k: 2 * v for k, v in first.items()}
     assert vc.programs_built <= 1
+
+
+def test_two_launches_with_a_narrow_stage_build_one_program(monkeypatch):
+    """The erasure rule's program of a launch over the threshold holds
+    both widths: maps of one structure launch it whatever their
+    weights, and each launch counts the pairs its narrow stage took."""
+    monkeypatch.setattr(V, "RETRY_MIN_LANES", 256)
+    monkeypatch.setattr(V, "RETRY_NARROW", 2)
+    cm = tree([3, 5, 2])                 # 30 osds; no other test's shape
+    weights = [W] * 30
+    weights[4] = 0
+    xs = np.arange(0, 256 * 17, 17)
+    built, pairs = [], []
+    for m in weight_steps(cm, 2):
+        vc = VectorCrush(m, 1)
+        got = vc.map_pgs(xs, 6, weights)
+        assert np.array_equal(got, scalar(m, 1, xs, 6, weights))
+        built.append(vc.programs_built)
+        pairs.append(vc.totals()["indep_retry_pairs"])
+    assert built == [1, 0]
+    assert min(pairs) > 0 and pairs[0] != pairs[1]
 
 
 @pytest.mark.parametrize("make,reason", [

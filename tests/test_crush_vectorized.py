@@ -289,14 +289,17 @@ def _primitives(jaxpr) -> set:
 
 
 @pytest.mark.parametrize("x64", [False, True], ids=["x64_off", "x64_on"])
-@pytest.mark.parametrize("ruleno,narrow", [(0, False), (1, False), (0, True)],
-                         ids=["firstn", "indep", "firstn_narrow"])
+@pytest.mark.parametrize("ruleno,narrow", [(0, False), (1, False), (0, True),
+                                           (1, True)],
+                         ids=["firstn", "indep", "firstn_narrow",
+                              "indep_narrow"])
 def test_no_64_bit_type_reaches_the_device(ruleno, narrow, x64, monkeypatch):
     """The mapper's program holds no 64-bit type whether an embedding
     process has jax's x64 flag on or off, maps like the scalar engine
     under both, and map_pgs never touches the flag; ``narrow``: the
-    program of a long launch, with the compaction of the lanes left,
-    their gather, the narrow loop and the scatter back."""
+    program of a long launch, with the compaction of the lanes (or, of
+    the erasure rule, the pairs) left, their gather, the narrow loop and
+    the scatter back."""
     import jax
     import jax.numpy as jnp
     import ceph_tpu.crush.vectorized as V
@@ -322,7 +325,7 @@ def test_no_64_bit_type_reaches_the_device(ruleno, narrow, x64, monkeypatch):
         # no way into the flag from the mapper
         monkeypatch.setattr(jax, "enable_x64", None)
         assert np.array_equal(vc.map_pgs(xs, 3, weights), want)
-    assert (vc.retry_lanes > 0) == narrow
+    assert (vc.retry_lanes + vc.indep_retry_pairs > 0) == narrow
 
 
 # -- the benchmark cell's own widths ----------------------------------------
@@ -462,3 +465,162 @@ def test_retry_at_the_width_of_what_is_left(case, monkeypatch):
     if tree == "exhausting":
         # slots were left unplaced, in lanes that placed a later one
         assert (got == CRUSH_ITEM_NONE).any()
+
+
+# -- the erasure rule's retry at the width of what is left ------------------
+
+def indep_tree(case: str):
+    """(map, ruleno, numrep, osd weights) under an indep rule: the
+    cell's tree at 11 slots (all weights in, or a third of the OSDs
+    out: the rule's five leaf tries fail in some hosts, so not only
+    collisions leave a pair undefined); nine hosts for 11 slots (two
+    slots of every lane stay undefined until the tries run out); a map
+    whose root and hosts carry weight-sets of two positions (the
+    descent draws at position 0, the leaf at the slot's); a flat map
+    under plain ``choose indep`` with OSDs out and reweighted."""
+    from ceph_tpu.crush.builder import (ROOT_ID, build_hierarchy,
+                                        erasure_rule)
+
+    if case in ("indep11", "third_out"):
+        cm, _, _, weights = cell_tree(case)
+        return cm, 1, 11, weights
+    if case == "short":
+        return build_hierarchy([3, 3, 2]), 1, 11, [0x10000] * 18
+    rng = np.random.default_rng(4200)
+    if case == "choose_args":
+        cm = build_hierarchy([4, 4, 4])
+        cm.choose_args = {
+            bid: {"weight_set": [
+                [int(rng.integers(1 << 14, 1 << 18)) for _ in b.items]
+                for _ in range(2)]}
+            for bid, b in cm.buckets.items()
+            if bid == ROOT_ID or b.items[0] >= 0}
+        return cm, 1, 6, [0x10000] * 64
+    cm = build_flat_map(40)
+    cm.add_rule(erasure_rule(1, ROOT_ID, choose_type=0, leaf=False))
+    weights = [0x10000] * 40
+    for osd in rng.choice(40, size=12, replace=False):
+        weights[int(osd)] = int(rng.choice([0, 0x8000]))
+    return cm, 1, 6, weights
+
+
+# tree, lanes, RETRY_NARROW (None: the module's own widths, which these
+# lane counts are under), whether pairs went to the narrow stage, whether
+# a full-width pass ran after the first (None: as the passes happen to
+# leave it)
+INDEP_RETRY_CASES = {
+    # all weights in: pass one leaves ~5 % of the 11,264 pairs
+    "cell-narrow": ("indep11", 1024, 16, True, False),
+    "cell-wide": ("indep11", 1024, 128, None, True),
+    "cell-below_threshold": ("indep11", 1024, None, False, False),
+    "third_out-narrow": ("third_out", 1024, 8, True, False),
+    "third_out-wide": ("third_out", 1024, 64, None, True),
+    "third_out-below_threshold": ("third_out", 1024, None, False, False),
+    # the pairs that no host is left for run out of tries inside the
+    # narrow loop
+    "short-narrow": ("short", 128, 2, True, None),
+    "choose_args-narrow": ("choose_args", 512, 4, True, False),
+    "plain_choose-narrow": ("plain_choose", 512, 2, True, False),
+}
+
+
+@pytest.mark.parametrize("case", list(INDEP_RETRY_CASES))
+def test_indep_retry_at_the_width_of_what_is_left(case, monkeypatch):
+    """crush_indep over a launch long enough to finish the (lane, slot)
+    pairs its full-width passes leave in a narrow loop: id for id the
+    scalar mapper's result (and the C oracle's, where it is built and
+    knows the map) whichever width ran, holes at their positions, the
+    mapper's totals saying which did."""
+    import ceph_tpu.crush.vectorized as V
+    from ceph_tpu.native import available, crush_oracle_do_rule
+
+    tree, lanes, narrow, narrow_ran, wide_ran = INDEP_RETRY_CASES[case]
+    if narrow is not None:
+        monkeypatch.setattr(V, "RETRY_MIN_LANES", lanes)
+        monkeypatch.setattr(V, "RETRY_NARROW", narrow)
+    cm, ruleno, numrep, weights = indep_tree(tree)
+    xs = cell_pps(lanes, 2)
+    vc = VectorCrush(cm, ruleno)
+    got = vc.map_pgs(xs, numrep, weights)
+    want = scalar_batch(cm, ruleno, xs, numrep, weights)
+    assert np.array_equal(got, want)
+    if available() and tree != "choose_args":
+        # the oracle takes no choose_args
+        for lane, x in enumerate(xs):
+            assert crush_oracle_do_rule(cm, ruleno, int(x), numrep,
+                                        weights) == list(want[lane]), lane
+    totals = vc.totals()
+    assert totals["fused_launches"] == 1 and totals["indep_passes"] >= 2
+    if narrow_ran is not None:
+        assert (totals["indep_retry_pairs"] > 0) == narrow_ran, totals
+    if wide_ran is not None:
+        assert (totals["wide_retries"] > 0) == wide_ran, totals
+    if tree == "short":
+        # nine hosts: two holes a lane, wherever the collisions fell,
+        # and every try was made for them
+        assert ((got == CRUSH_ITEM_NONE).sum(axis=1) == 2).all()
+        assert len({tuple(row) for row in got == CRUSH_ITEM_NONE}) > 8
+        assert totals["indep_passes"] == 100
+
+
+def test_indep_resolves_a_pass_in_slot_order(monkeypatch):
+    """A lane in which two slots b < a are both undefined after pass
+    one and draw the same host in pass two: b takes it, so a's
+    candidate collides with what was placed in the same pass and a
+    waits for pass three.  The lanes are found with the scalar mapper
+    alone (the rule cut to one and to two tries, the candidates by its
+    own bucket choice); the narrow stage, which draws both candidates
+    at once, must resolve them in slot order."""
+    import copy
+
+    import ceph_tpu.crush.vectorized as V
+    from ceph_tpu.crush.mapper import CrushWork, _crush_bucket_choose
+    from ceph_tpu.crush.types import CRUSH_RULE_SET_CHOOSE_TRIES
+
+    cm, ruleno, numrep, weights = cell_tree("indep11")
+    host_of = {osd: b.id for b in cm.buckets.values() if b.items[0] >= 0
+               for osd in b.items}
+    work = CrushWork(cm)
+
+    def candidate(x: int, r: int) -> int:
+        """The host the descent from the root draws for (x, r)."""
+        item = -1
+        while item not in host_of.values():
+            bucket = cm.buckets[item]
+            item = _crush_bucket_choose(bucket, work.work[bucket.id], x, r)
+        return item
+
+    def with_tries(n: int):
+        m = copy.deepcopy(cm)
+        for step in m.rules[ruleno].steps:
+            if step.op == CRUSH_RULE_SET_CHOOSE_TRIES:
+                step.arg1 = n
+        return m
+
+    xs = cell_pps(4096, 2)[576:704]
+    one, two = (scalar_batch(with_tries(n), ruleno, xs, numrep, weights)
+                for n in (1, 2))
+    same_pass = []
+    for lane, x in enumerate(xs):
+        holes = np.flatnonzero(one[lane] == CRUSH_ITEM_NONE)
+        for i, b in enumerate(holes):
+            for a in holes[i + 1:]:
+                host = candidate(int(x), int(b) + numrep)
+                if (host == candidate(int(x), int(a) + numrep)
+                        and host_of.get(int(two[lane, b])) == host):
+                    same_pass.append((lane, int(b), int(a)))
+    assert same_pass, "no lane here tests the order"
+    for lane, _, a in same_pass:
+        assert two[lane, a] == CRUSH_ITEM_NONE
+
+    monkeypatch.setattr(V, "RETRY_MIN_LANES", len(xs))
+    monkeypatch.setattr(V, "RETRY_NARROW", 4)
+    vc = VectorCrush(with_tries(2), ruleno)
+    assert np.array_equal(vc.map_pgs(xs, numrep, weights), two)
+    # pass two was the narrow stage's
+    assert vc.indep_retry_pairs > 0 and vc.wide_retries == 0
+    got = VectorCrush(cm, ruleno).map_pgs(xs, numrep, weights)
+    assert np.array_equal(got, scalar_batch(cm, ruleno, xs, numrep, weights))
+    for lane, b, a in same_pass:
+        assert got[lane, b] == two[lane, b]
+        assert host_of[int(got[lane, a])] != host_of[int(got[lane, b])]

@@ -110,11 +110,16 @@ def test_bulk_crush_scalar_and_fused_rows_agree():
         assert np.array_equal(srows, frows), rule
 
 
-def test_build_adds_the_mappers_retry_counts_to_its_perf_set(monkeypatch):
-    """A warm build over a replicated pool hands the perf set it was
-    given what its fused launches added to the mapper's running totals:
-    the monitor's ``perf dump`` shows how many lanes the narrow retry
-    loops finished and how many full-width passes followed a first."""
+@pytest.mark.parametrize("pool,rule,count", [
+    (1, 0, "retry_lanes"), (2, 1, "indep_retry_pairs")],
+    ids=["replicated", "erasure"])
+def test_build_adds_the_mappers_retry_counts_to_its_perf_set(
+        pool, rule, count, monkeypatch):
+    """A warm build over one pool hands the perf set it was given what
+    its fused launches added to the mapper's running totals: the
+    monitor's ``perf dump`` shows how many lanes (a replicated rule) or
+    (lane, slot) pairs (an erasure rule) the narrow retry loops
+    finished and how many full-width passes followed a first."""
     import ceph_tpu.crush.vectorized as V
     from ceph_tpu.common.perf import PerfCounters
     from ceph_tpu.mon.pg_mapping import _vector_crush_for
@@ -123,19 +128,20 @@ def test_build_adds_the_mappers_retry_counts_to_its_perf_set(monkeypatch):
     # a structure no other test compiles: the mapper is this test's own
     m = make_map(11, fanouts=[5, 7], pg_num=512, down_frac=0.0,
                  out_frac=0.1)
-    del m.pools[2]
+    del m.pools[3 - pool]
     PGMapping.build(m, fused="always")          # compiles and warms
-    vc = _vector_crush_for(m.crush, 0)
+    vc = _vector_crush_for(m.crush, rule)
     before = vc.totals()
-    assert before["fused_launches"] == 1 and before["retry_lanes"] > 0
+    assert before["fused_launches"] == 1 and before[count] > 0
     perf = PerfCounters("placement_cache")
     pm = PGMapping.build(m, perf=perf)          # warm: fused by itself
     assert pm.fused_pools == 1
     assert vc.totals() == {k: 2 * v for k, v in before.items()}
     for name, total in vc.totals().items():
         assert perf.get(name) == total - before[name], name
-    assert perf.get("retry_lanes") > 0
-    assert {"retry_lanes", "wide_retries"} <= set(perf.dump())
+    assert perf.get(count) > 0
+    assert {"retry_lanes", "wide_retries", "indep_passes",
+            "indep_retry_pairs"} <= set(perf.dump())
 
 
 def brute_delta(old: OSDMap, new: OSDMap) -> set:

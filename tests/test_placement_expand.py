@@ -215,3 +215,31 @@ def test_indep_program_carries_its_scope_around_the_loop(numrep):
     assert "module @jit_crush_indep " in text
     assert re.search(r'crush_indep/[^"]*straw2_draw', text)
     assert "crush_retry" not in text
+
+
+def test_indep_program_over_the_threshold_nests_its_narrow_stage(
+        monkeypatch):
+    """A launch long enough for a narrow stage: ``crush_retry`` inside
+    ``crush_indep`` around the narrow loop's draws, and one draw body a
+    stage and bucket level (the full-width loop's and the narrow
+    loop's), however many slots the rule fills."""
+    import re
+
+    import jax.numpy as jnp
+    import ceph_tpu.crush.vectorized as V
+    from ceph_tpu.crush.vectorized import VectorCrush
+
+    monkeypatch.setattr(V, "RETRY_MIN_LANES", 64)
+    vc = VectorCrush(build_hierarchy([4, 4, 2]), 1)
+    draws = []
+    for numrep in (6, 11):
+        text = vc.crush_indep.lower(
+            vc, jnp.arange(64, dtype=jnp.int32), numrep,
+            jnp.full((32,), W, jnp.int32)).as_text(debug_info=True)
+        assert re.search(r'crush_indep/crush_retry/[^"]*straw2_draw', text)
+        assert re.search(r'crush_indep/while/[^"]*straw2_draw', text)
+        assert "crush_retry/crush_indep" not in text
+        # crush_ln's two lookups, a one-hot product each: two a draw
+        draws.append(len(re.findall(r"stablehlo\.dot_general", text)))
+    # two stages x three levels x two lookups, not one set a slot
+    assert draws == [12, 12]

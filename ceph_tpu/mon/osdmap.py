@@ -330,7 +330,9 @@ class OSDMap:
         across weight-only epochs),
         fused_declined (and fused_declined_<reason>), the time of a
         recompute and of its stages (launch, ingest) and of a delta,
-        lookups, delta_pgs.
+        lookups, delta_pgs, and how much of a table left the array
+        path: ingest_shifted_pgs (rows that lost an OSD and closed up)
+        and acting_overrides (pg_temp rows kept beside the array).
         Daemons adopt it into their PerfCountersCollection so `perf
         dump` and the chaos driver see it."""
         if self._placement_perf is None:
@@ -397,7 +399,9 @@ class OSDMap:
 
         Served from the epoch-memoized full-cluster table (OSDMapMapping
         analog, mon/pg_mapping.py): CRUSH runs once per map generation
-        in bulk, and this is an O(1) array read.  The per-PG scalar
+        in bulk, and this is an O(1) array read: the pool's
+        (pg_num, size) rows as the launch's filter left them, one row
+        turned into fresh lists of Python ints.  The per-PG scalar
         pipeline survives as _pg_to_up_acting_scalar -- the oracle the
         parity suite holds the table to, entry for entry."""
         pm = self.placement_cache()

@@ -162,17 +162,29 @@ class PGMapping:
     """The full-cluster placement table for one OSDMap epoch.
 
     ``up`` and ``acting`` per (pool, raw pg), entry-identical to the
-    per-PG ``pg_to_up_acting`` result.  Instances are immutable
-    snapshots: a new epoch builds a new PGMapping (OSDMap memoizes one
-    per mutation generation and hands the previous one to ``delta``)."""
+    per-PG ``pg_to_up_acting`` result.  A pool's rows are ONE
+    ``(pg_num, size)`` int64 array, as the live filter left it: holes
+    -1, a replicated row's survivors first with -1 behind them (and,
+    only for a pool in which some row lost an OSD, the per-row count
+    of survivors beside it).  ``acting`` is that same array except for
+    the PGs a ``pg_temp`` overrides, kept as lists in a small dict per
+    pool (an override may be of any length).  Python lists are made for
+    the rows somebody looks up, never for a table.  Instances are
+    immutable snapshots: a new epoch builds a new PGMapping (OSDMap
+    memoizes one per mutation generation and hands the previous one to
+    ``delta``)."""
 
     def __init__(self, epoch: int) -> None:
         self.epoch = epoch
         self.fused_pools = 0
         self.scalar_pools = 0
-        # pool_id -> list[list[int]] indexed by raw pg
-        self._up: dict[int, list[list[int]]] = {}
-        self._acting: dict[int, list[list[int]]] = {}
+        self.shifted_pgs = 0        # rows that lost an OSD and closed up
+        # pool_id -> (pg_num, size) rows indexed by raw pg
+        self._up: dict[int, np.ndarray] = {}
+        # pool_id -> survivors per row, for the shifting pools with a
+        # short row; pool_id -> {pg: acting list} where pg_temp rules
+        self._kept: dict[int, np.ndarray] = {}
+        self._temp: dict[int, dict[int, list[int]]] = {}
         self._pg_num: dict[int, int] = {}
         self._pg_num_mask: dict[int, int] = {}
 
@@ -210,6 +222,9 @@ class PGMapping:
             perf.inc("bulk_recomputes")
             perf.inc("fused_pools", pm.fused_pools)
             perf.inc("scalar_pools", pm.scalar_pools)
+            perf.inc("ingest_shifted_pgs", pm.shifted_pgs)
+            perf.inc("acting_overrides",
+                     sum(len(t) for t in pm._temp.values()))
             perf.tinc("recompute", dt)
             total = sum(pm._pg_num.values())
             if dt > 0:
@@ -219,13 +234,13 @@ class PGMapping:
 
     def _ingest_pool(self, osdmap, pool_id: int, pool,
                      rows: np.ndarray, live: np.ndarray) -> None:
-        """Raw CRUSH rows -> up/acting lists with the full OSDMap
+        """Raw CRUSH rows -> the pool's table with the full OSDMap
         semantics applied in bulk (OSDMap.cc _apply_upmap,
-        _raw_to_up_osds, pg_temp), vectorized where the data is dense
-        (the rows leave numpy as lists in one ``tolist``: a Python
-        loop over 24,576 rows cost an epoch 81-86 ms on the chip's
-        host, PERF.md section 6, PR 41) and per-entry only for the
-        sparse override dicts."""
+        _raw_to_up_osds, pg_temp).  ``rows`` is edited in place and
+        kept as the table: it never leaves numpy (24,576 lists an
+        epoch cost the chip's host 9.5 ms to make and 9.1 to compare,
+        ledger, PR 42); only the sparse override dicts are walked per
+        entry."""
         n_live = live.shape[0]
         # upmap rewrite first (it edits the RAW result): sparse dict,
         # touch only the pgs that carry items
@@ -242,22 +257,21 @@ class PGMapping:
                     pgid, [int(o) for o in rows[pg]])
         # live filter, holes normalized to -1 (EC shard ids ride the
         # position, so indep pools keep holes; replicated compact)
-        valid = ((rows != CRUSH_ITEM_NONE) & (rows >= 0)
-                 & (rows < n_live))
-        ok = np.zeros_like(valid)
-        ok[valid] = live[rows[valid]]
-        if pool.can_shift_osds():
-            # survivors first and in order (a stable sort on "dropped");
-            # the rows that lost one are cut to what they kept
-            kept = ok.sum(axis=1)
-            up = np.take_along_axis(
-                rows, np.argsort(~ok, axis=1, kind="stable"),
-                axis=1).tolist()
-            for pg in np.flatnonzero(kept < rows.shape[1]).tolist():
-                up[pg] = up[pg][:kept[pg]]
-        else:
-            up = np.where(ok, rows, -1).tolist()
-        acting = list(up)           # shared rows until pg_temp overrides
+        ok = ((rows >= 0) & (rows < n_live)     # CRUSH_ITEM_NONE is past
+              & live.take(rows, mode="clip"))   # any osd id
+        shifts = pool.can_shift_osds()
+        if not ok.all():
+            rows[~ok] = -1
+            if shifts:
+                # survivors first and in order (a stable sort on
+                # "dropped"), for the rows that lost one alone
+                short = np.flatnonzero(~ok.all(axis=1))
+                rows[short] = np.take_along_axis(
+                    rows[short], np.argsort(~ok[short], axis=1,
+                                            kind="stable"), axis=1)
+                self._kept[pool_id] = ok.sum(axis=1)
+                self.shifted_pgs += len(short)
+        temps = self._temp[pool_id] = {}
         for pgid, temp in osdmap.pg_temp.items():
             if not pgid.startswith(prefix):
                 continue
@@ -270,11 +284,11 @@ class PGMapping:
             act = [int(o) if (o != CRUSH_ITEM_NONE and o >= 0
                               and o < n_live and live[o]) else -1
                    for o in temp]
-            if pool.can_shift_osds():
+            if shifts:
                 act = [o for o in act if o >= 0]
-            acting[pg] = act if act else up[pg]
-        self._up[pool_id] = up
-        self._acting[pool_id] = acting
+            if act:                 # else: acting falls back to up
+                temps[pg] = act
+        self._up[pool_id] = rows
         self._pg_num[pool_id] = pool.pg_num
         self._pg_num_mask[pool_id] = pool.pg_num_mask
 
@@ -283,20 +297,25 @@ class PGMapping:
         b, mask = self._pg_num[pool_id], self._pg_num_mask[pool_id]
         return ps & mask if (ps & mask) < b else ps & (mask >> 1)
 
+    def _row(self, pool_id: int, pg: int) -> tuple[list[int], list[int]]:
+        kept = self._kept.get(pool_id)
+        row = self._up[pool_id][pg]
+        up = (row if kept is None else row[:kept[pg]]).tolist()
+        return up, list(self._temp[pool_id].get(pg, up))
+
     def lookup(self, pool_id: int,
                ps: int) -> tuple[list[int], list[int]]:
-        """(up, acting) for a pg: one table read.  Returns fresh lists
-        (callers historically mutate/keep the per-call result)."""
-        pg = self.raw_pg(pool_id, ps)
-        return list(self._up[pool_id][pg]), \
-            list(self._acting[pool_id][pg])
+        """(up, acting) for a pg: one array read.  Returns fresh lists
+        of Python ints (callers historically mutate/keep the per-call
+        result, and an ``np.int64`` breaks the wire encoders)."""
+        return self._row(pool_id, self.raw_pg(pool_id, ps))
 
     def iter_all(self):
-        """Yield (pool_id, pg, up, acting) over the whole table."""
-        for pool_id, ups in self._up.items():
-            acts = self._acting[pool_id]
-            for pg in range(len(ups)):
-                yield pool_id, pg, ups[pg], acts[pg]
+        """Yield (pool_id, pg, up, acting) over the whole table, the
+        lists made as it goes."""
+        for pool_id, rows in self._up.items():
+            for pg in range(rows.shape[0]):
+                yield pool_id, pg, *self._row(pool_id, pg)
 
     def pg_count(self) -> int:
         return sum(self._pg_num.values())
@@ -321,22 +340,31 @@ class PGMapping:
             perf.inc("delta_pgs", len(changed))
         return changed
 
+    def _lens(self, pool_id: int, n: int):
+        """A pool's row lengths over its first ``n`` pgs: the width, or
+        the survivors per row where a row is short."""
+        kept = self._kept.get(pool_id)
+        return self._up[pool_id].shape[1] if kept is None else kept[:n]
+
     def _diff(self, prev: "PGMapping") -> list[tuple[int, int]]:
         changed: list[tuple[int, int]] = []
-        pools = set(self._up) | set(prev._up)
-        for pool_id in sorted(pools):
-            cur_u = self._up.get(pool_id)
-            old_u = prev._up.get(pool_id)
-            if cur_u is None or old_u is None:
-                src = cur_u if cur_u is not None else old_u
+        for pool_id in sorted(set(self._up) | set(prev._up)):
+            cur = self._up.get(pool_id)
+            old = prev._up.get(pool_id)
+            if cur is None or old is None:
+                src = cur if cur is not None else old
                 changed.extend((pool_id, pg) for pg in range(len(src)))
                 continue
-            cur_a = self._acting[pool_id]
-            old_a = prev._acting[pool_id]
-            span = max(len(cur_u), len(old_u))
-            for pg in range(span):
-                if (pg >= len(cur_u) or pg >= len(old_u)
-                        or cur_u[pg] != old_u[pg]
-                        or cur_a[pg] != old_a[pg]):
-                    changed.append((pool_id, pg))
+            # rows are equal where they are equally long and agree over
+            # the common width (what lies behind a row's length is -1)
+            n, w = min(len(cur), len(old)), min(cur.shape[1], old.shape[1])
+            moved = (cur[:n, :w] != old[:n, :w]).any(axis=1)
+            moved |= self._lens(pool_id, n) != prev._lens(pool_id, n)
+            for pg in self._temp[pool_id].keys() | prev._temp[pool_id].keys():
+                if pg < n and not moved[pg]:
+                    moved[pg] = (self._row(pool_id, pg)[1]
+                                 != prev._row(pool_id, pg)[1])
+            pgs = np.flatnonzero(moved).tolist()
+            pgs.extend(range(n, max(len(cur), len(old))))
+            changed.extend((pool_id, pg) for pg in pgs)
         return changed

@@ -302,7 +302,7 @@ def test_an_expansion_through_the_commands_reaches_osds_and_client():
             for m in maps:
                 assert m.epoch == mon.osdmap.epoch
                 got = m.placement_cache()
-                assert got._up == want._up and got._acting == want._acting
+                assert list(got.iter_all()) == list(want.iter_all())
 
         def holders_of(osd: int) -> int:
             return sum(osd in up for _, _, up, _ in

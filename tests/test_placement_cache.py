@@ -8,6 +8,7 @@ upmaps, pg_temp, EC + replicated pools -- plus delta-correctness
 read is impossible after apply_incremental)."""
 
 import random
+from dataclasses import asdict
 
 import numpy as np
 import pytest
@@ -83,8 +84,7 @@ def test_fused_and_scalar_builds_agree():
     scalar = PGMapping.build(m, fused="never")
     assert fused.fused_pools == len(m.pools)
     assert scalar.scalar_pools == len(m.pools)
-    assert fused._up == scalar._up
-    assert fused._acting == scalar._acting
+    assert list(fused.iter_all()) == list(scalar.iter_all())
     assert_table_matches_scalar(m, fused)
 
 
@@ -242,7 +242,7 @@ def test_serialized_roundtrip_keeps_parity():
     assert_table_matches_scalar(m2, m2.placement_cache())
     # and the two tables agree with each other
     a, b = m.placement_cache(), m2.placement_cache()
-    assert a._up == b._up and a._acting == b._acting
+    assert list(a.iter_all()) == list(b.iter_all())
 
 
 def test_lookup_counters_and_recompute_counter():
@@ -255,3 +255,202 @@ def test_lookup_counters_and_recompute_counter():
     m.apply_incremental(Incremental(epoch=m.epoch + 1, new_down=[0]))
     m.pg_to_up_acting(1, 0)
     assert m.placement_perf.dump()["bulk_recomputes"] == 2
+
+
+# -- the table as arrays (PR 43): every case through the public readers ------
+
+def plain_map(pg_num: int = 16) -> OSDMap:
+    """16 OSDs in 4 hosts, all up and in; a replicated pool (1, size 3)
+    and an erasure pool (2, size 4); no upmap, no pg_temp."""
+    m = make_map(0, fanouts=[4, 4], pg_num=pg_num, down_frac=0.0,
+                 out_frac=0.0)
+    m.pg_temp.clear()
+    m.pg_upmap_items.clear()
+    m.invalidate_placement_cache()
+    return m
+
+
+def pool_dict(m: OSDMap, pid: int, **changes) -> dict:
+    return dict(asdict(m.pools[pid]), **changes)
+
+
+def holders(m: OSDMap, pid: int, n: int) -> list[int]:
+    """The n OSDs that hold most PGs of a pool (so taking them down
+    leaves short rows behind)."""
+    count: dict[int, int] = {}
+    for p, _, up, _ in m.placement_cache().iter_all():
+        for o in up if p == pid else ():
+            count[o] = count.get(o, 0) + 1
+    return sorted(count, key=lambda o: (-count[o], o))[:n]
+
+
+def case_replicated_two_down(m):
+    yield dict(new_down=holders(m, 1, 2))
+    pm, perf = m.placement_cache(), m.placement_perf
+    assert perf.get("ingest_shifted_pgs") > 0          # the sort path ran
+    assert any(len(up) < 3 for p, _, up, _ in pm.iter_all() if p == 1)
+    assert all(o >= 0 for p, _, up, _ in pm.iter_all() if p == 1
+               for o in up)                            # closed up, no holes
+
+
+def case_erasure_one_down(m):
+    shifted = m.placement_perf.get("ingest_shifted_pgs")
+    (victim,) = holders(m, 2, 1)
+    del m.pools[1]
+    m.invalidate_placement_cache()
+    yield dict(new_down=[victim])
+    rows = [up for p, _, up, _ in m.placement_cache().iter_all() if p == 2]
+    assert all(len(up) == 4 and victim not in up for up in rows)
+    assert any(-1 in up[:-1] for up in rows)           # a hole in position
+    assert m.placement_perf.get("ingest_shifted_pgs") == shifted
+
+
+def case_pg_temp_set_and_cleared(m):
+    down = holders(m, 1, 1)
+    yield dict(new_down=down)
+    live = [o for o in sorted(m.osds) if o not in down]
+    temps = {"1.3": live[:1], "1.5": live[2:6],        # shorter, longer
+             "1.7": down + [99],                       # nobody live: up
+             "2.2": [live[4], down[0], live[5], live[6]]}
+    before = m.placement_perf.get("acting_overrides")
+    delta = yield dict(new_pg_temp=temps)
+    assert delta == [(1, 3), (1, 5), (2, 2)]
+    assert m.placement_perf.get("acting_overrides") - before == 3
+    assert m.pg_to_up_acting(1, 3)[1] == live[:1]
+    assert m.pg_to_up_acting(1, 5)[1] == live[2:6]
+    up, acting = m.pg_to_up_acting(1, 7)
+    assert acting == up and acting is not up
+    assert m.pg_to_up_acting(2, 2)[1] == [live[4], -1, live[5], live[6]]
+    delta = yield dict(new_pg_temp={k: [] for k in temps})
+    assert delta == [(1, 3), (1, 5), (2, 2)]
+    assert m.placement_perf.get("acting_overrides") - before == 3
+
+
+def case_pg_upmap_items(m):
+    moved = []
+    items = {}
+    for pid in (1, 2):
+        up, _ = m.pg_to_up_acting(pid, 4)
+        to = next(o for o in sorted(m.osds) if o not in up)
+        items[f"{pid}.4"] = [[up[1], to]]
+        moved.append((pid, 4))
+    delta = yield dict(new_pg_upmap_items=items)
+    assert delta == moved
+    assert m.pg_to_up_acting(1, 4)[0][1] == items["1.4"][0][1]
+    delta = yield dict(removed_pg_upmap_items=list(items))
+    assert delta == moved
+
+
+def case_pool_created_then_deleted(m):
+    delta = yield dict(new_pools={3: {"pool_id": 3, "name": "fresh",
+                                      "pg_num": 8, "pgp_num": 8,
+                                      "size": 2}})
+    assert delta == [(3, pg) for pg in range(8)]
+    delta = yield dict(removed_pools=[1])
+    assert delta == [(1, pg) for pg in range(16)]
+
+
+def case_pg_num_doubled(m):
+    delta = yield dict(new_pools={2: pool_dict(m, 2, pg_num=32,
+                                               pgp_num=32)})
+    assert [pg for pid, pg in delta if pg >= 16] == list(range(16, 32))
+    assert {pid for pid, _ in delta} == {2}
+
+
+def case_size_three_to_two(m):
+    yield dict(new_down=holders(m, 1, 1))     # some rows are two long
+    delta = yield dict(new_pools={1: pool_dict(m, 1, size=2)})
+    # a row that had lost one reads the same at either width
+    assert 0 < len(delta) < 16 and {pid for pid, _ in delta} == {1}
+
+
+CASES = [case_replicated_two_down, case_erasure_one_down,
+         case_pg_temp_set_and_cleared, case_pg_upmap_items,
+         case_pool_created_then_deleted, case_pg_num_doubled,
+         case_size_three_to_two]
+
+
+@pytest.mark.parametrize(
+    "case", CASES, ids=[c.__name__.removeprefix("case_") for c in CASES])
+def test_table_and_delta_through_the_public_readers(case):
+    """Each case is a walk of epochs: after every step the whole table
+    is the scalar pipeline's, entry for entry, in lists of Python ints,
+    and ``delta`` is the brute-force diff, sorted."""
+    m = plain_map()
+    assert m.placement_cache().delta(m.placement_cache()) == []
+    walk = case(m)
+    step = next(walk)
+    while True:
+        before = OSDMap.from_dict(m.to_dict())
+        prev = m.placement_cache()
+        m.apply_incremental(Incremental(epoch=m.epoch + 1, **step))
+        cur = m.placement_cache()
+        assert cur is not prev
+        assert_table_matches_scalar(m, cur)
+        for pid, pg, up, acting in cur.iter_all():
+            assert (up, acting) == m._pg_to_up_acting_scalar(pid, pg)
+            assert all(type(o) is int for o in up + acting)
+        delta = cur.delta(prev, perf=m.placement_perf)
+        assert delta == sorted(brute_delta(before, m))
+        assert all(type(x) is int for pair in delta for x in pair)
+        assert cur.delta(cur) == []
+        try:
+            step = walk.send(delta)
+        except StopIteration:
+            break
+
+
+def test_a_table_of_the_expansion_cells_shape_makes_no_per_pg_object(
+        monkeypatch):
+    """The mechanism (PR 43): two builds and a delta over 16,384 x 3
+    and 8,192 x 11 rows leave the launch's arrays as the table, where
+    a list a PG is 24,576 tracked objects a build; a lookup hands back
+    lists of Python ints, for both pool kinds and an overridden PG."""
+    import gc
+    import ceph_tpu.mon.pg_mapping as pgm
+
+    m = OSDMap()
+    m.epoch, m.max_osd = 1, 1000
+    m.osds = {o: OsdInfo(up=True, in_cluster=True) for o in range(1000)}
+    m.pools[1] = PoolSpec(pool_id=1, name="rep", size=3, pg_num=16384,
+                          pgp_num=16384)
+    m.pools[2] = PoolSpec(pool_id=2, name="ec", type=POOL_TYPE_ERASURE,
+                          size=11, min_size=9, pg_num=8192, pgp_num=8192,
+                          crush_rule=1)
+    m.pg_temp = {"1.10": [5, 6], "2.20": list(range(40, 51))}
+    epoch = [0]
+
+    def rows_of_epoch(crush, rule, xs, numrep, weights, **kw):
+        """A launch's answer: the same rows every epoch but for 100
+        PGs a pool that the epoch moves."""
+        rows = np.random.default_rng(rule).integers(
+            0, 1000, (len(xs), numrep)).astype(np.int64)
+        moved = np.random.default_rng([rule, epoch[0]]).choice(
+            len(xs), 100, replace=False)
+        rows[moved, 0] = (rows[moved, 0] + 1 + epoch[0]) % 1000
+        return rows, True
+
+    monkeypatch.setattr(pgm, "bulk_crush", rows_of_epoch)
+    gc.collect()
+    gc.disable()
+    try:
+        before = len(gc.get_objects())
+        tables = []
+        for epoch[0] in (1, 2):
+            tables.append(PGMapping.build(m, perf=m.placement_perf))
+        delta = tables[1].delta(tables[0], perf=m.placement_perf)
+        made = len(gc.get_objects()) - before
+    finally:
+        gc.enable()
+    # each table moved its own 100 PGs a pool off the common rows
+    assert 300 < len(delta) <= 400 and made < 1000, (len(delta), made)
+    perf = m.placement_perf.dump()
+    assert perf["ingest_shifted_pgs"] == 0 and perf["acting_overrides"] == 4
+    for pid, pg, width in ((1, 0, 3), (2, 0, 11), (1, 0x10, 3),
+                           (2, 0x20, 11)):
+        up, acting = tables[1].lookup(pid, pg)
+        assert type(up) is list and type(acting) is list
+        assert len(up) == width and acting is not up
+        assert all(type(o) is int for o in up + acting)
+    assert tables[1].lookup(1, 0x10)[1] == [5, 6]
+    assert tables[1].lookup(2, 0x20)[1] == list(range(40, 51))

@@ -43,8 +43,8 @@ from ..core import Finding
 from ..registry import ProjectChecker, register
 
 # the launch entry points of the batched data plane, by Class.method
-# (or bare function) name; the dynamic scalar_calls_on_batched_paths
-# gate exercises exactly these (bench.py --integrity / --osd-path)
+# (or bare function) name; the dynamic scalar-call gate exercises
+# exactly these (tests/test_crc_batch.py)
 ROOTS = (
     "CodecBatcher.encode",
     "CodecBatcher.decode",

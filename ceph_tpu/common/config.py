@@ -19,9 +19,7 @@ OPT_FLOAT = "float"
 OPT_STR = "str"
 OPT_BOOL = "bool"
 
-LEVEL_BASIC = "basic"
 LEVEL_ADVANCED = "advanced"
-LEVEL_DEV = "dev"
 
 _CASTERS = {
     OPT_INT: int,
@@ -66,15 +64,8 @@ DEFAULT_SCHEMA: list[Option] = [
            "seconds between peer pings", min=0.01),
     Option("osd_heartbeat_grace", OPT_FLOAT, 4.0,
            "seconds of silence before reporting a peer down", min=0.1),
-    Option("osd_pool_default_size", OPT_INT, 3,
-           "replica count for new pools", min=1),
-    Option("osd_pool_default_min_size", OPT_INT, 2,
-           "min replicas to accept writes", min=1),
-    Option("osd_pool_default_pg_num", OPT_INT, 32,
-           "pg count for new pools", min=1),
     Option("osd_recovery_max_active", OPT_INT, 3,
            "max concurrent recovery ops per OSD", min=1),
-    Option("osd_client_op_priority", OPT_INT, 63, "client op priority"),
     Option("osd_scrub_interval", OPT_FLOAT, 0.0,
            "seconds after a PG's last scrub until its primary "
            "schedules the next; 0 = scheduled scrubs off unless set",
@@ -84,11 +75,6 @@ DEFAULT_SCHEMA: list[Option] = [
     Option("mon_osd_down_out_interval", OPT_FLOAT, 600.0,
            "seconds down before auto-out", min=0.0),
     Option("mon_lease", OPT_FLOAT, 5.0, "paxos leader lease seconds"),
-    Option("osd_erasure_code_plugins", OPT_STR, "tpu isa jerasure",
-           "plugins preloaded at daemon start"),
-    Option("osd_pool_default_erasure_code_profile", OPT_STR,
-           "plugin=tpu k=2 m=1 technique=reed_sol_van",
-           "default EC profile"),
     Option("osd_peering_retry_base", OPT_FLOAT, 0.5,
            "initial peering retry delay (doubles per attempt)",
            min=0.01),
@@ -207,39 +193,6 @@ DEFAULT_SCHEMA: list[Option] = [
            "mgr dashboard port (0 = ephemeral)", min=0),
     Option("telemetry_on", OPT_BOOL, False,
            "enable the mgr telemetry module"),
-    # -- loadgen (the cluster traffic harness, ceph_tpu/loadgen) ----------
-    Option("loadgen_rados_handles", OPT_INT, 8,
-           "Rados connections the client swarm multiplexes over",
-           min=1),
-    Option("loadgen_op_timeout", OPT_FLOAT, 30.0,
-           "per-op client deadline; exceeding it is a wedged op",
-           min=0.1),
-    Option("loadgen_open_max_inflight", OPT_INT, 1024,
-           "open-loop safety valve: max ops in flight before the "
-           "dispatcher stalls (stalls are reported, not hidden)",
-           min=1),
-    Option("loadgen_preload_concurrency", OPT_INT, 64,
-           "concurrent writes while preloading the working set",
-           min=1),
-    Option("loadgen_kill_osds", OPT_INT, 1,
-           "OSDs killed by the recovery-interference phase", min=0),
-    Option("loadgen_recovery_settle", OPT_FLOAT, 15.0,
-           "seconds allowed for the mon to mark the victim down",
-           min=0.1),
-    Option("loadgen_hist_growth", OPT_FLOAT, 2 ** 0.125,
-           "latency histogram bucket growth factor (bounds the "
-           "relative error of reported percentiles)", min=1.0001),
-    Option("loadgen_hist_min_s", OPT_FLOAT, 1e-5,
-           "latency histogram first bucket upper bound (seconds)",
-           min=1e-9),
-    Option("debug_osd", OPT_INT, 1, "osd log verbosity", min=0, max=20,
-           level=LEVEL_DEV),
-    Option("debug_mon", OPT_INT, 1, "mon log verbosity", min=0, max=20,
-           level=LEVEL_DEV),
-    Option("debug_ms", OPT_INT, 0, "messenger log verbosity", min=0,
-           max=20, level=LEVEL_DEV),
-    Option("log_max_recent", OPT_INT, 1000,
-           "ring-buffered log entries kept for crash dump", min=0),
 ]
 
 

@@ -18,8 +18,8 @@ Straggler mode: ``straggler()`` arms per-peer HEAVY-TAIL delay
 profiles (seeded lognormal / pareto draws from a per-(seed, peer) RNG
 stream, so each peer's delay sequence replays independently of
 cross-peer message ordering) -- the induced-straggler workload the
-hedged-read engine (osd/hedged_gather.py) and ``bench.py
---straggler`` measure against.
+hedged-read engine (osd/hedged_gather.py) is tested against
+(tests/test_hedged_reads.py).
 """
 
 from __future__ import annotations

@@ -1,30 +1,8 @@
-"""Cluster-scale closed-loop traffic harness.
+"""The in-process cluster (``cluster.SimCluster``): a mon and N OSDs on
+one event loop, with the kill / revive / wait helpers the benchmark's
+drivers, ``chip_smoke.py``, ``tools/chaos.py`` and the tests share.
 
-Lazy exports (PEP 562, like ceph_tpu.ops): the OSD adopts
-``loadgen.stats.PERF`` at construction time, and that import must not
-drag the swarm -> librados -> osd import chain back in (cycle) nor
-any heavy dependency.
+The load generator this package was named for is gone (traffic is
+``benchmark/traffic`` + ``benchmark/drivers``); the import path stays
+because the benchmark's files name it.
 """
-
-_EXPORTS = {
-    "WorkloadSpec": ".spec",
-    "Op": ".spec",
-    "payload_for": ".spec",
-    "LatencyHistogram": ".histogram",
-    "SimCluster": ".cluster",
-    "ClientSwarm": ".swarm",
-    "PhaseResult": ".swarm",
-    "run_workload": ".driver",
-    "deterministic_view": ".driver",
-    "degradation_ratios": ".driver",
-}
-
-__all__ = sorted(_EXPORTS)
-
-
-def __getattr__(name: str):
-    mod = _EXPORTS.get(name)
-    if mod is None:
-        raise AttributeError(name)
-    import importlib
-    return getattr(importlib.import_module(mod, __name__), name)

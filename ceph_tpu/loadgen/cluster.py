@@ -1,15 +1,14 @@
 """SimCluster: mon + N OSDs in one process, scaled past toy size.
 
-The vstart-style bring-up that ``bench.py --osd-path`` and
-``tools/chaos.py`` each grew privately, factored out and scaled: OSDs
-boot in small concurrent batches (serial boot of 64+ daemons pays one
-mon round trip each), large clusters get slower heartbeats plus the
-capped heartbeat fanout (``osd_heartbeat_max_peers``) so the ping
-mesh stays O(N), and the kill/revive/wait helpers the chaos driver
-pioneered live here for any harness to reuse.
+The vstart-style bring-up, scaled: OSDs boot in small concurrent
+batches (serial boot of 64+ daemons pays one mon round trip each),
+large clusters get slower heartbeats plus the capped heartbeat fanout
+(``osd_heartbeat_max_peers``) so the ping mesh stays O(N), and the
+kill/revive/wait helpers the chaos driver pioneered live here for any
+harness to reuse.
 
 ``ChaosCluster`` (tools/chaos.py) subclasses this and adds its raw
-messenger client; the loadgen swarm talks librados instead.
+messenger client.
 """
 
 from __future__ import annotations
@@ -71,11 +70,11 @@ class SimCluster:
         rebuilds per epoch, which saturates the event loop, delays
         heartbeats, triggers FALSE failure reports and feeds back into
         more epochs (observed as a 48-OSD bring-up wedged for minutes).
-        Lowering the fused first-compile threshold (the same module
-        knob ``bench.py --placement --smoke`` pins) makes the first
-        post-pool-create rebuild pay one jit compile and every later
-        epoch a ~ms vectorized launch.  An explicit operator override
-        via CEPH_TPU_PLACEMENT_FUSED_MIN is respected.
+        Lowering the fused first-compile threshold (the module knob
+        ``pg_mapping.FUSED_MIN_LANES``) makes the first post-pool-create
+        rebuild pay one jit compile and every later epoch a ~ms
+        vectorized launch.  An explicit operator override via
+        CEPH_TPU_PLACEMENT_FUSED_MIN is respected.
         """
         import os
         if n_osds < 24 or "CEPH_TPU_PLACEMENT_FUSED_MIN" in os.environ:

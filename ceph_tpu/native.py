@@ -2,8 +2,8 @@
 
 Builds lazily with make on first use if the .so is absent; every entry
 point has a numpy fallback so the framework stays functional without a
-toolchain.  The native GF path is also the CPU baseline the TPU kernels
-are measured against in bench.py (the ISA-L-technique stand-in).
+toolchain.  The native GF path (the ISA-L-technique stand-in) is the
+oracle ``chip_smoke.py`` holds the device codec to.
 """
 
 from __future__ import annotations
@@ -155,7 +155,7 @@ def gf8_matmul(matrix: np.ndarray, data: np.ndarray) -> np.ndarray:
 # scalar-call accounting: the batched integrity pipeline
 # (ops/crc32c_batch.py) owns the "integrity" perf counter set; every
 # per-buffer call through here is counted against it so perf dumps and
-# bench.py --integrity can prove the hot paths ride the batched API.
+# tests/test_crc_batch.py can prove the hot paths ride the batched API.
 # Resolved lazily: processes that never checksum never import ops.
 _integrity_perf = None
 

@@ -37,9 +37,9 @@ amortized, the XOR/CRC side-path dominates):
 
 Observability: the module-global ``PERF`` ("integrity") counts batched
 vs scalar calls, bytes hashed and fused-launch hits; ``native.crc32c``
-reports every remaining per-buffer call into the same set, so
-``bench.py --integrity`` can prove the codec-batcher and deep-scrub
-paths ride the batched API (scalar-call count ~ 0).
+reports every remaining per-buffer call into the same set, so a test
+can prove the codec-batcher and deep-scrub paths ride the batched API
+(scalar-call delta 0: ``tests/test_crc_batch.py``).
 
 This module must stay importable without jax (blockstore/scrub/native
 fallback are jax-free); the device kernel imports lazily.

@@ -51,8 +51,8 @@ lazily.
 Observability: the process-wide ``PERF`` ("datapath") set -- hits,
 misses, host bytes avoided vs read, evictions, resident bytes -- is
 adopted into OSD perf dumps next to "integrity" and "ec_batch", and
-``bench.py --datapath`` uses it to PROVE cache-hit reads and scrub
-verifies move zero shard bytes across the host boundary.
+``tests/test_datapath_cache.py`` uses it to PROVE cache-hit reads and
+scrub verifies move zero shard bytes across the host boundary.
 """
 
 from __future__ import annotations
@@ -122,7 +122,7 @@ class DeviceShardCache:
     def note_host_read(nbytes: int) -> None:
         """A consumer materialized shard bytes through the store (the
         host round trip the cache exists to avoid).  Called at every
-        miss-path fill so the bench can assert the steady-state delta
+        miss-path fill so a test can assert the steady-state delta
         is ZERO on cache-hit reads and scrub verifies."""
         PERF.inc("host_reads")
         PERF.inc("host_bytes_read", int(nbytes))

@@ -477,8 +477,8 @@ class ECBackend(PGBackend):
         # repair-I/O observability (perf counter set "ec_recovery"):
         # the bytes recovery actually gathers vs ships is the whole
         # point of the recovery-bandwidth-optimal codes -- chaos and
-        # bench.py --recovery pin the per-code ratios on these instead
-        # of trusting the repair-math claim
+        # tests/test_ec_degraded.py pin the per-code ratios on these
+        # instead of trusting the repair-math claim
         self.perf_recovery = perf.create("ec_recovery") \
             if perf is not None else None
         # what a write read of the old object (the OSD-wide "ec_pipeline"
@@ -781,8 +781,7 @@ class ECBackend(PGBackend):
              for s in remote],
             collect=True, timeout=timeout)
         # same sub-read accounting as the hedged path, so a hedged-vs-
-        # unhedged comparison (bench.py --straggler's extra-bytes gate)
-        # reads one counter set either way
+        # unhedged comparison reads one counter set either way
         if self.hedger is not None:
             self.hedger.note("subreads", len(remote))
             self.hedger.note("subread_bytes",

@@ -149,11 +149,6 @@ class OSD:
         # report client-vs-recovery QoS behavior instead of inferring
         # it from latency alone
         self.sched = MClockScheduler(perf=self.perf.create("scheduler"))
-        # the traffic harness's process-wide workload counters (ops and
-        # bytes the client swarm pushed); adopting them means a plain
-        # `perf dump` shows offered load next to what the daemon did
-        from ..loadgen.stats import PERF as _workload_perf
-        self.perf.adopt(_workload_perf)
         # the map owns the placement-cache counters (they live and die
         # with it); adopt them so `perf dump` includes the set.  A
         # full-map ingest re-adopts the fresh map's instance.

@@ -228,7 +228,7 @@ def test_real_tree_graph_sanity():
 def test_reach_origin_daemons_charges_shared_helper(tmp_path):
     """A boundary reach inside a shared helper is charged to every
     daemon class whose code can run it -- plain-function callers
-    (tools, loadgen) contribute no daemon origin."""
+    (the tools, the in-process cluster) contribute no daemon origin."""
     from ceph_tpu.analysis.checkers.cross_daemon_state import (
         reach_origin_daemons)
     g = graph_of(tmp_path, {

@@ -423,6 +423,55 @@ def test_scrub_map_digests_ride_batched_api():
         assert smap[oid]["data_digest"] == native.crc32c(data), oid
 
 
+def test_batcher_write_then_scrub_make_no_scalar_crc_calls():
+    """The write path's encode (CRCs fused into the launch) followed by
+    a deep-scrub digest pass over a store: at least one fused launch,
+    batched calls, and not one per-buffer ``native.crc32c`` call."""
+    from ceph_tpu.ec import registry
+    from ceph_tpu.os.store import MemStore
+    from ceph_tpu.os.transaction import Transaction
+    from ceph_tpu.osd.codec_batcher import CodecBatcher
+    from ceph_tpu.osd.ec_util import StripeInfo
+    from ceph_tpu.osd.scrub import build_scrub_map
+    codec = registry().factory("tpu", {"k": "4", "m": "2",
+                                       "technique": "reed_sol_van"})
+    si = StripeInfo.for_codec(codec, stripe_unit=1024)
+    batcher = CodecBatcher(max_batch=32, flush_timeout=0.05)
+    rng = np.random.default_rng(15)
+    datas = [rng.integers(0, 256, si.stripe_width * n,
+                          dtype=np.uint8).tobytes() for n in (3, 2, 4)]
+    store = MemStore()
+    store.queue_transaction(Transaction().create_collection("c"))
+    payloads = {}
+    for i in range(24):
+        payloads[f"o{i}"] = rng.integers(0, 256, 4096,
+                                         dtype=np.uint8).tobytes()
+        t = Transaction()
+        t.write("c", f"o{i}", 0, payloads[f"o{i}"])
+        store.queue_transaction(t)
+
+    async def drive():
+        enc = await asyncio.gather(*(
+            si.encode_async(codec, d, batcher=batcher, with_crc=True)
+            for d in datas))
+        return enc, await build_scrub_map(store, "c", deep=True)
+
+    keys = ("scalar_calls", "batched_calls", "fused_launches")
+    before = [cb.PERF.get(key) for key in keys]
+    enc, smap = run(drive())
+    scalar, batched, fused = (cb.PERF.get(key) - b
+                              for key, b in zip(keys, before))
+    assert scalar == 0, "a batched path made per-buffer CRC calls"
+    assert batched >= 1 and fused >= 1
+    for data, (shards, crcs) in zip(datas, enc):
+        want = si.encode(codec, data)
+        for i in want:
+            assert np.array_equal(shards[i], want[i]), i
+            assert crcs[i] == native.crc32c(want[i].tobytes()), i
+    for oid, data in payloads.items():
+        assert smap[oid]["data_digest"] == native.crc32c(data), oid
+
+
 def test_blockstore_write_read_csums_batched(tmp_path):
     from ceph_tpu.os.blockstore import BlockStore
     from ceph_tpu.os.transaction import Transaction

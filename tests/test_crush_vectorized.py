@@ -18,6 +18,12 @@ def scalar_batch(m, rule, xs, numrep, weights):
     return np.asarray(out, dtype=np.int64)
 
 
+def test_import_does_not_flip_global_x64():
+    import jax
+    import ceph_tpu.crush.vectorized  # noqa: F401 -- the old offender
+    assert jax.config.jax_enable_x64 is False
+
+
 def test_flat_firstn_matches_scalar():
     m = build_flat_map(12)
     weights = [0x10000] * 12

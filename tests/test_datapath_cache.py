@@ -295,6 +295,92 @@ def test_kill_revive_never_serves_stale_resident_bytes():
     run(main())
 
 
+# -- cluster: the steady phases move no shard bytes through the store --------
+
+@pytest.mark.parametrize("phase", ["read", "scrub", "degraded_read"])
+def test_cached_steady_phase_reads_no_shard_bytes_from_the_store(phase):
+    """After full-stripe writes every acting shard is resident, so a
+    read pass, a deep scrub of every PG and a degraded read pass (one
+    OSD stopped and marked down) each hit the cache and read ZERO
+    shard bytes from the store, by the ``datapath`` counters; every
+    byte read equals what was written.  The control makes the zero
+    mean something: with residency dropped the same pass returns the
+    same bytes and the same counters count the store reads.  Each
+    write batch is one device launch (``ec_batch``)."""
+    from ceph_tpu.client.rados import Rados
+    from ceph_tpu.loadgen.cluster import SimCluster
+    from ceph_tpu.osd.scrub import scrub_pg
+
+    async def main():
+        cluster = await SimCluster.create(4)
+        rados = await Rados(cluster.addr, name="client.dp").connect()
+        try:
+            await rados.mon_command(
+                "osd erasure-code-profile set",
+                {"name": "prof", "profile": {
+                    "plugin": "tpu", "k": "2", "m": "1",
+                    "technique": "reed_sol_van"}})
+            await rados.pool_create("ecpool", pg_num=8,
+                                    pool_type="erasure",
+                                    erasure_code_profile="prof")
+            io = await rados.open_ioctx("ecpool")
+            rng = np.random.default_rng(14)
+            objs = {f"o{i}": rng.integers(0, 256, 3 * 8192 + 100,
+                                          dtype=np.uint8).tobytes()
+                    for i in range(12)}
+            await asyncio.gather(*(io.write_full(oid, data)
+                                   for oid, data in objs.items()))
+            batch = cluster.perf_counters("ec_batch")
+            assert batch["batches"] == batch["mesh_launches"] > 0
+
+            async def one_pass():
+                if phase == "scrub":
+                    pgs = [pg for osd in cluster.osds
+                           if not osd.is_stopped()
+                           for pg in osd.pgs.values()
+                           if pg.is_primary()
+                           and pg.pool.name == "ecpool"]
+                    results = [await scrub_pg(pg) for pg in pgs]
+                    assert all(r.clean for r in results)
+                    assert sum(r.objects_scrubbed
+                               for r in results) == len(objs)
+                    return None
+                return {oid: await io.read(oid) for oid in objs}
+
+            def counters():
+                got = cluster.perf_counters("datapath")
+                return [got.get(key, 0) for key in
+                        ("hits", "host_reads", "host_bytes_read")]
+
+            if phase == "degraded_read":
+                await cluster.kill_osd(3)
+                assert await cluster.wait_down(3)
+                degraded0 = cluster.perf_counters("ec_degraded").get(
+                    "degraded_reads", 0)
+            hits0, reads0, bytes0 = counters()
+            cached = await one_pass()
+            hits1, reads1, bytes1 = counters()
+            assert hits1 > hits0, "the pass never hit the cache"
+            assert (reads1, bytes1) == (reads0, bytes0), \
+                "a cached steady pass read shard bytes from the store"
+            if phase == "degraded_read":
+                assert cluster.perf_counters("ec_degraded").get(
+                    "degraded_reads", 0) > degraded0
+            # control: the same pass cold
+            for osd in cluster.osds:
+                if not osd.is_stopped():
+                    osd.shard_cache.clear()
+            cold = await one_pass()
+            assert counters()[2] > bytes1, \
+                "the counter missed the cold pass's store reads"
+            if cached is not None:
+                assert cached == objs and cold == objs
+        finally:
+            await rados.shutdown()
+            await cluster.stop()
+    run(main())
+
+
 # -- write path: donated launches feed residency -----------------------------
 
 def test_write_path_populates_cache_and_donation_is_safe():

@@ -442,6 +442,14 @@ def test_hedged_reads_byte_parity_and_no_retry_coupling():
                 o.perf.get("ec_degraded").get("gather_retries")
                 for o in c.osds)
             assert retries1 == retries0, "hedged ops scheduled retries"
+            # nor left a sub-read behind: once the pass has settled no
+            # ``OSD.start_request`` task is pending (a live one means a
+            # gather returned without reaping its stragglers)
+            await asyncio.sleep(0.05)
+            leaked = [t for t in asyncio.all_tasks() if not t.done()
+                      and getattr(t.get_coro(), "__name__", "")
+                      == "_issue"]
+            assert not leaked, leaked
             # unhedged oracle: same bytes through the full-set gather
             inj.clear()
             for o in c.osds:

@@ -7,6 +7,7 @@ operate on the files the daemons left behind."""
 import asyncio
 import json
 import os
+import sys
 
 import pytest
 
@@ -121,3 +122,26 @@ def test_offline_tools_roundtrip(tmp_path, capsys):
         json.dump(final_map, f)
     assert osdmaptool.main([replay_path, "--test-map-pgs"]) == 0
     assert "pool pg count: 4" in capsys.readouterr().out
+
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+
+def test_crush_bench_cli_times_verified_launches():
+    """python -m ceph_tpu.tools.crush_bench (BASELINE config 5's own
+    entry point) runs end to end: exit 0, a mapping rate, and the
+    verified lanes sampled from the timed launches."""
+    import subprocess
+
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
+    r = subprocess.run(
+        [sys.executable, "-m", "ceph_tpu.tools.crush_bench",
+         "--pgs", "20000", "--batch", "10000", "--verify", "16"],
+        capture_output=True, text=True, cwd=REPO, env=env, timeout=240)
+    assert r.returncode == 0, r.stderr[-2000:]
+    res = json.loads(r.stdout.strip().splitlines()[-1])
+    assert res["metric"] == "crush_bulk_mappings_per_s"
+    assert res["value"] > 0
+    assert res["n_mappings"] == 20000 and res["launches"] == 2
+    assert res["verified_lanes"] == 16
+    assert res["lane_exact_vs_scalar"] is True

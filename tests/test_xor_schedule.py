@@ -111,9 +111,8 @@ def test_liberation_parity(k, w):
 
 def test_cse_fires_and_headline_reduction():
     """Term count strictly below the naive row-by-row XOR count, and
-    the Cauchy k=8,m=3 headline matrix clears the 30% floor (the
-    ISSUE acceptance gate, also enforced by bench --osd-path
-    --smoke)."""
+    the Cauchy k=8,m=3 headline matrix clears the 30% floor, as a
+    count of terms."""
     bm = cauchy_bm(8, 3, 8, True)
     sched = XS.compile_schedule(bm)
     assert sched.n_terms < sched.naive_terms

@@ -42,7 +42,7 @@ if REPO_ROOT not in sys.path:
 
 from ceph_tpu import analysis                            # noqa: E402
 
-DEFAULT_PATHS = ["ceph_tpu", "tools", "bench.py"]
+DEFAULT_PATHS = ["ceph_tpu", "tools"]
 DEFAULT_BASELINE = os.path.join(REPO_ROOT, "tools",
                                 "lint_baseline.txt")
 DEFAULT_SEAM_REPORT = os.path.join(REPO_ROOT, "SEAM_AUDIT.json")

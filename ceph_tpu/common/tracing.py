@@ -53,7 +53,7 @@ from .perf import PerfCounters
 # benchmark's per-layer metrics read these prefixes letter for letter
 SECTION_LAYERS = ("client", "wire", "osd_op", "osd_read", "store",
                   "batcher", "device_wait", "recovery", "scrub", "loop",
-                  "placement")
+                  "placement", "registry")
 
 
 class _NoSection:

@@ -7,6 +7,15 @@ src/test/erasure-code/ceph_erasure_code_benchmark.cc:170).  Parity bytes
 are identical to the `isa` plugin (same generator matrices, same GF(2^8)
 field); only the execution engine differs: stripes are batched into one
 MXU bit-matmul launch (see ceph_tpu/ops/gf2kernels.py).
+
+What a caller of the batch entry points paid is counted in the
+plugin's ``ec_registry`` set (``codec.perf``): ``launches``, ``stripes``,
+``bytes_in``, ``bytes_out``, ``engine_<name>`` (the engine that served,
+per launch), ``parity_gates`` (first launches of a matrix held to the
+host oracle), ``table_hits`` / ``table_misses`` (decode matrices taken
+from, or built into, the DecodeTableCache); where its thread was is in
+the ``registry.*`` sections (``marshal`` here, the rest in
+``gf_matmul_batch_device``).
 """
 
 from __future__ import annotations
@@ -15,12 +24,17 @@ import numpy as np
 
 from .isa import ErasureCodeIsa, K_VANDERMONDE
 from ..registry import ErasureCodePlugin
+from ...common.perf import PerfCounters
+from ...common.tracing import section
+from ...gf import build_decode_matrix, erasure_signature
+from ...gf.matrices import decode_index_for
 from ...ops.jax_backend import JaxBackend
 
 
 class ErasureCodeTpu(ErasureCodeIsa):
     def __init__(self, technique: str = K_VANDERMONDE) -> None:
-        super().__init__(technique=technique, backend=JaxBackend())
+        self.perf = PerfCounters("ec_registry")
+        super().__init__(technique=technique, backend=JaxBackend(self.perf))
 
     # -- batched entry points (OSD CodecBatcher / bench fast path) ----------
     def encode_batch(self, data: np.ndarray, out_np: bool = False):
@@ -33,8 +47,6 @@ class ErasureCodeTpu(ErasureCodeIsa):
         grouping key the per-OSD CodecBatcher uses to decide which
         reconstruction submissions may share a decode_batch launch
         (same signature = same decode matrix = same math)."""
-        from ...gf import erasure_signature
-        from ...gf.matrices import decode_index_for
         return erasure_signature(
             decode_index_for(self.k, set(erasures)), list(erasures))
 
@@ -48,19 +60,42 @@ class ErasureCodeTpu(ErasureCodeIsa):
         matrix = self.decode_matrix_for(erasures)
         return self.backend.matmul_batch(matrix, chunks, out_np=out_np)
 
+    def decode_stripes(self, erasures: list[int], stripes: np.ndarray,
+                       out_np: bool = False):
+        """Recover ``erasures`` from whole stripes in host memory.
+
+        ``stripes`` is (B, k+m, L) with chunk i of every stripe at
+        ``[:, i]`` (the chunk map a caller of ``decode`` hands over,
+        for B stripes at once); what lies at an erased position is
+        never read.  The k survivors are gathered here in decode_index
+        order; the result is (B, len(erasures), L), row p the chunk
+        ``erasures[p]``."""
+        with section("registry.marshal"):
+            index = decode_index_for(self.k, set(erasures))
+            # chunk by chunk into a C-ordered array: ``stripes[:, index]``
+            # comes back with the chunk axis outermost in memory and the
+            # upload would copy the whole batch a second time
+            survivors = np.empty(
+                (stripes.shape[0], self.k, stripes.shape[2]), stripes.dtype)
+            for row, chunk in enumerate(index):
+                survivors[:, row] = stripes[:, chunk]
+        return self.decode_batch(erasures, survivors, out_np=out_np)
+
     def decode_matrix_for(self, erasures) -> np.ndarray:
         """The decode matrix an erasure pattern selects, through the
         DecodeTableCache.  Shared by ``decode_batch`` and the sharded
         MeshCodec decode path, so both launch engines compute with the
         identical matrix (byte parity by construction)."""
-        from ...gf import build_decode_matrix
         signature = self.decode_signature(erasures)
         entry = self.tcache.get(signature)
         if entry is None:
-            matrix, decode_index = build_decode_matrix(
-                self.encode_matrix, self.k, list(erasures))
+            self.perf.inc("table_misses")
+            with section("registry.matrix"):
+                matrix, decode_index = build_decode_matrix(
+                    self.encode_matrix, self.k, list(erasures))
             self.tcache.put(signature, matrix, decode_index)
         else:
+            self.perf.inc("table_hits")
             matrix, decode_index = entry
         return matrix
 

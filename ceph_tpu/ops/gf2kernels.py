@@ -36,6 +36,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
+from ..common.tracing import section
 from ..gf.gf8 import matrix_to_bitmatrix
 
 # column-tile width for the pallas kernel; also the padding bucket for the
@@ -58,6 +59,23 @@ def bucket_batch(b: int) -> int:
     while n < b:
         n *= 2
     return n
+
+
+# what the batch programs carry in a device trace, whichever engine
+# serves: the module is ``jit_registry_gf_<engine>``, its operations
+# sit under the scope
+REGISTRY_SCOPE = "registry_gf"
+
+
+def registry_program(engine: str, fn):
+    """``fn`` jitted as ``registry_gf_<engine>`` under ``REGISTRY_SCOPE``:
+    a trace tells the registry's launches from anything else a process
+    runs (parallel/sharded_ec.py names the mesh's the same way)."""
+    def program(*args):
+        with jax.named_scope(REGISTRY_SCOPE):
+            return fn(*args)
+    program.__name__ = program.__qualname__ = f"{REGISTRY_SCOPE}_{engine}"
+    return jax.jit(program)
 
 
 @functools.lru_cache(maxsize=256)
@@ -196,7 +214,7 @@ def _make_pallas_batch_fn(r8: int, k: int, b: int, l: int, tile: int):
                                memory_space=pltpu.VMEM),
         interpret=_interpret(),
     )
-    return jax.jit(fn)
+    return registry_program("v1", fn)
 
 
 # ---------------------------------------------------------------------------
@@ -339,7 +357,7 @@ def _make_pallas_batch_fn_gN(r8: int, k: int, b: int, l: int, g: int,
                                memory_space=pltpu.VMEM),
         interpret=_interpret(),
     )
-    return jax.jit(fn)
+    return registry_program("gN", fn)
 
 
 @functools.lru_cache(maxsize=512)
@@ -450,17 +468,20 @@ _gN_verified: set[tuple] = set()
 
 
 def _run_gN(matrix: np.ndarray, xd, b: int, k: int, l: int, cfg: dict,
-            g: int, tile: int):
+            g: int, tile: int, perf=None):
     """Launch the MXU-packed kernel.  A compile failure propagates; a
     first-launch parity miss raises ``KernelParityError``."""
     mat_bytes = matrix.tobytes()
     r = matrix.shape[0]
     fn = _compiled_batch_gN(8 * r, k, b, l, g, cfg["unpack"], cfg["mm"],
                             cfg["pack"], tile)
-    out = fn(_w_gN_device(mat_bytes, r, k, g, cfg["mm"]), xd)
+    with section("registry.matrix"):
+        w = _w_gN_device(mat_bytes, r, k, g, cfg["mm"])
+    out = fn(w, xd)
     key = (mat_bytes, b, l, tuple(sorted(cfg.items())), g)
     if key not in _gN_verified:
-        check_batch_parity("gN pallas kernel", matrix, xd, out, min(g, 2))
+        check_batch_parity("gN pallas kernel", matrix, xd, out, min(g, 2),
+                           perf)
         _gN_verified.add(key)
     return out
 
@@ -472,21 +493,26 @@ class KernelParityError(RuntimeError):
 
 
 def check_batch_parity(what: str, matrix: np.ndarray, xd, out,
-                       nb: int) -> None:
+                       nb: int, perf=None) -> None:
     """One-time byte-parity gate vs the host oracle on a small slice of
-    a (B, k, L) launch; raises ``KernelParityError`` on a miss."""
+    a (B, k, L) launch; raises ``KernelParityError`` on a miss.  It
+    waits for the launch it checks: ``perf`` counts it
+    (``parity_gates``) and the section times it."""
     from ..gf import gf_matmul
     ncheck = min(256, xd.shape[2])
-    # lint: disable=device-path-host-sync -- one-time parity gate vs the host oracle, bounded slice
-    got = np.asarray(out[:nb, :, :ncheck])
-    # lint: disable=device-path-host-sync -- one-time parity gate vs the host oracle, bounded slice
-    sample = np.asarray(xd[:nb, :, :ncheck])
-    for i in range(nb):
-        if not np.array_equal(got[i], gf_matmul(matrix, sample[i])):
-            raise KernelParityError(
-                f"{what} disagrees with the host GF oracle "
-                f"(matrix {matrix.shape}, batch shape {tuple(xd.shape)}, "
-                f"stripe {i})")
+    if perf is not None:
+        perf.inc("parity_gates")
+    with section("registry.matrix"):
+        # lint: disable=device-path-host-sync -- one-time parity gate vs the host oracle, bounded slice
+        got = np.asarray(out[:nb, :, :ncheck])
+        # lint: disable=device-path-host-sync -- one-time parity gate vs the host oracle, bounded slice
+        sample = np.asarray(xd[:nb, :, :ncheck])
+        for i in range(nb):
+            if not np.array_equal(got[i], gf_matmul(matrix, sample[i])):
+                raise KernelParityError(
+                    f"{what} disagrees with the host GF oracle "
+                    f"(matrix {matrix.shape}, batch shape "
+                    f"{tuple(xd.shape)}, stripe {i})")
 
 
 @functools.lru_cache(maxsize=512)
@@ -498,7 +524,7 @@ def _compiled_batch(r8: int, k: int, b: int, l: int, use_pallas: bool):
         flat = xd.transpose(1, 0, 2).reshape(k, b * l)
         out = _gf_matmul_math(w, flat)
         return out.reshape(r8 // 8, b, l).transpose(1, 0, 2)
-    return jax.jit(fn)
+    return registry_program("xla", fn)
 
 
 def _select_batch_engine(matrix: np.ndarray, b: int, k: int, l: int):
@@ -532,23 +558,56 @@ def batch_engine(matrix: np.ndarray, b: int, k: int, l: int) -> str:
     return _select_batch_engine(matrix, b, k, l)[0]
 
 
-def gf_matmul_batch_device(matrix: np.ndarray, data, *, out_np: bool = False):
+def gf_matmul_batch_device(matrix: np.ndarray, data, *, out_np: bool = False,
+                           perf=None):
     """Batched stripes: (B, k, L) -> (B, r, L), ONE device dispatch
     (layout changes included: everything lives under one jit).  The
     engine is ``batch_engine``'s choice; whatever it picks either
-    serves or raises."""
+    serves or raises.
+
+    ``data`` in host memory is uploaded here and ``out_np`` brings the
+    result back to it, each step in turn and under its own section:
+    ``registry.upload`` (until the bytes are on the device),
+    ``registry.launch`` (engine choice and dispatch; the matrix's
+    device copy and a first launch's parity gate are ``registry.matrix``
+    inside it), ``registry.device_wait`` (until the kernel is done),
+    ``registry.copy_out`` (until the last byte is readable on the
+    host).  A device array in and ``out_np=False`` skip the copies and
+    wait for nothing.  ``perf`` (the plugin's ``ec_registry`` set) counts
+    the launch: ``launches``, ``stripes``, ``bytes_in``, ``bytes_out``,
+    ``engine_<name>``, ``parity_gates``."""
     b, k, l = data.shape
     matrix = np.ascontiguousarray(matrix, dtype=np.uint8)
-    xd = jnp.asarray(data, dtype=jnp.uint8)
-    engine, plan = _select_batch_engine(matrix, b, k, l)
-    if engine == "sched":
-        from .xor_schedule import sched_matmul_batch_device
-        out = sched_matmul_batch_device(plan, matrix, xd, b, k, l)
-    elif engine == "gN":
-        out = _run_gN(matrix, xd, b, k, l, *plan)
+    if isinstance(data, jax.Array):
+        xd = jnp.asarray(data, dtype=jnp.uint8)
     else:
-        w = bitmatrix_device(matrix)
-        fn = _compiled_batch(w.shape[0], k, b, l, engine == "v1")
-        out = fn(w, xd)
-    # lint: disable=device-path-host-sync -- the single post-launch materialization (caller opts in via out_np)
-    return np.asarray(out) if out_np else out
+        with section("registry.upload"):
+            xd = jax.device_put(np.ascontiguousarray(data, dtype=np.uint8))
+            # lint: disable=device-path-host-sync -- the upload is timed apart from the kernel it feeds; the launch needs its last byte either way
+            xd.block_until_ready()
+    with section("registry.launch"):
+        engine, plan = _select_batch_engine(matrix, b, k, l)
+        if engine == "sched":
+            from .xor_schedule import sched_matmul_batch_device
+            out = sched_matmul_batch_device(plan, matrix, xd, b, k, l, perf)
+        elif engine == "gN":
+            out = _run_gN(matrix, xd, b, k, l, *plan, perf)
+        else:
+            with section("registry.matrix"):
+                w = bitmatrix_device(matrix)
+            fn = _compiled_batch(w.shape[0], k, b, l, engine == "v1")
+            out = fn(w, xd)
+    if perf is not None:
+        perf.inc("launches")
+        perf.inc(f"engine_{engine}")
+        perf.inc("stripes", b)
+        perf.inc("bytes_in", b * k * l)
+        perf.inc("bytes_out", b * matrix.shape[0] * l)
+    if not out_np:
+        return out
+    with section("registry.device_wait"):
+        # lint: disable=device-path-host-sync -- the caller asked for host bytes (out_np): the wait is timed apart from the copy
+        out.block_until_ready()
+    with section("registry.copy_out"):
+        # lint: disable=device-path-host-sync -- the single post-launch materialization (caller opts in via out_np)
+        return np.asarray(out)

@@ -13,9 +13,13 @@ HOST_FALLBACK_BYTES = 0  # parity-critical: keep everything on one code path
 class JaxBackend:
     name = "jax"
 
+    def __init__(self, perf=None) -> None:
+        self.perf = perf        # the plugin's ``ec_registry`` set, or none
+
     def matmul(self, matrix: np.ndarray, data: np.ndarray) -> np.ndarray:
         return gf_matmul_device(matrix, data, out_np=True)
 
     def matmul_batch(self, matrix: np.ndarray, data: np.ndarray,
                      out_np: bool = False):
-        return gf_matmul_batch_device(matrix, data, out_np=out_np)
+        return gf_matmul_batch_device(matrix, data, out_np=out_np,
+                                      perf=self.perf)

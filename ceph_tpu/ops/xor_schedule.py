@@ -497,7 +497,7 @@ def apply_bits_traced(sched: XorSchedule, data_u8):
 
 @functools.lru_cache(maxsize=512)
 def _compiled_sched_batch(digest: str, b: int, k: int, l: int):
-    import jax
+    from .gf2kernels import registry_program
     sched = registered(digest)
 
     def fn(xd):  # (B, k, L) -> (B, r, L), whole path under one jit
@@ -505,7 +505,7 @@ def _compiled_sched_batch(digest: str, b: int, k: int, l: int):
         out = apply_bits_traced(sched, flat)
         return out.reshape(-1, b, l).transpose(1, 0, 2)
 
-    return jax.jit(fn)
+    return registry_program("sched", fn)
 
 
 # (digest, shape) keys whose scheduled launch passed its one-time
@@ -569,7 +569,7 @@ def want_scheduled(bitmatrix: np.ndarray, lane: int, backend: str,
 
 
 def sched_matmul_batch_device(sched: XorSchedule, matrix: np.ndarray,
-                              xd, b: int, k: int, l: int):
+                              xd, b: int, k: int, l: int, perf=None):
     """Launch the scheduled kernel family for a (B, k, L) device batch
     of the (r, k) GF(2^8) coefficient ``matrix``; returns the (B, r, L)
     device output.  Same padding buckets and one-launch contract as the
@@ -581,7 +581,7 @@ def sched_matmul_batch_device(sched: XorSchedule, matrix: np.ndarray,
     key = (sched.digest, b, k, l)
     if key not in _sched_verified:
         check_batch_parity("scheduled XOR kernel", matrix, xd, out,
-                           min(b, 2))
+                           min(b, 2), perf)
         _sched_verified.add(key)
     STATS.note_launch(sched)
     return out

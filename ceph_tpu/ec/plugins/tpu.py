@@ -11,10 +11,12 @@ MXU bit-matmul launch (see ceph_tpu/ops/gf2kernels.py).
 What a caller of the batch entry points paid is counted in the
 plugin's ``ec_registry`` set (``codec.perf``): ``launches``, ``stripes``,
 ``bytes_in``, ``bytes_out``, ``engine_<name>`` (the engine that served,
-per launch), ``parity_gates`` (first launches of a matrix held to the
-host oracle), ``table_hits`` / ``table_misses`` (decode matrices taken
-from, or built into, the DecodeTableCache); where its thread was is in
-the ``registry.*`` sections (``marshal`` here, the rest in
+per call), ``slabs`` / ``pipelined`` / ``staging_waits`` (how a call from
+host memory to host memory streamed through the device),
+``parity_gates`` (first launches of a matrix held to the host oracle),
+``table_hits`` / ``table_misses`` (decode matrices taken from, or built
+into, the DecodeTableCache); where its thread was is in the
+``registry.*`` sections (a table miss's ``matrix`` here, the rest in
 ``gf_matmul_batch_device``).
 """
 
@@ -38,7 +40,9 @@ class ErasureCodeTpu(ErasureCodeIsa):
 
     # -- batched entry points (OSD CodecBatcher / bench fast path) ----------
     def encode_batch(self, data: np.ndarray, out_np: bool = False):
-        """(B, k, L) data chunks -> (B, m, L) parity chunks, one launch."""
+        """(B, k, L) data chunks -> (B, m, L) parity chunks: one launch,
+        or from host memory to host memory (``out_np``) a pipeline of
+        slabs (``gf_matmul_batch_device``)."""
         return self.backend.matmul_batch(
             self.encode_matrix[self.k:], data, out_np=out_np)
 
@@ -67,19 +71,14 @@ class ErasureCodeTpu(ErasureCodeIsa):
         ``stripes`` is (B, k+m, L) with chunk i of every stripe at
         ``[:, i]`` (the chunk map a caller of ``decode`` hands over,
         for B stripes at once); what lies at an erased position is
-        never read.  The k survivors are gathered here in decode_index
-        order; the result is (B, len(erasures), L), row p the chunk
-        ``erasures[p]``."""
-        with section("registry.marshal"):
-            index = decode_index_for(self.k, set(erasures))
-            # chunk by chunk into a C-ordered array: ``stripes[:, index]``
-            # comes back with the chunk axis outermost in memory and the
-            # upload would copy the whole batch a second time
-            survivors = np.empty(
-                (stripes.shape[0], self.k, stripes.shape[2]), stripes.dtype)
-            for row, chunk in enumerate(index):
-                survivors[:, row] = stripes[:, chunk]
-        return self.decode_batch(erasures, survivors, out_np=out_np)
+        never read.  The k survivors are gathered in decode_index
+        order on the way to the device (``gf_matmul_batch_device``'s
+        ``rows``: slab by slab when the result is asked back to host
+        memory, never the whole batch at once); the result is
+        (B, len(erasures), L), row p the chunk ``erasures[p]``."""
+        return self.backend.matmul_batch(
+            self.decode_matrix_for(erasures), stripes,
+            rows=decode_index_for(self.k, set(erasures)), out_np=out_np)
 
     def decode_matrix_for(self, erasures) -> np.ndarray:
         """The decode matrix an erasure pattern selects, through the

@@ -27,6 +27,7 @@ caller -- never a quiet drop to a slower engine.
 
 from __future__ import annotations
 
+import collections
 import functools
 import json
 import os
@@ -558,56 +559,150 @@ def batch_engine(matrix: np.ndarray, b: int, k: int, l: int) -> str:
     return _select_batch_engine(matrix, b, k, l)[0]
 
 
-def gf_matmul_batch_device(matrix: np.ndarray, data, *, out_np: bool = False,
-                           perf=None):
-    """Batched stripes: (B, k, L) -> (B, r, L), ONE device dispatch
-    (layout changes included: everything lives under one jit).  The
-    engine is ``batch_engine``'s choice; whatever it picks either
-    serves or raises.
+# input bytes of one slab of a host-to-host call (``_slab_stripes``)
+SLAB_BYTES = 32 << 20
 
-    ``data`` in host memory is uploaded here and ``out_np`` brings the
-    result back to it, each step in turn and under its own section:
-    ``registry.upload`` (until the bytes are on the device),
-    ``registry.launch`` (engine choice and dispatch; the matrix's
-    device copy and a first launch's parity gate are ``registry.matrix``
-    inside it), ``registry.device_wait`` (until the kernel is done),
-    ``registry.copy_out`` (until the last byte is readable on the
-    host).  A device array in and ``out_np=False`` skip the copies and
-    wait for nothing.  ``perf`` (the plugin's ``ec_registry`` set) counts
-    the launch: ``launches``, ``stripes``, ``bytes_in``, ``bytes_out``,
-    ``engine_<name>``, ``parity_gates``."""
-    b, k, l = data.shape
-    matrix = np.ascontiguousarray(matrix, dtype=np.uint8)
-    if isinstance(data, jax.Array):
-        xd = jnp.asarray(data, dtype=jnp.uint8)
+
+def _slab_stripes(b: int, k: int, l: int) -> int:
+    """Stripes a slab of a (B, k, L) host-to-host call holds:
+    ``SLAB_BYTES`` of input in whole groups of the packed engine, one
+    group at least, the batch at most."""
+    g = pick_group(k, b)
+    return min(b, max(g, SLAB_BYTES // (k * l) // g * g))
+
+
+def _gather_rows(data, rows, lo: int, hi: int, into: np.ndarray):
+    """Chunks ``rows`` of stripes ``lo:hi`` of a (B, n, L) host array
+    into the C-ordered ``into``, chunk by chunk (``data[lo:hi, rows]``
+    comes back with the chunk axis outermost in memory, and an upload
+    of it copies the slab a second time); no other chunk is read."""
+    for row, chunk in enumerate(rows):
+        into[:hi - lo, row] = data[lo:hi, chunk]
+    return into[:hi - lo]
+
+
+def _launch_batch(matrix: np.ndarray, xd, perf=None):
+    """One (B, k, L) device array through ``batch_engine``'s choice:
+    (engine name, the (B, r, L) device result, not waited for)."""
+    b, k, l = xd.shape
+    engine, plan = _select_batch_engine(matrix, b, k, l)
+    if engine == "sched":
+        from .xor_schedule import sched_matmul_batch_device
+        out = sched_matmul_batch_device(plan, matrix, xd, b, k, l, perf)
+    elif engine == "gN":
+        out = _run_gN(matrix, xd, b, k, l, *plan, perf)
     else:
-        with section("registry.upload"):
-            xd = jax.device_put(np.ascontiguousarray(data, dtype=np.uint8))
-            # lint: disable=device-path-host-sync -- the upload is timed apart from the kernel it feeds; the launch needs its last byte either way
-            xd.block_until_ready()
-    with section("registry.launch"):
-        engine, plan = _select_batch_engine(matrix, b, k, l)
-        if engine == "sched":
-            from .xor_schedule import sched_matmul_batch_device
-            out = sched_matmul_batch_device(plan, matrix, xd, b, k, l, perf)
-        elif engine == "gN":
-            out = _run_gN(matrix, xd, b, k, l, *plan, perf)
+        with section("registry.matrix"):
+            w = bitmatrix_device(matrix)
+        fn = _compiled_batch(w.shape[0], k, b, l, engine == "v1")
+        out = fn(w, xd)
+    return engine, out
+
+
+def gf_matmul_batch_device(matrix: np.ndarray, data, *, rows=None,
+                           out_np: bool = False, perf=None):
+    """Batched stripes: (B, k, L) -> (B, r, L) (layout changes
+    included: a launch lives under one jit).  With ``rows``, ``data`` is
+    (B, n, L) and stripe s's operands are ``data[s, rows]``, k of its n
+    chunks in that order; no other chunk is read.  The engine is
+    ``batch_engine``'s choice; whatever it picks either serves or raises.
+
+    A device array in, or ``out_np=False``, is ONE dispatch, and
+    nothing is waited for.  ``data`` in host memory with the result
+    asked back to it (``out_np``) streams through the device in slabs
+    of ``_slab_stripes`` stripes, each through the same program at the
+    slab's shape; a batch no larger than a slab is one slab.  Per slab
+    and under its own section: ``registry.marshal`` (with ``rows``: the
+    gather into one of two staging buffers, refilled only once the
+    launch that read it is done), ``registry.upload`` (``device_put``;
+    a one-slab call waits until the bytes are on the device, a slab of
+    many does not), ``registry.launch`` (engine choice and dispatch; the
+    matrix's device copy and a first launch's parity gate are
+    ``registry.matrix`` inside it), then the result's copy to the host
+    is started and the slab two back is landed in its rows of the
+    call's one result array (``registry.drain``).  So slab i+1's gather
+    and upload, slab i's kernel and slab i-1's copy-out are in flight
+    together and three slabs at most live on the device.  The call
+    closes with the last slab's ``registry.device_wait`` and
+    ``registry.copy_out`` (until the last byte is readable on the
+    host); the result is a C-ordered array of the caller's own.
+
+    ``perf`` (the plugin's ``ec_registry`` set) counts a call once,
+    however many slabs: ``launches``, ``stripes``, ``bytes_in``,
+    ``bytes_out``, ``engine_<name>``; and ``slabs`` (device launches),
+    ``pipelined`` (calls of more than one slab), ``staging_waits``
+    (refills that had to wait for a launch), ``parity_gates``."""
+    b, _, l = data.shape
+    matrix = np.ascontiguousarray(matrix, dtype=np.uint8)
+    r, k = matrix.shape
+    on_host = not isinstance(data, jax.Array)
+    step = _slab_stripes(b, k, l) if on_host and out_np else b
+    spans = [(lo, min(lo + step, b)) for lo in range(0, b, step)]
+    if on_host and rows is not None:
+        staging = [np.empty((step, k, l), np.uint8) for _ in spans[:2]]
+    result = np.empty((b, r, l), np.uint8) if len(spans) > 1 else None
+    flying: collections.deque = collections.deque()   # (lo, hi, out)
+    waits = 0
+
+    def land() -> None:
+        lo, hi, out = flying.popleft()
+        # lint: disable=device-path-host-sync -- the caller asked for host bytes (out_np); the copy was started at the launch
+        result[lo:hi] = np.asarray(out)
+
+    for i, (lo, hi) in enumerate(spans):
+        if not on_host:
+            xd = jnp.asarray(data, dtype=jnp.uint8)
+            if rows is not None:
+                xd = jnp.take(xd, jnp.asarray(rows), axis=1)
         else:
-            with section("registry.matrix"):
-                w = bitmatrix_device(matrix)
-            fn = _compiled_batch(w.shape[0], k, b, l, engine == "v1")
-            out = fn(w, xd)
+            if rows is None:
+                slab = np.ascontiguousarray(data[lo:hi], dtype=np.uint8)
+            else:
+                with section("registry.marshal"):
+                    # slab i - 2's launch read this buffer's upload
+                    reader = flying[0][2] if len(flying) == 2 else None
+                    if reader is not None and not reader.is_ready():
+                        waits += 1
+                        # lint: disable=device-path-host-sync -- a staging buffer is refilled only after the launch that read it
+                        reader.block_until_ready()
+                    slab = _gather_rows(data, rows, lo, hi, staging[i % 2])
+            with section("registry.upload"):
+                xd = jax.device_put(slab)
+                if result is None:
+                    # lint: disable=device-path-host-sync -- a one-slab upload is timed apart from the kernel it feeds; the launch needs its last byte either way
+                    xd.block_until_ready()
+        with section("registry.launch"):
+            engine, out = _launch_batch(matrix, xd, perf)
+            if i == 0:
+                served = engine
+            if result is not None:
+                out.copy_to_host_async()
+        flying.append((lo, hi, out))
+        if len(flying) > 2:
+            with section("registry.drain"):
+                land()
     if perf is not None:
         perf.inc("launches")
-        perf.inc(f"engine_{engine}")
+        perf.inc(f"engine_{served}")
         perf.inc("stripes", b)
         perf.inc("bytes_in", b * k * l)
-        perf.inc("bytes_out", b * matrix.shape[0] * l)
+        perf.inc("bytes_out", b * r * l)
+        perf.inc("slabs", len(spans))
+        if result is not None:
+            perf.inc("pipelined")
+        if waits:
+            perf.inc("staging_waits", waits)
     if not out_np:
         return out
+    while len(flying) > 1:
+        with section("registry.drain"):
+            land()
     with section("registry.device_wait"):
         # lint: disable=device-path-host-sync -- the caller asked for host bytes (out_np): the wait is timed apart from the copy
         out.block_until_ready()
     with section("registry.copy_out"):
-        # lint: disable=device-path-host-sync -- the single post-launch materialization (caller opts in via out_np)
-        return np.asarray(out)
+        if result is None:
+            # lint: disable=device-path-host-sync -- the single post-launch materialization (caller opts in via out_np)
+            return np.asarray(out)
+        land()
+        return result

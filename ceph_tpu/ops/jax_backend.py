@@ -20,6 +20,6 @@ class JaxBackend:
         return gf_matmul_device(matrix, data, out_np=True)
 
     def matmul_batch(self, matrix: np.ndarray, data: np.ndarray,
-                     out_np: bool = False):
-        return gf_matmul_batch_device(matrix, data, out_np=out_np,
-                                      perf=self.perf)
+                     out_np: bool = False, rows=None):
+        return gf_matmul_batch_device(matrix, data, rows=rows,
+                                      out_np=out_np, perf=self.perf)

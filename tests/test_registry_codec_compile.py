@@ -1,7 +1,8 @@
 """The registry cell's kernel compiled for the chip it runs on, without
-the chip: ``gN`` at ``(1024, 8, 131072)`` for r = 1, 2 and 3 output rows
-through the TPU compiler for a described v5e (nothing runs, nothing is
-timed).  What the Pallas interpreter cannot show -- a block shape Mosaic
+the chip: ``gN`` at ``(1024, 8, 131072)``, the whole batch a device-resident
+caller launches, and at the slab a call from host memory streams it in
+(``gf2kernels._slab_stripes``), for r = 1, 2 and 3 output rows through the
+TPU compiler for a described v5e (nothing runs, nothing is timed).  What the Pallas interpreter cannot show -- a block shape Mosaic
 declines, a launch that does not fit the device -- fails here.  One
 file: the worker that is given it loads the TPU library, inside the
 fixture, and no other does.
@@ -30,9 +31,7 @@ def one_chip():
     return SingleDeviceSharding(topo.devices[0])
 
 
-@pytest.mark.parametrize("rows", [1, 2, 3])
-def test_gN_compiles_for_the_v5e_at_the_cells_shape(one_chip, rows,
-                                                    monkeypatch):
+def compile_gN(one_chip, monkeypatch, batch, rows):
     import jax
     import jax.numpy as jnp
     import ceph_tpu.ops.gf2kernels as g
@@ -40,21 +39,44 @@ def test_gN_compiles_for_the_v5e_at_the_cells_shape(one_chip, rows,
     # the CPU backend of a test run would build the interpreter's kernel
     monkeypatch.setattr(g, "_interpret", lambda: False)
     cfg = g._g2_cfg(K)
-    plan = g._gN_plan(K, B, L, cfg)
+    plan = g._gN_plan(K, batch, L, cfg)
     assert plan == (2, g.LANE_TILE)         # two stripes a step, 8192 lanes
     group, tile = plan
     # the maker, not ``_compiled_batch_gN``: nothing built here is cached
-    fn = g._make_pallas_batch_fn_gN(8 * rows, K, B, L, group, tile,
+    fn = g._make_pallas_batch_fn_gN(8 * rows, K, batch, L, group, tile,
                                     cfg["unpack"], cfg["mm"], cfg["pack"])
     w = jax.ShapeDtypeStruct((group * 8 * rows, 8 * group * K), jnp.int8,
                              sharding=one_chip)
-    xd = jax.ShapeDtypeStruct((B, K, L), jnp.uint8, sharding=one_chip)
+    xd = jax.ShapeDtypeStruct((batch, K, L), jnp.uint8, sharding=one_chip)
     compiled = fn.lower(w, xd).compile()
     assert "tpu_custom_call" in compiled.as_text()
     mem = compiled.memory_analysis()
-    assert mem.output_size_in_bytes == B * rows * L
-    assert mem.argument_size_in_bytes >= B * K * L
+    assert mem.output_size_in_bytes == batch * rows * L
+    assert mem.argument_size_in_bytes >= batch * K * L
+    return mem
+
+
+@pytest.mark.parametrize("rows", [1, 2, 3])
+def test_gN_compiles_for_the_v5e_at_the_cells_shape(one_chip, rows,
+                                                    monkeypatch):
+    mem = compile_gN(one_chip, monkeypatch, B, rows)
     # the whole launch (1 GiB in, the rows out, the compiler's padded copy
     # of the result) leaves most of the chip's memory free
     assert (mem.argument_size_in_bytes + mem.output_size_in_bytes
             + mem.temp_size_in_bytes) < HBM_BYTES / 4
+
+
+@pytest.mark.parametrize("rows", [1, 2, 3])
+def test_gN_compiles_for_the_v5e_at_the_slabs_shape(one_chip, rows,
+                                                    monkeypatch):
+    import ceph_tpu.ops.gf2kernels as g
+
+    slab = g._slab_stripes(B, K, L)
+    assert slab * K * L == g.SLAB_BYTES and B % slab == 0
+    mem = compile_gN(one_chip, monkeypatch, slab, rows)
+    # three slabs live on the device at most (one uploading, one in the
+    # kernel, one copying out): arguments, outputs and temporaries of all
+    # three are a hundredth of the chip's memory, where the whole batch
+    # at once is a tenth
+    assert 3 * (mem.argument_size_in_bytes + mem.output_size_in_bytes
+                + mem.temp_size_in_bytes) < HBM_BYTES / 64

@@ -3,7 +3,11 @@
 of k=8, m=3 decoded from numpy, one engine for every count of output
 rows, and what a call leaves behind to be measured by: the
 ``registry.*`` sections, the ``ec_registry`` counters, the programs'
-names and scope.
+names and scope.  A call from numpy to numpy streams through the device
+in slabs (``gf2kernels.SLAB_BYTES``; the toy batches here are one slab
+unless a test lowers it): the same bytes at every number of slabs, two
+staging buffers refilled only behind the launch that read them, one
+fresh result array a call.
 """
 
 from __future__ import annotations
@@ -20,6 +24,11 @@ from ceph_tpu.gf import gf_matmul
 
 K, M, B, L = 8, 3, 4, 256
 N = K + M
+WIDE = 10               # stripes of the batches that run as several slabs
+# stripes a slab -> the slabs a WIDE batch is cut into
+SLABS = {10: [10], 6: [6, 4], 4: [4, 4, 2], 2: [2, 2, 2, 2, 2]}
+every_cut = pytest.mark.parametrize("per_slab", list(SLABS), ids=[
+    f"{len(cut)}slabs" for cut in SLABS.values()])
 PATTERNS = [list(p) for e in range(1, M + 1)
             for p in itertools.combinations(range(N), e)]
 ENGINES = ("sched", "gN", "v1", "xla")
@@ -62,6 +71,27 @@ def stripes():
 
 
 @pytest.fixture(scope="module")
+def wide():
+    """(WIDE, k+m, L) whole stripes, as ``stripes``."""
+    codec = registry().factory("isa", {"k": str(K), "m": str(M)})
+    data = np.random.default_rng(46).integers(
+        0, 256, (WIDE, K, L), dtype=np.uint8)
+    parity = np.stack([gf_matmul(codec.encode_matrix[K:], d) for d in data])
+    return np.concatenate([data, parity], axis=1)
+
+
+@pytest.fixture
+def slab_of(monkeypatch):
+    """Sets the slab to so many of this file's stripes."""
+    import ceph_tpu.ops.gf2kernels as g
+
+    def set_slab(stripes: int) -> None:
+        monkeypatch.setattr(g, "SLAB_BYTES", stripes * K * L)
+        assert g._slab_stripes(WIDE, K, L) == stripes
+    return set_slab
+
+
+@pytest.fixture(scope="module")
 def tpu_codec():
     """One plugin for the 231 patterns: its DecodeTableCache (256)
     holds them all, as a long-lived caller's would."""
@@ -97,19 +127,56 @@ def test_every_erasure_pattern_decodes_from_numpy(tpu_codec, stripes,
     assert np.array_equal(again, got)
 
 
-def test_the_survivors_are_gathered_once_into_a_c_ordered_array(stripes):
-    """``stripes[:, index]`` comes back chunk axis outermost, and an
-    upload of it copies the whole batch again: the gather makes the
-    array the upload takes as it is."""
+class Watched(np.ndarray):
+    """A chunk map that writes down every index it is read by."""
+    reads = None
+
+    def __getitem__(self, key):
+        if self.reads is not None:
+            self.reads.append(key)
+        return super().__getitem__(key)
+
+
+@every_cut
+def test_each_slabs_survivors_are_gathered_once_into_a_c_ordered_array(
+        packed, monkeypatch, slab_of, wide, per_slab):
+    """``stripes[lo:hi, index]`` comes back chunk axis outermost, and an
+    upload of it copies the slab again: the gather makes the array the
+    upload takes as it is, each stripe's survivors once, and reads
+    nothing at an erased position."""
+    slab_of(per_slab)
+    erased, index = [0, 9], [1, 2, 3, 4, 5, 6, 7, 8]
+    gathered, uploaded = [], []
+    real = packed._gather_rows
+
+    def gather(data, rows, lo, hi, into):
+        slab = real(data, rows, lo, hi, into)
+        gathered.append((lo, hi, slab.flags["C_CONTIGUOUS"], slab.copy()))
+        return slab
+
+    monkeypatch.setattr(packed, "_gather_rows", gather)
+    real_launch = packed._launch_batch
+    monkeypatch.setattr(
+        packed, "_launch_batch", lambda matrix, xd, perf=None:
+        uploaded.append(np.array(xd)) or real_launch(matrix, xd, perf))
+    watched = wide.view(Watched)
+    watched.reads = []
     codec = registry().factory("tpu", {"k": str(K), "m": str(M)})
-    seen = []
-    codec.decode_batch = lambda erasures, chunks, out_np=False: \
-        seen.append(chunks) or chunks
-    codec.decode_stripes([0, 9], stripes, out_np=True)
-    (chunks,) = seen
-    assert chunks.flags["C_CONTIGUOUS"] and chunks.shape == (B, K, L)
-    assert np.array_equal(chunks, stripes[:, [1, 2, 3, 4, 5, 6, 7, 8]])
-    assert not stripes[:, [1, 2, 3, 4, 5, 6, 7, 8]].flags["C_CONTIGUOUS"]
+    got = codec.decode_stripes(erased, watched, out_np=True)
+    assert np.array_equal(got, wide[:, erased])
+    spans = [(lo, hi) for lo, hi, _, _ in gathered]
+    assert [hi - lo for lo, hi in spans] == SLABS[per_slab]
+    assert spans[0][0] == 0 and spans[-1][1] == WIDE
+    assert all(a[1] == b[0] for a, b in zip(spans, spans[1:]))
+    for (lo, hi, c_ordered, slab), xd in zip(gathered, uploaded):
+        assert c_ordered and slab.shape == (hi - lo, K, L)
+        assert np.array_equal(slab, wide[lo:hi, index])
+        assert np.array_equal(xd, slab)     # uploaded as it was gathered
+    assert not wide[:, index].flags["C_CONTIGUOUS"]
+    # every read is (stripes of one slab, one surviving chunk), each once
+    assert sorted((key[0].start, key[0].stop, key[1])
+                  for key in watched.reads) == sorted(
+        (lo, hi, chunk) for lo, hi in spans for chunk in index)
 
 
 @pytest.mark.parametrize("erased", [[4], [4, 9], [0, 4, 9]],
@@ -173,13 +240,12 @@ def test_one_encode_and_one_decode_move_sections_and_counters(
     assert sections.count("registry.matrix") == 2    # the matrix, the gate
     assert codec.perf.dump() == {
         "launches": 1, "engine_gN": 1, "stripes": B, "parity_gates": 1,
-        "bytes_in": B * K * L, "bytes_out": B * M * L}
+        "bytes_in": B * K * L, "bytes_out": B * M * L, "slabs": 1}
 
     sections.clear()
     erased = [1, 9]
     codec.decode_stripes(erased, stripes, out_np=True)
-    assert sections[0] == "registry.marshal"
-    assert sections[1] == "registry.matrix"          # the table miss
+    assert sections[0] == "registry.matrix"          # the table miss
     assert [s for s in sections if s != "registry.matrix"] == [
         "registry.marshal", "registry.upload", "registry.launch",
         "registry.device_wait", "registry.copy_out"]
@@ -187,8 +253,9 @@ def test_one_encode_and_one_decode_move_sections_and_counters(
     assert two["launches"] == 2 and two["stripes"] == 2 * B
     assert two["bytes_in"] == 2 * B * K * L
     assert two["bytes_out"] == B * M * L + B * len(erased) * L
-    assert two["engine_gN"] == two["parity_gates"] == 2
+    assert two["engine_gN"] == two["parity_gates"] == two["slabs"] == 2
     assert two["table_misses"] == 1 and "table_hits" not in two
+    assert "pipelined" not in two and "staging_waits" not in two
 
 
 def test_gates_and_table_misses_count_once_per_new_signature(packed,
@@ -207,6 +274,200 @@ def test_gates_and_table_misses_count_once_per_new_signature(packed,
     assert dump["table_misses"] == 4 and dump["parity_gates"] == 4
 
 
+# -- the slab pipeline --------------------------------------------------------
+
+@every_cut
+def test_an_encode_is_the_same_bytes_at_every_number_of_slabs(
+        packed, slab_of, wide, per_slab):
+    codec = registry().factory("tpu", {"k": str(K), "m": str(M)})
+    whole = codec.encode_batch(wide[:, :K], out_np=True)        # one launch
+    slab_of(per_slab)
+    got = codec.encode_batch(wide[:, :K], out_np=True)
+    assert np.array_equal(got, whole) and np.array_equal(got, wide[:, K:])
+    assert got.flags["C_CONTIGUOUS"] and got.shape == (WIDE, M, L)
+    dump = codec.perf.dump()
+    cut = SLABS[per_slab]
+    assert dump["launches"] == dump["engine_gN"] == 2
+    assert dump["stripes"] == 2 * WIDE
+    assert dump["bytes_in"] == 2 * WIDE * K * L
+    assert dump["bytes_out"] == 2 * WIDE * M * L
+    assert dump["slabs"] == 1 + len(cut)
+    assert dump.get("pipelined", 0) == (len(cut) > 1)
+    # one gate a new shape: the whole batch, the slab, a ragged last slab
+    assert dump["parity_gates"] == len({WIDE, *cut})
+
+
+@pytest.mark.parametrize("erased", PATTERNS,
+                         ids=["-".join(map(str, p)) for p in PATTERNS])
+def test_every_erasure_pattern_decodes_in_three_ragged_slabs(
+        tpu_codec, slab_of, wide, erased):
+    """4 + 4 + 2 stripes through two staging buffers: the bytes of the
+    one-launch call and of the plain product."""
+    blanked = wide.copy()
+    blanked[:, erased] = 0x5A
+    whole = tpu_codec.decode_stripes(erased, blanked, out_np=True)
+    before = tpu_codec.perf.dump()
+    slab_of(4)
+    got = tpu_codec.decode_stripes(erased, blanked, out_np=True)
+    assert got.shape == (WIDE, len(erased), L) and got.flags["C_CONTIGUOUS"]
+    assert np.array_equal(got, whole)
+    assert np.array_equal(got, wide[:, erased])
+    after = tpu_codec.perf.dump()
+    assert after["launches"] - before["launches"] == 1
+    assert after["slabs"] - before["slabs"] == 3
+    assert after["pipelined"] - before.get("pipelined", 0) == 1
+
+
+@pytest.mark.parametrize("per_slab", [10, 4], ids=["1slab", "3slabs"])
+def test_the_result_is_the_callers_own_every_call(packed, slab_of, wide,
+                                                  per_slab):
+    """The driver keeps one encode's whole output across later calls
+    and compares all of it: a recycled or aliased result is a wrong
+    result."""
+    slab_of(per_slab)
+    codec = registry().factory("tpu", {"k": str(K), "m": str(M)})
+    first = codec.encode_batch(wide[:, :K], out_np=True)
+    kept = first.copy()
+    other = np.ascontiguousarray(wide[::-1, :K]) ^ 0xFF
+    second = codec.encode_batch(other, out_np=True)
+    third = codec.decode_stripes([0, 1, 2], wide, out_np=True)
+    assert not np.shares_memory(first, second)
+    assert not np.shares_memory(first, third)
+    assert np.array_equal(first, kept) and np.array_equal(first, wide[:, K:])
+    assert not np.array_equal(second, first)
+    assert np.array_equal(third, wide[:, :3])
+    for out in (first, second, third):
+        assert out.flags["C_CONTIGUOUS"] and out.dtype == np.uint8
+
+
+class LaterOut:
+    """A launch's result that is not done until somebody waits for it."""
+
+    def __init__(self, log, slab, value):
+        self.log, self.slab, self.value, self.done = log, slab, value, False
+
+    def is_ready(self):
+        return self.done
+
+    def block_until_ready(self):
+        if not self.done:
+            self.done = True
+            self.log.append(("done", self.slab))
+        return self
+
+    def copy_to_host_async(self):
+        self.log.append(("copy_started", self.slab))
+
+    def __array__(self, dtype=None, copy=None):
+        self.block_until_ready()            # host bytes follow the kernel
+        return self.value
+
+
+@pytest.mark.parametrize("per_slab", [6, 4, 2], ids=[
+    f"{len(SLABS[n])}slabs" for n in (6, 4, 2)])
+def test_a_staging_buffer_is_refilled_only_behind_the_launch_that_read_it(
+        packed, monkeypatch, slab_of, wide, per_slab):
+    """``device_put`` may alias the numpy memory (CPU) or read it until
+    the transfer completes (TPU): a buffer is written again only after
+    the launch that read its upload is done.  Launches here never
+    finish by themselves, so every refill has to wait, and is counted."""
+    slab_of(per_slab)
+    erased = [3, 8, 10]
+    codec = registry().factory("tpu", {"k": str(K), "m": str(M)})
+    matrix = codec.decode_matrix_for(erased)
+    log: list[tuple] = []
+    buffers: dict[int, np.ndarray] = {}
+    outs: list[LaterOut] = []
+    read_by: dict[int, list[LaterOut]] = {}
+    real = packed._gather_rows
+
+    def gather(data, rows, lo, hi, into):
+        buffers[id(into)] = into
+        pending = [o.slab for o in read_by.get(id(into), ()) if not o.done]
+        log.append(("fill", id(into), pending))
+        return real(data, rows, lo, hi, into)
+
+    def launch(matrix_, xd, perf=None):
+        host = np.asarray(xd)
+        (buf,) = [key for key, arr in buffers.items()
+                  if np.array_equal(arr[:len(host)], host)]
+        out = LaterOut(log, len(outs), np.stack(
+            [gf_matmul(matrix, stripe) for stripe in host]))
+        outs.append(out)
+        read_by.setdefault(buf, []).append(out)
+        log.append(("launch", out.slab, buf))
+        return "gN", out
+
+    monkeypatch.setattr(packed, "_gather_rows", gather)
+    monkeypatch.setattr(packed, "_launch_batch", launch)
+    got = codec.decode_stripes(erased, wide, out_np=True)
+    assert np.array_equal(got, wide[:, erased])
+    slabs = len(SLABS[per_slab])
+    fills = [ev for ev in log if ev[0] == "fill"]
+    fill_at = [i for i, ev in enumerate(log) if ev[0] == "fill"]
+    assert len(fills) == len(outs) == slabs
+    assert len(buffers) == 2                        # two, reused
+    assert [ev[1] for ev in fills] == [fills[i % 2][1] for i in range(slabs)]
+    assert all(ev[2] == [] for ev in fills), fills  # nothing unfinished
+    # each slab: filled, launched, its copy to the host started, and
+    # only later waited for
+    for n in range(slabs):
+        at = {kind: i for i, (kind, *rest) in enumerate(log)
+              if kind != "fill" and rest[0] == n}
+        assert at["launch"] < at["copy_started"] < at["done"]
+    # slab n's buffer is refilled for slab n+2, after slab n is done and
+    # while slab n+1 is still in flight
+    for n in range(slabs - 2):
+        assert log.index(("done", n)) < fill_at[n + 2] \
+            < log.index(("done", n + 1))
+    dump = codec.perf.dump()
+    assert dump["slabs"] == slabs and dump["pipelined"] == 1
+    assert dump.get("staging_waits", 0) == slabs - 2
+
+
+def test_a_call_of_many_slabs_drains_and_closes_with_one_copy_out(
+        packed, sections, slab_of, wide):
+    slab_of(2)                                      # five slabs
+    codec = registry().factory("tpu", {"k": str(K), "m": str(M)})
+    codec.encode_batch(wide[:, :K], out_np=True)
+    flat = [s for s in sections if s != "registry.matrix"]
+    per = ["registry.upload", "registry.launch"]
+    assert flat == (per * 3 + ["registry.drain"] + per + ["registry.drain"]
+                    + per + ["registry.drain"] * 2
+                    + ["registry.device_wait", "registry.copy_out"])
+    sections.clear()
+    codec.decode_stripes([2, 9], wide, out_np=True)
+    flat = [s for s in sections if s != "registry.matrix"]
+    per = ["registry.marshal"] + per
+    assert flat == (per * 3 + ["registry.drain"] + per + ["registry.drain"]
+                    + per + ["registry.drain"] * 2
+                    + ["registry.device_wait", "registry.copy_out"])
+    assert sections[0] == "registry.matrix"         # the table miss
+    dump = codec.perf.dump()
+    assert dump["launches"] == dump["pipelined"] == dump["engine_gN"] == 2
+    assert dump["slabs"] == 10 and dump["stripes"] == 2 * WIDE
+    assert dump["parity_gates"] == 2                # one a matrix: one shape
+
+
+def test_a_parity_miss_on_the_first_slab_raises_out_of_the_call(
+        packed, slab_of, wide):
+    slab_of(4)
+    codec = registry().factory("tpu", {"k": str(K), "m": str(M)})
+    real = packed._compiled_batch_gN
+    launched = []
+    packed._compiled_batch_gN = lambda *a: (
+        lambda w, xd, fn=real(*a): launched.append(xd.shape) or fn(w, xd) ^ 1)
+    try:
+        with pytest.raises(packed.KernelParityError):
+            codec.encode_batch(wide[:, :K], out_np=True)
+        with pytest.raises(packed.KernelParityError):
+            codec.decode_stripes([1], wide, out_np=True)
+    finally:
+        packed._compiled_batch_gN = real
+    assert launched == [(4, K, L)] * 2              # nothing served after it
+    assert "launches" not in codec.perf.dump()
+
+
 def test_a_device_array_in_and_out_skips_the_copies(sections, stripes):
     import jax
     import jax.numpy as jnp
@@ -216,6 +477,12 @@ def test_a_device_array_in_and_out_skips_the_copies(sections, stripes):
     assert isinstance(out, jax.Array)
     assert set(sections) <= {"registry.launch", "registry.matrix"}
     assert np.array_equal(np.asarray(out), stripes[:, K:])
+    # a chunk map on the device: the survivors are selected there
+    lost = codec.decode_stripes([0, 9], jnp.asarray(stripes))
+    assert isinstance(lost, jax.Array)
+    assert set(sections) <= {"registry.launch", "registry.matrix"}
+    assert np.array_equal(np.asarray(lost), stripes[:, [0, 9]])
+    assert codec.perf.dump()["slabs"] == 2 == codec.perf.dump()["launches"]
 
 
 @pytest.mark.parametrize("engine", ENGINES)

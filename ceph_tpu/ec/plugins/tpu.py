@@ -13,6 +13,10 @@ plugin's ``ec_registry`` set (``codec.perf``): ``launches``, ``stripes``,
 ``bytes_in``, ``bytes_out``, ``engine_<name>`` (the engine that served,
 per call), ``slabs`` / ``pipelined`` / ``staging_waits`` (how a call from
 host memory to host memory streamed through the device),
+``arena_hits`` / ``arena_misses`` (the result and the two staging
+buffers of such a call, each borrowed from the process's host arena: a
+buffer an earlier call's caller dropped, or a fresh allocation; a
+result stays its caller's own until no array views it),
 ``parity_gates`` (first launches of a matrix held to the host oracle),
 ``table_hits`` / ``table_misses`` (decode matrices taken from, or built
 into, the DecodeTableCache); where its thread was is in the

@@ -39,6 +39,7 @@ import jax.numpy as jnp
 
 from ..common.tracing import section
 from ..gf.gf8 import matrix_to_bitmatrix
+from .host_arena import HostArena
 
 # column-tile width for the pallas kernel; also the padding bucket for the
 # XLA path so recompiles stay bounded
@@ -374,6 +375,7 @@ def clear_kernel_cache() -> None:
                _bitmatrix_device, _tuned_cfgs):
         getattr(fn, "cache_clear", lambda: None)()
     _gN_verified.clear()
+    _arena.clear()
     from .xor_schedule import clear_schedule_cache
     clear_schedule_cache()
 
@@ -561,6 +563,10 @@ def batch_engine(matrix: np.ndarray, b: int, k: int, l: int) -> str:
 
 # input bytes of one slab of a host-to-host call (``_slab_stripes``)
 SLAB_BYTES = 32 << 20
+# host bytes the process keeps at rest for the next such call's result
+# and staging (``host_arena.HostArena``); a buffer past it is dropped
+ARENA_BYTES = 1 << 30
+_arena = HostArena(ARENA_BYTES)
 
 
 def _slab_stripes(b: int, k: int, l: int) -> int:
@@ -627,82 +633,117 @@ def gf_matmul_batch_device(matrix: np.ndarray, data, *, rows=None,
     ``registry.copy_out`` (until the last byte is readable on the
     host); the result is a C-ordered array of the caller's own.
 
+    Where the host memory of a call of several slabs comes from: the
+    process's ``HostArena`` (``host_arena.py``; ``ARENA_BYTES`` at rest
+    at most), so that a caller's loop writes into pages it has touched
+    before and not into 448 MiB of fresh ones a call.  The result is a
+    ``lease``: exactly ``(B, r, L)`` over the smallest kept buffer that
+    fits, else over a fresh one; it, and every slice cut from it, is
+    the caller's alone until the last array over its memory is gone,
+    and only then does the buffer go back for a later call.  The two
+    staging buffers are taken at the call's start and given back at
+    its end, also when the call raises: a staging buffer is refilled,
+    or given back, only behind the launch that read its upload, and
+    before it returns either way the call has waited for every launch
+    it made.  A call of one slab borrows nothing: its result is
+    ``np.asarray`` of the launch's.
+
     ``perf`` (the plugin's ``ec_registry`` set) counts a call once,
     however many slabs: ``launches``, ``stripes``, ``bytes_in``,
     ``bytes_out``, ``engine_<name>``; and ``slabs`` (device launches),
     ``pipelined`` (calls of more than one slab), ``staging_waits``
-    (refills that had to wait for a launch), ``parity_gates``."""
+    (refills that had to wait for a launch), ``parity_gates``,
+    ``arena_hits`` / ``arena_misses`` (one a buffer borrowed, result or
+    staging: a kept one, or a fresh allocation)."""
     b, _, l = data.shape
     matrix = np.ascontiguousarray(matrix, dtype=np.uint8)
     r, k = matrix.shape
     on_host = not isinstance(data, jax.Array)
     step = _slab_stripes(b, k, l) if on_host and out_np else b
     spans = [(lo, min(lo + step, b)) for lo in range(0, b, step)]
+    result = _arena.lease((b, r, l), perf) if len(spans) > 1 else None
+    borrowed: list[np.ndarray] = []     # staging to give back at the end
     if on_host and rows is not None:
-        staging = [np.empty((step, k, l), np.uint8) for _ in spans[:2]]
-    result = np.empty((b, r, l), np.uint8) if len(spans) > 1 else None
+        if result is not None:
+            borrowed = [_arena.take(step * k * l, perf) for _ in range(2)]
+            staging = [buf[:step * k * l].reshape(step, k, l)
+                       for buf in borrowed]
+        else:
+            staging = [np.empty((step, k, l), np.uint8)]
     flying: collections.deque = collections.deque()   # (lo, hi, out)
     waits = 0
+    xd = None
 
     def land() -> None:
         lo, hi, out = flying.popleft()
         # lint: disable=device-path-host-sync -- the caller asked for host bytes (out_np); the copy was started at the launch
         result[lo:hi] = np.asarray(out)
 
-    for i, (lo, hi) in enumerate(spans):
-        if not on_host:
-            xd = jnp.asarray(data, dtype=jnp.uint8)
-            if rows is not None:
-                xd = jnp.take(xd, jnp.asarray(rows), axis=1)
-        else:
-            if rows is None:
-                slab = np.ascontiguousarray(data[lo:hi], dtype=np.uint8)
+    try:
+        for i, (lo, hi) in enumerate(spans):
+            if not on_host:
+                xd = jnp.asarray(data, dtype=jnp.uint8)
+                if rows is not None:
+                    xd = jnp.take(xd, jnp.asarray(rows), axis=1)
             else:
-                with section("registry.marshal"):
-                    # slab i - 2's launch read this buffer's upload
-                    reader = flying[0][2] if len(flying) == 2 else None
-                    if reader is not None and not reader.is_ready():
-                        waits += 1
-                        # lint: disable=device-path-host-sync -- a staging buffer is refilled only after the launch that read it
-                        reader.block_until_ready()
-                    slab = _gather_rows(data, rows, lo, hi, staging[i % 2])
-            with section("registry.upload"):
-                xd = jax.device_put(slab)
-                if result is None:
-                    # lint: disable=device-path-host-sync -- a one-slab upload is timed apart from the kernel it feeds; the launch needs its last byte either way
-                    xd.block_until_ready()
-        with section("registry.launch"):
-            engine, out = _launch_batch(matrix, xd, perf)
-            if i == 0:
-                served = engine
+                if rows is None:
+                    slab = np.ascontiguousarray(data[lo:hi], dtype=np.uint8)
+                else:
+                    with section("registry.marshal"):
+                        # slab i - 2's launch read this buffer's upload
+                        reader = flying[0][2] if len(flying) == 2 else None
+                        if reader is not None and not reader.is_ready():
+                            waits += 1
+                            # lint: disable=device-path-host-sync -- a staging buffer is refilled only after the launch that read it
+                            reader.block_until_ready()
+                        slab = _gather_rows(data, rows, lo, hi,
+                                            staging[i % 2])
+                with section("registry.upload"):
+                    xd = jax.device_put(slab)
+                    if result is None:
+                        # lint: disable=device-path-host-sync -- a one-slab upload is timed apart from the kernel it feeds; the launch needs its last byte either way
+                        xd.block_until_ready()
+            with section("registry.launch"):
+                engine, out = _launch_batch(matrix, xd, perf)
+                if i == 0:
+                    served = engine
+                if result is not None:
+                    out.copy_to_host_async()
+            flying.append((lo, hi, out))
+            if len(flying) > 2:
+                with section("registry.drain"):
+                    land()
+        if perf is not None:
+            perf.inc("launches")
+            perf.inc(f"engine_{served}")
+            perf.inc("stripes", b)
+            perf.inc("bytes_in", b * k * l)
+            perf.inc("bytes_out", b * r * l)
+            perf.inc("slabs", len(spans))
             if result is not None:
-                out.copy_to_host_async()
-        flying.append((lo, hi, out))
-        if len(flying) > 2:
+                perf.inc("pipelined")
+            if waits:
+                perf.inc("staging_waits", waits)
+        if not out_np:
+            return out
+        while len(flying) > 1:
             with section("registry.drain"):
                 land()
-    if perf is not None:
-        perf.inc("launches")
-        perf.inc(f"engine_{served}")
-        perf.inc("stripes", b)
-        perf.inc("bytes_in", b * k * l)
-        perf.inc("bytes_out", b * r * l)
-        perf.inc("slabs", len(spans))
-        if result is not None:
-            perf.inc("pipelined")
-        if waits:
-            perf.inc("staging_waits", waits)
-    if not out_np:
-        return out
-    while len(flying) > 1:
-        with section("registry.drain"):
+        with section("registry.device_wait"):
+            # lint: disable=device-path-host-sync -- the caller asked for host bytes (out_np): the wait is timed apart from the copy
+            out.block_until_ready()
+        with section("registry.copy_out"):
+            if result is None:
+                # lint: disable=device-path-host-sync -- the single post-launch materialization (caller opts in via out_np)
+                return np.asarray(out)
             land()
-    with section("registry.device_wait"):
-        # lint: disable=device-path-host-sync -- the caller asked for host bytes (out_np): the wait is timed apart from the copy
-        out.block_until_ready()
-    with section("registry.copy_out"):
-        if result is None:
-            # lint: disable=device-path-host-sync -- the single post-launch materialization (caller opts in via out_np)
-            return np.asarray(out)
-        land()
-        return result
+            return result
+    finally:
+        if borrowed:
+            # a call that raised may leave a slab on its way to the device
+            for held in (xd, *(out for _, _, out in flying)):
+                if held is not None:
+                    # lint: disable=device-path-host-sync -- staging goes back only behind the last launch that read it
+                    held.block_until_ready()
+            for buf in borrowed:
+                _arena.give(buf)

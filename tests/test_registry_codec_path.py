@@ -7,13 +7,20 @@ names and scope.  A call from numpy to numpy streams through the device
 in slabs (``gf2kernels.SLAB_BYTES``; the toy batches here are one slab
 unless a test lowers it): the same bytes at every number of slabs, two
 staging buffers refilled only behind the launch that read them, one
-fresh result array a call.
+result array a call.  The result and the staging of a call of several
+slabs are borrowed from the process's host arena
+(``ops/host_arena.py``): a result is the caller's own until the last
+array over its memory is gone, and only then the next call's.
 """
 
 from __future__ import annotations
 
 import contextlib
+import gc
 import itertools
+import sys
+import threading
+import time
 
 import numpy as np
 import pytest
@@ -57,6 +64,33 @@ def packed():
 
     g._gN_verified.clear()
     return g
+
+
+@pytest.fixture
+def arena(monkeypatch):
+    """An empty host arena of the process's cap in the kernel module's
+    place, with every buffer that comes back to it written down."""
+    import ceph_tpu.ops.gf2kernels as g
+    from ceph_tpu.ops.host_arena import HostArena
+
+    class Logged(HostArena):
+        def __init__(self, cap):
+            super().__init__(cap)
+            self.events: list[tuple] = []
+
+        def give(self, buf):
+            self.events.append(("given", buf.ctypes.data))
+            super().give(buf)
+
+    fresh = Logged(g.ARENA_BYTES)
+    monkeypatch.setattr(g, "_arena", fresh)
+    return fresh
+
+
+def leases(codec) -> tuple[int, int]:
+    """(arena_hits, arena_misses) of a plugin's ``ec_registry`` set."""
+    dump = codec.perf.dump()
+    return dump.get("arena_hits", 0), dump.get("arena_misses", 0)
 
 
 @pytest.fixture(scope="module")
@@ -228,7 +262,7 @@ def sections(monkeypatch):
 
 
 def test_one_encode_and_one_decode_move_sections_and_counters(
-        packed, sections, stripes):
+        packed, sections, stripes, arena, slab_of, wide):
     assert "registry" in tracing.SECTION_LAYERS
     codec = registry().factory("tpu", {"k": str(K), "m": str(M)})
     assert codec.perf.name == "ec_registry" and codec.perf.dump() == {}
@@ -256,6 +290,23 @@ def test_one_encode_and_one_decode_move_sections_and_counters(
     assert two["engine_gN"] == two["parity_gates"] == two["slabs"] == 2
     assert two["table_misses"] == 1 and "table_hits" not in two
     assert "pipelined" not in two and "staging_waits" not in two
+    # a call of one slab borrows nothing
+    assert "arena_hits" not in two and "arena_misses" not in two
+
+    # several slabs: an encode borrows its result, a decode its result
+    # and two staging buffers, each counted once as a hit or a miss
+    slab_of(4)
+    parity = codec.encode_batch(wide[:, :K], out_np=True)
+    assert leases(codec) == (0, 1)                   # nothing kept yet
+    del parity
+    lost = codec.decode_stripes(erased, wide, out_np=True)
+    assert leases(codec) == (1, 3)       # the encode's buffer; new staging
+    del lost
+    codec.decode_stripes(erased, wide, out_np=True)
+    assert leases(codec) == (4, 3)
+    three = codec.perf.dump()
+    assert three["launches"] == 5 and three["pipelined"] == 3
+    assert three["slabs"] == 2 + 3 * 3
 
 
 def test_gates_and_table_misses_count_once_per_new_signature(packed,
@@ -340,6 +391,205 @@ def test_the_result_is_the_callers_own_every_call(packed, slab_of, wide,
         assert out.flags["C_CONTIGUOUS"] and out.dtype == np.uint8
 
 
+# -- the host arena -----------------------------------------------------------
+
+def test_a_kept_slice_keeps_the_buffer_out_of_the_arena(
+        packed, arena, slab_of, wide):
+    """numpy hangs every view of a result on the result's owner: the
+    buffer is nobody else's while one stripe of it is alive, whatever
+    was dropped and whatever ran since."""
+    slab_of(4)
+    codec = registry().factory("tpu", {"k": str(K), "m": str(M)})
+    out = codec.encode_batch(wide[:, :K], out_np=True)
+    lo, hi = out.ctypes.data, out.ctypes.data + out.nbytes
+    piece = out[3][1:, ::2]                 # a view of a view
+    del out
+    gc.collect()
+    assert arena.at_rest() == 0 and arena.events == []
+    other = np.ascontiguousarray(wide[::-1, :K]) ^ 0xFF
+    for _ in range(2):                      # the second reuses the first's
+        later = codec.encode_batch(other, out_np=True)
+        assert not lo <= later.ctypes.data < hi
+        assert not np.shares_memory(later, piece)
+        assert np.array_equal(later[0], gf_matmul(
+            codec.encode_matrix[K:], other[0]))
+        del later
+    assert np.array_equal(piece, wide[3, K + 1:, ::2])
+    assert leases(codec) == (1, 2)
+    assert arena.at_rest() == WIDE * M * L
+    del piece
+    assert arena.events[-1] == ("given", lo)
+    assert arena.at_rest() == 2 * WIDE * M * L
+
+
+@pytest.mark.parametrize("erased", [[], [5], [0, 7], [2, 6, 10]],
+                         ids=["encode", "r1", "r2", "r3"])
+def test_a_dropped_results_buffer_is_the_next_calls(
+        packed, arena, slab_of, wide, erased):
+    """The same memory again, counted as a hit, holding exactly the
+    next call's bytes in exactly its shape: a smaller result borrows
+    the larger buffer and is (b, r, l), C-ordered, writeable."""
+    slab_of(4)
+    codec = registry().factory("tpu", {"k": str(K), "m": str(M)})
+    first = codec.encode_batch(wide[:, :K], out_np=True)
+    address = first.ctypes.data
+    del first
+    assert arena.events == [("given", address)]
+    hits, misses = leases(codec)
+    other = wide ^ 0x3C
+    if erased:
+        got = codec.decode_stripes(erased, wide, out_np=True)
+        want = wide[:, erased]
+    else:
+        got = codec.encode_batch(other[:, :K], out_np=True)
+        want = np.stack([gf_matmul(codec.encode_matrix[K:], d)
+                         for d in other[:, :K]])
+    assert got.ctypes.data == address
+    assert leases(codec) == (hits + 1, misses + (2 if erased else 0))
+    assert got.shape == (WIDE, len(erased) or M, L) and got.dtype == np.uint8
+    assert got.flags["C_CONTIGUOUS"] and got.flags["WRITEABLE"]
+    assert got.strides == (got.shape[1] * L, L, 1)
+    assert np.array_equal(got, want)
+    got[:] = 0                              # the caller's own, to write too
+    assert arena.at_rest() == (2 * 4 * K * L if erased else 0)
+
+
+def test_the_cap_drops_what_would_pass_it(packed, monkeypatch, slab_of, wide):
+    from ceph_tpu.ops.host_arena import HostArena
+
+    # the process's own: room for the cell's result and two slabs, twice
+    assert packed._arena.cap == packed.ARENA_BYTES >= 2 * (
+        1024 * M * 131072 + 2 * packed.SLAB_BYTES)
+    small = HostArena(100)
+    a, b, c = small.take(60), small.take(40), small.take(41)
+    for buf in (a, c, b):
+        small.give(buf)
+    assert small.at_rest() == 100           # 60 + 40: 41 did not fit
+    assert small.take(41) is a and small.take(41).nbytes == 41
+    # the codec path under a cap one byte short of its result
+    slab_of(4)
+    tight = HostArena(WIDE * M * L - 1)
+    monkeypatch.setattr(packed, "_arena", tight)
+    codec = registry().factory("tpu", {"k": str(K), "m": str(M)})
+    for n in range(1, 3):
+        out = codec.encode_batch(wide[:, :K], out_np=True)
+        assert np.array_equal(out, wide[:, K:])
+        del out
+        assert tight.at_rest() == 0 and leases(codec) == (0, n)
+    lost = codec.decode_stripes([1, 9], wide[:, :, :128], out_np=True)
+    address = lost.ctypes.data
+    del lost                                # a smaller result fits
+    assert tight.at_rest() == WIDE * 2 * 128
+    assert codec.decode_stripes([4], wide[:, :, :128],
+                                out_np=True).ctypes.data == address
+
+
+def test_clear_kernel_cache_empties_the_arena(packed, slab_of, wide):
+    slab_of(4)
+    codec = registry().factory("tpu", {"k": str(K), "m": str(M)})
+    codec.decode_stripes([0], wide, out_np=True)
+    assert packed._arena.at_rest() >= 2 * 4 * K * L + WIDE * L
+    packed.clear_kernel_cache()
+    assert packed._arena.at_rest() == 0
+    out = codec.decode_stripes([0], wide, out_np=True)
+    assert np.array_equal(out, wide[:, [0]])
+
+
+@pytest.mark.parametrize("how", ["one_slab", "one_slab_rows", "device_in",
+                                 "device_rows", "host_in_device_out"])
+def test_a_call_that_is_one_launch_borrows_nothing(packed, arena, slab_of,
+                                                   wide, how):
+    import jax
+    import jax.numpy as jnp
+
+    codec = registry().factory("tpu", {"k": str(K), "m": str(M)})
+    if how.startswith("one_slab"):
+        out = (codec.encode_batch(wide[:, :K], out_np=True)
+               if how == "one_slab"
+               else codec.decode_stripes([4], wide, out_np=True))
+        assert isinstance(out, np.ndarray) and out.base is not None
+    else:
+        slab_of(4)                          # a host result would be 3 slabs
+        if how == "device_in":
+            out = codec.encode_batch(jnp.asarray(wide[:, :K]), out_np=True)
+        elif how == "device_rows":
+            out = codec.decode_stripes([4], jnp.asarray(wide))
+        else:
+            out = codec.decode_stripes([4], wide, out_np=False)
+        assert isinstance(out, np.ndarray if how == "device_in"
+                          else jax.Array)
+    want = wide[:, K:] if how in ("one_slab", "device_in") else wide[:, [4]]
+    assert np.array_equal(np.asarray(out), want)
+    assert codec.perf.dump()["slabs"] == 1
+    assert leases(codec) == (0, 0)
+    del out
+    gc.collect()
+    assert arena.at_rest() == 0 and arena.events == []
+
+
+def test_two_threads_never_hold_one_buffer(packed, arena, slab_of, wide):
+    """More borrowers than cores on a short switch interval: whoever
+    holds a buffer, by ``take`` or by ``lease``, finds in it what it
+    wrote; and two threads of codec calls get their own exact bytes."""
+    from ceph_tpu.ops.host_arena import HostArena
+
+    shared = HostArena(1 << 16)
+    wrong: list = []
+    stop = threading.Event()
+
+    def borrow(me: int) -> None:
+        rng = np.random.default_rng(me)
+        while not stop.is_set():
+            size = int(rng.integers(1, 4096))
+            if me % 2:
+                buf = shared.take(size)
+                mine = buf[:size]
+            else:
+                mine = shared.lease((size,))
+            mine[:] = me
+            time.sleep(0)                   # let the others run
+            if not (mine == me).all():
+                wrong.append(me)
+            if me % 2:
+                shared.give(buf)
+            del mine
+            if shared.at_rest() > shared.cap:
+                wrong.append("cap")
+
+    def call(me: int) -> None:
+        data = np.ascontiguousarray(wide[:, :K]) ^ me
+        want = np.stack([gf_matmul(codec.encode_matrix[K:], d)
+                         for d in data])
+        for _ in range(4):
+            got = codec.encode_batch(data, out_np=True)
+            if not np.array_equal(got, want):
+                wrong.append(("call", me))
+
+    slab_of(4)
+    codec = registry().factory("tpu", {"k": str(K), "m": str(M)})
+    codec.encode_batch(wide[:, :K], out_np=True)    # compiled and gated
+    interval = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)
+    try:
+        threads = [threading.Thread(target=borrow, args=(n,))
+                   for n in range(1, 17)]
+        calls = [threading.Thread(target=call, args=(n,)) for n in (1, 2)]
+        for t in threads + calls:
+            t.start()
+        for t in calls:
+            t.join(timeout=120)
+        stop.set()
+        for t in threads:
+            t.join(timeout=30)
+    finally:
+        stop.set()
+        sys.setswitchinterval(interval)
+    assert not any(t.is_alive() for t in threads + calls)
+    assert wrong == []
+    hits, misses = leases(codec)
+    assert hits + misses == 9 and misses >= 1
+
+
 class LaterOut:
     """A launch's result that is not done until somebody waits for it."""
 
@@ -363,19 +613,14 @@ class LaterOut:
         return self.value
 
 
-@pytest.mark.parametrize("per_slab", [6, 4, 2], ids=[
-    f"{len(SLABS[n])}slabs" for n in (6, 4, 2)])
-def test_a_staging_buffer_is_refilled_only_behind_the_launch_that_read_it(
-        packed, monkeypatch, slab_of, wide, per_slab):
-    """``device_put`` may alias the numpy memory (CPU) or read it until
-    the transfer completes (TPU): a buffer is written again only after
-    the launch that read its upload is done.  Launches here never
-    finish by themselves, so every refill has to wait, and is counted."""
-    slab_of(per_slab)
-    erased = [3, 8, 10]
-    codec = registry().factory("tpu", {"k": str(K), "m": str(M)})
-    matrix = codec.decode_matrix_for(erased)
-    log: list[tuple] = []
+class SlabFailed(RuntimeError):
+    pass
+
+
+def later_launches(packed, monkeypatch, matrix, log, fail_at=None):
+    """Gathers and launches written down in ``log``, the launches as
+    ``LaterOut``s (launch ``fail_at`` raises instead): (staging buffers
+    by id, the outs)."""
     buffers: dict[int, np.ndarray] = {}
     outs: list[LaterOut] = []
     read_by: dict[int, list[LaterOut]] = {}
@@ -388,6 +633,8 @@ def test_a_staging_buffer_is_refilled_only_behind_the_launch_that_read_it(
         return real(data, rows, lo, hi, into)
 
     def launch(matrix_, xd, perf=None):
+        if len(outs) == fail_at:
+            raise SlabFailed(fail_at)
         host = np.asarray(xd)
         (buf,) = [key for key, arr in buffers.items()
                   if np.array_equal(arr[:len(host)], host)]
@@ -400,6 +647,24 @@ def test_a_staging_buffer_is_refilled_only_behind_the_launch_that_read_it(
 
     monkeypatch.setattr(packed, "_gather_rows", gather)
     monkeypatch.setattr(packed, "_launch_batch", launch)
+    return buffers, outs
+
+
+@pytest.mark.parametrize("per_slab", [6, 4, 2], ids=[
+    f"{len(SLABS[n])}slabs" for n in (6, 4, 2)])
+def test_a_staging_buffer_is_refilled_only_behind_the_launch_that_read_it(
+        packed, monkeypatch, arena, slab_of, wide, per_slab):
+    """``device_put`` may alias the numpy memory (CPU) or read it until
+    the transfer completes (TPU): a buffer is written again, or given
+    back to the arena, only after the launch that read its upload is
+    done.  Launches here never finish by themselves, so every refill
+    has to wait, and is counted."""
+    slab_of(per_slab)
+    erased = [3, 8, 10]
+    codec = registry().factory("tpu", {"k": str(K), "m": str(M)})
+    matrix = codec.decode_matrix_for(erased)
+    log = arena.events
+    buffers, outs = later_launches(packed, monkeypatch, matrix, log)
     got = codec.decode_stripes(erased, wide, out_np=True)
     assert np.array_equal(got, wide[:, erased])
     slabs = len(SLABS[per_slab])
@@ -413,16 +678,57 @@ def test_a_staging_buffer_is_refilled_only_behind_the_launch_that_read_it(
     # only later waited for
     for n in range(slabs):
         at = {kind: i for i, (kind, *rest) in enumerate(log)
-              if kind != "fill" and rest[0] == n}
+              if kind not in ("fill", "given") and rest[0] == n}
         assert at["launch"] < at["copy_started"] < at["done"]
     # slab n's buffer is refilled for slab n+2, after slab n is done and
     # while slab n+1 is still in flight
     for n in range(slabs - 2):
         assert log.index(("done", n)) < fill_at[n + 2] \
             < log.index(("done", n + 1))
+    # both go back to the arena at the call's end, behind the last launch
+    # (the result is the caller's: it has not come back)
+    assert log[-2:] == [("given", into.ctypes.data)
+                        for into in buffers.values()]
+    assert log.index(("done", slabs - 1)) == len(log) - 3
+    assert arena.at_rest() == 2 * per_slab * K * L
     dump = codec.perf.dump()
     assert dump["slabs"] == slabs and dump["pipelined"] == 1
     assert dump.get("staging_waits", 0) == slabs - 2
+    assert leases(codec) == (0, 3)
+
+
+@pytest.mark.parametrize("fail_at", [0, 1, 2], ids=[
+    "first_slab", "second_slab", "ragged_last_slab"])
+def test_staging_goes_back_behind_the_launches_in_flight_when_a_slab_raises(
+        packed, monkeypatch, arena, slab_of, wide, fail_at):
+    """4 + 4 + 2 stripes and a launch that raises: the launches before
+    it still read their uploads, so the call waits for them, and only
+    then gives both staging buffers back; the error reaches the
+    caller."""
+    slab_of(4)
+    erased = [3, 8, 10]
+    codec = registry().factory("tpu", {"k": str(K), "m": str(M)})
+    matrix = codec.decode_matrix_for(erased)
+    log = arena.events
+    buffers, outs = later_launches(packed, monkeypatch, matrix, log,
+                                   fail_at=fail_at)
+    with pytest.raises(SlabFailed):
+        codec.decode_stripes(erased, wide, out_np=True)
+    assert len(outs) == fail_at and all(out.done for out in outs)
+    assert [ev for ev in log if ev[0] == "done"] == [
+        ("done", n) for n in range(fail_at)]
+    # the two staging buffers first, behind every launch's end ...
+    first = next(i for i, ev in enumerate(log) if ev[0] == "given")
+    assert all(ev[0] != "done" for ev in log[first:])
+    assert {into.ctypes.data for into in buffers.values()} <= {
+        ev[1] for ev in log[first:first + 2]}
+    # ... and the result nobody got, once the error lets go of the
+    # call's frame
+    gc.collect()
+    assert [ev[0] for ev in log].count("given") == 3
+    assert arena.at_rest() == 2 * 4 * K * L + WIDE * len(erased) * L
+    assert "launches" not in codec.perf.dump()      # it served nothing
+    assert leases(codec) == (0, 3)
 
 
 def test_a_call_of_many_slabs_drains_and_closes_with_one_copy_out(

@@ -39,6 +39,19 @@ def toy_cell(name: str) -> harness.Cell:
     return cell
 
 
+def client_ms_per_op(sl: dict, per: str = "client.complete") -> float:
+    """Self time of the ``client.*`` sections per finished op in a slice
+    a span reader loaded.  The sections are in every store cell's trace
+    and, since PR 49, in no metric (0.013-0.029 ms of ops of 17-53 ms on
+    the chip: ledger, PR 47), so a cell's listed host layers add up to
+    the slice per finished op less this."""
+    from benchmark.readers import span_time
+    times = span_time.self_times(sl["pieces"])
+    return 1e3 * sum(secs for name, secs in times.items()
+                     if name and name.startswith("client.")) \
+        / sl["started"][per]
+
+
 def rehearse(name: str, seed: int = 7, seconds: float = 1.0,
              traced: bool = False) -> dict:
     harness.build_native()

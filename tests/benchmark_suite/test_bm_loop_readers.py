@@ -223,8 +223,10 @@ def test_loop_metric_lists_the_three_4m_cells_and_reads_nothing_from_nothing(
     assert reader.read(spec["spec"], {}) is None
     for cell in CELLS:
         assert metric in harness.Cell(cell).per_layer
+    # wherever they stand in the list: a later PR appends behind them
     manifest = json.loads((harness.ROOT / "BENCHMARK.json").read_text())
-    assert [p["name"] for p in manifest["per_layer"][-7:]] == LOOP_METRICS
+    listed = [p["name"] for p in manifest["per_layer"]]
+    assert [n for n in listed if n in LOOP_METRICS] == LOOP_METRICS
 
 
 def test_traced_toy_rehearsal_splits_its_uncovered_time(monkeypatch):

@@ -246,20 +246,36 @@ def test_device_ms_per_launch_scrub_takes_the_digests_time_over_their_count():
 
 # -- the metric files -----------------------------------------------------------
 
+# what PR 38 brought for the cell (less host_ms_per_op.client.under_scrub,
+# retired by PR 49), in the manifest's order
+SCRUB_METRICS = [
+    "scrubbed_mibps", "scrub_active_share", "scrub_chunk_ms.maps",
+    "scrub_chunk_ms.digest", "scrub_chunk_ms.compare", "scrub_chunk_ms.rest",
+    "host_ms_per_op.wire.under_scrub",
+    "host_ms_per_op.osd_op.under_scrub", "host_ms_per_op.store.under_scrub",
+    "host_ms_per_op.batcher.under_scrub",
+    "host_ms_per_op.device_wait.under_scrub",
+    "host_ms_per_op.unsectioned.under_scrub",
+    "host_ms_per_op.scrub.under_scrub", "scrub_device_digest_share",
+    "scrub_thread_ms_per_mib.host", "scrub_thread_ms_per_mib.device",
+    "scrub_wire_bytes_per_digested_byte", "device_ms_per_launch.scrub",
+    "device_idle_share.scrub", "scrub_crc_hbm_share"]
+
+
 def scrub_metrics() -> list[str]:
     return harness.Cell(CELL).per_layer
 
 
-def test_the_cells_metrics_list_the_cell_alone_and_follow_the_accepted_ones():
+def test_the_cells_metrics_name_the_cell_and_follow_the_accepted_ones():
     manifest = json.loads((harness.ROOT / "BENCHMARK.json").read_text())
     names = [p["name"] for p in manifest["per_layer"]]
     mine = scrub_metrics()
-    assert len(mine) == 21
-    at = [names.index(n) for n in mine]
-    assert at == list(range(at[0], at[0] + 21))
-    # appended after everything the benchmark had (the driver reads an entry
-    # put in the middle as an edit); what a later PR appends may follow, so
-    # the end of the list is not pinned here
+    assert set(SCRUB_METRICS) <= set(mine)
+    at = [names.index(n) for n in SCRUB_METRICS]
+    # in the order PR 38 appended them, after everything the benchmark had
+    # then (the driver reads an entry put in the middle as an edit); how
+    # many the cell lists and what a later PR appends is not pinned here
+    assert at == sorted(at)
     assert at[0] > names.index("loop_max_phase_ms")
     layers = {p["layer"] for p in manifest["per_layer"] if p["name"] in mine}
     accepted = {p["layer"] for p in manifest["per_layer"]
@@ -269,29 +285,21 @@ def test_the_cells_metrics_list_the_cell_alone_and_follow_the_accepted_ones():
     for entry in manifest["per_layer"]:
         if entry["name"] in mine:
             spec = harness.layer_metric(entry["name"])
-            assert entry["workloads"] == [CELL] == spec["workloads"]
+            assert CELL in entry["workloads"] == spec["workloads"]
             for key in ("unit", "better", "source", "layer", "moves"):
                 assert spec[key] == entry[key], (entry["name"], key)
             assert entry["moves"] == (
                 "op_p95_ms" if entry["name"].startswith("scrub_chunk_ms")
                 else "client_mibps")
     assert len(json.dumps(manifest)) < 64 * 1024
-    assert len(manifest["workloads"]) == 8 and len(manifest["configs"]) == 7
-    assert all(w["chips"] == 1 for w in manifest["workloads"])
+    # by lookup: a later PR's cell or configuration trips nothing here
+    cells = {w["name"]: w for w in manifest["workloads"]}
+    configs = {c["name"] for c in manifest["configs"]}
+    assert cells[CELL]["config"] in configs and cells[CELL]["chips"] == 1
+    assert len(cells) <= 24 and len(configs) <= 24
 
 
-@pytest.mark.parametrize("metric", [
-    "scrubbed_mibps", "scrub_active_share", "scrub_chunk_ms.maps",
-    "scrub_chunk_ms.digest", "scrub_chunk_ms.compare", "scrub_chunk_ms.rest",
-    "host_ms_per_op.client.under_scrub", "host_ms_per_op.wire.under_scrub",
-    "host_ms_per_op.osd_op.under_scrub", "host_ms_per_op.store.under_scrub",
-    "host_ms_per_op.batcher.under_scrub",
-    "host_ms_per_op.device_wait.under_scrub",
-    "host_ms_per_op.unsectioned.under_scrub",
-    "host_ms_per_op.scrub.under_scrub", "scrub_device_digest_share",
-    "scrub_thread_ms_per_mib.host", "scrub_thread_ms_per_mib.device",
-    "scrub_wire_bytes_per_digested_byte", "device_ms_per_launch.scrub",
-    "device_idle_share.scrub", "scrub_crc_hbm_share"])
+@pytest.mark.parametrize("metric", SCRUB_METRICS)
 def test_each_scrub_metric_reads_nothing_from_nothing(metric):
     """What the parent hands a reader laid over it: no fact of the
     scrub, so ``None`` and no exception, and the line leaves the metric

@@ -34,6 +34,7 @@ from ...common.perf import PerfCounters
 from ...common.tracing import section
 from ...gf import build_decode_matrix, erasure_signature
 from ...gf.matrices import decode_index_for
+from ...ops.gf2kernels import LanePieces
 from ...ops.jax_backend import JaxBackend
 
 
@@ -83,6 +84,75 @@ class ErasureCodeTpu(ErasureCodeIsa):
         return self.backend.matmul_batch(
             self.decode_matrix_for(erasures), stripes,
             rows=decode_index_for(self.k, set(erasures)), out_np=out_np)
+
+    # -- objects of unequal size in one call (host memory in and out) -------
+    def encode_objects(self, objects) -> list[np.ndarray]:
+        """Per-object byte arrays of any sizes -> per object its
+        ``(m, L_i)`` parity chunks, ``L_i = get_chunk_size(size_i)``.
+
+        An object is chunked as ``encode_prepare`` chunks it (k chunks
+        of ``L_i`` bytes, the tail of the last data chunks zero) on its
+        way into the slab, never in a copy of its own: the call's
+        chunks lie end to end on the lane axis and stream through
+        ``gf_matmul_batch_device`` in slabs of one width.  The results
+        are views of the call's one leased buffer (row p of object i's
+        is its parity chunk p, contiguous): the buffer is the caller's
+        until the last of them is gone."""
+        k = self.k
+        with section("registry.prepare"):
+            lengths, blocks, tails = [], [], []
+            for obj in objects:
+                obj = obj if isinstance(obj, np.ndarray) \
+                    else np.frombuffer(obj, np.uint8)
+                size = obj.size
+                length = self.get_chunk_size(size)
+                full = size // length if length else 0
+                whole = full * length
+                mine = [(0, obj[:whole].reshape(full, length))] if full else []
+                if whole < size:
+                    mine.append((full, obj[whole:].reshape(1, size - whole)))
+                lengths.append(length)
+                blocks.append(mine)
+                tails.append((full, size - whole) if full < k else None)
+            pieces = LanePieces(lengths, blocks, tails)
+        return self._matmul_objects(self.encode_matrix[k:], pieces)
+
+    def decode_objects(self, erasures: list[int],
+                       chunk_maps) -> list[np.ndarray]:
+        """Recover ``erasures`` (one pattern a call) for objects of any
+        sizes: ``chunk_maps[i]`` is object i's ``(k+m, L_i)`` chunk map
+        in host memory, chunk j at ``[j]``; what lies at an erased
+        position is never read.  The survivors are the first k ids not
+        erased, ascending, taken run by run of neighbouring ids on the
+        way into the slab.  Per object the ``(len(erasures), L_i)``
+        erased chunks, row p the chunk ``erasures[p]``, as views of the
+        call's one leased buffer (``encode_objects``)."""
+        matrix = self.decode_matrix_for(erasures)
+        with section("registry.prepare"):
+            index = decode_index_for(self.k, set(erasures))
+            # runs of neighbouring survivor ids: (operand row, id, count)
+            runs: list[list[int]] = []
+            for row, chunk in enumerate(index):
+                if runs and runs[-1][1] + runs[-1][2] == chunk:
+                    runs[-1][2] += 1
+                else:
+                    runs.append([row, chunk, 1])
+            pieces = LanePieces(
+                [chunks.shape[1] for chunks in chunk_maps],
+                [[(row, chunks[first:first + count])
+                  for row, first, count in runs] for chunks in chunk_maps],
+                [None] * len(chunk_maps))
+        return self._matmul_objects(matrix, pieces)
+
+    def _matmul_objects(self, matrix: np.ndarray,
+                        pieces: LanePieces) -> list[np.ndarray]:
+        if not pieces.lanes:
+            return [np.empty((len(matrix), 0), np.uint8)
+                    for _ in pieces.lengths]
+        out = self.backend.matmul_batch(matrix, pieces, out_np=True)
+        with section("registry.prepare"):
+            return [out[:, start:end] for start, end in
+                    zip(pieces.starts.tolist(), pieces.ends.tolist())]
 
     def decode_matrix_for(self, erasures) -> np.ndarray:
         """The decode matrix an erasure pattern selects, through the

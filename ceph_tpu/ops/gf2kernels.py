@@ -465,7 +465,7 @@ def _gN_plan(k: int, b: int, l: int, cfg: dict) -> tuple[int, int] | None:
     return (g, tile) if tile else None
 
 
-# (matrix, shape, cfg) keys whose packed kernel passed its one-time
+# (matrix, shape, cfg or engine) keys whose kernel passed its one-time
 # byte-parity gate vs the host oracle
 _gN_verified: set[tuple] = set()
 
@@ -577,6 +577,73 @@ def _slab_stripes(b: int, k: int, l: int) -> int:
     return min(b, max(g, SLAB_BYTES // (k * l) // g * g))
 
 
+def _slab_lanes(k: int) -> int:
+    """Lanes a slab of a call over pieces of unequal length holds:
+    ``SLAB_BYTES`` of input across the k operand rows, in whole lane
+    tiles.  One width whatever the call's mix of lengths, so one
+    program a count of output rows."""
+    lanes = SLAB_BYTES // k
+    if lanes >= LANE_TILE:
+        return lanes // LANE_TILE * LANE_TILE
+    return max(128, lanes // 128 * 128)
+
+
+class LanePieces:
+    """The host operands of one call over pieces of unequal length (an
+    object's chunks, ``L_i`` bytes each), laid end to end on the lane
+    axis: the GF product acts on every byte column alone, so the k
+    operand rows of all pieces are one ``(k, lanes)`` row of columns,
+    which ``gf_matmul_batch_device`` cuts into slabs of
+    ``_slab_lanes`` columns wherever a piece begins or ends (a piece
+    may lie across two slabs).
+
+    ``lengths[i]`` is piece i's ``L_i``; ``blocks[i]`` its operand
+    bytes as ``(first operand row, 2-D host array)`` pairs, row j of a
+    block being columns ``0:block.shape[1]`` of operand row
+    ``first + j``; ``tails[i]`` is None or ``(row, col)``: from column
+    ``col`` of operand row ``row`` to the piece's end no block has
+    bytes and the operand is zero.  Nothing is copied until ``fill``,
+    and nothing outside the blocks is ever read."""
+
+    def __init__(self, lengths, blocks, tails) -> None:
+        self.lengths = np.asarray(lengths, dtype=np.int64)
+        self.ends = np.cumsum(self.lengths)
+        self.starts = self.ends - self.lengths
+        self.lanes = int(self.ends[-1]) if len(self.ends) else 0
+        self.blocks = blocks
+        self.tails = tails
+
+    def within(self, lo: int, hi: int):
+        """The pieces that have columns among ``lo:hi`` of the call's
+        lanes: ``(piece, a, b, off)``, its columns ``a:b`` lying at
+        column ``off`` of the slab that starts at ``lo``."""
+        first = int(np.searchsorted(self.ends, lo, side="right"))
+        last = int(np.searchsorted(self.starts, hi, side="left"))
+        for i, start, length in zip(range(first, last),
+                                    self.starts[first:last].tolist(),
+                                    self.lengths[first:last].tolist()):
+            a, b = max(lo - start, 0), min(hi - start, length)
+            yield i, a, b, start + a - lo
+
+    def fill(self, lo: int, hi: int, into: np.ndarray) -> np.ndarray:
+        """Columns ``lo:hi`` of the call's lanes into ``into[0, :,
+        :hi - lo]`` (``into`` is a staging buffer ``(1, k, slab
+        lanes)``; what lies past ``hi - lo`` is left as it was: those
+        lanes' results are dropped)."""
+        rows = into[0]
+        for i, a, b, off in self.within(lo, hi):
+            for row, block in self.blocks[i]:
+                part = block[:, a:b]
+                rows[row:row + part.shape[0],
+                     off:off + part.shape[1]] = part
+            tail = self.tails[i]
+            if tail is not None:
+                row, col = tail
+                rows[row, off + min(max(col - a, 0), b - a):off + b - a] = 0
+                rows[row + 1:, off:off + b - a] = 0
+        return into
+
+
 def _gather_rows(data, rows, lo: int, hi: int, into: np.ndarray):
     """Chunks ``rows`` of stripes ``lo:hi`` of a (B, n, L) host array
     into the C-ordered ``into``, chunk by chunk (``data[lo:hi, rows]``
@@ -602,6 +669,10 @@ def _launch_batch(matrix: np.ndarray, xd, perf=None):
             w = bitmatrix_device(matrix)
         fn = _compiled_batch(w.shape[0], k, b, l, engine == "v1")
         out = fn(w, xd)
+        key = (matrix.tobytes(), b, l, engine)
+        if key not in _gN_verified:
+            check_batch_parity(f"{engine} kernel", matrix, xd, out, 1, perf)
+            _gN_verified.add(key)
     return engine, out
 
 
@@ -648,22 +719,44 @@ def gf_matmul_batch_device(matrix: np.ndarray, data, *, rows=None,
     it made.  A call of one slab borrows nothing: its result is
     ``np.asarray`` of the launch's.
 
+    ``data`` a ``LanePieces`` (pieces of unequal length, always from
+    host memory to host memory) goes through the same loop with a slab
+    cut over the call's LANES: every slab is one ``(1, k,
+    _slab_lanes(k))`` launch whatever lengths the call mixes (one
+    program a count of output rows; the last slab's spare lanes are
+    launched and dropped), ``registry.marshal`` is ``LanePieces.fill``
+    into the staging buffer, and a slab lands in its columns of the
+    call's ``(r, lanes)`` result, which is leased, as the staging is,
+    at any number of slabs: the caller cuts each piece's ``(r, L_i)``
+    out of it as a view.
+
     ``perf`` (the plugin's ``ec_registry`` set) counts a call once,
     however many slabs: ``launches``, ``stripes``, ``bytes_in``,
     ``bytes_out``, ``engine_<name>``; and ``slabs`` (device launches),
     ``pipelined`` (calls of more than one slab), ``staging_waits``
     (refills that had to wait for a launch), ``parity_gates``,
     ``arena_hits`` / ``arena_misses`` (one a buffer borrowed, result or
-    staging: a kept one, or a fresh allocation)."""
-    b, _, l = data.shape
+    staging: a kept one, or a fresh allocation); for a ``LanePieces``
+    call ``objects`` (its pieces), ``lanes`` (the columns the caller
+    asked for), ``lanes_launched`` (slabs x slab width) and
+    ``lanes_padded`` (their difference) in the place of ``stripes``."""
     matrix = np.ascontiguousarray(matrix, dtype=np.uint8)
     r, k = matrix.shape
+    ragged = isinstance(data, LanePieces)
     on_host = not isinstance(data, jax.Array)
-    step = _slab_stripes(b, k, l) if on_host and out_np else b
-    spans = [(lo, min(lo + step, b)) for lo in range(0, b, step)]
-    result = _arena.lease((b, r, l), perf) if len(spans) > 1 else None
+    if ragged:
+        # one virtual stripe a slab: the call's lanes, a slab's width each
+        l, step, out_np = _slab_lanes(k), 1, True
+        spans = [(lo, min(lo + l, data.lanes))
+                 for lo in range(0, data.lanes, l)]
+        result = _arena.lease((r, data.lanes), perf)
+    else:
+        b, _, l = data.shape
+        step = _slab_stripes(b, k, l) if on_host and out_np else b
+        spans = [(lo, min(lo + step, b)) for lo in range(0, b, step)]
+        result = _arena.lease((b, r, l), perf) if len(spans) > 1 else None
     borrowed: list[np.ndarray] = []     # staging to give back at the end
-    if on_host and rows is not None:
+    if on_host and (ragged or rows is not None):
         if result is not None:
             borrowed = [_arena.take(step * k * l, perf) for _ in range(2)]
             staging = [buf[:step * k * l].reshape(step, k, l)
@@ -677,7 +770,11 @@ def gf_matmul_batch_device(matrix: np.ndarray, data, *, rows=None,
     def land() -> None:
         lo, hi, out = flying.popleft()
         # lint: disable=device-path-host-sync -- the caller asked for host bytes (out_np); the copy was started at the launch
-        result[lo:hi] = np.asarray(out)
+        host = np.asarray(out)
+        if ragged:
+            result[:, lo:hi] = host[0, :, :hi - lo]
+        else:
+            result[lo:hi] = host
 
     try:
         for i, (lo, hi) in enumerate(spans):
@@ -686,7 +783,7 @@ def gf_matmul_batch_device(matrix: np.ndarray, data, *, rows=None,
                 if rows is not None:
                     xd = jnp.take(xd, jnp.asarray(rows), axis=1)
             else:
-                if rows is None:
+                if rows is None and not ragged:
                     slab = np.ascontiguousarray(data[lo:hi], dtype=np.uint8)
                 else:
                     with section("registry.marshal"):
@@ -696,8 +793,9 @@ def gf_matmul_batch_device(matrix: np.ndarray, data, *, rows=None,
                             waits += 1
                             # lint: disable=device-path-host-sync -- a staging buffer is refilled only after the launch that read it
                             reader.block_until_ready()
-                        slab = _gather_rows(data, rows, lo, hi,
-                                            staging[i % 2])
+                        slab = (data.fill(lo, hi, staging[i % 2]) if ragged
+                                else _gather_rows(data, rows, lo, hi,
+                                                  staging[i % 2]))
                 with section("registry.upload"):
                     xd = jax.device_put(slab)
                     if result is None:
@@ -716,11 +814,20 @@ def gf_matmul_batch_device(matrix: np.ndarray, data, *, rows=None,
         if perf is not None:
             perf.inc("launches")
             perf.inc(f"engine_{served}")
-            perf.inc("stripes", b)
-            perf.inc("bytes_in", b * k * l)
-            perf.inc("bytes_out", b * r * l)
+            if ragged:
+                launched = len(spans) * l
+                perf.inc("objects", len(data.lengths))
+                perf.inc("lanes", data.lanes)
+                perf.inc("lanes_launched", launched)
+                perf.inc("lanes_padded", launched - data.lanes)
+                perf.inc("bytes_in", k * data.lanes)
+                perf.inc("bytes_out", r * data.lanes)
+            else:
+                perf.inc("stripes", b)
+                perf.inc("bytes_in", b * k * l)
+                perf.inc("bytes_out", b * r * l)
             perf.inc("slabs", len(spans))
-            if result is not None:
+            if len(spans) > 1:
                 perf.inc("pipelined")
             if waits:
                 perf.inc("staging_waits", waits)

@@ -14,8 +14,12 @@ is what a caller of the interface waits for, upload and copy-out
 included, not a kernel over device-resident data.  It is the op that the
 benchmark cell ``rs_k8m3_codec_1m_b1024`` measures (benchmark/drivers/
 codec_loop.py reaches the plugin through the same two calls); the first
-call of a shape compiles and is not timed.  The engine that served is
-printed on stderr; stdout stays the one contract line.
+call of a shape compiles and is not timed.  With several sizes
+(``--size 4096,65536,1048576``) a call is B objects of EACH size, their
+order shuffled, through ``encode_objects`` / ``decode_objects``: the op
+of ``cauchy_k10m4_codec_mixed_4k_1m`` (benchmark/drivers/
+codec_objects_loop.py).  The engine that served is printed on stderr;
+stdout stays the one contract line.
 """
 
 from __future__ import annotations
@@ -39,6 +43,46 @@ def parse_profile(args) -> dict:
     profile.setdefault("k", str(args.k))
     profile.setdefault("m", str(args.m))
     return profile
+
+
+def _mixed_objects(codec, sizes: list[int], batch: int) -> list[np.ndarray]:
+    """``batch`` objects of each of ``sizes``, one flat array each, in a
+    shuffled order."""
+    if not hasattr(codec, "decode_objects"):
+        raise SystemExit("several sizes need a plugin with entry points "
+                         "over objects of unequal size (encode_objects, "
+                         "decode_objects): tpu")
+    rng = np.random.default_rng(0)
+    objects = [rng.integers(0, 256, size, dtype=np.uint8)
+               for size in sizes for _ in range(batch)]
+    return [objects[i] for i in rng.permutation(len(objects))]
+
+
+def run_mixed(codec, sizes: list[int], iterations: int, batch: int,
+              workload: str, erasures: int, exhaustive: bool,
+              verify: bool) -> tuple[float, int]:
+    """The encode or decode loop over ``batch`` objects of each size a
+    call, host buffers in and out."""
+    n, k = codec.get_chunk_count(), codec.get_data_chunk_count()
+    objects = _mixed_objects(codec, sizes, batch)
+    parity = codec.encode_objects(objects)          # compiles: not timed
+    kib = sum(obj.size for obj in objects) * iterations // 1024
+    if workload == "encode":
+        begin = time.perf_counter()
+        for _ in range(iterations):
+            codec.encode_objects(objects)
+        return time.perf_counter() - begin, kib
+    maps = [np.concatenate([np.stack([
+        codec.encode_prepare(obj)[i] for i in range(k)]), par])
+        for obj, par in zip(objects, parity)]
+    codec.decode_objects(list(range(erasures)), maps)
+    begin = time.perf_counter()
+    for erased in _draws(n, erasures, exhaustive, iterations):
+        got = codec.decode_objects(erased, maps)
+        if verify and not all(np.array_equal(lost, stripe[erased])
+                              for lost, stripe in zip(got, maps)):
+            raise SystemExit(f"byte parity FAILED for erasures {erased}")
+    return time.perf_counter() - begin, kib
 
 
 def run_encode(codec, size: int, iterations: int, batch: int) -> tuple[float, int]:
@@ -89,6 +133,19 @@ def count_erasures(n: int, erasures: int):
         yield list(combo)
 
 
+def _draws(n: int, erasures: int, exhaustive: bool, iterations: int):
+    """The erased ids of each of ``iterations`` batch decodes: every
+    pattern in turn, or drawn at random."""
+    patterns = list(count_erasures(n, erasures)) if exhaustive else None
+    rng = np.random.default_rng(42)
+    for i in range(iterations):
+        if patterns is not None:
+            yield patterns[i % len(patterns)]
+        else:
+            yield sorted(int(e) for e in
+                         rng.choice(n, size=erasures, replace=False))
+
+
 def run_decode_batch(codec, size: int, iterations: int, erasures: int,
                      exhaustive: bool, verify: bool,
                      batch: int) -> tuple[float, int]:
@@ -98,16 +155,9 @@ def run_decode_batch(codec, size: int, iterations: int, erasures: int,
     data = _batch_data(codec, size, batch)
     stripes = np.concatenate(
         [data, codec.encode_batch(data, out_np=True)], axis=1)
-    patterns = list(count_erasures(n, erasures)) if exhaustive else None
-    rng = np.random.default_rng(42)
     codec.decode_stripes(list(range(erasures)), stripes, out_np=True)
     begin = time.perf_counter()
-    for i in range(iterations):
-        if patterns is not None:
-            erased = patterns[i % len(patterns)]
-        else:
-            erased = sorted(int(e) for e in
-                            rng.choice(n, size=erasures, replace=False))
+    for erased in _draws(n, erasures, exhaustive, iterations):
         got = codec.decode_stripes(erased, stripes, out_np=True)
         if verify and not np.array_equal(got, stripes[:, erased]):
             raise SystemExit(f"byte parity FAILED for erasures {erased}")
@@ -155,8 +205,9 @@ def main(argv=None) -> int:
     p.add_argument("--plugin", default="tpu")
     p.add_argument("-k", type=int, default=8)
     p.add_argument("-m", type=int, default=3)
-    p.add_argument("-s", "--size", type=int, default=1 << 20,
-                   help="object size per op (bytes)")
+    p.add_argument("-s", "--size", default=str(1 << 20),
+                   help="object size per op (bytes); several, comma-"
+                        "separated: --batch objects of each in one call")
     p.add_argument("-i", "--iterations", type=int, default=10)
     p.add_argument("-w", "--workload", choices=("encode", "decode"),
                    default="encode")
@@ -175,18 +226,24 @@ def main(argv=None) -> int:
     profile = parse_profile(args)
     codec = registry().factory(args.plugin, profile)
 
-    if args.workload == "encode":
-        elapsed, kib = run_encode(codec, args.size, args.iterations,
+    sizes = [int(size) for size in args.size.split(",")]
+    size = sizes[0]
+    exhaustive = args.erasures_generation == "exhaustive"
+    verify = args.verify or exhaustive
+    if len(sizes) > 1:
+        elapsed, kib = run_mixed(codec, sizes, args.iterations, args.batch,
+                                 args.workload, args.erasures, exhaustive,
+                                 verify)
+    elif args.workload == "encode":
+        elapsed, kib = run_encode(codec, size, args.iterations,
                                   args.batch)
     else:
-        exhaustive = args.erasures_generation == "exhaustive"
-        verify = args.verify or exhaustive
         if args.batch > 1:
             elapsed, kib = run_decode_batch(
-                codec, args.size, args.iterations, args.erasures,
+                codec, size, args.iterations, args.erasures,
                 exhaustive, verify, args.batch)
         else:
-            elapsed, kib = run_decode(codec, args.size, args.iterations,
+            elapsed, kib = run_decode(codec, size, args.iterations,
                                       args.erasures, exhaustive, verify)
     report_engine(codec)
     print(f"{elapsed:.6f}\t{kib}")

@@ -6,6 +6,10 @@ TPU compiler for a described v5e (nothing runs, nothing is timed).  What the Pal
 declines, a launch that does not fit the device -- fails here.  One
 file: the worker that is given it loads the TPU library, inside the
 fixture, and no other does.
+
+Since PR 50 also the one program a call over objects of unequal size
+launches: k=10 at ``(1, 10, _slab_lanes(10))``, r = 4 and r = 2, by the
+engine the shape selects on a TPU backend.
 """
 
 from __future__ import annotations
@@ -78,5 +82,37 @@ def test_gN_compiles_for_the_v5e_at_the_slabs_shape(one_chip, rows,
     # kernel, one copying out): arguments, outputs and temporaries of all
     # three are a hundredth of the chip's memory, where the whole batch
     # at once is a tenth
+    assert 3 * (mem.argument_size_in_bytes + mem.output_size_in_bytes
+                + mem.temp_size_in_bytes) < HBM_BYTES / 64
+
+
+@pytest.mark.parametrize("rows", [4, 2])
+def test_the_k10_slab_of_lanes_compiles_for_the_v5e(one_chip, rows,
+                                                    monkeypatch):
+    """benchmark/configs/cauchy_k10m4_registry_codec.json: every slab of
+    a call over objects of unequal size is ``(1, 10, 3350528)``, an
+    encode's r = 4 and a decode of two's r = 2."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+    import ceph_tpu.ops.gf2kernels as g
+
+    monkeypatch.setattr(g, "_interpret", lambda: False)
+    monkeypatch.setattr(g, "_want_pallas", lambda: True)
+    k = 10
+    lanes = g._slab_lanes(k)
+    assert lanes == 3350528 and lanes % g.LANE_TILE == 0
+    matrix = np.arange(1, rows * k + 1, dtype=np.uint8).reshape(rows, k)
+    assert g.batch_engine(matrix, 1, k, lanes) == "v1"
+    # the maker, not ``_compiled_batch``: nothing built here is cached
+    fn = g._make_pallas_batch_fn(8 * rows, k, 1, lanes, g._pick_tile(lanes))
+    w = jax.ShapeDtypeStruct((8 * rows, 8 * k), jnp.int8, sharding=one_chip)
+    xd = jax.ShapeDtypeStruct((1, k, lanes), jnp.uint8, sharding=one_chip)
+    compiled = fn.lower(w, xd).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    mem = compiled.memory_analysis()
+    assert mem.argument_size_in_bytes >= k * lanes
+    assert mem.output_size_in_bytes >= rows * lanes
+    # three slabs live on the device at most
     assert 3 * (mem.argument_size_in_bytes + mem.output_size_in_bytes
                 + mem.temp_size_in_bytes) < HBM_BYTES / 64

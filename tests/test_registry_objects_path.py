@@ -1,0 +1,553 @@
+"""The registry's batch path over objects of unequal size
+(``ErasureCodeTpu.encode_objects`` / ``decode_objects`` ->
+``JaxBackend.matmul_batch`` -> ``gf2kernels.gf_matmul_batch_device`` with
+a ``LanePieces``): the objects' chunks lie end to end on the lane axis
+and stream through the one slab loop in slabs of one width, whatever
+sizes a call mixes.  Held here, at small size on the CPU, to the plain
+reference (``benchmark/reference/``) and to the host ``isa`` plugin
+object by object: ``encode_prepare``'s chunking and zero tail, every
+double erasure of k=10, m=4, objects that meet a slab's edge, one
+program a count of output rows, and the staging and lease rules of the
+uniform call (``test_registry_codec_path.py``) under this one.
+"""
+
+from __future__ import annotations
+
+import gc
+import itertools
+
+import numpy as np
+import pytest
+
+from benchmark.reference import codec as ref
+from benchmark.reference import codec_objects as ref_objects
+from ceph_tpu.ec import registry
+from test_registry_codec_path import (LaterOut, SlabFailed, Watched, arena,
+                                      leases, sections)
+
+__all__ = ["arena", "sections"]         # fixtures of the uniform call's file
+
+K, M = 10, 4
+N = K + M
+PROFILE = {"k": str(K), "m": str(M), "technique": "cauchy"}
+REF = {"k": K, "m": M, "technique": "cauchy"}
+SIZES = [1, 31, 32, 4095, 4096, 4097, 40 << 10, (1 << 20) - 1]
+DOUBLES = [list(p) for p in itertools.combinations(range(N), 2)]
+OTHERS = [[0], [9], [13], [0, 1, 2], [3, 9, 12], [11, 12, 13],
+          [0, 1, 2, 3], [2, 6, 10, 13], [10, 11, 12, 13]]
+LANES = 1024            # a slab of most tests
+
+
+@pytest.fixture(scope="module", autouse=True)
+def pallas_engine():
+    """The engine choice of a TPU backend (``v1`` at k=10, ``gN`` at
+    k=8, through the Pallas interpreter): the matrix is an operand, so
+    91 patterns are one program (the CPU's own choice, ``sched``,
+    compiles one a matrix)."""
+    import ceph_tpu.ops.gf2kernels as g
+
+    mp = pytest.MonkeyPatch()
+    mp.setattr(g, "_want_pallas", lambda: True)
+    g.clear_kernel_cache()
+    yield
+    mp.undo()
+    g.clear_kernel_cache()
+
+
+@pytest.fixture
+def kernels():
+    """The kernel module with no launch verified yet."""
+    import ceph_tpu.ops.gf2kernels as g
+
+    g._gN_verified.clear()
+    return g
+
+
+@pytest.fixture
+def slab_lanes(monkeypatch, kernels):
+    """Sets the slab to so many lanes of a k=10 call."""
+    def set_lanes(lanes: int, k: int = K) -> None:
+        monkeypatch.setattr(kernels, "SLAB_BYTES", k * lanes)
+        assert kernels._slab_lanes(k) == lanes
+    return set_lanes
+
+
+def objects_of(sizes, seed: int = 50) -> list[np.ndarray]:
+    rng = np.random.default_rng(seed)
+    return [rng.integers(0, 256, size, dtype=np.uint8) for size in sizes]
+
+
+def maps_of(objects, parity, k: int = K) -> list[np.ndarray]:
+    return [np.concatenate([ref.chunks_of(k, obj.tobytes()), par])
+            for obj, par in zip(objects, parity)]
+
+
+@pytest.fixture(scope="module")
+def tpu_codec():
+    return registry().factory("tpu", PROFILE)
+
+
+@pytest.fixture(scope="module")
+def isa_codec():
+    return registry().factory("isa", PROFILE)
+
+
+@pytest.fixture(scope="module")
+def small():
+    """Twelve objects of seven sizes and their chunk maps, parity by the
+    reference."""
+    objects = objects_of([4096, 100, 8192, 1, 5000, 4096, 333, 16384, 32,
+                          4097, 100, 8192], seed=7)
+    return objects, maps_of(objects,
+                            ref_objects.parity_of_objects(REF, objects))
+
+
+def test_there_are_91_double_erasures():
+    assert len(DOUBLES) == 91
+
+
+def test_the_slab_width_comes_from_the_shape_alone(kernels):
+    lanes = kernels._slab_lanes(K)
+    assert lanes == 3350528 == 409 * kernels.LANE_TILE
+    assert K * lanes <= kernels.SLAB_BYTES < K * (lanes + kernels.LANE_TILE)
+    assert kernels._slab_lanes(8) == kernels.SLAB_BYTES // 8
+    # the process's arena holds the cell's result and two slabs, twice
+    assert kernels.ARENA_BYTES >= 2 * (M * 107233280 // 2
+                                       + 2 * kernels.SLAB_BYTES)
+
+
+def test_the_listed_sizes_in_one_call_equal_the_reference_and_isa(
+        tpu_codec, isa_codec, slab_lanes):
+    slab_lanes(8192)
+    objects = objects_of(SIZES)
+    parity = tpu_codec.encode_objects(objects)
+    want = ref_objects.parity_of_objects(REF, objects)
+    assert len(parity) == len(objects)
+    for obj, got, par in zip(objects, parity, want):
+        length = ref.chunk_bytes(K, obj.size)
+        assert got.shape == (M, length) == par.shape and got.dtype == np.uint8
+        assert tpu_codec.get_chunk_size(obj.size) == length
+        assert np.array_equal(got, par), obj.size
+        host = isa_codec.encode(set(range(N)), obj.tobytes())
+        for r in range(M):
+            assert got[r].flags["C_CONTIGUOUS"]         # a chunk is whole
+            assert np.array_equal(got[r], host[K + r]), (obj.size, r)
+    # and back: a decode of the last data chunk and a parity chunk
+    chunk_maps = maps_of(objects, parity)
+    lost = tpu_codec.decode_objects([K - 1, K + 1], chunk_maps)
+    for obj, stripe, got in zip(objects, chunk_maps, lost):
+        assert np.array_equal(got, stripe[[K - 1, K + 1]])
+        assert np.array_equal(got, ref.recovered(REF, stripe,
+                                                 [K - 1, K + 1]))
+        assert ref_objects.tail_nonzero(K, obj.size, K - 1, got[0]) == 0
+        host = isa_codec.decode({K - 1, K + 1}, {
+            i: stripe[i] for i in range(N) if i not in (K - 1, K + 1)})
+        assert np.array_equal(got[0], host[K - 1])
+        assert np.array_equal(got[1], host[K + 1])
+
+
+def test_bytes_objects_and_k8_m3_once(slab_lanes):
+    slab_lanes(LANES, 8)
+    profile = {"k": 8, "m": 3, "technique": "reed_sol_van"}
+    codec = registry().factory("tpu", {"k": "8", "m": "3"})
+    host = registry().factory("isa", {"k": "8", "m": "3"})
+    objects = objects_of([4096, 1, 9000, 4095, 65536], seed=8)
+    parity = codec.encode_objects([obj.tobytes() for obj in objects])
+    want = ref_objects.parity_of_objects(profile, objects)
+    for obj, got, par in zip(objects, parity, want):
+        assert np.array_equal(got, par)
+        chunks = host.encode(set(range(11)), obj.tobytes())
+        assert all(np.array_equal(got[r], chunks[8 + r]) for r in range(3))
+    chunk_maps = maps_of(objects, parity, 8)
+    for erased in ([0], [7, 8], [1, 4, 10]):
+        for stripe, got in zip(chunk_maps,
+                               codec.decode_objects(erased, chunk_maps)):
+            assert np.array_equal(got, stripe[erased])
+            assert np.array_equal(got, ref.recovered(profile, stripe, erased))
+    assert codec.perf.dump()["engine_gN"] == codec.perf.dump()["launches"]
+
+
+@pytest.mark.parametrize("erased", DOUBLES + OTHERS,
+                         ids=["-".join(map(str, p))
+                              for p in DOUBLES + OTHERS])
+def test_every_double_erasure_and_some_others_decode(tpu_codec, slab_lanes,
+                                                     small, erased):
+    """Poisoned at the erased positions, which are never read; the
+    erased chunks' own bytes back, and the reference's from the
+    survivors."""
+    slab_lanes(LANES)
+    objects, chunk_maps = small
+    poisoned = [stripe.copy() for stripe in chunk_maps]
+    for stripe in poisoned:
+        stripe[erased] = 0xA5
+    got = tpu_codec.decode_objects(erased, poisoned)
+    assert len(got) == len(objects)
+    for stripe, lost in zip(chunk_maps, got):
+        assert lost.shape == (len(erased), stripe.shape[1])
+        assert np.array_equal(lost, stripe[erased])
+    for i in (0, 3, 7):
+        assert np.array_equal(got[i], ref.recovered(REF, chunk_maps[i],
+                                                    erased))
+
+
+def test_an_erased_position_is_never_read(slab_lanes, small):
+    slab_lanes(LANES)
+    _, chunk_maps = small
+    erased = [2, 11]
+    watched = []
+    for stripe in chunk_maps:
+        view = stripe.view(Watched)
+        view.reads = []
+        watched.append(view)
+    codec = registry().factory("tpu", PROFILE)
+    got = codec.decode_objects(erased, watched)
+    for stripe, lost in zip(chunk_maps, got):
+        assert np.array_equal(lost, stripe[erased])
+    for view in watched:
+        assert view.reads                       # read, and by rows only:
+        for key in view.reads:                  # runs of survivor ids
+            rows = key if isinstance(key, slice) else key[0]
+            assert isinstance(rows, slice) and rows.step is None
+            assert not set(range(rows.start, rows.stop)) & set(erased)
+        assert {i for key in view.reads for i in range(
+            (key if isinstance(key, slice) else key[0]).start,
+            (key if isinstance(key, slice) else key[0]).stop)} == {
+            0, 1, 3, 4, 5, 6, 7, 8, 9, 10}
+
+
+def uploads_of(kernels, monkeypatch) -> list[np.ndarray]:
+    """Every slab as it was uploaded, copied before its launch."""
+    uploaded: list[np.ndarray] = []
+    real = kernels._launch_batch
+    monkeypatch.setattr(
+        kernels, "_launch_batch", lambda matrix, xd, perf=None:
+        uploaded.append(np.array(xd)) or real(matrix, xd, perf))
+    return uploaded
+
+
+# objects against a slab of 1024 lanes: 416-lane objects (4 KiB) make
+# the second end at 832, the third lie across the edge in its middle;
+# 4 KiB + 1920 B + 4 KiB (416 + 192 + 416 = 1024) end exactly at it; a
+# 2240-byte object (224 lanes) more ends one 32-byte lane group past it
+EDGES = {"across_in_the_middle": [4096, 4096, 4096, 4096],
+         "exactly_at_the_end": [4096, 1920, 4096, 4096],
+         "one_lane_group_past": [4096, 1920, 4096 + 320, 4096],
+         "a_piece_of_three_slabs": [100, 25000, 4096]}
+
+
+@pytest.mark.parametrize("case", list(EDGES))
+def test_objects_that_meet_a_slabs_edge(kernels, monkeypatch, slab_lanes,
+                                        isa_codec, case):
+    """The builder's choice is to let an object lie across two slabs:
+    the lanes of a call are cut every ``_slab_lanes`` columns wherever
+    an object begins or ends, and only the call's last slab is padded.
+    What is uploaded is exactly the tool's chunks, laid end to end."""
+    slab_lanes(LANES)
+    uploaded = uploads_of(kernels, monkeypatch)
+    objects = objects_of(EDGES[case], seed=len(case))
+    lengths = [ref.chunk_bytes(K, obj.size) for obj in objects]
+    ends = np.cumsum(lengths)
+    if case == "across_in_the_middle":
+        assert ends[1] < LANES < ends[2]
+    elif case == "exactly_at_the_end":
+        assert ends[2] == LANES
+    elif case == "one_lane_group_past":
+        assert ends[2] == LANES + 32
+    else:
+        assert ends[1] - lengths[1] < LANES and ends[1] > 2 * LANES
+    codec = registry().factory("tpu", PROFILE)
+    parity = codec.encode_objects(objects)
+    row = np.concatenate([ref.chunks_of(K, obj.tobytes())
+                          for obj in objects], axis=1)
+    slabs = -(-row.shape[1] // LANES)
+    assert len(uploaded) == slabs
+    assert all(xd.shape == (1, K, LANES) for xd in uploaded)
+    sent = np.concatenate([xd[0] for xd in uploaded], axis=1)
+    assert np.array_equal(sent[:, :row.shape[1]], row)
+    for obj, got in zip(objects, parity):
+        host = isa_codec.encode(set(range(N)), obj.tobytes())
+        assert all(np.array_equal(got[r], host[K + r]) for r in range(M))
+    dump = codec.perf.dump()
+    assert dump["slabs"] == slabs and dump["objects"] == len(objects)
+    assert dump["lanes"] == row.shape[1]
+    assert dump["lanes_launched"] == slabs * LANES
+    assert dump["lanes_padded"] == slabs * LANES - row.shape[1]
+    assert dump["bytes_in"] == K * row.shape[1]
+    assert dump["bytes_out"] == M * row.shape[1]
+    assert dump.get("pipelined", 0) == (slabs > 1) and "stripes" not in dump
+    # and the decode across the same edges
+    chunk_maps = maps_of(objects, parity)
+    for stripe, lost in zip(chunk_maps,
+                            codec.decode_objects([0, K - 1], chunk_maps)):
+        assert np.array_equal(lost, stripe[[0, K - 1]])
+
+
+def test_the_tail_of_every_last_data_chunk_is_zero_in_what_is_encoded(
+        kernels, monkeypatch, slab_lanes):
+    """Staging is reused and never cleared: the zeros past an object's
+    end are written with every fill, over whatever the slab two back
+    left there, and no byte of a neighbour is inside an object's
+    lanes."""
+    slab_lanes(LANES)
+    uploaded = uploads_of(kernels, monkeypatch)
+    codec = registry().factory("tpu", PROFILE)
+    loud = [np.full(size, 0xFF, np.uint8) for size in [4096] * 12]
+    codec.encode_objects(loud)                  # both buffers all ones
+    del uploaded[:]
+    sizes = [1, 4095, 33, 4096, 700, 2, 4097, 31, 5000, 64]
+    objects = [np.full(size, 0xFF, np.uint8) for size in sizes]
+    codec.encode_objects(objects)
+    sent = np.concatenate([xd[0] for xd in uploaded], axis=1)
+    at = 0
+    for size in sizes:
+        length = ref.chunk_bytes(K, size)
+        mine = sent[:, at:at + length].reshape(-1)
+        assert (mine[:size] == 0xFF).all() and not mine[size:].any(), size
+        at += length
+
+
+def test_an_empty_call_and_a_call_of_one_object(kernels, arena, slab_lanes,
+                                                isa_codec):
+    slab_lanes(LANES)
+    codec = registry().factory("tpu", PROFILE)
+    assert codec.encode_objects([]) == []
+    assert codec.decode_objects([1, 2], []) == []
+    (nothing,) = codec.encode_objects([np.empty(0, np.uint8)])
+    assert nothing.shape == (M, 0)
+    assert codec.perf.dump().get("launches", 0) == 0
+    (obj,) = objects_of([5000])
+    (parity,) = codec.encode_objects([obj])
+    host = isa_codec.encode(set(range(N)), obj.tobytes())
+    assert all(np.array_equal(parity[r], host[K + r]) for r in range(M))
+    (stripe,) = maps_of([obj], [parity])
+    (lost,) = codec.decode_objects([4, 12], [stripe])
+    assert np.array_equal(lost, stripe[[4, 12]])
+    dump = codec.perf.dump()
+    assert dump["launches"] == dump["slabs"] == dump["objects"] == 2
+    assert "pipelined" not in dump
+    # a call of one slab borrows too: its result and two staging buffers
+    assert leases(codec) == (2, 4)
+
+
+def test_one_program_a_count_of_output_rows_whatever_the_mix(
+        kernels, slab_lanes):
+    slab_lanes(LANES)
+    kernels.clear_kernel_cache()
+    codec = registry().factory("tpu", PROFILE)
+    mixes = [[4096, 100, 8192, 1, 5000], [333, 16384, 32, 4097, 100, 8192,
+                                          65536, 7]]
+    for sizes in mixes:
+        objects = objects_of(sizes, seed=len(sizes))
+        parity = codec.encode_objects(objects)
+        chunk_maps = maps_of(objects, parity)
+        for erased in ([0, 1], [5, 13], [9, 10]):
+            for stripe, lost in zip(
+                    chunk_maps, codec.decode_objects(erased, chunk_maps)):
+                assert np.array_equal(lost, stripe[erased])
+    # r = 4 and r = 2 at the slab's shape, and nothing else
+    assert kernels._compiled_batch.cache_info().currsize == 2
+    assert kernels._compiled_batch_gN.cache_info().currsize == 0
+    assert kernels.batch_engine(codec.encode_matrix[K:], 1, K, LANES) == "v1"
+    dump = codec.perf.dump()
+    assert dump["engine_v1"] == dump["launches"] == 8
+    assert dump["table_misses"] == 3 and dump["table_hits"] == 3
+    assert dump["parity_gates"] == 4                # one a matrix: one shape
+
+
+def test_sections_and_counters_of_a_call_over_objects(kernels, sections,
+                                                      arena, slab_lanes,
+                                                      small):
+    slab_lanes(LANES)
+    objects, chunk_maps = small
+    lanes = sum(stripe.shape[1] for stripe in chunk_maps)
+    slabs = -(-lanes // LANES)
+    assert slabs == 6
+    codec = registry().factory("tpu", PROFILE)
+    parity = codec.encode_objects(objects)
+    flat = [s for s in sections if s != "registry.matrix"]
+    per = ["registry.marshal", "registry.upload", "registry.launch"]
+    assert flat == (["registry.prepare"] + per * 3 + ["registry.drain"]
+                    + (per + ["registry.drain"]) * 3
+                    + ["registry.drain"] * 1
+                    + ["registry.device_wait", "registry.copy_out",
+                       "registry.prepare"])
+    dump = codec.perf.dump()
+    dump.pop("staging_waits", None)     # as the launches happen to finish
+    assert dump == {
+        "launches": 1, "engine_v1": 1, "objects": len(objects),
+        "lanes": lanes, "lanes_launched": slabs * LANES,
+        "lanes_padded": slabs * LANES - lanes, "bytes_in": K * lanes,
+        "bytes_out": M * lanes, "slabs": slabs, "pipelined": 1,
+        "arena_misses": 3, "parity_gates": 1}
+    del parity
+    sections.clear()
+    codec.decode_objects([3, 7], chunk_maps)
+    assert sections[0] == "registry.matrix"          # the table miss
+    assert sections[1] == "registry.prepare" == sections[-1]
+    two = codec.perf.dump()
+    assert two["table_misses"] == 1 and two["objects"] == 2 * len(objects)
+    assert two["bytes_out"] == (M + 2) * lanes
+    assert leases(codec) == (3, 3)                   # all three kept ones
+
+
+# -- leases and staging, as the uniform call keeps them ------------------------
+
+def test_the_results_are_views_of_one_lease_and_keep_it(kernels, arena,
+                                                        slab_lanes, small):
+    slab_lanes(LANES)
+    objects, chunk_maps = small
+    lanes = sum(stripe.shape[1] for stripe in chunk_maps)
+    codec = registry().factory("tpu", PROFILE)
+    parity = codec.encode_objects(objects)
+    base = parity[0].ctypes.data
+    for got, stripe in zip(parity, chunk_maps):
+        assert got.strides == (lanes, 1) and got.flags["WRITEABLE"]
+        assert base <= got.ctypes.data < base + lanes
+    staging = 2 * K * LANES
+    assert arena.at_rest() == staging               # the result is out
+    kept = parity[5][1:, ::2]                       # a view of a view
+    want = chunk_maps[5][K + 1:, ::2].copy()
+    del parity, got
+    gc.collect()
+    assert arena.at_rest() == staging
+    for _ in range(2):                              # later calls: other memory
+        later = codec.encode_objects(objects[::-1])
+        assert not any(np.shares_memory(out, kept) for out in later)
+        del later
+    assert np.array_equal(kept, want)
+    del kept
+    assert arena.events[-1] == ("given", base)
+    assert arena.at_rest() == staging + 2 * M * lanes
+    hits, misses = leases(codec)
+    assert (hits, misses) == (5, 4)
+
+
+def later_fills(kernels, monkeypatch, matrix, log, fail_at=None):
+    """Fills and launches written down in ``log``, the launches as
+    ``LaterOut``s that are done only once somebody waits (launch
+    ``fail_at`` raises instead): (staging buffers by id, the outs)."""
+    from ceph_tpu.gf import gf_matmul
+
+    buffers: dict[int, np.ndarray] = {}
+    outs: list[LaterOut] = []
+    read_by: dict[int, list[LaterOut]] = {}
+    real = kernels.LanePieces.fill
+
+    def fill(self, lo, hi, into):
+        buffers[id(into)] = into
+        pending = [o.slab for o in read_by.get(id(into), ()) if not o.done]
+        log.append(("fill", id(into), pending))
+        return real(self, lo, hi, into)
+
+    def launch(matrix_, xd, perf=None):
+        if len(outs) == fail_at:
+            raise SlabFailed(fail_at)
+        host = np.asarray(xd)
+        (buf,) = [key for key, arr in buffers.items()
+                  if np.array_equal(arr, host)]
+        out = LaterOut(log, len(outs), gf_matmul(matrix, host[0])[None])
+        outs.append(out)
+        read_by.setdefault(buf, []).append(out)
+        log.append(("launch", out.slab, buf))
+        return "v1", out
+
+    monkeypatch.setattr(kernels.LanePieces, "fill", fill)
+    monkeypatch.setattr(kernels, "_launch_batch", launch)
+    return buffers, outs
+
+
+def test_staging_is_refilled_only_behind_the_launch_that_read_it(
+        kernels, monkeypatch, arena, slab_lanes, small):
+    slab_lanes(LANES)
+    objects, chunk_maps = small
+    codec = registry().factory("tpu", PROFILE)
+    log = arena.events
+    buffers, outs = later_fills(kernels, monkeypatch,
+                                codec.encode_matrix[K:], log)
+    parity = codec.encode_objects(objects)
+    for got, stripe in zip(parity, chunk_maps):
+        assert np.array_equal(got, stripe[K:])
+    slabs = 6
+    fills = [ev for ev in log if ev[0] == "fill"]
+    fill_at = [i for i, ev in enumerate(log) if ev[0] == "fill"]
+    assert len(fills) == len(outs) == slabs and len(buffers) == 2
+    assert [ev[1] for ev in fills] == [fills[i % 2][1] for i in range(slabs)]
+    assert all(ev[2] == [] for ev in fills), fills  # nothing unfinished
+    for n in range(slabs - 2):
+        assert log.index(("done", n)) < fill_at[n + 2] \
+            < log.index(("done", n + 1))
+    assert log[-2:] == [("given", into.ctypes.data)
+                        for into in buffers.values()]
+    assert log.index(("done", slabs - 1)) == len(log) - 3
+    assert codec.perf.dump()["staging_waits"] == slabs - 2
+
+
+@pytest.mark.parametrize("fail_at", [0, 2, 5], ids=[
+    "first_slab", "third_slab", "padded_last_slab"])
+def test_buffers_come_back_when_a_slab_raises(kernels, monkeypatch, arena,
+                                              slab_lanes, small, fail_at):
+    slab_lanes(LANES)
+    objects, chunk_maps = small
+    lanes = sum(stripe.shape[1] for stripe in chunk_maps)
+    codec = registry().factory("tpu", PROFILE)
+    log = arena.events
+    buffers, outs = later_fills(kernels, monkeypatch,
+                                codec.encode_matrix[K:], log,
+                                fail_at=fail_at)
+    with pytest.raises(SlabFailed):
+        codec.encode_objects(objects)
+    assert len(outs) == fail_at and all(out.done for out in outs)
+    first = next(i for i, ev in enumerate(log) if ev[0] == "given")
+    assert all(ev[0] != "done" for ev in log[first:])
+    assert {into.ctypes.data for into in buffers.values()} <= {
+        ev[1] for ev in log[first:first + 2]}
+    gc.collect()                # the result nobody got
+    assert [ev[0] for ev in log].count("given") == 3
+    assert arena.at_rest() == 2 * K * LANES + M * lanes
+    assert "launches" not in codec.perf.dump()
+    assert leases(codec) == (0, 3)
+
+
+def test_a_parity_miss_raises_out_of_a_call_over_objects(kernels, slab_lanes,
+                                                         small):
+    """Either serves or raises: a matrix's first launch at the slab's
+    shape is held to the host oracle, whichever engine serves."""
+    slab_lanes(LANES)
+    codec = registry().factory("tpu", PROFILE)
+    real = kernels._compiled_batch
+    kernels._gN_verified.clear()
+    launched = []
+    kernels._compiled_batch = lambda *a: (
+        lambda w, xd, fn=real(*a): launched.append(xd.shape) or fn(w, xd) ^ 1)
+    try:
+        with pytest.raises(kernels.KernelParityError):
+            codec.encode_objects(small[0])
+        with pytest.raises(kernels.KernelParityError):
+            codec.decode_objects([1, 2], small[1])
+    finally:
+        kernels._compiled_batch = real
+    assert launched == [(1, K, LANES)] * 2          # nothing served after it
+    assert "launches" not in codec.perf.dump()
+    assert codec.perf.dump()["parity_gates"] == 2
+
+
+@pytest.mark.parametrize("workload", ["encode", "decode"])
+def test_the_tool_times_the_mixed_call_and_names_the_engine(
+        slab_lanes, capsys, workload):
+    """``ec_bench --batch B --size a,b,c``: B objects of each size a
+    call through the same two entry points the cell's driver calls."""
+    from ceph_tpu.tools import ec_bench
+
+    slab_lanes(LANES)
+    assert ec_bench.main([
+        "--plugin", "tpu", "-k", str(K), "-m", str(M), "-P",
+        "technique=cauchy", "-s", "4096,100,9000", "--batch", "3", "-i", "2",
+        "-w", workload, "-e", "2", "--verify"]) == 0
+    out, err = capsys.readouterr()
+    seconds, kib = out.strip().split("\t")
+    assert float(seconds) > 0
+    assert int(kib) == 3 * (4096 + 100 + 9000) * 2 // 1024
+    # the warm-up call and the timed ones (a decode: the encode that
+    # made its inputs too), one engine on every launch
+    assert err.strip() == f"engine: v1 x{3 if workload == 'encode' else 4} " \
+        "launches"

@@ -13,7 +13,11 @@ plugin's ``ec_registry`` set (``codec.perf``): ``launches``, ``stripes``,
 ``bytes_in``, ``bytes_out``, ``engine_<name>`` (the engine that served,
 per call), ``slabs`` / ``pipelined`` / ``staging_waits`` (how a call from
 host memory to host memory streamed through the device),
-``arena_hits`` / ``arena_misses`` (the result and the two staging
+``gathers`` / ``gathers_ahead`` (slabs of such a call whose survivors or
+pieces its worker thread gathered into staging, and those of them that
+were ready when the caller's thread came to upload them: how often the
+gather stage ran ahead of the uploads),
+``arena_hits`` / ``arena_misses`` (the result and the three staging
 buffers of such a call, each borrowed from the process's host arena: a
 buffer an earlier call's caller dropped, or a fresh allocation; a
 result stays its caller's own until no array views it),
@@ -21,7 +25,8 @@ result stays its caller's own until no array views it),
 ``table_hits`` / ``table_misses`` (decode matrices taken from, or built
 into, the DecodeTableCache); where its thread was is in the
 ``registry.*`` sections (a table miss's ``matrix`` here, the rest in
-``gf_matmul_batch_device``).
+``gf_matmul_batch_device``; ``registry.gather`` is the worker's, on its
+own thread).
 """
 
 from __future__ import annotations

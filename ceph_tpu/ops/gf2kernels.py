@@ -31,6 +31,7 @@ import collections
 import functools
 import json
 import os
+from concurrent.futures import ThreadPoolExecutor
 
 import numpy as np
 
@@ -689,20 +690,36 @@ def gf_matmul_batch_device(matrix: np.ndarray, data, *, rows=None,
     asked back to it (``out_np``) streams through the device in slabs
     of ``_slab_stripes`` stripes, each through the same program at the
     slab's shape; a batch no larger than a slab is one slab.  Per slab
-    and under its own section: ``registry.marshal`` (with ``rows``: the
-    gather into one of two staging buffers, refilled only once the
-    launch that read it is done), ``registry.upload`` (``device_put``;
-    a one-slab call waits until the bytes are on the device, a slab of
-    many does not), ``registry.launch`` (engine choice and dispatch; the
-    matrix's device copy and a first launch's parity gate are
-    ``registry.matrix`` inside it), then the result's copy to the host
-    is started and the slab two back is landed in its rows of the
-    call's one result array (``registry.drain``).  So slab i+1's gather
-    and upload, slab i's kernel and slab i-1's copy-out are in flight
-    together and three slabs at most live on the device.  The call
-    closes with the last slab's ``registry.device_wait`` and
+    and under its own section on the caller's thread:
+    ``registry.marshal`` (with ``rows``: the slab's survivors in a
+    staging buffer; a one-slab call gathers them there and then, a call
+    of several waits for its worker, below), ``registry.upload``
+    (``device_put``; a one-slab call waits until the bytes are on the
+    device, a slab of many does not), ``registry.launch`` (engine choice
+    and dispatch; the matrix's device copy and a first launch's parity
+    gate are ``registry.matrix`` inside it), then the result's copy to
+    the host is started and the slab two back is landed in its rows of
+    the call's one result array (``registry.drain``).  The call closes
+    with the last slab's ``registry.device_wait`` and
     ``registry.copy_out`` (until the last byte is readable on the
     host); the result is a C-ordered array of the caller's own.
+
+    The gather of a call of several slabs is a stage of its own, on a
+    worker thread the call starts and ends (``registry-gather``; one a
+    call in flight, so two callers never wait for each other's): the
+    caller hands it slab 0 at once and slab i+1 before it uploads slab
+    i, over THREE staging buffers (one being filled, one on its way up,
+    one a launch still reads), and ``registry.marshal`` is only the wait
+    for the slab about to go up.  The worker's time is ``registry.gather`` on its own
+    thread: the wait for the launch that read the buffer's last upload
+    (slab i-2's, dispatched two iterations before), then
+    ``_gather_rows`` or ``LanePieces.fill``, whose numpy copies release
+    the GIL.  So slab i+1's gather, slab i's upload, slab i-1's kernel
+    and slab i-2's copy-out are in flight together, on two threads, the
+    link and the device, and three slabs at most live on the device.  A
+    call without a gather (no ``rows``, no pieces: the caller's array is
+    uploaded as it is), of one slab, or from a device array starts no
+    worker.
 
     Where the host memory of a call of several slabs comes from: the
     process's ``HostArena`` (``host_arena.py``; ``ARENA_BYTES`` at rest
@@ -711,35 +728,41 @@ def gf_matmul_batch_device(matrix: np.ndarray, data, *, rows=None,
     ``lease``: exactly ``(B, r, L)`` over the smallest kept buffer that
     fits, else over a fresh one; it, and every slice cut from it, is
     the caller's alone until the last array over its memory is gone,
-    and only then does the buffer go back for a later call.  The two
-    staging buffers are taken at the call's start and given back at
-    its end, also when the call raises: a staging buffer is refilled,
+    and only then does the buffer go back for a later call.  The
+    staging buffers (a slab each, three at most) are taken at the
+    call's start and given back at its end, also when the call raises,
+    on either thread (the worker's exception is the call's, raised
+    where the caller asks for that slab): a staging buffer is refilled,
     or given back, only behind the launch that read its upload, and
-    before it returns either way the call has waited for every launch
-    it made.  A call of one slab borrows nothing: its result is
-    ``np.asarray`` of the launch's.
+    before it returns either way the call has waited for its worker
+    and for every launch it made; no thread outlives it.  A uniform
+    call of one slab borrows nothing: its result is ``np.asarray`` of
+    the launch's.
 
     ``data`` a ``LanePieces`` (pieces of unequal length, always from
     host memory to host memory) goes through the same loop with a slab
     cut over the call's LANES: every slab is one ``(1, k,
     _slab_lanes(k))`` launch whatever lengths the call mixes (one
     program a count of output rows; the last slab's spare lanes are
-    launched and dropped), ``registry.marshal`` is ``LanePieces.fill``
-    into the staging buffer, and a slab lands in its columns of the
-    call's ``(r, lanes)`` result, which is leased, as the staging is,
-    at any number of slabs: the caller cuts each piece's ``(r, L_i)``
-    out of it as a view.
+    launched and dropped), the gather is ``LanePieces.fill`` into the
+    staging buffer, and a slab lands in its columns of the call's
+    ``(r, lanes)`` result, which is leased, as the staging is, at any
+    number of slabs: the caller cuts each piece's ``(r, L_i)`` out of
+    it as a view.
 
     ``perf`` (the plugin's ``ec_registry`` set) counts a call once,
     however many slabs: ``launches``, ``stripes``, ``bytes_in``,
     ``bytes_out``, ``engine_<name>``; and ``slabs`` (device launches),
-    ``pipelined`` (calls of more than one slab), ``staging_waits``
-    (refills that had to wait for a launch), ``parity_gates``,
-    ``arena_hits`` / ``arena_misses`` (one a buffer borrowed, result or
-    staging: a kept one, or a fresh allocation); for a ``LanePieces``
-    call ``objects`` (its pieces), ``lanes`` (the columns the caller
-    asked for), ``lanes_launched`` (slabs x slab width) and
-    ``lanes_padded`` (their difference) in the place of ``stripes``."""
+    ``pipelined`` (calls of more than one slab), ``gathers`` (slabs
+    whose staging the worker filled), ``gathers_ahead`` (those already
+    filled when the caller's thread came for them), ``staging_waits``
+    (refills that had to wait for a launch, counted where the worker
+    waits), ``parity_gates``, ``arena_hits`` / ``arena_misses`` (one a
+    buffer borrowed, result or staging: a kept one, or a fresh
+    allocation); for a ``LanePieces`` call ``objects`` (its pieces),
+    ``lanes`` (the columns the caller asked for), ``lanes_launched``
+    (slabs x slab width) and ``lanes_padded`` (their difference) in the
+    place of ``stripes``."""
     matrix = np.ascontiguousarray(matrix, dtype=np.uint8)
     r, k = matrix.shape
     ragged = isinstance(data, LanePieces)
@@ -756,16 +779,38 @@ def gf_matmul_batch_device(matrix: np.ndarray, data, *, rows=None,
         spans = [(lo, min(lo + step, b)) for lo in range(0, b, step)]
         result = _arena.lease((b, r, l), perf) if len(spans) > 1 else None
     borrowed: list[np.ndarray] = []     # staging to give back at the end
+    worker = None           # fills a slab's staging ahead of its upload
     if on_host and (ragged or rows is not None):
         if result is not None:
-            borrowed = [_arena.take(step * k * l, perf) for _ in range(2)]
+            # a slab being filled, one on its way up, one a launch reads
+            borrowed = [_arena.take(step * k * l, perf)
+                        for _ in range(min(3, len(spans)))]
             staging = [buf[:step * k * l].reshape(step, k, l)
                        for buf in borrowed]
         else:
             staging = [np.empty((step, k, l), np.uint8)]
+        if len(spans) > 1:
+            worker = ThreadPoolExecutor(1, "registry-gather")
     flying: collections.deque = collections.deque()   # (lo, hi, out)
-    waits = 0
-    xd = None
+    waits = ahead = 0
+    xd = filling = None
+
+    def fill(i: int) -> np.ndarray:
+        lo, hi = spans[i]
+        into = staging[i % len(staging)]
+        return (data.fill(lo, hi, into) if ragged
+                else _gather_rows(data, rows, lo, hi, into))
+
+    def fill_ahead(i: int, reader) -> np.ndarray:
+        """Slab i's staging, on the worker, behind ``reader``: the
+        launch that read the buffer's last upload (slab i - 3's)."""
+        nonlocal waits
+        with section("registry.gather"):
+            if reader is not None and not reader.is_ready():
+                waits += 1
+                # lint: disable=device-path-host-sync -- a staging buffer is refilled only after the launch that read it
+                reader.block_until_ready()
+            return fill(i)
 
     def land() -> None:
         lo, hi, out = flying.popleft()
@@ -777,6 +822,8 @@ def gf_matmul_batch_device(matrix: np.ndarray, data, *, rows=None,
             result[lo:hi] = host
 
     try:
+        if worker is not None:
+            filling = worker.submit(fill_ahead, 0, None)
         for i, (lo, hi) in enumerate(spans):
             if not on_host:
                 xd = jnp.asarray(data, dtype=jnp.uint8)
@@ -787,15 +834,17 @@ def gf_matmul_batch_device(matrix: np.ndarray, data, *, rows=None,
                     slab = np.ascontiguousarray(data[lo:hi], dtype=np.uint8)
                 else:
                     with section("registry.marshal"):
-                        # slab i - 2's launch read this buffer's upload
-                        reader = flying[0][2] if len(flying) == 2 else None
-                        if reader is not None and not reader.is_ready():
-                            waits += 1
-                            # lint: disable=device-path-host-sync -- a staging buffer is refilled only after the launch that read it
-                            reader.block_until_ready()
-                        slab = (data.fill(lo, hi, staging[i % 2]) if ragged
-                                else _gather_rows(data, rows, lo, hi,
-                                                  staging[i % 2]))
+                        if worker is None:
+                            slab = fill(i)
+                        else:
+                            ahead += filling.done()
+                            slab = filling.result()
+                            if i + 1 < len(spans):
+                                # slab i - 2's launch read the upload of
+                                # the buffer slab i + 1 goes into
+                                filling = worker.submit(
+                                    fill_ahead, i + 1, flying[0][2]
+                                    if len(flying) == 2 else None)
                 with section("registry.upload"):
                     xd = jax.device_put(slab)
                     if result is None:
@@ -829,6 +878,9 @@ def gf_matmul_batch_device(matrix: np.ndarray, data, *, rows=None,
             perf.inc("slabs", len(spans))
             if len(spans) > 1:
                 perf.inc("pipelined")
+            if worker is not None:
+                perf.inc("gathers", len(spans))
+                perf.inc("gathers_ahead", ahead)
             if waits:
                 perf.inc("staging_waits", waits)
         if not out_np:
@@ -846,6 +898,9 @@ def gf_matmul_batch_device(matrix: np.ndarray, data, *, rows=None,
             land()
             return result
     finally:
+        if worker is not None:
+            # a call that raised may leave a fill running: it ends first
+            worker.shutdown(wait=True, cancel_futures=True)
         if borrowed:
             # a call that raised may leave a slab on its way to the device
             for held in (xd, *(out for _, _, out in flying)):

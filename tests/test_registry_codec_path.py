@@ -5,16 +5,19 @@ rows, and what a call leaves behind to be measured by: the
 ``registry.*`` sections, the ``ec_registry`` counters, the programs'
 names and scope.  A call from numpy to numpy streams through the device
 in slabs (``gf2kernels.SLAB_BYTES``; the toy batches here are one slab
-unless a test lowers it): the same bytes at every number of slabs, two
-staging buffers refilled only behind the launch that read them, one
-result array a call.  The result and the staging of a call of several
-slabs are borrowed from the process's host arena
-(``ops/host_arena.py``): a result is the caller's own until the last
-array over its memory is gone, and only then the next call's.
+unless a test lowers it): the same bytes at every number of slabs, one
+result array a call, and the gather of a call of several slabs on a
+worker thread of the call's own, one slab ahead of the caller's uploads
+over three staging buffers, each refilled only behind the launch that
+read it.  The result and the staging of a call of several slabs are
+borrowed from the process's host arena (``ops/host_arena.py``): a
+result is the caller's own until the last array over its memory is
+gone, and only then the next call's.
 """
 
 from __future__ import annotations
 
+import collections
 import contextlib
 import gc
 import itertools
@@ -25,9 +28,11 @@ import time
 import numpy as np
 import pytest
 
+from benchmark.reference import codec as ref
 from ceph_tpu.common import tracing
 from ceph_tpu.ec import registry
 from ceph_tpu.gf import gf_matmul
+from ceph_tpu.gf.matrices import decode_index_for
 
 K, M, B, L = 8, 3, 4, 256
 N = K + M
@@ -39,6 +44,7 @@ every_cut = pytest.mark.parametrize("per_slab", list(SLABS), ids=[
 PATTERNS = [list(p) for e in range(1, M + 1)
             for p in itertools.combinations(range(N), e)]
 ENGINES = ("sched", "gN", "v1", "xla")
+GATHERER = "registry-gather_0"      # the one thread of a call's worker
 
 
 @pytest.fixture(scope="module", autouse=True)
@@ -243,17 +249,31 @@ def test_every_count_of_output_rows_reaches_the_packed_engine(
         packed._compiled_batch_gN = real
 
 
+class Opened(list):
+    """The sections the test's own thread opened, in order of opening;
+    ``elsewhere``: those of every other thread, by the thread's name."""
+
+    def __init__(self) -> None:
+        super().__init__()
+        self.mine = threading.get_ident()
+        self.elsewhere: dict[str, list[str]] = {}
+
+
 @pytest.fixture
 def sections(monkeypatch):
-    """Every section the registry path opens, in order of opening."""
+    """Every section the registry path opens, thread by thread."""
     import ceph_tpu.ec.plugins.tpu as plugin
     import ceph_tpu.ops.gf2kernels as g
 
-    opened: list[str] = []
+    opened = Opened()
 
     @contextlib.contextmanager
     def record(name):
-        opened.append(name)
+        if threading.get_ident() == opened.mine:
+            opened.append(name)
+        else:
+            opened.elsewhere.setdefault(
+                threading.current_thread().name, []).append(name)
         yield
 
     monkeypatch.setattr(g, "section", record)
@@ -290,23 +310,30 @@ def test_one_encode_and_one_decode_move_sections_and_counters(
     assert two["engine_gN"] == two["parity_gates"] == two["slabs"] == 2
     assert two["table_misses"] == 1 and "table_hits" not in two
     assert "pipelined" not in two and "staging_waits" not in two
-    # a call of one slab borrows nothing
+    # a call of one slab borrows nothing and gathers on its own thread
     assert "arena_hits" not in two and "arena_misses" not in two
+    assert "gathers" not in two and "gathers_ahead" not in two
+    assert sections.elsewhere == {}
 
     # several slabs: an encode borrows its result, a decode its result
-    # and two staging buffers, each counted once as a hit or a miss
+    # and three staging buffers, each counted once as a hit or a miss
     slab_of(4)
     parity = codec.encode_batch(wide[:, :K], out_np=True)
     assert leases(codec) == (0, 1)                   # nothing kept yet
+    assert "gathers" not in codec.perf.dump()        # no gather, no worker
     del parity
     lost = codec.decode_stripes(erased, wide, out_np=True)
-    assert leases(codec) == (1, 3)       # the encode's buffer; new staging
+    assert leases(codec) == (1, 4)       # the encode's buffer; new staging
     del lost
     codec.decode_stripes(erased, wide, out_np=True)
-    assert leases(codec) == (4, 3)
+    assert leases(codec) == (5, 4)
     three = codec.perf.dump()
     assert three["launches"] == 5 and three["pipelined"] == 3
     assert three["slabs"] == 2 + 3 * 3
+    # the decodes' slabs were filled by their workers, and by nobody else
+    assert three["gathers"] == 2 * 3
+    assert 0 <= three["gathers_ahead"] <= three["gathers"]
+    assert sections.elsewhere == {GATHERER: ["registry.gather"] * 6}
 
 
 def test_gates_and_table_misses_count_once_per_new_signature(packed,
@@ -352,8 +379,9 @@ def test_an_encode_is_the_same_bytes_at_every_number_of_slabs(
                          ids=["-".join(map(str, p)) for p in PATTERNS])
 def test_every_erasure_pattern_decodes_in_three_ragged_slabs(
         tpu_codec, slab_of, wide, erased):
-    """4 + 4 + 2 stripes through two staging buffers: the bytes of the
-    one-launch call and of the plain product."""
+    """4 + 4 + 2 stripes through three staging buffers, filled by the
+    call's worker: the bytes of the one-launch call and of the plain
+    product."""
     blanked = wide.copy()
     blanked[:, erased] = 0x5A
     whole = tpu_codec.decode_stripes(erased, blanked, out_np=True)
@@ -367,6 +395,37 @@ def test_every_erasure_pattern_decodes_in_three_ragged_slabs(
     assert after["launches"] - before["launches"] == 1
     assert after["slabs"] - before["slabs"] == 3
     assert after["pipelined"] - before.get("pipelined", 0) == 1
+    assert after["gathers"] - before.get("gathers", 0) == 3
+
+
+@pytest.mark.parametrize("per_slab,slabs", [(14, 1), (8, 2), (6, 3), (2, 7)],
+                         ids=["1slab", "2slabs", "3slabs", "7slabs"])
+def test_a_decode_from_rows_is_the_references_bytes_at_any_number_of_slabs(
+        packed, monkeypatch, per_slab, slabs):
+    """14 stripes whose parity is ``benchmark/reference/``'s, recovered
+    through one slab (gathered on the caller's thread), two (a buffer
+    each), three (all three buffers, none refilled) and seven (every
+    buffer refilled behind its reader)."""
+    profile = {"k": K, "m": M, "technique": "reed_sol_van"}
+    data = np.random.default_rng(51).integers(
+        0, 256, (14, K, L), dtype=np.uint8)
+    stripes = np.concatenate([data, ref.parity_of(profile, data)], axis=1)
+    monkeypatch.setattr(packed, "SLAB_BYTES", per_slab * K * L)
+    codec = registry().factory("tpu", {"k": str(K), "m": str(M)})
+    before = set(threading.enumerate())
+    for erased in ([6], [0, 9], [3, 8, 10]):
+        blanked = stripes.copy()
+        blanked[:, erased] = 0xA5
+        got = codec.decode_stripes(erased, blanked, out_np=True)
+        assert np.array_equal(got, stripes[:, erased])
+        assert np.array_equal(got[5], ref.recovered(profile, blanked[5],
+                                                    erased))
+    assert set(threading.enumerate()) == before
+    dump = codec.perf.dump()
+    assert dump["slabs"] == 3 * slabs
+    assert dump.get("gathers", 0) == (3 * slabs if slabs > 1 else 0)
+    assert dump.get("gathers_ahead", 0) <= dump.get("gathers", 0)
+    assert dump.get("pipelined", 0) == (3 if slabs > 1 else 0)
 
 
 @pytest.mark.parametrize("per_slab", [10, 4], ids=["1slab", "3slabs"])
@@ -445,13 +504,13 @@ def test_a_dropped_results_buffer_is_the_next_calls(
         want = np.stack([gf_matmul(codec.encode_matrix[K:], d)
                          for d in other[:, :K]])
     assert got.ctypes.data == address
-    assert leases(codec) == (hits + 1, misses + (2 if erased else 0))
+    assert leases(codec) == (hits + 1, misses + (3 if erased else 0))
     assert got.shape == (WIDE, len(erased) or M, L) and got.dtype == np.uint8
     assert got.flags["C_CONTIGUOUS"] and got.flags["WRITEABLE"]
     assert got.strides == (got.shape[1] * L, L, 1)
     assert np.array_equal(got, want)
     got[:] = 0                              # the caller's own, to write too
-    assert arena.at_rest() == (2 * 4 * K * L if erased else 0)
+    assert arena.at_rest() == (3 * 4 * K * L if erased else 0)
 
 
 def test_the_cap_drops_what_would_pass_it(packed, monkeypatch, slab_of, wide):
@@ -530,7 +589,8 @@ def test_a_call_that_is_one_launch_borrows_nothing(packed, arena, slab_of,
 def test_two_threads_never_hold_one_buffer(packed, arena, slab_of, wide):
     """More borrowers than cores on a short switch interval: whoever
     holds a buffer, by ``take`` or by ``lease``, finds in it what it
-    wrote; and two threads of codec calls get their own exact bytes."""
+    wrote; and two threads of codec calls, each call's gather on a
+    worker of that call's own, get their own exact bytes."""
     from ceph_tpu.ops.host_arena import HostArena
 
     shared = HostArena(1 << 16)
@@ -556,18 +616,27 @@ def test_two_threads_never_hold_one_buffer(packed, arena, slab_of, wide):
             if shared.at_rest() > shared.cap:
                 wrong.append("cap")
 
+    erased = [2, 9]
+    index = decode_index_for(K, set(erased))
+
     def call(me: int) -> None:
-        data = np.ascontiguousarray(wide[:, :K]) ^ me
+        stripes = wide ^ me
+        data = np.ascontiguousarray(stripes[:, :K])
         want = np.stack([gf_matmul(codec.encode_matrix[K:], d)
                          for d in data])
-        for _ in range(4):
-            got = codec.encode_batch(data, out_np=True)
-            if not np.array_equal(got, want):
-                wrong.append(("call", me))
+        lost = np.stack([gf_matmul(codec.decode_matrix_for(erased), d[index])
+                         for d in stripes])
+        for n in range(4):
+            got = (codec.decode_stripes(erased, stripes, out_np=True) if n % 2
+                   else codec.encode_batch(data, out_np=True))
+            if not np.array_equal(got, lost if n % 2 else want):
+                wrong.append(("call", me, n))
 
     slab_of(4)
     codec = registry().factory("tpu", {"k": str(K), "m": str(M)})
     codec.encode_batch(wide[:, :K], out_np=True)    # compiled and gated
+    codec.decode_stripes(erased, wide, out_np=True)
+    before = set(threading.enumerate())
     interval = sys.getswitchinterval()
     sys.setswitchinterval(1e-5)
     try:
@@ -585,13 +654,19 @@ def test_two_threads_never_hold_one_buffer(packed, arena, slab_of, wide):
         stop.set()
         sys.setswitchinterval(interval)
     assert not any(t.is_alive() for t in threads + calls)
+    assert set(threading.enumerate()) == before     # no worker is left
     assert wrong == []
+    # a result a call, and three staging buffers a decode
     hits, misses = leases(codec)
-    assert hits + misses == 9 and misses >= 1
+    assert hits + misses == 5 + 2 * (2 + 2 * 4) and misses >= 1
+    dump = codec.perf.dump()
+    assert dump["gathers"] == 5 * 3 >= dump["gathers_ahead"]
 
 
 class LaterOut:
-    """A launch's result that is not done until somebody waits for it."""
+    """A launch's result that is not done until somebody waits for it,
+    on whichever thread."""
+    lock = threading.Lock()
 
     def __init__(self, log, slab, value):
         self.log, self.slab, self.value, self.done = log, slab, value, False
@@ -600,9 +675,10 @@ class LaterOut:
         return self.done
 
     def block_until_ready(self):
-        if not self.done:
-            self.done = True
-            self.log.append(("done", self.slab))
+        with self.lock:
+            if not self.done:
+                self.done = True
+                self.log.append(("done", self.slab))
         return self
 
     def copy_to_host_async(self):
@@ -617,35 +693,60 @@ class SlabFailed(RuntimeError):
     pass
 
 
-def later_launches(packed, monkeypatch, matrix, log, fail_at=None):
-    """Gathers and launches written down in ``log``, the launches as
-    ``LaterOut``s (launch ``fail_at`` raises instead): (staging buffers
-    by id, the outs)."""
+def later_launches(packed, monkeypatch, matrix, log, slabs, fail_at=None,
+                   fill_fails_at=None):
+    """Gathers (``_gather_rows`` and ``LanePieces.fill`` alike) and
+    launches of a call of ``slabs`` slabs written down in ``log`` (a
+    fill with the thread it ran on), the launches as ``LaterOut``s
+    (launch ``fail_at``, or the gather of slab ``fill_fails_at``, raises
+    instead): (staging buffers by id, the outs).
+
+    A launch is held until the worker has come to the next slab's fill,
+    so that what the two threads write down comes in one order every
+    time: launch n is handed fill n + 1 first, and the worker has waited
+    for that buffer's reader before the caller lands it."""
     buffers: dict[int, np.ndarray] = {}
     outs: list[LaterOut] = []
     read_by: dict[int, list[LaterOut]] = {}
-    real = packed._gather_rows
+    come = collections.defaultdict(threading.Event)     # slab -> its fill
+    real_rows, real_fill = packed._gather_rows, packed.LanePieces.fill
 
-    def gather(data, rows, lo, hi, into):
+    def begin(into):
+        slab = sum(ev[0] == "fill" for ev in log)
         buffers[id(into)] = into
         pending = [o.slab for o in read_by.get(id(into), ()) if not o.done]
-        log.append(("fill", id(into), pending))
-        return real(data, rows, lo, hi, into)
+        log.append(("fill", id(into), pending,
+                    threading.current_thread().name))
+        come[slab].set()
+        if slab == fill_fails_at:
+            raise SlabFailed(slab)
+
+    def gather(data, rows, lo, hi, into):
+        begin(into)
+        return real_rows(data, rows, lo, hi, into)
+
+    def fill(self, lo, hi, into):
+        begin(into)
+        return real_fill(self, lo, hi, into)
 
     def launch(matrix_, xd, perf=None):
-        if len(outs) == fail_at:
-            raise SlabFailed(fail_at)
+        slab = len(outs)
+        if slab == fail_at:
+            raise SlabFailed(slab)
+        if slab + 1 < slabs:
+            assert come[slab + 1].wait(timeout=30), slab
         host = np.asarray(xd)
         (buf,) = [key for key, arr in buffers.items()
                   if np.array_equal(arr[:len(host)], host)]
-        out = LaterOut(log, len(outs), np.stack(
+        out = LaterOut(log, slab, np.stack(
             [gf_matmul(matrix, stripe) for stripe in host]))
         outs.append(out)
         read_by.setdefault(buf, []).append(out)
-        log.append(("launch", out.slab, buf))
-        return "gN", out
+        log.append(("launch", slab, buf))
+        return packed.batch_engine(matrix, *host.shape), out
 
     monkeypatch.setattr(packed, "_gather_rows", gather)
+    monkeypatch.setattr(packed.LanePieces, "fill", fill)
     monkeypatch.setattr(packed, "_launch_batch", launch)
     return buffers, outs
 
@@ -658,77 +759,119 @@ def test_a_staging_buffer_is_refilled_only_behind_the_launch_that_read_it(
     the transfer completes (TPU): a buffer is written again, or given
     back to the arena, only after the launch that read its upload is
     done.  Launches here never finish by themselves, so every refill
-    has to wait, and is counted."""
+    has to wait, on the worker that fills, and is counted."""
     slab_of(per_slab)
     erased = [3, 8, 10]
     codec = registry().factory("tpu", {"k": str(K), "m": str(M)})
     matrix = codec.decode_matrix_for(erased)
     log = arena.events
-    buffers, outs = later_launches(packed, monkeypatch, matrix, log)
+    slabs = len(SLABS[per_slab])
+    held = min(3, slabs)                # a staging buffer a slab, three at most
+    before = set(threading.enumerate())
+    buffers, outs = later_launches(packed, monkeypatch, matrix, log, slabs)
     got = codec.decode_stripes(erased, wide, out_np=True)
     assert np.array_equal(got, wide[:, erased])
-    slabs = len(SLABS[per_slab])
+    assert set(threading.enumerate()) == before     # the worker is gone
     fills = [ev for ev in log if ev[0] == "fill"]
     fill_at = [i for i, ev in enumerate(log) if ev[0] == "fill"]
     assert len(fills) == len(outs) == slabs
-    assert len(buffers) == 2                        # two, reused
-    assert [ev[1] for ev in fills] == [fills[i % 2][1] for i in range(slabs)]
+    assert len(buffers) == held                     # three, reused
+    assert [ev[1] for ev in fills] == [fills[i % held][1]
+                                       for i in range(slabs)]
     assert all(ev[2] == [] for ev in fills), fills  # nothing unfinished
+    assert {ev[3] for ev in fills} == {GATHERER}    # none on this thread
     # each slab: filled, launched, its copy to the host started, and
     # only later waited for
     for n in range(slabs):
         at = {kind: i for i, (kind, *rest) in enumerate(log)
               if kind not in ("fill", "given") and rest[0] == n}
-        assert at["launch"] < at["copy_started"] < at["done"]
-    # slab n's buffer is refilled for slab n+2, after slab n is done and
-    # while slab n+1 is still in flight
-    for n in range(slabs - 2):
-        assert log.index(("done", n)) < fill_at[n + 2] \
+        assert fill_at[n] < at["launch"] < at["copy_started"] < at["done"]
+    # slab n's buffer is refilled for slab n+3, after slab n is done and
+    # while slab n+1 is still in flight; slab n+1's gather is handed
+    # over before slab n is launched
+    for n in range(slabs - 3):
+        assert log.index(("done", n)) < fill_at[n + 3] \
             < log.index(("done", n + 1))
-    # both go back to the arena at the call's end, behind the last launch
+    for n in range(slabs - 1):
+        assert fill_at[n + 1] < log.index(("launch", n, fills[n][1]))
+    # all go back to the arena at the call's end, behind the last launch
     # (the result is the caller's: it has not come back)
-    assert log[-2:] == [("given", into.ctypes.data)
-                        for into in buffers.values()]
-    assert log.index(("done", slabs - 1)) == len(log) - 3
-    assert arena.at_rest() == 2 * per_slab * K * L
+    assert log[-held:] == [("given", into.ctypes.data)
+                           for into in buffers.values()]
+    assert log.index(("done", slabs - 1)) == len(log) - held - 1
+    assert arena.at_rest() == held * per_slab * K * L
     dump = codec.perf.dump()
     assert dump["slabs"] == slabs and dump["pipelined"] == 1
-    assert dump.get("staging_waits", 0) == slabs - 2
-    assert leases(codec) == (0, 3)
+    assert dump.get("staging_waits", 0) == max(slabs - 3, 0)
+    assert dump["gathers"] == slabs >= dump["gathers_ahead"] >= 0
+    assert leases(codec) == (0, 1 + held)
 
 
 @pytest.mark.parametrize("fail_at", [0, 1, 2], ids=[
     "first_slab", "second_slab", "ragged_last_slab"])
 def test_staging_goes_back_behind_the_launches_in_flight_when_a_slab_raises(
         packed, monkeypatch, arena, slab_of, wide, fail_at):
-    """4 + 4 + 2 stripes and a launch that raises: the launches before
-    it still read their uploads, so the call waits for them, and only
-    then gives both staging buffers back; the error reaches the
-    caller."""
+    """4 + 4 + 2 stripes and a launch that raises: the worker may be
+    filling the next slab and the launches before still read their
+    uploads, so the call waits for both, and only then gives the three
+    staging buffers back; the error reaches the caller."""
     slab_of(4)
     erased = [3, 8, 10]
     codec = registry().factory("tpu", {"k": str(K), "m": str(M)})
     matrix = codec.decode_matrix_for(erased)
     log = arena.events
-    buffers, outs = later_launches(packed, monkeypatch, matrix, log,
+    before = set(threading.enumerate())
+    buffers, outs = later_launches(packed, monkeypatch, matrix, log, 3,
                                    fail_at=fail_at)
     with pytest.raises(SlabFailed):
         codec.decode_stripes(erased, wide, out_np=True)
+    assert set(threading.enumerate()) == before
     assert len(outs) == fail_at and all(out.done for out in outs)
     assert [ev for ev in log if ev[0] == "done"] == [
         ("done", n) for n in range(fail_at)]
-    # the two staging buffers first, behind every launch's end ...
+    came_back(arena, codec, buffers, result=WIDE * len(erased) * L)
+
+
+def came_back(arena, codec, buffers, result: int) -> None:
+    """After a call of three staging buffers that raised: the three
+    first, behind every fill's and every launch's end, then the result
+    of ``result`` bytes that nobody got, once the error lets go of the
+    call's frame; and nothing was served."""
+    log = arena.events
     first = next(i for i, ev in enumerate(log) if ev[0] == "given")
-    assert all(ev[0] != "done" for ev in log[first:])
+    assert all(ev[0] == "given" for ev in log[first:])
     assert {into.ctypes.data for into in buffers.values()} <= {
-        ev[1] for ev in log[first:first + 2]}
-    # ... and the result nobody got, once the error lets go of the
-    # call's frame
+        ev[1] for ev in log[first:first + 3]}
     gc.collect()
-    assert [ev[0] for ev in log].count("given") == 3
-    assert arena.at_rest() == 2 * 4 * K * L + WIDE * len(erased) * L
-    assert "launches" not in codec.perf.dump()      # it served nothing
-    assert leases(codec) == (0, 3)
+    assert len(log) - first == 4
+    (staging,) = {into.nbytes for into in buffers.values()}
+    assert arena.at_rest() == 3 * staging + result
+    dump = codec.perf.dump()
+    assert "launches" not in dump and "gathers" not in dump
+    assert leases(codec) == (0, 4)
+
+
+@pytest.mark.parametrize("fill_fails_at", [0, 1, 2], ids=[
+    "first_slab", "second_slab", "ragged_last_slab"])
+def test_a_gather_that_raises_on_the_worker_comes_out_of_the_call(
+        packed, monkeypatch, arena, slab_of, wide, fill_fails_at):
+    """The worker's exception is the call's: it comes out where the
+    caller's thread asks for that slab, behind the launches made before
+    it, and all three staging buffers go back."""
+    slab_of(4)
+    erased = [3, 8, 10]
+    codec = registry().factory("tpu", {"k": str(K), "m": str(M)})
+    matrix = codec.decode_matrix_for(erased)
+    before = set(threading.enumerate())
+    buffers, outs = later_launches(packed, monkeypatch, matrix, arena.events,
+                                   3, fill_fails_at=fill_fails_at)
+    with pytest.raises(SlabFailed) as caught:
+        codec.decode_stripes(erased, wide, out_np=True)
+    assert caught.value.args == (fill_fails_at,)
+    del caught                              # and the frames it holds
+    assert set(threading.enumerate()) == before
+    assert len(outs) == fill_fails_at and all(out.done for out in outs)
+    came_back(arena, codec, buffers, result=WIDE * len(erased) * L)
 
 
 def test_a_call_of_many_slabs_drains_and_closes_with_one_copy_out(
@@ -741,14 +884,18 @@ def test_a_call_of_many_slabs_drains_and_closes_with_one_copy_out(
     assert flat == (per * 3 + ["registry.drain"] + per + ["registry.drain"]
                     + per + ["registry.drain"] * 2
                     + ["registry.device_wait", "registry.copy_out"])
+    assert sections.elsewhere == {}                 # no gather, no worker
     sections.clear()
     codec.decode_stripes([2, 9], wide, out_np=True)
+    # this thread's sections are what they were when it gathered itself
     flat = [s for s in sections if s != "registry.matrix"]
     per = ["registry.marshal"] + per
     assert flat == (per * 3 + ["registry.drain"] + per + ["registry.drain"]
                     + per + ["registry.drain"] * 2
                     + ["registry.device_wait", "registry.copy_out"])
     assert sections[0] == "registry.matrix"         # the table miss
+    # and the gathers are the worker's, a section each
+    assert sections.elsewhere == {GATHERER: ["registry.gather"] * 5}
     dump = codec.perf.dump()
     assert dump["launches"] == dump["pipelined"] == dump["engine_gN"] == 2
     assert dump["slabs"] == 10 and dump["stripes"] == 2 * WIDE

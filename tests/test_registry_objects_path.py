@@ -15,6 +15,7 @@ from __future__ import annotations
 
 import gc
 import itertools
+import threading
 
 import numpy as np
 import pytest
@@ -22,8 +23,9 @@ import pytest
 from benchmark.reference import codec as ref
 from benchmark.reference import codec_objects as ref_objects
 from ceph_tpu.ec import registry
-from test_registry_codec_path import (LaterOut, SlabFailed, Watched, arena,
-                                      leases, sections)
+from test_registry_codec_path import (GATHERER, SlabFailed, Watched, arena,
+                                      came_back, later_launches, leases,
+                                      sections)
 
 __all__ = ["arena", "sections"]         # fixtures of the uniform call's file
 
@@ -285,14 +287,14 @@ def test_objects_that_meet_a_slabs_edge(kernels, monkeypatch, slab_lanes,
 def test_the_tail_of_every_last_data_chunk_is_zero_in_what_is_encoded(
         kernels, monkeypatch, slab_lanes):
     """Staging is reused and never cleared: the zeros past an object's
-    end are written with every fill, over whatever the slab two back
+    end are written with every fill, over whatever the slab three back
     left there, and no byte of a neighbour is inside an object's
     lanes."""
     slab_lanes(LANES)
     uploaded = uploads_of(kernels, monkeypatch)
     codec = registry().factory("tpu", PROFILE)
     loud = [np.full(size, 0xFF, np.uint8) for size in [4096] * 12]
-    codec.encode_objects(loud)                  # both buffers all ones
+    codec.encode_objects(loud)                  # all three buffers all ones
     del uploaded[:]
     sizes = [1, 4095, 33, 4096, 700, 2, 4097, 31, 5000, 64]
     objects = [np.full(size, 0xFF, np.uint8) for size in sizes]
@@ -304,6 +306,39 @@ def test_the_tail_of_every_last_data_chunk_is_zero_in_what_is_encoded(
         mine = sent[:, at:at + length].reshape(-1)
         assert (mine[:size] == 0xFF).all() and not mine[size:].any(), size
         at += length
+
+
+@pytest.mark.parametrize("width,slabs", [(5376, 1), (2688, 2), (1792, 3),
+                                         (768, 7)],
+                         ids=["1slab", "2slabs", "3slabs", "7slabs"])
+def test_the_references_bytes_at_1_2_3_and_7_slabs(slab_lanes, small, width,
+                                                   slabs):
+    """5280 lanes through one slab (filled on the caller's thread), two
+    (a buffer each), three (all three buffers, none refilled) and seven
+    (every buffer refilled behind its reader): parity and recovered
+    chunks are ``benchmark/reference/``'s, and what lies at an erased
+    position is not read."""
+    slab_lanes(width)
+    objects, chunk_maps = small
+    lanes = sum(stripe.shape[1] for stripe in chunk_maps)
+    assert -(-lanes // width) == slabs
+    codec = registry().factory("tpu", PROFILE)
+    before = set(threading.enumerate())
+    for got, stripe in zip(codec.encode_objects(objects), chunk_maps):
+        assert np.array_equal(got, stripe[K:])
+    for erased in ([4], [0, 12], [2, 9, 13]):
+        blanked = [stripe.copy() for stripe in chunk_maps]
+        for stripe in blanked:
+            stripe[erased] = 0xA5
+        lost = codec.decode_objects(erased, blanked)
+        for got, stripe in zip(lost, chunk_maps):
+            assert np.array_equal(got, stripe[erased])
+        assert np.array_equal(lost[7], ref.recovered(REF, blanked[7], erased))
+    assert set(threading.enumerate()) == before     # no worker outlives it
+    dump = codec.perf.dump()
+    assert dump["slabs"] == 4 * slabs
+    assert dump.get("gathers", 0) == (4 * slabs if slabs > 1 else 0)
+    assert dump.get("gathers_ahead", 0) <= dump.get("gathers", 0)
 
 
 def test_an_empty_call_and_a_call_of_one_object(kernels, arena, slab_lanes,
@@ -325,8 +360,11 @@ def test_an_empty_call_and_a_call_of_one_object(kernels, arena, slab_lanes,
     dump = codec.perf.dump()
     assert dump["launches"] == dump["slabs"] == dump["objects"] == 2
     assert "pipelined" not in dump
-    # a call of one slab borrows too: its result and two staging buffers
-    assert leases(codec) == (2, 4)
+    # a call of one slab borrows too: its result and one staging buffer
+    # (the decode's smaller result fits the buffer the encode gave back);
+    # it has no slab to fill ahead, and no worker
+    assert leases(codec) == (1, 3)
+    assert "gathers" not in dump and "gathers_ahead" not in dump
 
 
 def test_one_program_a_count_of_output_rows_whatever_the_mix(
@@ -371,23 +409,28 @@ def test_sections_and_counters_of_a_call_over_objects(kernels, sections,
                     + ["registry.drain"] * 1
                     + ["registry.device_wait", "registry.copy_out",
                        "registry.prepare"])
+    # the fills are the worker's, a section each on its own thread
+    assert sections.elsewhere == {GATHERER: ["registry.gather"] * slabs}
     dump = codec.perf.dump()
     dump.pop("staging_waits", None)     # as the launches happen to finish
+    assert 0 <= dump.pop("gathers_ahead") <= slabs  # as the fills do
     assert dump == {
         "launches": 1, "engine_v1": 1, "objects": len(objects),
         "lanes": lanes, "lanes_launched": slabs * LANES,
         "lanes_padded": slabs * LANES - lanes, "bytes_in": K * lanes,
         "bytes_out": M * lanes, "slabs": slabs, "pipelined": 1,
-        "arena_misses": 3, "parity_gates": 1}
+        "gathers": slabs, "arena_misses": 4, "parity_gates": 1}
     del parity
     sections.clear()
     codec.decode_objects([3, 7], chunk_maps)
     assert sections[0] == "registry.matrix"          # the table miss
     assert sections[1] == "registry.prepare" == sections[-1]
+    assert [s for s in sections if s != "registry.matrix"] == flat
     two = codec.perf.dump()
     assert two["table_misses"] == 1 and two["objects"] == 2 * len(objects)
     assert two["bytes_out"] == (M + 2) * lanes
-    assert leases(codec) == (3, 3)                   # all three kept ones
+    assert two["gathers"] == 2 * slabs >= two["gathers_ahead"]
+    assert leases(codec) == (4, 4)                   # all four kept ones
 
 
 # -- leases and staging, as the uniform call keeps them ------------------------
@@ -403,7 +446,7 @@ def test_the_results_are_views_of_one_lease_and_keep_it(kernels, arena,
     for got, stripe in zip(parity, chunk_maps):
         assert got.strides == (lanes, 1) and got.flags["WRITEABLE"]
         assert base <= got.ctypes.data < base + lanes
-    staging = 2 * K * LANES
+    staging = 3 * K * LANES
     assert arena.at_rest() == staging               # the result is out
     kept = parity[5][1:, ::2]                       # a view of a view
     want = chunk_maps[5][K + 1:, ::2].copy()
@@ -419,41 +462,7 @@ def test_the_results_are_views_of_one_lease_and_keep_it(kernels, arena,
     assert arena.events[-1] == ("given", base)
     assert arena.at_rest() == staging + 2 * M * lanes
     hits, misses = leases(codec)
-    assert (hits, misses) == (5, 4)
-
-
-def later_fills(kernels, monkeypatch, matrix, log, fail_at=None):
-    """Fills and launches written down in ``log``, the launches as
-    ``LaterOut``s that are done only once somebody waits (launch
-    ``fail_at`` raises instead): (staging buffers by id, the outs)."""
-    from ceph_tpu.gf import gf_matmul
-
-    buffers: dict[int, np.ndarray] = {}
-    outs: list[LaterOut] = []
-    read_by: dict[int, list[LaterOut]] = {}
-    real = kernels.LanePieces.fill
-
-    def fill(self, lo, hi, into):
-        buffers[id(into)] = into
-        pending = [o.slab for o in read_by.get(id(into), ()) if not o.done]
-        log.append(("fill", id(into), pending))
-        return real(self, lo, hi, into)
-
-    def launch(matrix_, xd, perf=None):
-        if len(outs) == fail_at:
-            raise SlabFailed(fail_at)
-        host = np.asarray(xd)
-        (buf,) = [key for key, arr in buffers.items()
-                  if np.array_equal(arr, host)]
-        out = LaterOut(log, len(outs), gf_matmul(matrix, host[0])[None])
-        outs.append(out)
-        read_by.setdefault(buf, []).append(out)
-        log.append(("launch", out.slab, buf))
-        return "v1", out
-
-    monkeypatch.setattr(kernels.LanePieces, "fill", fill)
-    monkeypatch.setattr(kernels, "_launch_batch", launch)
-    return buffers, outs
+    assert (hits, misses) == (7, 5)
 
 
 def test_staging_is_refilled_only_behind_the_launch_that_read_it(
@@ -462,24 +471,34 @@ def test_staging_is_refilled_only_behind_the_launch_that_read_it(
     objects, chunk_maps = small
     codec = registry().factory("tpu", PROFILE)
     log = arena.events
-    buffers, outs = later_fills(kernels, monkeypatch,
-                                codec.encode_matrix[K:], log)
+    slabs = 6
+    before = set(threading.enumerate())
+    buffers, outs = later_launches(kernels, monkeypatch,
+                                codec.encode_matrix[K:], log, slabs)
     parity = codec.encode_objects(objects)
     for got, stripe in zip(parity, chunk_maps):
         assert np.array_equal(got, stripe[K:])
-    slabs = 6
+    assert set(threading.enumerate()) == before     # the worker is gone
     fills = [ev for ev in log if ev[0] == "fill"]
     fill_at = [i for i, ev in enumerate(log) if ev[0] == "fill"]
-    assert len(fills) == len(outs) == slabs and len(buffers) == 2
-    assert [ev[1] for ev in fills] == [fills[i % 2][1] for i in range(slabs)]
+    assert len(fills) == len(outs) == slabs and len(buffers) == 3
+    assert [ev[1] for ev in fills] == [fills[i % 3][1] for i in range(slabs)]
     assert all(ev[2] == [] for ev in fills), fills  # nothing unfinished
-    for n in range(slabs - 2):
-        assert log.index(("done", n)) < fill_at[n + 2] \
+    assert {ev[3] for ev in fills} == {GATHERER}    # none on this thread
+    # slab n's buffer is refilled for slab n+3, behind slab n's launch and
+    # while slab n+1 is in flight; a fill is handed over a slab ahead
+    for n in range(slabs - 3):
+        assert log.index(("done", n)) < fill_at[n + 3] \
             < log.index(("done", n + 1))
-    assert log[-2:] == [("given", into.ctypes.data)
+    for n in range(slabs - 1):
+        assert fill_at[n] < fill_at[n + 1] \
+            < log.index(("launch", n, fills[n][1]))
+    assert log[-3:] == [("given", into.ctypes.data)
                         for into in buffers.values()]
-    assert log.index(("done", slabs - 1)) == len(log) - 3
-    assert codec.perf.dump()["staging_waits"] == slabs - 2
+    assert log.index(("done", slabs - 1)) == len(log) - 4
+    dump = codec.perf.dump()
+    assert dump["staging_waits"] == slabs - 3
+    assert dump["gathers"] == slabs >= dump["gathers_ahead"] >= 0
 
 
 @pytest.mark.parametrize("fail_at", [0, 2, 5], ids=[
@@ -490,22 +509,39 @@ def test_buffers_come_back_when_a_slab_raises(kernels, monkeypatch, arena,
     objects, chunk_maps = small
     lanes = sum(stripe.shape[1] for stripe in chunk_maps)
     codec = registry().factory("tpu", PROFILE)
-    log = arena.events
-    buffers, outs = later_fills(kernels, monkeypatch,
-                                codec.encode_matrix[K:], log,
+    before = set(threading.enumerate())
+    buffers, outs = later_launches(kernels, monkeypatch,
+                                codec.encode_matrix[K:], arena.events, 6,
                                 fail_at=fail_at)
     with pytest.raises(SlabFailed):
         codec.encode_objects(objects)
+    assert set(threading.enumerate()) == before
     assert len(outs) == fail_at and all(out.done for out in outs)
-    first = next(i for i, ev in enumerate(log) if ev[0] == "given")
-    assert all(ev[0] != "done" for ev in log[first:])
-    assert {into.ctypes.data for into in buffers.values()} <= {
-        ev[1] for ev in log[first:first + 2]}
-    gc.collect()                # the result nobody got
-    assert [ev[0] for ev in log].count("given") == 3
-    assert arena.at_rest() == 2 * K * LANES + M * lanes
-    assert "launches" not in codec.perf.dump()
-    assert leases(codec) == (0, 3)
+    came_back(arena, codec, buffers, result=M * lanes)
+
+
+@pytest.mark.parametrize("fill_fails_at", [0, 1, 4, 5], ids=[
+    "first_slab", "second_slab", "a_refill", "padded_last_slab"])
+def test_a_fill_that_raises_on_the_worker_comes_out_of_the_call(
+        kernels, monkeypatch, arena, slab_lanes, small, fill_fails_at):
+    """The worker's exception is the call's, raised where the caller's
+    thread asks for that slab: the launches made before it are waited
+    for, all three staging buffers go back, and no thread is left."""
+    slab_lanes(LANES)
+    objects, chunk_maps = small
+    lanes = sum(stripe.shape[1] for stripe in chunk_maps)
+    codec = registry().factory("tpu", PROFILE)
+    before = set(threading.enumerate())
+    buffers, outs = later_launches(kernels, monkeypatch,
+                                codec.encode_matrix[K:], arena.events, 6,
+                                fill_fails_at=fill_fails_at)
+    with pytest.raises(SlabFailed) as caught:
+        codec.encode_objects(objects)
+    assert caught.value.args == (fill_fails_at,)
+    del caught                              # and the frames it holds
+    assert set(threading.enumerate()) == before
+    assert len(outs) == fill_fails_at and all(out.done for out in outs)
+    came_back(arena, codec, buffers, result=M * lanes)
 
 
 def test_a_parity_miss_raises_out_of_a_call_over_objects(kernels, slab_lanes,

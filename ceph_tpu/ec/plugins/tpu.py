@@ -24,9 +24,11 @@ result stays its caller's own until no array views it),
 ``parity_gates`` (first launches of a matrix held to the host oracle),
 ``table_hits`` / ``table_misses`` (decode matrices taken from, or built
 into, the DecodeTableCache); where its thread was is in the
-``registry.*`` sections (a table miss's ``matrix`` here, the rest in
-``gf_matmul_batch_device``; ``registry.gather`` is the worker's, on its
-own thread).
+``registry.*`` sections (a table miss's ``matrix`` and the per-object
+``prepare`` here, the rest in ``gf_matmul_batch_device``: a landing's
+three waits are ``registry.drain.kernel`` / ``.link`` / ``.land`` inside
+``registry.drain``; ``registry.gather``, with ``registry.gather.wait``
+inside it, is the worker's, on its own thread).
 """
 
 from __future__ import annotations

@@ -699,10 +699,18 @@ def gf_matmul_batch_device(matrix: np.ndarray, data, *, rows=None,
     and dispatch; the matrix's device copy and a first launch's parity
     gate are ``registry.matrix`` inside it), then the result's copy to
     the host is started and the slab two back is landed in its rows of
-    the call's one result array (``registry.drain``).  The call closes
-    with the last slab's ``registry.device_wait`` and
+    the call's one result array (``registry.drain``, its three waits
+    nested in it under names of their own: ``registry.drain.kernel``
+    until the slab's launch is done, whether the device or the upload
+    of its operand was late, opened only where the launch is not done
+    when the landing comes to it, so that a landing blocks once where
+    it need not block twice; ``registry.drain.link`` until the copy-out
+    started at the launch has brought its bytes to the host;
+    ``registry.drain.land`` the host copy into the result).  The call
+    closes with the last slab's ``registry.device_wait`` and
     ``registry.copy_out`` (until the last byte is readable on the
-    host); the result is a C-ordered array of the caller's own.
+    host, and into the result); the result is a C-ordered array of the
+    caller's own.
 
     The gather of a call of several slabs is a stage of its own, on a
     worker thread the call starts and ends (``registry-gather``; one a
@@ -710,11 +718,13 @@ def gf_matmul_batch_device(matrix: np.ndarray, data, *, rows=None,
     caller hands it slab 0 at once and slab i+1 before it uploads slab
     i, over THREE staging buffers (one being filled, one on its way up,
     one a launch still reads), and ``registry.marshal`` is only the wait
-    for the slab about to go up.  The worker's time is ``registry.gather`` on its own
-    thread: the wait for the launch that read the buffer's last upload
-    (slab i-2's, dispatched two iterations before), then
-    ``_gather_rows`` or ``LanePieces.fill``, whose numpy copies release
-    the GIL.  So slab i+1's gather, slab i's upload, slab i-1's kernel
+    for the slab about to go up.  The worker's time is
+    ``registry.gather`` on its own thread: the wait for the launch that
+    read the buffer's last upload (slab i-2's, dispatched two
+    iterations before; ``registry.gather.wait`` nested in it, only
+    where there is something to wait for), then ``_gather_rows`` or
+    ``LanePieces.fill``, whose numpy copies release the GIL.  So slab
+    i+1's gather, slab i's upload, slab i-1's kernel
     and slab i-2's copy-out are in flight together, on two threads, the
     link and the device, and three slabs at most live on the device.  A
     call without a gather (no ``rows``, no pieces: the caller's array is
@@ -808,18 +818,33 @@ def gf_matmul_batch_device(matrix: np.ndarray, data, *, rows=None,
         with section("registry.gather"):
             if reader is not None and not reader.is_ready():
                 waits += 1
-                # lint: disable=device-path-host-sync -- a staging buffer is refilled only after the launch that read it
-                reader.block_until_ready()
+                with section("registry.gather.wait"):
+                    # lint: disable=device-path-host-sync -- a staging buffer is refilled only after the launch that read it
+                    reader.block_until_ready()
             return fill(i)
 
-    def land() -> None:
-        lo, hi, out = flying.popleft()
-        # lint: disable=device-path-host-sync -- the caller asked for host bytes (out_np); the copy was started at the launch
-        host = np.asarray(out)
+    def put(lo: int, hi: int, host: np.ndarray) -> None:
         if ragged:
             result[:, lo:hi] = host[0, :, :hi - lo]
         else:
             result[lo:hi] = host
+
+    def drain() -> None:
+        """The oldest slab in flight into its place in the result, each
+        of its waits under a name of its own.  A launch that is done is
+        not waited for: the landing then blocks once, as it would
+        without the names."""
+        with section("registry.drain"):
+            lo, hi, out = flying.popleft()
+            if not out.is_ready():
+                with section("registry.drain.kernel"):
+                    # lint: disable=device-path-host-sync -- the wait for the launch, timed apart from the copy-out that follows it
+                    out.block_until_ready()
+            with section("registry.drain.link"):
+                # lint: disable=device-path-host-sync -- the caller asked for host bytes (out_np); the copy was started at the launch
+                host = np.asarray(out)
+            with section("registry.drain.land"):
+                put(lo, hi, host)
 
     try:
         if worker is not None:
@@ -858,8 +883,7 @@ def gf_matmul_batch_device(matrix: np.ndarray, data, *, rows=None,
                     out.copy_to_host_async()
             flying.append((lo, hi, out))
             if len(flying) > 2:
-                with section("registry.drain"):
-                    land()
+                drain()
         if perf is not None:
             perf.inc("launches")
             perf.inc(f"engine_{served}")
@@ -886,8 +910,7 @@ def gf_matmul_batch_device(matrix: np.ndarray, data, *, rows=None,
         if not out_np:
             return out
         while len(flying) > 1:
-            with section("registry.drain"):
-                land()
+            drain()
         with section("registry.device_wait"):
             # lint: disable=device-path-host-sync -- the caller asked for host bytes (out_np): the wait is timed apart from the copy
             out.block_until_ready()
@@ -895,7 +918,9 @@ def gf_matmul_batch_device(matrix: np.ndarray, data, *, rows=None,
             if result is None:
                 # lint: disable=device-path-host-sync -- the single post-launch materialization (caller opts in via out_np)
                 return np.asarray(out)
-            land()
+            lo, hi, out = flying.popleft()
+            # lint: disable=device-path-host-sync -- the caller asked for host bytes (out_np); the copy was started at the launch
+            put(lo, hi, np.asarray(out))
             return result
     finally:
         if worker is not None:

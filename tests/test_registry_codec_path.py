@@ -251,12 +251,16 @@ def test_every_count_of_output_rows_reaches_the_packed_engine(
 
 class Opened(list):
     """The sections the test's own thread opened, in order of opening;
-    ``elsewhere``: those of every other thread, by the thread's name."""
+    ``elsewhere``: those of every other thread, by the thread's name;
+    ``under``: for every name, the sections it was opened directly
+    inside (``""``: none); ``open_now``: those entered and not left."""
 
     def __init__(self) -> None:
         super().__init__()
         self.mine = threading.get_ident()
         self.elsewhere: dict[str, list[str]] = {}
+        self.under: dict[str, set[str]] = {}
+        self.open_now: list[str] = []
 
 
 @pytest.fixture
@@ -266,19 +270,77 @@ def sections(monkeypatch):
     import ceph_tpu.ops.gf2kernels as g
 
     opened = Opened()
+    stacks: dict[int, list[str]] = {}
 
     @contextlib.contextmanager
     def record(name):
-        if threading.get_ident() == opened.mine:
+        me = threading.get_ident()
+        stack = stacks.setdefault(me, [])
+        opened.under.setdefault(name, set()).add(stack[-1] if stack else "")
+        if me == opened.mine:
             opened.append(name)
         else:
             opened.elsewhere.setdefault(
                 threading.current_thread().name, []).append(name)
-        yield
+        stack.append(name)
+        opened.open_now.append(name)
+        try:
+            yield
+        finally:
+            stack.pop()
+            opened.open_now.remove(name)
 
     monkeypatch.setattr(g, "section", record)
     monkeypatch.setattr(plugin, "section", record)
     return opened
+
+
+UPLOAD = ["registry.upload", "registry.launch"]
+GATHERED = ["registry.marshal"] + UPLOAD
+# a landing inside the loop or at the close; the wait for a launch that
+# is not done yet, which comes first in it, is opened only where it waits
+KERNEL = "registry.drain.kernel"
+DRAIN = ["registry.drain", "registry.drain.link", "registry.drain.land"]
+CLOSE = ["registry.device_wait", "registry.copy_out"]
+# every section that is opened inside another, and the one it is opened in
+NESTED = {KERNEL: "registry.drain",
+          "registry.drain.link": "registry.drain",
+          "registry.drain.land": "registry.drain",
+          "registry.gather.wait": "registry.gather"}
+
+
+def caller_sections(slabs: int, per: list[str]) -> list[str]:
+    """What the caller's thread opens, in order, in a call of ``slabs``
+    slabs, ``registry.matrix``, ``registry.prepare`` and the kernel
+    waits apart: ``per`` a slab, the slab two back landed behind every
+    launch from the third on, the rest but one landed at the close, and
+    the last under ``registry.copy_out``."""
+    out: list[str] = []
+    for i in range(slabs):
+        out += per + (DRAIN if i >= 2 else [])
+    return out + DRAIN * (min(slabs, 2) - 1) + CLOSE
+
+
+def flat_of(opened: list[str]) -> list[str]:
+    """The caller's sections without those a call may or may not open;
+    a kernel wait is the first thing in its landing wherever it is."""
+    for i, name in enumerate(opened):
+        if name == KERNEL:
+            assert opened[i - 1] == "registry.drain", opened[i - 1]
+            assert opened[i + 1] == "registry.drain.link", opened[i + 1]
+    return [s for s in opened
+            if s not in ("registry.matrix", "registry.prepare", KERNEL)]
+
+
+def nesting_holds(opened: Opened) -> None:
+    """Each section was opened where it belongs: the nested ones inside
+    theirs and nowhere else, and all are closed."""
+    assert opened.open_now == []
+    for name, inside in opened.under.items():
+        if name in NESTED:
+            assert inside == {NESTED[name]}, (name, inside)
+        elif name != "registry.matrix":
+            assert inside == {""}, (name, inside)
 
 
 def test_one_encode_and_one_decode_move_sections_and_counters(
@@ -334,6 +396,7 @@ def test_one_encode_and_one_decode_move_sections_and_counters(
     assert three["gathers"] == 2 * 3
     assert 0 <= three["gathers_ahead"] <= three["gathers"]
     assert sections.elsewhere == {GATHERER: ["registry.gather"] * 6}
+    nesting_holds(sections)
 
 
 def test_gates_and_table_misses_count_once_per_new_signature(packed,
@@ -668,8 +731,9 @@ class LaterOut:
     on whichever thread."""
     lock = threading.Lock()
 
-    def __init__(self, log, slab, value):
+    def __init__(self, log, slab, value, unreadable=False):
         self.log, self.slab, self.value, self.done = log, slab, value, False
+        self.unreadable = unreadable        # its copy to the host raises
 
     def is_ready(self):
         return self.done
@@ -686,6 +750,8 @@ class LaterOut:
 
     def __array__(self, dtype=None, copy=None):
         self.block_until_ready()            # host bytes follow the kernel
+        if self.unreadable:
+            raise SlabFailed(self.slab)
         return self.value
 
 
@@ -694,11 +760,12 @@ class SlabFailed(RuntimeError):
 
 
 def later_launches(packed, monkeypatch, matrix, log, slabs, fail_at=None,
-                   fill_fails_at=None):
+                   fill_fails_at=None, land_fails_at=None):
     """Gathers (``_gather_rows`` and ``LanePieces.fill`` alike) and
     launches of a call of ``slabs`` slabs written down in ``log`` (a
     fill with the thread it ran on), the launches as ``LaterOut``s
-    (launch ``fail_at``, or the gather of slab ``fill_fails_at``, raises
+    (launch ``fail_at``, the gather of slab ``fill_fails_at``, or the
+    copy to the host of slab ``land_fails_at``'s result, raises
     instead): (staging buffers by id, the outs).
 
     A launch is held until the worker has come to the next slab's fill,
@@ -739,7 +806,8 @@ def later_launches(packed, monkeypatch, matrix, log, slabs, fail_at=None,
         (buf,) = [key for key, arr in buffers.items()
                   if np.array_equal(arr[:len(host)], host)]
         out = LaterOut(log, slab, np.stack(
-            [gf_matmul(matrix, stripe) for stripe in host]))
+            [gf_matmul(matrix, stripe) for stripe in host]),
+            unreadable=slab == land_fails_at)
         outs.append(out)
         read_by.setdefault(buf, []).append(out)
         log.append(("launch", slab, buf))
@@ -754,7 +822,7 @@ def later_launches(packed, monkeypatch, matrix, log, slabs, fail_at=None,
 @pytest.mark.parametrize("per_slab", [6, 4, 2], ids=[
     f"{len(SLABS[n])}slabs" for n in (6, 4, 2)])
 def test_a_staging_buffer_is_refilled_only_behind_the_launch_that_read_it(
-        packed, monkeypatch, arena, slab_of, wide, per_slab):
+        packed, monkeypatch, arena, sections, slab_of, wide, per_slab):
     """``device_put`` may alias the numpy memory (CPU) or read it until
     the transfer completes (TPU): a buffer is written again, or given
     back to the arena, only after the launch that read its upload is
@@ -805,6 +873,17 @@ def test_a_staging_buffer_is_refilled_only_behind_the_launch_that_read_it(
     assert dump.get("staging_waits", 0) == max(slabs - 3, 0)
     assert dump["gathers"] == slabs >= dump["gathers_ahead"] >= 0
     assert leases(codec) == (0, 1 + held)
+    # the worker's sections: a fill a slab, and inside every refill's
+    # the wait for the launch that read the buffer (none is done here)
+    assert sections.elsewhere == {GATHERER: (
+        ["registry.gather"] * held
+        + ["registry.gather", "registry.gather.wait"] * (slabs - held))}
+    assert flat_of(sections) == caller_sections(slabs, GATHERED)
+    # a landing waits for its launch under the wait's own name, unless
+    # the worker had to have it done before it refilled the buffer
+    by_caller = [n for n in range(slabs - 1) if n + 3 >= slabs]
+    assert sections.count(KERNEL) == len(by_caller)
+    nesting_holds(sections)
 
 
 @pytest.mark.parametrize("fail_at", [0, 1, 2], ids=[
@@ -832,11 +911,13 @@ def test_staging_goes_back_behind_the_launches_in_flight_when_a_slab_raises(
     came_back(arena, codec, buffers, result=WIDE * len(erased) * L)
 
 
-def came_back(arena, codec, buffers, result: int) -> None:
+def came_back(arena, codec, buffers, result: int,
+              counted: bool = False) -> None:
     """After a call of three staging buffers that raised: the three
     first, behind every fill's and every launch's end, then the result
     of ``result`` bytes that nobody got, once the error lets go of the
-    call's frame; and nothing was served."""
+    call's frame; and nothing was served (``counted``: it raised at the
+    close, behind its last launch, where a call is counted)."""
     log = arena.events
     first = next(i for i, ev in enumerate(log) if ev[0] == "given")
     assert all(ev[0] == "given" for ev in log[first:])
@@ -847,14 +928,14 @@ def came_back(arena, codec, buffers, result: int) -> None:
     (staging,) = {into.nbytes for into in buffers.values()}
     assert arena.at_rest() == 3 * staging + result
     dump = codec.perf.dump()
-    assert "launches" not in dump and "gathers" not in dump
+    assert ("launches" in dump) == ("gathers" in dump) == counted
     assert leases(codec) == (0, 4)
 
 
 @pytest.mark.parametrize("fill_fails_at", [0, 1, 2], ids=[
     "first_slab", "second_slab", "ragged_last_slab"])
 def test_a_gather_that_raises_on_the_worker_comes_out_of_the_call(
-        packed, monkeypatch, arena, slab_of, wide, fill_fails_at):
+        packed, monkeypatch, arena, sections, slab_of, wide, fill_fails_at):
     """The worker's exception is the call's: it comes out where the
     caller's thread asks for that slab, behind the launches made before
     it, and all three staging buffers go back."""
@@ -872,6 +953,134 @@ def test_a_gather_that_raises_on_the_worker_comes_out_of_the_call(
     assert set(threading.enumerate()) == before
     assert len(outs) == fill_fails_at and all(out.done for out in outs)
     came_back(arena, codec, buffers, result=WIDE * len(erased) * L)
+    # the fill that raised left its section, and the caller's its marshal
+    assert sections.open_now == []
+    assert sections.elsewhere[GATHERER] == \
+        ["registry.gather"] * (fill_fails_at + 1)
+
+
+@pytest.mark.parametrize("land_fails_at", [0, 1, 2], ids=[
+    "in_the_loop", "at_the_close", "under_copy_out"])
+def test_a_landing_that_raises_leaves_no_section_open_and_no_staging_out(
+        packed, monkeypatch, arena, sections, slab_of, wide, land_fails_at):
+    """4 + 4 + 2 stripes and a result whose copy to the host raises:
+    slab 0's behind the third launch, slab 1's at the close, slab 2's
+    under ``registry.copy_out``.  The wait for a launch that was not
+    done came first, under its own name; the sections around the copy
+    are left on the way out, the launches still in flight are waited
+    for, and the three staging buffers go back."""
+    slab_of(4)
+    erased = [3, 8, 10]
+    codec = registry().factory("tpu", {"k": str(K), "m": str(M)})
+    matrix = codec.decode_matrix_for(erased)
+    before = set(threading.enumerate())
+    buffers, outs = later_launches(packed, monkeypatch, matrix, arena.events,
+                                   3, land_fails_at=land_fails_at)
+    with pytest.raises(SlabFailed) as caught:
+        codec.decode_stripes(erased, wide, out_np=True)
+    assert caught.value.args == (land_fails_at,)
+    del caught                              # and the frames it holds
+    assert set(threading.enumerate()) == before
+    assert len(outs) == 3 and all(out.done for out in outs)
+    whole = caller_sections(3, GATHERED)
+    upto = len(whole) - 1 if land_fails_at == 2 else [
+        i for i, name in enumerate(whole)
+        if name == "registry.drain.link"][land_fails_at]
+    assert flat_of(sections) == whole[:upto + 1]
+    # no launch here is done before somebody waits: both landings did
+    assert sections.count(KERNEL) == min(land_fails_at + 1, 2)
+    nesting_holds(sections)
+    came_back(arena, codec, buffers, result=WIDE * len(erased) * L,
+              counted=land_fails_at > 0)
+
+
+@pytest.mark.parametrize("per_slab,slabs", [(14, 1), (8, 2), (6, 3), (2, 7)],
+                         ids=["1slab", "2slabs", "3slabs", "7slabs"])
+def test_each_threads_sections_in_order_at_1_2_3_and_7_slabs(
+        packed, monkeypatch, sections, per_slab, slabs):
+    """14 stripes through one, two, three and seven slabs, a uniform
+    encode and a decode from ``rows``: the caller's sections in order
+    with the waits of every landing nested in its ``registry.drain``
+    (the last slab's is ``registry.copy_out`` as it was), the worker's
+    a fill a slab with the wait for a launch inside the refills that
+    had to."""
+    chunks = np.random.default_rng(52).integers(
+        0, 256, (14, N, L), dtype=np.uint8)
+    monkeypatch.setattr(packed, "SLAB_BYTES", per_slab * K * L)
+    codec = registry().factory("tpu", {"k": str(K), "m": str(M)})
+    codec.encode_batch(chunks[:, :K], out_np=True)
+    assert flat_of(sections) == caller_sections(slabs, UPLOAD)
+    assert sections.elsewhere == {}                 # no gather, no worker
+    assert "gathers" not in codec.perf.dump()
+    nesting_holds(sections)
+    sections.clear()
+    codec.decode_stripes([2, 9], chunks, out_np=True)
+    assert sections[0] == "registry.matrix"         # the table miss
+    assert flat_of(sections) == caller_sections(slabs, GATHERED)
+    nesting_holds(sections)
+    dump = codec.perf.dump()
+    if slabs == 1:                  # gathered here, under registry.marshal
+        assert sections.elsewhere == {}
+        assert "gathers" not in dump
+        return
+    mine = sections.elsewhere.pop(GATHERER)
+    assert sections.elsewhere == {}
+    assert [s for s in mine if s != "registry.gather.wait"] == \
+        ["registry.gather"] * slabs
+    # a wait is opened inside the fill of a refill, where it is counted
+    assert mine.count("registry.gather.wait") == dump.get("staging_waits", 0)
+    assert not any(s == "registry.gather.wait" for s in mine[:3])
+    assert dump["gathers"] == slabs >= dump["gathers_ahead"]
+    codec.decode_stripes([2, 9], chunks, out_np=True)
+    again = codec.perf.dump()
+    assert again["gathers"] == 2 * slabs and again["launches"] == 3
+
+
+class Fixed:
+    """A launch's result that says it is done, or not, whatever it is."""
+
+    def __init__(self, out, ready: bool, waited: list):
+        self.out, self.ready, self.waited = out, ready, waited
+
+    def is_ready(self):
+        return self.ready
+
+    def block_until_ready(self):
+        self.waited.append(threading.current_thread().name)
+        self.out.block_until_ready()
+        return self
+
+    def copy_to_host_async(self):
+        self.out.copy_to_host_async()
+
+    def __array__(self, dtype=None, copy=None):
+        return np.asarray(self.out)
+
+
+@pytest.mark.parametrize("ready", [True, False], ids=["done", "not_done"])
+def test_a_landing_waits_for_its_launch_only_where_it_is_not_done(
+        packed, monkeypatch, sections, slab_of, wide, ready):
+    """Five slabs, four landed under ``registry.drain``: a launch that
+    is done is not waited for, and the landing blocks once, in the copy
+    to the host, as it did before its waits had names; one that is not
+    is waited for first, under ``registry.drain.kernel``."""
+    slab_of(2)
+    waited: list[str] = []
+    real = packed._launch_batch
+
+    def launch(matrix, xd, perf=None):
+        engine, out = real(matrix, xd, perf)
+        return engine, Fixed(out, ready, waited)
+
+    monkeypatch.setattr(packed, "_launch_batch", launch)
+    codec = registry().factory("tpu", {"k": str(K), "m": str(M)})
+    parity = codec.encode_batch(wide[:, :K], out_np=True)
+    assert np.array_equal(parity, wide[:, K:])
+    assert flat_of(sections) == caller_sections(5, UPLOAD)
+    assert sections.count(KERNEL) == (0 if ready else 4)
+    # the last slab's wait is the call's registry.device_wait either way
+    assert len(waited) == 1 + sections.count(KERNEL)
+    nesting_holds(sections)
 
 
 def test_a_call_of_many_slabs_drains_and_closes_with_one_copy_out(
@@ -879,23 +1088,21 @@ def test_a_call_of_many_slabs_drains_and_closes_with_one_copy_out(
     slab_of(2)                                      # five slabs
     codec = registry().factory("tpu", {"k": str(K), "m": str(M)})
     codec.encode_batch(wide[:, :K], out_np=True)
-    flat = [s for s in sections if s != "registry.matrix"]
-    per = ["registry.upload", "registry.launch"]
-    assert flat == (per * 3 + ["registry.drain"] + per + ["registry.drain"]
-                    + per + ["registry.drain"] * 2
-                    + ["registry.device_wait", "registry.copy_out"])
+    flat = flat_of(sections)
+    assert flat == caller_sections(5, UPLOAD)
+    assert flat.count("registry.drain") == 4 == flat.count(
+        "registry.drain.link") == flat.count("registry.drain.land")
+    assert flat.count("registry.copy_out") == 1
     assert sections.elsewhere == {}                 # no gather, no worker
     sections.clear()
     codec.decode_stripes([2, 9], wide, out_np=True)
     # this thread's sections are what they were when it gathered itself
-    flat = [s for s in sections if s != "registry.matrix"]
-    per = ["registry.marshal"] + per
-    assert flat == (per * 3 + ["registry.drain"] + per + ["registry.drain"]
-                    + per + ["registry.drain"] * 2
-                    + ["registry.device_wait", "registry.copy_out"])
+    assert flat_of(sections) == caller_sections(5, GATHERED)
     assert sections[0] == "registry.matrix"         # the table miss
     # and the gathers are the worker's, a section each
-    assert sections.elsewhere == {GATHERER: ["registry.gather"] * 5}
+    assert [s for s in sections.elsewhere[GATHERER]
+            if s != "registry.gather.wait"] == ["registry.gather"] * 5
+    nesting_holds(sections)
     dump = codec.perf.dump()
     assert dump["launches"] == dump["pipelined"] == dump["engine_gN"] == 2
     assert dump["slabs"] == 10 and dump["stripes"] == 2 * WIDE

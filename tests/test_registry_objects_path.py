@@ -23,9 +23,10 @@ import pytest
 from benchmark.reference import codec as ref
 from benchmark.reference import codec_objects as ref_objects
 from ceph_tpu.ec import registry
-from test_registry_codec_path import (GATHERER, SlabFailed, Watched, arena,
-                                      came_back, later_launches, leases,
-                                      sections)
+from test_registry_codec_path import (GATHERED, GATHERER, KERNEL, SlabFailed,
+                                      Watched, arena, caller_sections,
+                                      came_back, flat_of, later_launches,
+                                      leases, nesting_holds, sections)
 
 __all__ = ["arena", "sections"]         # fixtures of the uniform call's file
 
@@ -341,6 +342,38 @@ def test_the_references_bytes_at_1_2_3_and_7_slabs(slab_lanes, small, width,
     assert dump.get("gathers_ahead", 0) <= dump.get("gathers", 0)
 
 
+@pytest.mark.parametrize("width,slabs", [(5376, 1), (2688, 2), (1792, 3),
+                                         (768, 7)],
+                         ids=["1slab", "2slabs", "3slabs", "7slabs"])
+def test_each_threads_sections_in_order_at_1_2_3_and_7_slabs(
+        kernels, sections, slab_lanes, small, width, slabs):
+    """A call over pieces opens what the uniform call opens, between its
+    two ``registry.prepare``: every landing's waits nested in its
+    ``registry.drain``, the last slab under ``registry.copy_out`` as it
+    was, and on the worker a fill a slab."""
+    slab_lanes(width)
+    objects, chunk_maps = small
+    codec = registry().factory("tpu", PROFILE)
+    codec.decode_objects([3, 7], chunk_maps)
+    assert sections[0] == "registry.matrix"          # the table miss
+    assert [s for s in sections
+            if s not in ("registry.matrix", KERNEL)] == (
+        ["registry.prepare"] + caller_sections(slabs, GATHERED)
+        + ["registry.prepare"])
+    assert flat_of(sections) == caller_sections(slabs, GATHERED)
+    nesting_holds(sections)
+    dump = codec.perf.dump()
+    if slabs == 1:                  # filled here, under registry.marshal
+        assert sections.elsewhere == {}
+        assert "gathers" not in dump
+        return
+    mine = sections.elsewhere[GATHERER]
+    assert [s for s in mine if s != "registry.gather.wait"] == \
+        ["registry.gather"] * slabs
+    assert mine.count("registry.gather.wait") == dump.get("staging_waits", 0)
+    assert dump["gathers"] == slabs
+
+
 def test_an_empty_call_and_a_call_of_one_object(kernels, arena, slab_lanes,
                                                 isa_codec):
     slab_lanes(LANES)
@@ -402,17 +435,20 @@ def test_sections_and_counters_of_a_call_over_objects(kernels, sections,
     assert slabs == 6
     codec = registry().factory("tpu", PROFILE)
     parity = codec.encode_objects(objects)
-    flat = [s for s in sections if s != "registry.matrix"]
-    per = ["registry.marshal", "registry.upload", "registry.launch"]
-    assert flat == (["registry.prepare"] + per * 3 + ["registry.drain"]
-                    + (per + ["registry.drain"]) * 3
-                    + ["registry.drain"] * 1
-                    + ["registry.device_wait", "registry.copy_out",
-                       "registry.prepare"])
-    # the fills are the worker's, a section each on its own thread
-    assert sections.elsewhere == {GATHERER: ["registry.gather"] * slabs}
+    flat = [s for s in sections if s not in ("registry.matrix", KERNEL)]
+    assert flat == (["registry.prepare"] + caller_sections(slabs, GATHERED)
+                    + ["registry.prepare"])
+    assert flat.count("registry.drain") == slabs - 1
+    # the fills are the worker's, a section each on its own thread, the
+    # wait for a launch inside the refills that had to
     dump = codec.perf.dump()
-    dump.pop("staging_waits", None)     # as the launches happen to finish
+    mine = sections.elsewhere[GATHERER]
+    assert sections.elsewhere == {GATHERER: mine}
+    assert [s for s in mine if s != "registry.gather.wait"] == \
+        ["registry.gather"] * slabs
+    assert mine.count("registry.gather.wait") == \
+        dump.pop("staging_waits", 0)    # as the launches happen to finish
+    nesting_holds(sections)
     assert 0 <= dump.pop("gathers_ahead") <= slabs  # as the fills do
     assert dump == {
         "launches": 1, "engine_v1": 1, "objects": len(objects),
@@ -466,7 +502,7 @@ def test_the_results_are_views_of_one_lease_and_keep_it(kernels, arena,
 
 
 def test_staging_is_refilled_only_behind_the_launch_that_read_it(
-        kernels, monkeypatch, arena, slab_lanes, small):
+        kernels, monkeypatch, arena, sections, slab_lanes, small):
     slab_lanes(LANES)
     objects, chunk_maps = small
     codec = registry().factory("tpu", PROFILE)
@@ -499,6 +535,14 @@ def test_staging_is_refilled_only_behind_the_launch_that_read_it(
     dump = codec.perf.dump()
     assert dump["staging_waits"] == slabs - 3
     assert dump["gathers"] == slabs >= dump["gathers_ahead"] >= 0
+    # no launch here is done until waited for: every refill's fill holds
+    # the wait, and the caller waits only for the two nobody refilled behind
+    assert sections.elsewhere == {GATHERER: (
+        ["registry.gather"] * 3
+        + ["registry.gather", "registry.gather.wait"] * (slabs - 3))}
+    assert flat_of(sections) == caller_sections(slabs, GATHERED)
+    assert sections.count(KERNEL) == 2
+    nesting_holds(sections)
 
 
 @pytest.mark.parametrize("fail_at", [0, 2, 5], ids=[
@@ -523,7 +567,8 @@ def test_buffers_come_back_when_a_slab_raises(kernels, monkeypatch, arena,
 @pytest.mark.parametrize("fill_fails_at", [0, 1, 4, 5], ids=[
     "first_slab", "second_slab", "a_refill", "padded_last_slab"])
 def test_a_fill_that_raises_on_the_worker_comes_out_of_the_call(
-        kernels, monkeypatch, arena, slab_lanes, small, fill_fails_at):
+        kernels, monkeypatch, arena, sections, slab_lanes, small,
+        fill_fails_at):
     """The worker's exception is the call's, raised where the caller's
     thread asks for that slab: the launches made before it are waited
     for, all three staging buffers go back, and no thread is left."""
@@ -542,6 +587,42 @@ def test_a_fill_that_raises_on_the_worker_comes_out_of_the_call(
     assert set(threading.enumerate()) == before
     assert len(outs) == fill_fails_at and all(out.done for out in outs)
     came_back(arena, codec, buffers, result=M * lanes)
+    assert sections.open_now == []          # the fill's and the marshal's
+    nesting_holds(sections)
+
+
+@pytest.mark.parametrize("land_fails_at", [0, 3, 4, 5], ids=[
+    "first_landing", "last_in_the_loop", "at_the_close", "under_copy_out"])
+def test_a_landing_that_raises_leaves_no_section_open_and_no_staging_out(
+        kernels, monkeypatch, arena, sections, slab_lanes, small,
+        land_fails_at):
+    """Six slabs and a result whose copy to the host raises, inside the
+    loop (slabs 0-3 land behind launches 2-5), at the close (slab 4) and
+    under ``registry.copy_out`` (slab 5): the error is the call's, every
+    section is left, every launch waited for, the staging given back."""
+    slab_lanes(LANES)
+    objects, chunk_maps = small
+    lanes = sum(stripe.shape[1] for stripe in chunk_maps)
+    codec = registry().factory("tpu", PROFILE)
+    before = set(threading.enumerate())
+    buffers, outs = later_launches(kernels, monkeypatch,
+                                   codec.encode_matrix[K:], arena.events, 6,
+                                   land_fails_at=land_fails_at)
+    with pytest.raises(SlabFailed) as caught:
+        codec.encode_objects(objects)
+    assert caught.value.args == (land_fails_at,)
+    del caught                              # and the frames it holds
+    assert set(threading.enumerate()) == before
+    assert len(outs) == min(land_fails_at + 3, 6)
+    assert all(out.done for out in outs)
+    whole = caller_sections(6, GATHERED)
+    upto = len(whole) - 1 if land_fails_at == 5 else [
+        i for i, name in enumerate(whole)
+        if name == "registry.drain.link"][land_fails_at]
+    assert flat_of(sections) == whole[:upto + 1]
+    nesting_holds(sections)
+    came_back(arena, codec, buffers, result=M * lanes,
+              counted=land_fails_at > 3)
 
 
 def test_a_parity_miss_raises_out_of_a_call_over_objects(kernels, slab_lanes,

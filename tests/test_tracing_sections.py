@@ -38,8 +38,12 @@ def _is_section(item: ast.withitem) -> bool:
 
 def section_faults(source: str) -> list[str]:
     """Lines where a ``with section(...)`` holds an await or a yield,
-    is itself ``async with``, or carries a name outside the layers."""
+    is itself ``async with``, or carries a name outside the layers: a
+    name is ``<layer>.<what>``, or ``<layer>.<what>.<part>`` for a part
+    of ``<layer>.<what>``, which the same module opens around it (a
+    reader that sums by name prefix then reads the whole as before)."""
     faults = []
+    names: dict[str, int] = {}
     for node in ast.walk(ast.parse(source)):
         if not isinstance(node, (ast.With, ast.AsyncWith)):
             continue
@@ -53,9 +57,12 @@ def section_faults(source: str) -> list[str]:
             if not (isinstance(arg, ast.Constant)
                     and isinstance(arg.value, str)
                     and arg.value.split(".")[0] in tracing.SECTION_LAYERS
-                    and "." in arg.value):
+                    and len(arg.value.split(".")) in (2, 3)
+                    and all(arg.value.split("."))):
                 faults.append(f"{node.lineno}: section name "
                               f"{ast.unparse(arg)}")
+            else:
+                names.setdefault(arg.value, node.lineno)
         for stmt in node.body:
             for inner in ast.walk(stmt):
                 if isinstance(inner, (ast.FunctionDef, ast.AsyncFunctionDef,
@@ -66,6 +73,10 @@ def section_faults(source: str) -> list[str]:
                                       ast.AsyncWith, ast.AsyncFor)):
                     faults.append(f"{inner.lineno}: "
                                   f"{type(inner).__name__} inside a section")
+    for name, lineno in names.items():
+        if name.count(".") == 2 and name.rpartition(".")[0] not in names:
+            faults.append(f"{lineno}: section {name} is a part of no "
+                          f"section this module opens")
     return faults
 
 
@@ -127,9 +138,45 @@ def test_the_wire_layer_has_a_section_for_each_way_through_it():
      "async with"),
     ("def f():\n  with section('nolayer.x'):\n    pass\n", "section name"),
     ("def f():\n  with section(name):\n    pass\n", "section name"),
+    ("def f():\n  with section('wire.x.y.z'):\n    pass\n", "section name"),
+    ("def f():\n  with section('wire..y'):\n    pass\n", "section name"),
+    ("def f():\n  with section('wire.x.y'):\n    pass\n", "a part of no"),
 ])
 def test_the_section_check_finds_what_it_looks_for(body, fault):
     assert any(fault in f for f in section_faults(body))
+
+
+def test_a_part_of_a_section_is_named_under_it():
+    ok = ("def f():\n  with section('wire.x'):\n    g()\n"
+          "def g():\n  with section('wire.x.y'):\n    pass\n")
+    assert section_faults(ok) == []
+
+
+def test_the_registry_layer_has_these_sections_and_no_others():
+    """The caller's thread, the worker's, and the parts of a landing:
+    ``registry.drain.kernel`` / ``.link`` / ``.land`` inside
+    ``registry.drain``, ``registry.gather.wait`` inside the worker's
+    ``registry.gather``.  PERF.md section 3 says who reads each."""
+    assert "registry" in tracing.SECTION_LAYERS
+    found: dict[str, set] = {}
+    for path in SECTIONED:
+        for node in ast.walk(ast.parse((ROOT / path).read_text())):
+            if isinstance(node, ast.With):
+                for i in node.items:
+                    if _is_section(i) and i.context_expr.args[0].value \
+                            .startswith("registry."):
+                        found.setdefault(i.context_expr.args[0].value,
+                                         set()).add(path)
+    whole = ["copy_out", "device_wait", "drain", "gather", "launch",
+             "marshal", "matrix", "prepare", "upload"]
+    parts = ["drain.kernel", "drain.land", "drain.link", "gather.wait"]
+    assert sorted(found) == sorted(f"registry.{name}"
+                                   for name in whole + parts)
+    # the slab loop is one function's: every part and its whole are there
+    for name in parts + ["drain", "copy_out", "gather", "marshal", "upload",
+                         "launch", "device_wait"]:
+        assert found[f"registry.{name}"] == {"ceph_tpu/ops/gf2kernels.py"}
+    assert found["registry.prepare"] == {"ceph_tpu/ec/plugins/tpu.py"}
 
 
 def test_section_is_a_shared_noop_until_jax_is_loaded():

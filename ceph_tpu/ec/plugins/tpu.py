@@ -12,13 +12,18 @@ What a caller of the batch entry points paid is counted in the
 plugin's ``ec_registry`` set (``codec.perf``): ``launches``, ``stripes``,
 ``bytes_in``, ``bytes_out``, ``engine_<name>`` (the engine that served,
 per call), ``slabs`` / ``pipelined`` / ``staging_waits`` (how a call from
-host memory to host memory streamed through the device),
+host memory to host memory streamed through the device, at most
+``gf2kernels.SLABS_IN_FLIGHT + 1`` slabs between ``device_put`` and
+landing), ``uploads_beside`` (summed over such a call's slabs, the
+earlier slabs in flight whose launch was not done when the slab's
+``device_put`` went out: over ``slabs``, how many slabs the link and
+the device carry beside a new upload),
 ``gathers`` / ``gathers_ahead`` (slabs of such a call whose survivors or
 pieces its worker thread gathered into staging, and those of them that
 were ready when the caller's thread came to upload them: how often the
 gather stage ran ahead of the uploads),
-``arena_hits`` / ``arena_misses`` (the result and the three staging
-buffers of such a call, each borrowed from the process's host arena: a
+``arena_hits`` / ``arena_misses`` (the result and the staging buffers
+of such a call, ``SLABS_IN_FLIGHT + 1`` at most, each borrowed from the process's host arena: a
 buffer an earlier call's caller dropped, or a fresh allocation; a
 result stays its caller's own until no array views it),
 ``parity_gates`` (first launches of a matrix held to the host oracle),

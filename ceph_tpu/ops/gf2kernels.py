@@ -564,8 +564,22 @@ def batch_engine(matrix: np.ndarray, b: int, k: int, l: int) -> str:
 
 # input bytes of one slab of a host-to-host call (``_slab_stripes``)
 SLAB_BYTES = 32 << 20
+# slabs such a call keeps between ``device_put`` and landing before its
+# thread blocks for the oldest; with the one it then lands,
+# ``SLABS_IN_FLIGHT + 1`` are on their way up, in the kernel or on their
+# way down, and as many staging buffers serve a call that gathers.  One
+# upload alone gets 4.5 GiB/s of the host's link and four or more in
+# flight 11.7-11.9; the smallest depth of a sweep of 2, 3, 4, 6, 8
+# within 2 % of the best at (32, 8, 131072) slabs under which the
+# (1, 10, 3350528) slabs lose nothing (PERF.md section 6, PR 53)
+SLABS_IN_FLIGHT = 6
 # host bytes the process keeps at rest for the next such call's result
-# and staging (``host_arena.HostArena``); a buffer past it is dropped
+# and staging (``host_arena.HostArena``); a buffer past it is dropped.
+# What a caller's loop leaves there while it keeps one result, as both
+# registry cells' drivers do (MiB; PERF.md section 7): at k=8, m=3 over
+# 1024 x 1 MiB a second encode's 384, a decode's 128 and
+# ``SLABS_IN_FLIGHT + 1`` staging buffers of 32 = 736; at k=10, m=4 over
+# 4608 objects of 4 KiB to 1 MiB 409 + 205 + 224 = 838
 ARENA_BYTES = 1 << 30
 _arena = HostArena(ARENA_BYTES)
 
@@ -698,9 +712,11 @@ def gf_matmul_batch_device(matrix: np.ndarray, data, *, rows=None,
     device, a slab of many does not), ``registry.launch`` (engine choice
     and dispatch; the matrix's device copy and a first launch's parity
     gate are ``registry.matrix`` inside it), then the result's copy to
-    the host is started and the slab two back is landed in its rows of
-    the call's one result array (``registry.drain``, its three waits
-    nested in it under names of their own: ``registry.drain.kernel``
+    the host is started and, once more than ``SLABS_IN_FLIGHT`` slabs
+    are between their ``device_put`` and their landing, the oldest of
+    them is landed in its rows of the call's one result array
+    (``registry.drain``, its three waits nested in it under names of
+    their own: ``registry.drain.kernel``
     until the slab's launch is done, whether the device or the upload
     of its operand was late, opened only where the launch is not done
     when the landing comes to it, so that a landing blocks once where
@@ -716,19 +732,21 @@ def gf_matmul_batch_device(matrix: np.ndarray, data, *, rows=None,
     worker thread the call starts and ends (``registry-gather``; one a
     call in flight, so two callers never wait for each other's): the
     caller hands it slab 0 at once and slab i+1 before it uploads slab
-    i, over THREE staging buffers (one being filled, one on its way up,
-    one a launch still reads), and ``registry.marshal`` is only the wait
-    for the slab about to go up.  The worker's time is
-    ``registry.gather`` on its own thread: the wait for the launch that
-    read the buffer's last upload (slab i-2's, dispatched two
-    iterations before; ``registry.gather.wait`` nested in it, only
-    where there is something to wait for), then ``_gather_rows`` or
+    i, over ``SLABS_IN_FLIGHT + 1`` staging buffers (one being filled,
+    and one for every slab in flight whose launch may still read its
+    upload), and ``registry.marshal`` is only the wait for the slab
+    about to go up.  The worker's time is ``registry.gather`` on its own
+    thread: the wait for the launch that read the buffer's last upload
+    (slab ``i + 1 - staging``'s, looked up by its index among the slabs
+    in flight; ``registry.gather.wait`` nested in it, only where there
+    is something to wait for), then ``_gather_rows`` or
     ``LanePieces.fill``, whose numpy copies release the GIL.  So slab
-    i+1's gather, slab i's upload, slab i-1's kernel
-    and slab i-2's copy-out are in flight together, on two threads, the
-    link and the device, and three slabs at most live on the device.  A
-    call without a gather (no ``rows``, no pieces: the caller's array is
-    uploaded as it is), of one slab, or from a device array starts no
+    i+1's gather and the uploads, kernels and copy-outs of slabs i down
+    to i - ``SLABS_IN_FLIGHT`` are in flight together, on two threads,
+    the link and the device, and ``SLABS_IN_FLIGHT + 1`` slabs at most
+    live on the device; a call of fewer slabs never reaches the depth.
+    A call without a gather (no ``rows``, no pieces: the caller's array
+    is uploaded as it is), of one slab, or from a device array starts no
     worker.
 
     Where the host memory of a call of several slabs comes from: the
@@ -739,11 +757,11 @@ def gf_matmul_batch_device(matrix: np.ndarray, data, *, rows=None,
     fits, else over a fresh one; it, and every slice cut from it, is
     the caller's alone until the last array over its memory is gone,
     and only then does the buffer go back for a later call.  The
-    staging buffers (a slab each, three at most) are taken at the
-    call's start and given back at its end, also when the call raises,
-    on either thread (the worker's exception is the call's, raised
-    where the caller asks for that slab): a staging buffer is refilled,
-    or given back, only behind the launch that read its upload, and
+    staging buffers (a slab each, ``SLABS_IN_FLIGHT + 1`` at most) are
+    taken at the call's start and given back at its end, also when the
+    call raises, on either thread (the worker's exception is the call's,
+    raised where the caller asks for that slab): a staging buffer is
+    refilled, or given back, only behind the launch that read its upload, and
     before it returns either way the call has waited for its worker
     and for every launch it made; no thread outlives it.  A uniform
     call of one slab borrows nothing: its result is ``np.asarray`` of
@@ -767,12 +785,16 @@ def gf_matmul_batch_device(matrix: np.ndarray, data, *, rows=None,
     whose staging the worker filled), ``gathers_ahead`` (those already
     filled when the caller's thread came for them), ``staging_waits``
     (refills that had to wait for a launch, counted where the worker
-    waits), ``parity_gates``, ``arena_hits`` / ``arena_misses`` (one a
-    buffer borrowed, result or staging: a kept one, or a fresh
-    allocation); for a ``LanePieces`` call ``objects`` (its pieces),
-    ``lanes`` (the columns the caller asked for), ``lanes_launched``
-    (slabs x slab width) and ``lanes_padded`` (their difference) in the
-    place of ``stripes``."""
+    waits), ``uploads_beside`` (over the call's slabs, the earlier slabs
+    in flight whose launch was not done when the slab's ``device_put``
+    went out: over ``slabs`` it is the mean number of slabs on their way
+    up or in the kernel beside a new upload, 0 for a call of one slab
+    and ``SLABS_IN_FLIGHT`` at most), ``parity_gates``, ``arena_hits`` /
+    ``arena_misses`` (one a buffer borrowed, result or staging: a kept
+    one, or a fresh allocation); for a ``LanePieces`` call ``objects``
+    (its pieces), ``lanes`` (the columns the caller asked for),
+    ``lanes_launched`` (slabs x slab width) and ``lanes_padded`` (their
+    difference) in the place of ``stripes``."""
     matrix = np.ascontiguousarray(matrix, dtype=np.uint8)
     r, k = matrix.shape
     ragged = isinstance(data, LanePieces)
@@ -792,17 +814,18 @@ def gf_matmul_batch_device(matrix: np.ndarray, data, *, rows=None,
     worker = None           # fills a slab's staging ahead of its upload
     if on_host and (ragged or rows is not None):
         if result is not None:
-            # a slab being filled, one on its way up, one a launch reads
+            # a slab being filled, and one for every slab in flight
+            # whose launch may still read its upload
             borrowed = [_arena.take(step * k * l, perf)
-                        for _ in range(min(3, len(spans)))]
+                        for _ in range(min(SLABS_IN_FLIGHT + 1, len(spans)))]
             staging = [buf[:step * k * l].reshape(step, k, l)
                        for buf in borrowed]
         else:
             staging = [np.empty((step, k, l), np.uint8)]
         if len(spans) > 1:
             worker = ThreadPoolExecutor(1, "registry-gather")
-    flying: collections.deque = collections.deque()   # (lo, hi, out)
-    waits = ahead = 0
+    flying: collections.deque = collections.deque()   # (slab, lo, hi, out)
+    waits = ahead = beside = 0
     xd = filling = None
 
     def fill(i: int) -> np.ndarray:
@@ -813,7 +836,9 @@ def gf_matmul_batch_device(matrix: np.ndarray, data, *, rows=None,
 
     def fill_ahead(i: int, reader) -> np.ndarray:
         """Slab i's staging, on the worker, behind ``reader``: the
-        launch that read the buffer's last upload (slab i - 3's)."""
+        launch that read the buffer's last upload (slab
+        ``i - len(staging)``'s; None where that slab has landed, or
+        there is none)."""
         nonlocal waits
         with section("registry.gather"):
             if reader is not None and not reader.is_ready():
@@ -835,7 +860,7 @@ def gf_matmul_batch_device(matrix: np.ndarray, data, *, rows=None,
         not waited for: the landing then blocks once, as it would
         without the names."""
         with section("registry.drain"):
-            lo, hi, out = flying.popleft()
+            _, lo, hi, out = flying.popleft()
             if not out.is_ready():
                 with section("registry.drain.kernel"):
                     # lint: disable=device-path-host-sync -- the wait for the launch, timed apart from the copy-out that follows it
@@ -865,12 +890,16 @@ def gf_matmul_batch_device(matrix: np.ndarray, data, *, rows=None,
                             ahead += filling.done()
                             slab = filling.result()
                             if i + 1 < len(spans):
-                                # slab i - 2's launch read the upload of
-                                # the buffer slab i + 1 goes into
+                                # the buffer slab i + 1 goes into was
+                                # last uploaded for the slab this many back
+                                last = i + 1 - len(staging)
                                 filling = worker.submit(
-                                    fill_ahead, i + 1, flying[0][2]
-                                    if len(flying) == 2 else None)
+                                    fill_ahead, i + 1, next(
+                                        (out for j, _, _, out in flying
+                                         if j == last), None))
                 with section("registry.upload"):
+                    beside += sum(not out.is_ready()
+                                  for _, _, _, out in flying)
                     xd = jax.device_put(slab)
                     if result is None:
                         # lint: disable=device-path-host-sync -- a one-slab upload is timed apart from the kernel it feeds; the launch needs its last byte either way
@@ -881,8 +910,8 @@ def gf_matmul_batch_device(matrix: np.ndarray, data, *, rows=None,
                     served = engine
                 if result is not None:
                     out.copy_to_host_async()
-            flying.append((lo, hi, out))
-            if len(flying) > 2:
+            flying.append((i, lo, hi, out))
+            if len(flying) > SLABS_IN_FLIGHT:
                 drain()
         if perf is not None:
             perf.inc("launches")
@@ -907,6 +936,8 @@ def gf_matmul_batch_device(matrix: np.ndarray, data, *, rows=None,
                 perf.inc("gathers_ahead", ahead)
             if waits:
                 perf.inc("staging_waits", waits)
+            if beside:
+                perf.inc("uploads_beside", beside)
         if not out_np:
             return out
         while len(flying) > 1:
@@ -918,7 +949,7 @@ def gf_matmul_batch_device(matrix: np.ndarray, data, *, rows=None,
             if result is None:
                 # lint: disable=device-path-host-sync -- the single post-launch materialization (caller opts in via out_np)
                 return np.asarray(out)
-            lo, hi, out = flying.popleft()
+            _, lo, hi, out = flying.popleft()
             # lint: disable=device-path-host-sync -- the caller asked for host bytes (out_np); the copy was started at the launch
             put(lo, hi, np.asarray(out))
             return result
@@ -928,7 +959,7 @@ def gf_matmul_batch_device(matrix: np.ndarray, data, *, rows=None,
             worker.shutdown(wait=True, cancel_futures=True)
         if borrowed:
             # a call that raised may leave a slab on its way to the device
-            for held in (xd, *(out for _, _, out in flying)):
+            for held in (xd, *(out for _, _, _, out in flying)):
                 if held is not None:
                     # lint: disable=device-path-host-sync -- staging goes back only behind the last launch that read it
                     held.block_until_ready()

@@ -8,8 +8,11 @@ in slabs (``gf2kernels.SLAB_BYTES``; the toy batches here are one slab
 unless a test lowers it): the same bytes at every number of slabs, one
 result array a call, and the gather of a call of several slabs on a
 worker thread of the call's own, one slab ahead of the caller's uploads
-over three staging buffers, each refilled only behind the launch that
-read it.  The result and the staging of a call of several slabs are
+over ``SLABS_IN_FLIGHT + 1`` staging buffers, each refilled only behind
+the launch that read it, and never more than ``SLABS_IN_FLIGHT + 1``
+slabs between ``device_put`` and landing (the tests of the loop's order
+of events run at depth 2, PR 46's, and at the module's own).  The result
+and the staging of a call of several slabs are
 borrowed from the process's host arena (``ops/host_arena.py``): a
 result is the caller's own until the last array over its memory is
 gone, and only then the next call's.
@@ -19,6 +22,7 @@ from __future__ import annotations
 
 import collections
 import contextlib
+import functools
 import gc
 import itertools
 import sys
@@ -28,6 +32,7 @@ import time
 import numpy as np
 import pytest
 
+import ceph_tpu.ops.gf2kernels as g
 from benchmark.reference import codec as ref
 from ceph_tpu.common import tracing
 from ceph_tpu.ec import registry
@@ -45,6 +50,29 @@ PATTERNS = [list(p) for e in range(1, M + 1)
             for p in itertools.combinations(range(N), e)]
 ENGINES = ("sched", "gN", "v1", "xla")
 GATHERER = "registry-gather_0"      # the one thread of a call's worker
+# the depths the loop's order of events is held at: PR 46's and the module's
+DEPTHS = sorted({2, g.SLABS_IN_FLIGHT})
+every_depth = pytest.mark.parametrize(
+    "depth", DEPTHS, ids=[f"depth{d}" for d in DEPTHS], indirect=True)
+# (depth, slabs): below, at and above the depth + 1 slabs a call keeps
+DEPTHS_AND_SLABS = [(d, n) for d in DEPTHS for n in (d, d + 1, d + 3)]
+every_depth_and_count = pytest.mark.parametrize(
+    "depth,slabs", DEPTHS_AND_SLABS, indirect=["depth"],
+    ids=[f"depth{d}-{n}slabs" for d, n in DEPTHS_AND_SLABS])
+
+
+@pytest.fixture
+def depth(request, monkeypatch):
+    """``SLABS_IN_FLIGHT`` set to the test's depth (the module's own
+    where the test names none)."""
+    value = getattr(request, "param", g.SLABS_IN_FLIGHT)
+    monkeypatch.setattr(g, "SLABS_IN_FLIGHT", value)
+    return value
+
+
+def staging_of(slabs: int) -> int:
+    """Staging buffers a gathering call of ``slabs`` slabs borrows."""
+    return min(g.SLABS_IN_FLIGHT + 1, slabs)
 
 
 @pytest.fixture(scope="module", autouse=True)
@@ -53,8 +81,6 @@ def packed_engine():
     ``gN``, here through the Pallas interpreter, whose matrix is an
     operand (the CPU's choice, ``sched``, compiles a program a matrix:
     231 of them)."""
-    import ceph_tpu.ops.gf2kernels as g
-
     mp = pytest.MonkeyPatch()
     mp.setattr(g, "_want_pallas", lambda: True)
     g.clear_kernel_cache()
@@ -66,8 +92,6 @@ def packed_engine():
 @pytest.fixture
 def packed():
     """The kernel module with no launch verified yet."""
-    import ceph_tpu.ops.gf2kernels as g
-
     g._gN_verified.clear()
     return g
 
@@ -76,7 +100,6 @@ def packed():
 def arena(monkeypatch):
     """An empty host arena of the process's cap in the kernel module's
     place, with every buffer that comes back to it written down."""
-    import ceph_tpu.ops.gf2kernels as g
     from ceph_tpu.ops.host_arena import HostArena
 
     class Logged(HostArena):
@@ -110,21 +133,36 @@ def stripes():
     return np.concatenate([data, parity], axis=1)
 
 
-@pytest.fixture(scope="module")
-def wide():
-    """(WIDE, k+m, L) whole stripes, as ``stripes``."""
+@functools.lru_cache(maxsize=None)
+def whole_stripes(count: int) -> np.ndarray:
+    """(count, k+m, L) whole stripes, as ``stripes``."""
     codec = registry().factory("isa", {"k": str(K), "m": str(M)})
     data = np.random.default_rng(46).integers(
-        0, 256, (WIDE, K, L), dtype=np.uint8)
+        0, 256, (count, K, L), dtype=np.uint8)
     parity = np.stack([gf_matmul(codec.encode_matrix[K:], d) for d in data])
     return np.concatenate([data, parity], axis=1)
+
+
+@pytest.fixture(scope="module")
+def wide():
+    """(WIDE, k+m, L) whole stripes."""
+    return whole_stripes(WIDE)
+
+
+@pytest.fixture
+def pairs(monkeypatch):
+    """Sets the slab to two stripes (one group of the packed engine at
+    k=8) and hands out whole stripes for so many slabs."""
+    def of(slabs: int) -> np.ndarray:
+        monkeypatch.setattr(g, "SLAB_BYTES", 2 * K * L)
+        assert g._slab_stripes(2 * slabs, K, L) == 2
+        return whole_stripes(2 * slabs)
+    return of
 
 
 @pytest.fixture
 def slab_of(monkeypatch):
     """Sets the slab to so many of this file's stripes."""
-    import ceph_tpu.ops.gf2kernels as g
-
     def set_slab(stripes: int) -> None:
         monkeypatch.setattr(g, "SLAB_BYTES", stripes * K * L)
         assert g._slab_stripes(WIDE, K, L) == stripes
@@ -267,8 +305,6 @@ class Opened(list):
 def sections(monkeypatch):
     """Every section the registry path opens, thread by thread."""
     import ceph_tpu.ec.plugins.tpu as plugin
-    import ceph_tpu.ops.gf2kernels as g
-
     opened = Opened()
     stacks: dict[int, list[str]] = {}
 
@@ -312,13 +348,14 @@ NESTED = {KERNEL: "registry.drain",
 def caller_sections(slabs: int, per: list[str]) -> list[str]:
     """What the caller's thread opens, in order, in a call of ``slabs``
     slabs, ``registry.matrix``, ``registry.prepare`` and the kernel
-    waits apart: ``per`` a slab, the slab two back landed behind every
-    launch from the third on, the rest but one landed at the close, and
-    the last under ``registry.copy_out``."""
+    waits apart: ``per`` a slab, the slab ``SLABS_IN_FLIGHT`` back
+    landed behind every launch once so many are in flight, the rest but
+    one landed at the close, and the last under ``registry.copy_out``."""
+    depth = g.SLABS_IN_FLIGHT
     out: list[str] = []
     for i in range(slabs):
-        out += per + (DRAIN if i >= 2 else [])
-    return out + DRAIN * (min(slabs, 2) - 1) + CLOSE
+        out += per + (DRAIN if i >= depth else [])
+    return out + DRAIN * (min(slabs, depth) - 1) + CLOSE
 
 
 def flat_of(opened: list[str]) -> list[str]:
@@ -343,8 +380,9 @@ def nesting_holds(opened: Opened) -> None:
             assert inside == {""}, (name, inside)
 
 
+@every_depth
 def test_one_encode_and_one_decode_move_sections_and_counters(
-        packed, sections, stripes, arena, slab_of, wide):
+        packed, sections, stripes, arena, slab_of, wide, depth):
     assert "registry" in tracing.SECTION_LAYERS
     codec = registry().factory("tpu", {"k": str(K), "m": str(M)})
     assert codec.perf.name == "ec_registry" and codec.perf.dump() == {}
@@ -375,21 +413,28 @@ def test_one_encode_and_one_decode_move_sections_and_counters(
     # a call of one slab borrows nothing and gathers on its own thread
     assert "arena_hits" not in two and "arena_misses" not in two
     assert "gathers" not in two and "gathers_ahead" not in two
+    # nor does its upload go out beside another slab's
+    assert "uploads_beside" not in two
     assert sections.elsewhere == {}
 
     # several slabs: an encode borrows its result, a decode its result
-    # and three staging buffers, each counted once as a hit or a miss
+    # and a staging buffer a slab (three slabs here, under the depth's
+    # staging at either depth), each counted once as a hit or a miss
     slab_of(4)
+    held = staging_of(3)
+    assert held == 3
     parity = codec.encode_batch(wide[:, :K], out_np=True)
     assert leases(codec) == (0, 1)                   # nothing kept yet
     assert "gathers" not in codec.perf.dump()        # no gather, no worker
     del parity
     lost = codec.decode_stripes(erased, wide, out_np=True)
-    assert leases(codec) == (1, 4)       # the encode's buffer; new staging
+    assert leases(codec) == (1, 1 + held)    # the encode's buffer; new staging
     del lost
     codec.decode_stripes(erased, wide, out_np=True)
-    assert leases(codec) == (5, 4)
+    assert leases(codec) == (2 + held, 1 + held)
     three = codec.perf.dump()
+    # three calls of three slabs: a slab's upload beside two others at most
+    assert 0 <= three.get("uploads_beside", 0) <= 3 * (0 + 1 + 2)
     assert three["launches"] == 5 and three["pipelined"] == 3
     assert three["slabs"] == 2 + 3 * 3
     # the decodes' slabs were filled by their workers, and by nobody else
@@ -442,7 +487,7 @@ def test_an_encode_is_the_same_bytes_at_every_number_of_slabs(
                          ids=["-".join(map(str, p)) for p in PATTERNS])
 def test_every_erasure_pattern_decodes_in_three_ragged_slabs(
         tpu_codec, slab_of, wide, erased):
-    """4 + 4 + 2 stripes through three staging buffers, filled by the
+    """4 + 4 + 2 stripes through a staging buffer each, filled by the
     call's worker: the bytes of the one-launch call and of the plain
     product."""
     blanked = wide.copy()
@@ -461,14 +506,15 @@ def test_every_erasure_pattern_decodes_in_three_ragged_slabs(
     assert after["gathers"] - before.get("gathers", 0) == 3
 
 
+@every_depth
 @pytest.mark.parametrize("per_slab,slabs", [(14, 1), (8, 2), (6, 3), (2, 7)],
                          ids=["1slab", "2slabs", "3slabs", "7slabs"])
 def test_a_decode_from_rows_is_the_references_bytes_at_any_number_of_slabs(
-        packed, monkeypatch, per_slab, slabs):
+        packed, monkeypatch, depth, per_slab, slabs):
     """14 stripes whose parity is ``benchmark/reference/``'s, recovered
-    through one slab (gathered on the caller's thread), two (a buffer
-    each), three (all three buffers, none refilled) and seven (every
-    buffer refilled behind its reader)."""
+    through one slab (gathered on the caller's thread), two and three (a
+    buffer each, none refilled) and seven (more than the depth's
+    staging: buffers refilled behind their readers)."""
     profile = {"k": K, "m": M, "technique": "reed_sol_van"}
     data = np.random.default_rng(51).integers(
         0, 256, (14, K, L), dtype=np.uint8)
@@ -489,6 +535,59 @@ def test_a_decode_from_rows_is_the_references_bytes_at_any_number_of_slabs(
     assert dump.get("gathers", 0) == (3 * slabs if slabs > 1 else 0)
     assert dump.get("gathers_ahead", 0) <= dump.get("gathers", 0)
     assert dump.get("pipelined", 0) == (3 if slabs > 1 else 0)
+    assert dump.get("uploads_beside", 0) <= 3 * depth * slabs
+    assert ("uploads_beside" in dump) <= (slabs > 1)
+
+
+REFERENCE = {"k": K, "m": M, "technique": "reed_sol_van"}
+
+
+@functools.lru_cache(maxsize=None)
+def reference_stripes(count: int) -> np.ndarray:
+    """(count, k+m, L) stripes whose parity is ``benchmark/reference/``'s."""
+    data = np.random.default_rng(53).integers(
+        0, 256, (count, K, L), dtype=np.uint8)
+    return np.concatenate([data, ref.parity_of(REFERENCE, data)], axis=1)
+
+
+@every_depth_and_count
+def test_an_encode_is_the_references_bytes_at_every_depth(
+        packed, pairs, depth, slabs):
+    pairs(slabs)
+    stripes = reference_stripes(2 * slabs)
+    codec = registry().factory("tpu", {"k": str(K), "m": str(M)})
+    got = codec.encode_batch(stripes[:, :K], out_np=True)
+    assert np.array_equal(got, stripes[:, K:])
+    dump = codec.perf.dump()
+    assert dump["slabs"] == slabs
+    assert dump.get("uploads_beside", 0) <= depth * slabs
+
+
+@every_depth
+@pytest.mark.parametrize("erased", PATTERNS,
+                         ids=["-".join(map(str, p)) for p in PATTERNS])
+def test_every_erasure_pattern_decodes_to_the_references_bytes_at_every_depth(
+        tpu_codec, pairs, depth, erased):
+    """Slabs of two stripes, three more of them than the deeper depth
+    keeps in flight, so staging buffers are refilled behind their
+    readers and slabs land inside the loop: the erased chunks are the
+    original bytes, and stripe 5's the reference's own reconstruction."""
+    slabs = max(DEPTHS) + 3
+    pairs(slabs)
+    stripes = reference_stripes(2 * slabs)
+    blanked = stripes.copy()
+    blanked[:, erased] = 0x5A
+    before = tpu_codec.perf.dump()
+    got = tpu_codec.decode_stripes(erased, blanked, out_np=True)
+    assert got.shape == (2 * slabs, len(erased), L)
+    assert got.flags["C_CONTIGUOUS"]
+    assert np.array_equal(got, stripes[:, erased])
+    assert np.array_equal(got[5], ref.recovered(REFERENCE, blanked[5], erased))
+    after = tpu_codec.perf.dump()
+    assert after["slabs"] - before["slabs"] == slabs
+    assert after["gathers"] - before.get("gathers", 0) == slabs
+    assert 0 <= after.get("uploads_beside", 0) \
+        - before.get("uploads_beside", 0) <= depth * slabs
 
 
 @pytest.mark.parametrize("per_slab", [10, 4], ids=["1slab", "3slabs"])
@@ -719,7 +818,7 @@ def test_two_threads_never_hold_one_buffer(packed, arena, slab_of, wide):
     assert not any(t.is_alive() for t in threads + calls)
     assert set(threading.enumerate()) == before     # no worker is left
     assert wrong == []
-    # a result a call, and three staging buffers a decode
+    # a result a call, and a staging buffer a slab of a decode's three
     hits, misses = leases(codec)
     assert hits + misses == 5 + 2 * (2 + 2 * 4) and misses >= 1
     dump = codec.perf.dump()
@@ -752,6 +851,7 @@ class LaterOut:
         self.block_until_ready()            # host bytes follow the kernel
         if self.unreadable:
             raise SlabFailed(self.slab)
+        self.log.append(("landed", self.slab))
         return self.value
 
 
@@ -819,59 +919,67 @@ def later_launches(packed, monkeypatch, matrix, log, slabs, fail_at=None,
     return buffers, outs
 
 
-@pytest.mark.parametrize("per_slab", [6, 4, 2], ids=[
-    f"{len(SLABS[n])}slabs" for n in (6, 4, 2)])
+@every_depth_and_count
 def test_a_staging_buffer_is_refilled_only_behind_the_launch_that_read_it(
-        packed, monkeypatch, arena, sections, slab_of, wide, per_slab):
+        packed, monkeypatch, arena, sections, pairs, depth, slabs):
     """``device_put`` may alias the numpy memory (CPU) or read it until
     the transfer completes (TPU): a buffer is written again, or given
     back to the arena, only after the launch that read its upload is
     done.  Launches here never finish by themselves, so every refill
     has to wait, on the worker that fills, and is counted."""
-    slab_of(per_slab)
+    chunks = pairs(slabs)
     erased = [3, 8, 10]
     codec = registry().factory("tpu", {"k": str(K), "m": str(M)})
     matrix = codec.decode_matrix_for(erased)
     log = arena.events
-    slabs = len(SLABS[per_slab])
-    held = min(3, slabs)                # a staging buffer a slab, three at most
+    held = staging_of(slabs)            # a staging buffer a slab, depth + 1 at most
+    assert held == min(depth + 1, slabs)
     before = set(threading.enumerate())
     buffers, outs = later_launches(packed, monkeypatch, matrix, log, slabs)
-    got = codec.decode_stripes(erased, wide, out_np=True)
-    assert np.array_equal(got, wide[:, erased])
+    got = codec.decode_stripes(erased, chunks, out_np=True)
+    assert np.array_equal(got, chunks[:, erased])
     assert set(threading.enumerate()) == before     # the worker is gone
     fills = [ev for ev in log if ev[0] == "fill"]
     fill_at = [i for i, ev in enumerate(log) if ev[0] == "fill"]
     assert len(fills) == len(outs) == slabs
-    assert len(buffers) == held                     # three, reused
+    assert len(buffers) == held                     # reused in turn
     assert [ev[1] for ev in fills] == [fills[i % held][1]
                                        for i in range(slabs)]
     assert all(ev[2] == [] for ev in fills), fills  # nothing unfinished
     assert {ev[3] for ev in fills} == {GATHERER}    # none on this thread
     # each slab: filled, launched, its copy to the host started, and
-    # only later waited for
+    # only later waited for and landed
     for n in range(slabs):
         at = {kind: i for i, (kind, *rest) in enumerate(log)
               if kind not in ("fill", "given") and rest[0] == n}
-        assert fill_at[n] < at["launch"] < at["copy_started"] < at["done"]
-    # slab n's buffer is refilled for slab n+3, after slab n is done and
-    # while slab n+1 is still in flight; slab n+1's gather is handed
+        assert fill_at[n] < at["launch"] < at["copy_started"] < at["done"] \
+            < at["landed"]
+    # never more than depth + 1 slabs between device_put and landing,
+    # and so many whenever the call has them
+    assert most_in_flight(log) == min(depth + 1, slabs)
+    # slab n's buffer is refilled for slab n + held, after slab n is done
+    # and while slab n+1 is still in flight; slab n+1's gather is handed
     # over before slab n is launched
-    for n in range(slabs - 3):
-        assert log.index(("done", n)) < fill_at[n + 3] \
+    for n in range(slabs - held):
+        assert log.index(("done", n)) < fill_at[n + held] \
             < log.index(("done", n + 1))
     for n in range(slabs - 1):
         assert fill_at[n + 1] < log.index(("launch", n, fills[n][1]))
     # all go back to the arena at the call's end, behind the last launch
-    # (the result is the caller's: it has not come back)
+    # and its landing (the result is the caller's: it has not come back)
     assert log[-held:] == [("given", into.ctypes.data)
                            for into in buffers.values()]
-    assert log.index(("done", slabs - 1)) == len(log) - held - 1
-    assert arena.at_rest() == held * per_slab * K * L
+    assert log[-held - 2:-held] == [("done", slabs - 1),
+                                    ("landed", slabs - 1)]
+    assert arena.at_rest() == held * 2 * K * L
     dump = codec.perf.dump()
     assert dump["slabs"] == slabs and dump["pipelined"] == 1
-    assert dump.get("staging_waits", 0) == max(slabs - 3, 0)
+    assert dump.get("staging_waits", 0) == slabs - held
     assert dump["gathers"] == slabs >= dump["gathers_ahead"] >= 0
+    # an upload goes out beside the slabs in flight whose launch nobody
+    # has waited for yet: depth of them at most
+    assert 0 < dump["uploads_beside"] <= sum(
+        min(i, depth) for i in range(slabs))
     assert leases(codec) == (0, 1 + held)
     # the worker's sections: a fill a slab, and inside every refill's
     # the wait for the launch that read the buffer (none is done here)
@@ -881,129 +989,163 @@ def test_a_staging_buffer_is_refilled_only_behind_the_launch_that_read_it(
     assert flat_of(sections) == caller_sections(slabs, GATHERED)
     # a landing waits for its launch under the wait's own name, unless
     # the worker had to have it done before it refilled the buffer
-    by_caller = [n for n in range(slabs - 1) if n + 3 >= slabs]
+    by_caller = [n for n in range(slabs - 1) if n + held >= slabs]
     assert sections.count(KERNEL) == len(by_caller)
     nesting_holds(sections)
 
 
-@pytest.mark.parametrize("fail_at", [0, 1, 2], ids=[
-    "first_slab", "second_slab", "ragged_last_slab"])
+def most_in_flight(log) -> int:
+    """The most slabs that were between their launch (their
+    ``device_put`` is the step before it, on the same thread) and their
+    landing at any one time; every one of them landed."""
+    up = most = 0
+    for kind, *_ in log:
+        up += (kind == "launch") - (kind == "landed")
+        most = max(most, up)
+    assert up == 0
+    return most
+
+
+WHERE = pytest.mark.parametrize("where", ["first", "second", "last"])
+
+
+def slab_at(where: str, slabs: int) -> int:
+    return {"first": 0, "second": 1, "last": slabs - 1}[where]
+
+
+@WHERE
+@every_depth_and_count
 def test_staging_goes_back_behind_the_launches_in_flight_when_a_slab_raises(
-        packed, monkeypatch, arena, slab_of, wide, fail_at):
-    """4 + 4 + 2 stripes and a launch that raises: the worker may be
-    filling the next slab and the launches before still read their
-    uploads, so the call waits for both, and only then gives the three
-    staging buffers back; the error reaches the caller."""
-    slab_of(4)
+        packed, monkeypatch, arena, pairs, depth, slabs, where):
+    """A launch that raises: the worker may be filling the next slab and
+    the launches before still read their uploads, so the call waits for
+    both, and only then gives its staging buffers back; the error
+    reaches the caller."""
+    chunks, fail_at = pairs(slabs), slab_at(where, slabs)
     erased = [3, 8, 10]
     codec = registry().factory("tpu", {"k": str(K), "m": str(M)})
     matrix = codec.decode_matrix_for(erased)
     log = arena.events
     before = set(threading.enumerate())
-    buffers, outs = later_launches(packed, monkeypatch, matrix, log, 3,
+    buffers, outs = later_launches(packed, monkeypatch, matrix, log, slabs,
                                    fail_at=fail_at)
     with pytest.raises(SlabFailed):
-        codec.decode_stripes(erased, wide, out_np=True)
+        codec.decode_stripes(erased, chunks, out_np=True)
     assert set(threading.enumerate()) == before
     assert len(outs) == fail_at and all(out.done for out in outs)
     assert [ev for ev in log if ev[0] == "done"] == [
         ("done", n) for n in range(fail_at)]
-    came_back(arena, codec, buffers, result=WIDE * len(erased) * L)
+    came_back(arena, codec, buffers, slabs,
+              result=len(chunks) * len(erased) * L)
 
 
-def came_back(arena, codec, buffers, result: int,
+def came_back(arena, codec, buffers, slabs: int, result: int,
               counted: bool = False) -> None:
-    """After a call of three staging buffers that raised: the three
-    first, behind every fill's and every launch's end, then the result
-    of ``result`` bytes that nobody got, once the error lets go of the
-    call's frame; and nothing was served (``counted``: it raised at the
-    close, behind its last launch, where a call is counted)."""
-    log = arena.events
+    """After a gathering call of ``slabs`` slabs that raised: its
+    staging buffers first, behind every fill's and every launch's end,
+    then the result of ``result`` bytes that nobody got, once the error
+    lets go of the call's frame; and nothing was served (``counted``: it
+    raised at the close, behind its last launch, where a call is
+    counted)."""
+    log, held = arena.events, staging_of(slabs)
     first = next(i for i, ev in enumerate(log) if ev[0] == "given")
     assert all(ev[0] == "given" for ev in log[first:])
     assert {into.ctypes.data for into in buffers.values()} <= {
-        ev[1] for ev in log[first:first + 3]}
+        ev[1] for ev in log[first:first + held]}
     gc.collect()
-    assert len(log) - first == 4
+    assert len(log) - first == held + 1
     (staging,) = {into.nbytes for into in buffers.values()}
-    assert arena.at_rest() == 3 * staging + result
+    assert arena.at_rest() == held * staging + result
     dump = codec.perf.dump()
     assert ("launches" in dump) == ("gathers" in dump) == counted
-    assert leases(codec) == (0, 4)
+    assert leases(codec) == (0, held + 1)
 
 
-@pytest.mark.parametrize("fill_fails_at", [0, 1, 2], ids=[
-    "first_slab", "second_slab", "ragged_last_slab"])
+@WHERE
+@every_depth_and_count
 def test_a_gather_that_raises_on_the_worker_comes_out_of_the_call(
-        packed, monkeypatch, arena, sections, slab_of, wide, fill_fails_at):
+        packed, monkeypatch, arena, sections, pairs, depth, slabs, where):
     """The worker's exception is the call's: it comes out where the
     caller's thread asks for that slab, behind the launches made before
-    it, and all three staging buffers go back."""
-    slab_of(4)
+    it, and every staging buffer goes back."""
+    chunks, fill_fails_at = pairs(slabs), slab_at(where, slabs)
     erased = [3, 8, 10]
     codec = registry().factory("tpu", {"k": str(K), "m": str(M)})
     matrix = codec.decode_matrix_for(erased)
     before = set(threading.enumerate())
     buffers, outs = later_launches(packed, monkeypatch, matrix, arena.events,
-                                   3, fill_fails_at=fill_fails_at)
+                                   slabs, fill_fails_at=fill_fails_at)
     with pytest.raises(SlabFailed) as caught:
-        codec.decode_stripes(erased, wide, out_np=True)
+        codec.decode_stripes(erased, chunks, out_np=True)
     assert caught.value.args == (fill_fails_at,)
     del caught                              # and the frames it holds
     assert set(threading.enumerate()) == before
     assert len(outs) == fill_fails_at and all(out.done for out in outs)
-    came_back(arena, codec, buffers, result=WIDE * len(erased) * L)
+    came_back(arena, codec, buffers, slabs,
+              result=len(chunks) * len(erased) * L)
     # the fill that raised left its section, and the caller's its marshal
     assert sections.open_now == []
-    assert sections.elsewhere[GATHERER] == \
+    assert [s for s in sections.elsewhere[GATHERER]
+            if s != "registry.gather.wait"] == \
         ["registry.gather"] * (fill_fails_at + 1)
 
 
-@pytest.mark.parametrize("land_fails_at", [0, 1, 2], ids=[
-    "in_the_loop", "at_the_close", "under_copy_out"])
+@WHERE
+@every_depth_and_count
 def test_a_landing_that_raises_leaves_no_section_open_and_no_staging_out(
-        packed, monkeypatch, arena, sections, slab_of, wide, land_fails_at):
-    """4 + 4 + 2 stripes and a result whose copy to the host raises:
-    slab 0's behind the third launch, slab 1's at the close, slab 2's
-    under ``registry.copy_out``.  The wait for a launch that was not
-    done came first, under its own name; the sections around the copy
-    are left on the way out, the launches still in flight are waited
-    for, and the three staging buffers go back."""
-    slab_of(4)
+        packed, monkeypatch, arena, sections, pairs, depth, slabs, where):
+    """A result whose copy to the host raises: inside the loop (the
+    slabs that land behind a later launch), at the close, or, the last
+    slab's, under ``registry.copy_out``.  The wait for a launch that was
+    not done came first, under its own name; the sections around the
+    copy are left on the way out, the launches still in flight are
+    waited for, and every staging buffer goes back."""
+    chunks, land_fails_at = pairs(slabs), slab_at(where, slabs)
     erased = [3, 8, 10]
     codec = registry().factory("tpu", {"k": str(K), "m": str(M)})
     matrix = codec.decode_matrix_for(erased)
     before = set(threading.enumerate())
     buffers, outs = later_launches(packed, monkeypatch, matrix, arena.events,
-                                   3, land_fails_at=land_fails_at)
+                                   slabs, land_fails_at=land_fails_at)
     with pytest.raises(SlabFailed) as caught:
-        codec.decode_stripes(erased, wide, out_np=True)
+        codec.decode_stripes(erased, chunks, out_np=True)
     assert caught.value.args == (land_fails_at,)
     del caught                              # and the frames it holds
     assert set(threading.enumerate()) == before
-    assert len(outs) == 3 and all(out.done for out in outs)
-    whole = caller_sections(3, GATHERED)
-    upto = len(whole) - 1 if land_fails_at == 2 else [
+    # slab n lands behind launch n + depth, or at the close behind all
+    in_loop = land_fails_at + depth < slabs
+    made = land_fails_at + depth + 1 if in_loop else slabs
+    assert len(outs) == made and all(out.done for out in outs)
+    whole = caller_sections(slabs, GATHERED)
+    upto = len(whole) - 1 if land_fails_at == slabs - 1 else [
         i for i, name in enumerate(whole)
         if name == "registry.drain.link"][land_fails_at]
     assert flat_of(sections) == whole[:upto + 1]
-    # no launch here is done before somebody waits: both landings did
-    assert sections.count(KERNEL) == min(land_fails_at + 1, 2)
+    # no launch here is done before somebody waits: every landing under
+    # ``registry.drain`` did, but for those whose buffer the worker had
+    # refilled by then (a launch is held until the next slab's fill has
+    # begun: fills up to slab ``made``'s, where there is one)
+    filled = min(made, slabs - 1)
+    assert sections.count(KERNEL) == sum(
+        n + staging_of(slabs) > filled
+        for n in range(min(land_fails_at + 1, slabs - 1)))
     nesting_holds(sections)
-    came_back(arena, codec, buffers, result=WIDE * len(erased) * L,
-              counted=land_fails_at > 0)
+    came_back(arena, codec, buffers, slabs,
+              result=len(chunks) * len(erased) * L, counted=not in_loop)
 
 
-@pytest.mark.parametrize("per_slab,slabs", [(14, 1), (8, 2), (6, 3), (2, 7)],
-                         ids=["1slab", "2slabs", "3slabs", "7slabs"])
+@every_depth
+@pytest.mark.parametrize("per_slab,slabs",
+                         [(14, 1), (8, 2), (6, 3), (4, 4), (2, 7)],
+                         ids=["1slab", "2slabs", "3slabs", "4slabs", "7slabs"])
 def test_each_threads_sections_in_order_at_1_2_3_and_7_slabs(
-        packed, monkeypatch, sections, per_slab, slabs):
-    """14 stripes through one, two, three and seven slabs, a uniform
-    encode and a decode from ``rows``: the caller's sections in order
-    with the waits of every landing nested in its ``registry.drain``
-    (the last slab's is ``registry.copy_out`` as it was), the worker's
-    a fill a slab with the wait for a launch inside the refills that
-    had to."""
+        packed, monkeypatch, sections, depth, per_slab, slabs):
+    """14 stripes through one, two, three, four and seven slabs, a
+    uniform encode and a decode from ``rows``: the caller's sections in
+    order with the waits of every landing nested in its
+    ``registry.drain`` (the last slab's is ``registry.copy_out`` as it
+    was), the worker's a fill a slab with the wait for a launch inside
+    the refills that had to."""
     chunks = np.random.default_rng(52).integers(
         0, 256, (14, N, L), dtype=np.uint8)
     monkeypatch.setattr(packed, "SLAB_BYTES", per_slab * K * L)
@@ -1029,8 +1171,12 @@ def test_each_threads_sections_in_order_at_1_2_3_and_7_slabs(
         ["registry.gather"] * slabs
     # a wait is opened inside the fill of a refill, where it is counted
     assert mine.count("registry.gather.wait") == dump.get("staging_waits", 0)
-    assert not any(s == "registry.gather.wait" for s in mine[:3])
+    assert not any(s == "registry.gather.wait"
+                   for s in mine[:staging_of(slabs)])
     assert dump["gathers"] == slabs >= dump["gathers_ahead"]
+    # two calls of several slabs so far, the encode and this decode
+    assert 0 <= dump.get("uploads_beside", 0) <= 2 * sum(
+        min(i, depth) for i in range(slabs))
     codec.decode_stripes([2, 9], chunks, out_np=True)
     again = codec.perf.dump()
     assert again["gathers"] == 2 * slabs and again["launches"] == 3
@@ -1057,9 +1203,10 @@ class Fixed:
         return np.asarray(self.out)
 
 
+@every_depth
 @pytest.mark.parametrize("ready", [True, False], ids=["done", "not_done"])
 def test_a_landing_waits_for_its_launch_only_where_it_is_not_done(
-        packed, monkeypatch, sections, slab_of, wide, ready):
+        packed, monkeypatch, sections, slab_of, wide, ready, depth):
     """Five slabs, four landed under ``registry.drain``: a launch that
     is done is not waited for, and the landing blocks once, in the copy
     to the host, as it did before its waits had names; one that is not
@@ -1081,6 +1228,10 @@ def test_a_landing_waits_for_its_launch_only_where_it_is_not_done(
     # the last slab's wait is the call's registry.device_wait either way
     assert len(waited) == 1 + sections.count(KERNEL)
     nesting_holds(sections)
+    # an upload is counted beside every slab in flight whose launch is
+    # not done when it goes out: the depth's worth once the queue is full
+    assert codec.perf.dump().get("uploads_beside", 0) == (
+        0 if ready else sum(min(i, depth) for i in range(5)))
 
 
 def test_a_call_of_many_slabs_drains_and_closes_with_one_copy_out(
@@ -1148,7 +1299,6 @@ def test_a_device_array_in_and_out_skips_the_copies(sections, stripes):
 @pytest.mark.parametrize("engine", ENGINES)
 def test_every_engines_program_carries_the_registry_name_and_scope(engine):
     import jax.numpy as jnp
-    import ceph_tpu.ops.gf2kernels as g
     from ceph_tpu.ops import xor_schedule
 
     matrix = registry().factory(

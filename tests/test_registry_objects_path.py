@@ -25,10 +25,12 @@ from benchmark.reference import codec_objects as ref_objects
 from ceph_tpu.ec import registry
 from test_registry_codec_path import (GATHERED, GATHERER, KERNEL, SlabFailed,
                                       Watched, arena, caller_sections,
-                                      came_back, flat_of, later_launches,
-                                      leases, nesting_holds, sections)
+                                      came_back, depth, every_depth, flat_of,
+                                      later_launches, leases, most_in_flight,
+                                      nesting_holds, sections, staging_of)
 
-__all__ = ["arena", "sections"]         # fixtures of the uniform call's file
+# fixtures of the uniform call's file
+__all__ = ["arena", "depth", "sections"]
 
 K, M = 10, 4
 N = K + M
@@ -105,6 +107,17 @@ def small():
                             ref_objects.parity_of_objects(REF, objects))
 
 
+@pytest.fixture
+def deep(slab_lanes, small, depth):
+    """Sets a slab width that cuts ``small``'s lanes into ``depth + 3``
+    slabs, more than the depth's staging: (lanes, slabs)."""
+    lanes = sum(stripe.shape[1] for stripe in small[1])
+    slabs = depth + 3
+    width = next(w for w in range(128, lanes, 128) if -(-lanes // w) == slabs)
+    slab_lanes(width)
+    return lanes, slabs
+
+
 def test_there_are_91_double_erasures():
     assert len(DOUBLES) == 91
 
@@ -114,9 +127,12 @@ def test_the_slab_width_comes_from_the_shape_alone(kernels):
     assert lanes == 3350528 == 409 * kernels.LANE_TILE
     assert K * lanes <= kernels.SLAB_BYTES < K * (lanes + kernels.LANE_TILE)
     assert kernels._slab_lanes(8) == kernels.SLAB_BYTES // 8
-    # the process's arena holds the cell's result and two slabs, twice
-    assert kernels.ARENA_BYTES >= 2 * (M * 107233280 // 2
-                                       + 2 * kernels.SLAB_BYTES)
+    # the process's arena holds what either registry cell's loop leaves
+    # at rest while its driver keeps one encode: a second encode's
+    # result, a decode's, and the staging of the loop's depth
+    staging = (kernels.SLABS_IN_FLIGHT + 1) * kernels.SLAB_BYTES
+    assert kernels.ARENA_BYTES >= (M + 2) * 107233280 + staging
+    assert kernels.ARENA_BYTES >= (3 + 1) * 1024 * 131072 + staging
 
 
 def test_the_listed_sizes_in_one_call_equal_the_reference_and_isa(
@@ -288,14 +304,15 @@ def test_objects_that_meet_a_slabs_edge(kernels, monkeypatch, slab_lanes,
 def test_the_tail_of_every_last_data_chunk_is_zero_in_what_is_encoded(
         kernels, monkeypatch, slab_lanes):
     """Staging is reused and never cleared: the zeros past an object's
-    end are written with every fill, over whatever the slab three back
-    left there, and no byte of a neighbour is inside an object's
-    lanes."""
+    end are written with every fill, over whatever an earlier slab left
+    there, and no byte of a neighbour is inside an object's lanes."""
     slab_lanes(LANES)
     uploaded = uploads_of(kernels, monkeypatch)
     codec = registry().factory("tpu", PROFILE)
-    loud = [np.full(size, 0xFF, np.uint8) for size in [4096] * 12]
-    codec.encode_objects(loud)                  # all three buffers all ones
+    enough = -(-(kernels.SLABS_IN_FLIGHT + 1) * LANES
+               // ref.chunk_bytes(K, 4096))
+    loud = [np.full(size, 0xFF, np.uint8) for size in [4096] * enough]
+    codec.encode_objects(loud)                  # every buffer all ones
     del uploaded[:]
     sizes = [1, 4095, 33, 4096, 700, 2, 4097, 31, 5000, 64]
     objects = [np.full(size, 0xFF, np.uint8) for size in sizes]
@@ -312,13 +329,14 @@ def test_the_tail_of_every_last_data_chunk_is_zero_in_what_is_encoded(
 @pytest.mark.parametrize("width,slabs", [(5376, 1), (2688, 2), (1792, 3),
                                          (768, 7)],
                          ids=["1slab", "2slabs", "3slabs", "7slabs"])
-def test_the_references_bytes_at_1_2_3_and_7_slabs(slab_lanes, small, width,
-                                                   slabs):
+@every_depth
+def test_the_references_bytes_at_1_2_3_and_7_slabs(slab_lanes, small, depth,
+                                                   width, slabs):
     """5280 lanes through one slab (filled on the caller's thread), two
-    (a buffer each), three (all three buffers, none refilled) and seven
-    (every buffer refilled behind its reader): parity and recovered
-    chunks are ``benchmark/reference/``'s, and what lies at an erased
-    position is not read."""
+    and three (a buffer each, none refilled) and seven (more than the
+    depth's staging: buffers refilled behind their readers): parity and
+    recovered chunks are ``benchmark/reference/``'s, and what lies at an
+    erased position is not read."""
     slab_lanes(width)
     objects, chunk_maps = small
     lanes = sum(stripe.shape[1] for stripe in chunk_maps)
@@ -340,6 +358,7 @@ def test_the_references_bytes_at_1_2_3_and_7_slabs(slab_lanes, small, width,
     assert dump["slabs"] == 4 * slabs
     assert dump.get("gathers", 0) == (4 * slabs if slabs > 1 else 0)
     assert dump.get("gathers_ahead", 0) <= dump.get("gathers", 0)
+    assert dump.get("uploads_beside", 0) <= 4 * depth * (slabs - 1)
 
 
 @pytest.mark.parametrize("width,slabs", [(5376, 1), (2688, 2), (1792, 3),
@@ -450,12 +469,15 @@ def test_sections_and_counters_of_a_call_over_objects(kernels, sections,
         dump.pop("staging_waits", 0)    # as the launches happen to finish
     nesting_holds(sections)
     assert 0 <= dump.pop("gathers_ahead") <= slabs  # as the fills do
+    assert 0 <= dump.pop("uploads_beside", 0) \
+        <= kernels.SLABS_IN_FLIGHT * slabs          # as the launches finish
+    held = staging_of(slabs)
     assert dump == {
         "launches": 1, "engine_v1": 1, "objects": len(objects),
         "lanes": lanes, "lanes_launched": slabs * LANES,
         "lanes_padded": slabs * LANES - lanes, "bytes_in": K * lanes,
         "bytes_out": M * lanes, "slabs": slabs, "pipelined": 1,
-        "gathers": slabs, "arena_misses": 4, "parity_gates": 1}
+        "gathers": slabs, "arena_misses": 1 + held, "parity_gates": 1}
     del parity
     sections.clear()
     codec.decode_objects([3, 7], chunk_maps)
@@ -466,7 +488,7 @@ def test_sections_and_counters_of_a_call_over_objects(kernels, sections,
     assert two["table_misses"] == 1 and two["objects"] == 2 * len(objects)
     assert two["bytes_out"] == (M + 2) * lanes
     assert two["gathers"] == 2 * slabs >= two["gathers_ahead"]
-    assert leases(codec) == (4, 4)                   # all four kept ones
+    assert leases(codec) == (1 + held, 1 + held)     # all the kept ones
 
 
 # -- leases and staging, as the uniform call keeps them ------------------------
@@ -482,7 +504,8 @@ def test_the_results_are_views_of_one_lease_and_keep_it(kernels, arena,
     for got, stripe in zip(parity, chunk_maps):
         assert got.strides == (lanes, 1) and got.flags["WRITEABLE"]
         assert base <= got.ctypes.data < base + lanes
-    staging = 3 * K * LANES
+    held = staging_of(6)
+    staging = held * K * LANES
     assert arena.at_rest() == staging               # the result is out
     kept = parity[5][1:, ::2]                       # a view of a view
     want = chunk_maps[5][K + 1:, ::2].copy()
@@ -497,17 +520,20 @@ def test_the_results_are_views_of_one_lease_and_keep_it(kernels, arena,
     del kept
     assert arena.events[-1] == ("given", base)
     assert arena.at_rest() == staging + 2 * M * lanes
-    hits, misses = leases(codec)
-    assert (hits, misses) == (7, 5)
+    # the staging new once and kept twice; a result new while ``kept``
+    # held the first, and that one kept for the last call
+    assert leases(codec) == (2 * held + 1, held + 2)
 
 
+@every_depth
 def test_staging_is_refilled_only_behind_the_launch_that_read_it(
-        kernels, monkeypatch, arena, sections, slab_lanes, small):
-    slab_lanes(LANES)
+        kernels, monkeypatch, arena, sections, deep, small, depth):
     objects, chunk_maps = small
     codec = registry().factory("tpu", PROFILE)
     log = arena.events
-    slabs = 6
+    _, slabs = deep
+    held = staging_of(slabs)
+    assert held == depth + 1
     before = set(threading.enumerate())
     buffers, outs = later_launches(kernels, monkeypatch,
                                 codec.encode_matrix[K:], log, slabs)
@@ -517,68 +543,79 @@ def test_staging_is_refilled_only_behind_the_launch_that_read_it(
     assert set(threading.enumerate()) == before     # the worker is gone
     fills = [ev for ev in log if ev[0] == "fill"]
     fill_at = [i for i, ev in enumerate(log) if ev[0] == "fill"]
-    assert len(fills) == len(outs) == slabs and len(buffers) == 3
-    assert [ev[1] for ev in fills] == [fills[i % 3][1] for i in range(slabs)]
+    assert len(fills) == len(outs) == slabs and len(buffers) == held
+    assert [ev[1] for ev in fills] == [fills[i % held][1]
+                                       for i in range(slabs)]
     assert all(ev[2] == [] for ev in fills), fills  # nothing unfinished
     assert {ev[3] for ev in fills} == {GATHERER}    # none on this thread
-    # slab n's buffer is refilled for slab n+3, behind slab n's launch and
-    # while slab n+1 is in flight; a fill is handed over a slab ahead
-    for n in range(slabs - 3):
-        assert log.index(("done", n)) < fill_at[n + 3] \
+    # slab n's buffer is refilled for slab n + held, behind slab n's launch
+    # and while slab n+1 is in flight; a fill is handed over a slab ahead
+    for n in range(slabs - held):
+        assert log.index(("done", n)) < fill_at[n + held] \
             < log.index(("done", n + 1))
     for n in range(slabs - 1):
         assert fill_at[n] < fill_at[n + 1] \
             < log.index(("launch", n, fills[n][1]))
-    assert log[-3:] == [("given", into.ctypes.data)
-                        for into in buffers.values()]
-    assert log.index(("done", slabs - 1)) == len(log) - 4
+    assert log[-held:] == [("given", into.ctypes.data)
+                           for into in buffers.values()]
+    assert log[-held - 2:-held] == [("done", slabs - 1),
+                                    ("landed", slabs - 1)]
+    # never more than depth + 1 slabs between device_put and landing
+    assert most_in_flight(log) == depth + 1
     dump = codec.perf.dump()
-    assert dump["staging_waits"] == slabs - 3
+    assert dump["staging_waits"] == slabs - held
+    assert 0 < dump["uploads_beside"] <= sum(
+        min(i, depth) for i in range(slabs))
     assert dump["gathers"] == slabs >= dump["gathers_ahead"] >= 0
     # no launch here is done until waited for: every refill's fill holds
-    # the wait, and the caller waits only for the two nobody refilled behind
+    # the wait, and the caller waits only for those nobody refilled behind
+    # (the last slab's wait is ``registry.device_wait``)
     assert sections.elsewhere == {GATHERER: (
-        ["registry.gather"] * 3
-        + ["registry.gather", "registry.gather.wait"] * (slabs - 3))}
+        ["registry.gather"] * held
+        + ["registry.gather", "registry.gather.wait"] * (slabs - held))}
     assert flat_of(sections) == caller_sections(slabs, GATHERED)
-    assert sections.count(KERNEL) == 2
+    assert sections.count(KERNEL) == held - 1
     nesting_holds(sections)
 
 
-@pytest.mark.parametrize("fail_at", [0, 2, 5], ids=[
+@every_depth
+@pytest.mark.parametrize("where", [
     "first_slab", "third_slab", "padded_last_slab"])
 def test_buffers_come_back_when_a_slab_raises(kernels, monkeypatch, arena,
-                                              slab_lanes, small, fail_at):
-    slab_lanes(LANES)
+                                              deep, small, depth, where):
     objects, chunk_maps = small
-    lanes = sum(stripe.shape[1] for stripe in chunk_maps)
+    lanes, slabs = deep
+    fail_at = {"first_slab": 0, "third_slab": 2,
+               "padded_last_slab": slabs - 1}[where]
     codec = registry().factory("tpu", PROFILE)
     before = set(threading.enumerate())
     buffers, outs = later_launches(kernels, monkeypatch,
-                                codec.encode_matrix[K:], arena.events, 6,
+                                codec.encode_matrix[K:], arena.events, slabs,
                                 fail_at=fail_at)
     with pytest.raises(SlabFailed):
         codec.encode_objects(objects)
     assert set(threading.enumerate()) == before
     assert len(outs) == fail_at and all(out.done for out in outs)
-    came_back(arena, codec, buffers, result=M * lanes)
+    came_back(arena, codec, buffers, slabs, result=M * lanes)
 
 
-@pytest.mark.parametrize("fill_fails_at", [0, 1, 4, 5], ids=[
+@every_depth
+@pytest.mark.parametrize("where", [
     "first_slab", "second_slab", "a_refill", "padded_last_slab"])
 def test_a_fill_that_raises_on_the_worker_comes_out_of_the_call(
-        kernels, monkeypatch, arena, sections, slab_lanes, small,
-        fill_fails_at):
+        kernels, monkeypatch, arena, sections, deep, small, depth, where):
     """The worker's exception is the call's, raised where the caller's
     thread asks for that slab: the launches made before it are waited
-    for, all three staging buffers go back, and no thread is left."""
-    slab_lanes(LANES)
+    for, every staging buffer goes back, and no thread is left."""
     objects, chunk_maps = small
-    lanes = sum(stripe.shape[1] for stripe in chunk_maps)
+    lanes, slabs = deep
+    fill_fails_at = {"first_slab": 0, "second_slab": 1,
+                     "a_refill": staging_of(slabs),
+                     "padded_last_slab": slabs - 1}[where]
     codec = registry().factory("tpu", PROFILE)
     before = set(threading.enumerate())
     buffers, outs = later_launches(kernels, monkeypatch,
-                                codec.encode_matrix[K:], arena.events, 6,
+                                codec.encode_matrix[K:], arena.events, slabs,
                                 fill_fails_at=fill_fails_at)
     with pytest.raises(SlabFailed) as caught:
         codec.encode_objects(objects)
@@ -586,43 +623,47 @@ def test_a_fill_that_raises_on_the_worker_comes_out_of_the_call(
     del caught                              # and the frames it holds
     assert set(threading.enumerate()) == before
     assert len(outs) == fill_fails_at and all(out.done for out in outs)
-    came_back(arena, codec, buffers, result=M * lanes)
+    came_back(arena, codec, buffers, slabs, result=M * lanes)
     assert sections.open_now == []          # the fill's and the marshal's
     nesting_holds(sections)
 
 
-@pytest.mark.parametrize("land_fails_at", [0, 3, 4, 5], ids=[
+@every_depth
+@pytest.mark.parametrize("where", [
     "first_landing", "last_in_the_loop", "at_the_close", "under_copy_out"])
 def test_a_landing_that_raises_leaves_no_section_open_and_no_staging_out(
-        kernels, monkeypatch, arena, sections, slab_lanes, small,
-        land_fails_at):
-    """Six slabs and a result whose copy to the host raises, inside the
-    loop (slabs 0-3 land behind launches 2-5), at the close (slab 4) and
-    under ``registry.copy_out`` (slab 5): the error is the call's, every
-    section is left, every launch waited for, the staging given back."""
-    slab_lanes(LANES)
+        kernels, monkeypatch, arena, sections, deep, small, depth, where):
+    """``depth + 3`` slabs and a result whose copy to the host raises,
+    inside the loop (slab n lands behind launch n + depth: slabs 0-2),
+    at the close (the last but one) and under ``registry.copy_out``
+    (the last): the error is the call's, every section is left, every
+    launch waited for, the staging given back."""
     objects, chunk_maps = small
-    lanes = sum(stripe.shape[1] for stripe in chunk_maps)
+    lanes, slabs = deep
+    last_inside = slabs - depth - 1
+    land_fails_at = {"first_landing": 0, "last_in_the_loop": last_inside,
+                     "at_the_close": slabs - 2,
+                     "under_copy_out": slabs - 1}[where]
     codec = registry().factory("tpu", PROFILE)
     before = set(threading.enumerate())
     buffers, outs = later_launches(kernels, monkeypatch,
-                                   codec.encode_matrix[K:], arena.events, 6,
-                                   land_fails_at=land_fails_at)
+                                   codec.encode_matrix[K:], arena.events,
+                                   slabs, land_fails_at=land_fails_at)
     with pytest.raises(SlabFailed) as caught:
         codec.encode_objects(objects)
     assert caught.value.args == (land_fails_at,)
     del caught                              # and the frames it holds
     assert set(threading.enumerate()) == before
-    assert len(outs) == min(land_fails_at + 3, 6)
+    assert len(outs) == min(land_fails_at + depth + 1, slabs)
     assert all(out.done for out in outs)
-    whole = caller_sections(6, GATHERED)
-    upto = len(whole) - 1 if land_fails_at == 5 else [
+    whole = caller_sections(slabs, GATHERED)
+    upto = len(whole) - 1 if land_fails_at == slabs - 1 else [
         i for i, name in enumerate(whole)
         if name == "registry.drain.link"][land_fails_at]
     assert flat_of(sections) == whole[:upto + 1]
     nesting_holds(sections)
-    came_back(arena, codec, buffers, result=M * lanes,
-              counted=land_fails_at > 3)
+    came_back(arena, codec, buffers, slabs, result=M * lanes,
+              counted=land_fails_at > last_inside)
 
 
 def test_a_parity_miss_raises_out_of_a_call_over_objects(kernels, slab_lanes,
